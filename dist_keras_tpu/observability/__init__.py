@@ -3,9 +3,9 @@ multi-host run reports.
 
 The reference's only instrumentation is trainer wall-clock timing
 (``record_training_start/stop``); this subsystem is the §5 "tracing" row
-grown to production shape, recording what a run was *doing* — so a hang,
-a ``BarrierTimeout`` or an unresponsive backend leaves a timeline naming
-the host and phase that stalled instead of silence:
+grown to production shape, recording what a run was *doing* — so a hang
+or a ``BarrierTimeout`` leaves a timeline naming the host and phase
+that stalled instead of silence:
 
 - :mod:`~dist_keras_tpu.observability.events` — append-only per-host
   JSONL under ``DK_OBS_DIR`` (atomic line writer; zero-cost no-op when

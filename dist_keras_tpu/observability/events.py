@@ -3,10 +3,8 @@
 The paper's only instrumentation was trainer wall-clock timing; after the
 two resilience PRs this repo has retries, fault points, two-phase
 checkpoint commits, coordination votes, barriers and heartbeats all
-happening silently — and the r05 bench died with an unattributable
-"backend unresponsive" because nothing recorded what the run was doing
-when it stopped.  This module is the recording layer every seam emits
-into:
+happening silently.  This module is the recording layer every seam
+emits into:
 
 - **One JSONL file per host** (``events-rank_{i}.jsonl``), so hosts never
   contend on a shared file; ``report.py`` merges them post-hoc into a
@@ -137,8 +135,8 @@ KNOWN_EVENTS = (
     # SLO plane (observability/slo.py)
     "slo_transition",
     # bench driver (repo-root bench.py)
-    "bench_probe_begin", "bench_probe_end", "bench_config_begin",
-    "bench_config_end", "bench_config_skipped", "bench_complete",
+    "bench_config_begin", "bench_config_end", "bench_config_skipped",
+    "bench_complete",
     # cluster simulator (sim/)
     "sim_scenario_begin", "sim_scenario_end",
     # continuous-batching decode engine (serving/decode.py,
@@ -157,9 +155,9 @@ KNOWN_EVENTS = (
 
 
 def _default_rank():
-    """This host's rank WITHOUT importing jax (the event log must work
-    before — and while — the device backend is wedged): the coordination
-    identity wins, then the launcher's jax.distributed id, then 0."""
+    """This host's rank WITHOUT importing jax (the event log is
+    import-light): the coordination identity wins, then the launcher's
+    jax.distributed id, then 0."""
     for v in (knobs.raw("DK_COORD_RANK"),
               os.environ.get("JAX_PROCESS_ID")):
         if v is not None:

@@ -1,7 +1,5 @@
-"""Perf attribution — CPU-measurable proxies for device-side perf claims.
+"""Perf attribution — host-side counts behind device-side perf claims.
 
-The device bench has been unresponsive since round 5 (BENCH_r05.json:
-probe timeout), which left every device-only perf claim unattributable.
 This layer records what the HOST can always measure, cheaply enough to
 stay on in production (<5% of train wall, gated):
 

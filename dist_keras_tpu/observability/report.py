@@ -7,10 +7,9 @@ The launcher (or anyone pointed at a ``DK_OBS_DIR`` after the fact —
 spans), coordination-op durations, retry counts, checkpoint commits,
 nonfinite-step totals, preemption attribution (WHICH rank got the
 signal, what step the cluster agreed to save), and the last-N events per
-host — which is exactly the artifact needed to attribute a hang like the
-r05 "backend unresponsive" bench failure or a ``BarrierTimeout`` to the
-host that stalled: the dead host's file simply *stops*, and the merged
-tail shows what every other host was waiting on.
+host — which is exactly the artifact needed to attribute a hang or a
+``BarrierTimeout`` to the host that stalled: the dead host's file simply
+*stops*, and the merged tail shows what every other host was waiting on.
 
 Strictly read-only and import-light (stdlib only): safe to run from a
 monitor loop against a live run's directory.

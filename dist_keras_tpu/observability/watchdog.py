@@ -28,8 +28,7 @@ compose your own or take :func:`default_rules`):
   2x regression forever.
 - :class:`ThroughputStall` — a counter that was advancing has not
   advanced for ``window_s`` (e.g. ``perf.dispatches``: the run is
-  alive but no work is retiring — the r05 "backend unresponsive"
-  signature).
+  alive but no work is retiring).
 - :class:`QueueDepthGrowth` — a gauge (e.g. ``serve.pending``) rising
   monotonically across the last ``samples`` ticks above ``min_depth``:
   offered load is outrunning service rate *before* the queue bound

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from dist_keras_tpu.parallel.mesh import SEQ_AXIS
-from dist_keras_tpu.utils import jax_compat
 
 _NEG_INF = -1e30
 
@@ -122,7 +121,7 @@ def ring_attention(q, k, v, axis=SEQ_AXIS, causal=False, scale=None,
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     attn_fn = attn_fn or _auto_block_fn()
-    n = jax_compat.axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     t_local = q.shape[1]
     q_start = idx * t_local

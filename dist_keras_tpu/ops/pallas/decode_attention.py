@@ -47,12 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-# dklint: ignore[broad-except] optional-backend import probe (CPU-only jax builds)
-except Exception:  # pragma: no cover - CPU-only jax builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from dist_keras_tpu.ops.pallas.flash_attention import (
     _NEG_INF,
@@ -109,7 +104,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[s]
-    q = q_ref[0]                                    # (1, D)
+    q = q_ref[0, 0]                                 # (1, D)
     k = k_ref[0, 0]                                 # (ps, D)
     v = v_ref[0, 0]                                 # (ps, D)
     logits = jax.lax.dot_general(
@@ -134,7 +129,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == nj - 1)
     def _emit():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
@@ -142,10 +137,6 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
     """The Pallas paged decode kernel (see module docstring for the
     contract).  Callers route through :func:`paged_attention_auto`,
     which gates this on the graduation verdict."""
-    if pltpu is None:  # pragma: no cover - CPU-only jax builds
-        raise ImportError(
-            "jax.experimental.pallas.tpu is unavailable in this build; "
-            "use paged_attention_reference instead")
     s, h, d = q.shape
     ps = k_pages.shape[2]
     n_pages = page_table.shape[1]
@@ -154,31 +145,34 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
     # index maps see (*grid_indices, *scalar_prefetch_refs): the page
     # table picks each grid step's K/V page BEFORE its DMA issues
     kv_map = lambda si, hi, j, pt, ln: (hi, pt[si, j], 0, 0)  # noqa: E731
-    q_map = lambda si, hi, j, pt, ln: (si, hi, 0)             # noqa: E731
+    # q/out ride as (S, H, 1, D): mosaic wants a block's last two dims
+    # (8k, 128k) or full-dim, which a (1, D) row over (..., 1, D) is
+    q_map = lambda si, hi, j, pt, ln: (si, hi, 0, 0)          # noqa: E731
     extra = ({} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))})
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, h, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, d), q_map),
+            pl.BlockSpec((1, 1, 1, d), q_map),
             pl.BlockSpec((1, 1, ps, d), kv_map),
             pl.BlockSpec((1, 1, ps, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), q_map),
+        out_specs=pl.BlockSpec((1, 1, 1, d), q_map),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
                         pltpu.VMEM((1, 1), jnp.float32),
                         pltpu.VMEM((1, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_sds((s, h, d), q.dtype, q),
+        out_shape=_sds((s, h, 1, d), q.dtype, q),
         interpret=interpret,
         name=_kernel_name("paged_decode"),
         **extra,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q[:, :, None, :], k_pages, v_pages)
+    return out[:, :, 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -196,10 +190,6 @@ def selfcheck(slots=4, heads=2, head_dim=64, page_size=8, n_pages=4,
         SelfCheckVerdict,
     )
 
-    if pltpu is None:  # pragma: no cover - CPU-only jax builds
-        return SelfCheckVerdict(
-            False, None, "unverifiable",
-            "jax.experimental.pallas.tpu unavailable in this build")
     if not interpret and not use_pallas():
         return SelfCheckVerdict(
             False, None, "unverifiable",
@@ -275,7 +265,7 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, lengths,
     geometry is ``"exact"`` (interpret mode off-TPU); the jnp reference
     otherwise.  The decode engine calls this inside its jitted step, so
     the decision is made once per traced shape."""
-    if knobs.get("DK_DECODE_KERNEL") and pltpu is not None:
+    if knobs.get("DK_DECODE_KERNEL"):
         s, h, d = q.shape
         interpret = not use_pallas()
         v = graduate(s, h, d, k_pages.shape[2], page_table.shape[1],

@@ -16,7 +16,6 @@ import jax
 from jax import lax
 
 from dist_keras_tpu.parallel.mesh import WORKER_AXIS
-from dist_keras_tpu.utils import jax_compat
 
 
 def tree_psum(tree, axis=WORKER_AXIS):
@@ -70,10 +69,9 @@ def tree_pvary(tree, axis=WORKER_AXIS):
     updates genuinely local; only explicit collectives then cross workers.
     """
     def _pvary(x):
-        vma = getattr(jax_compat.typeof(x), "vma", frozenset())
-        if axis in vma:  # already varying: pcast would reject
+        if axis in jax.typeof(x).vma:  # already varying: pcast would reject
             return x
-        return jax_compat.pvary_cast(x, (axis,))
+        return lax.pcast(x, (axis,), to="varying")
 
     return jax.tree.map(_pvary, tree)
 
@@ -83,7 +81,7 @@ def axis_index(axis=WORKER_AXIS):
 
 
 def axis_size(axis=WORKER_AXIS):
-    return jax_compat.axis_size(axis)
+    return lax.axis_size(axis)
 
 
 class AsyncMerge:

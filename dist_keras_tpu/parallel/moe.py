@@ -31,7 +31,6 @@ import optax
 from jax import lax
 
 from dist_keras_tpu.models.layers import glorot_uniform
-from dist_keras_tpu.utils import jax_compat
 
 EXPERT_AXIS = "experts"
 
@@ -115,7 +114,7 @@ def switch_moe_ep(params, x, axis=EXPERT_AXIS, capacity_factor=1.25,
 
     -> (out (N_local, d), aux_loss local mean-contribution).
     """
-    ep = jax_compat.axis_size(axis)
+    ep = lax.axis_size(axis)
     e_local = params["w1"].shape[0]
     num_experts = ep * e_local
     n = x.shape[0]
@@ -231,8 +230,6 @@ def make_moe_ep_train_step(mesh, cfg, optimizer=None, aux_weight=1e-2,
         layer_norm as _ln,
     )
 
-    from dist_keras_tpu.utils.jax_compat import shard_map
-
     if not cfg.get("moe_experts", 0):
         raise ValueError("make_moe_ep_train_step needs moe_experts > 0")
     tx = optimizer or optax.adam(1e-3)
@@ -290,7 +287,7 @@ def make_moe_ep_train_step(mesh, cfg, optimizer=None, aux_weight=1e-2,
 
         pspecs = moe_transformer_param_specs(params, axis)
         ospecs = match_specs_for_state(params, pspecs, opt_state)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspecs, ospecs, P(axis), P(axis)),
             out_specs=(pspecs, ospecs, P()),

@@ -46,8 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dist_keras_tpu.utils import jax_compat
-
 PIPE_AXIS = "stages"
 
 
@@ -62,12 +60,9 @@ def _pcast_like(tree, types):
     composed mesh the compute branch's outputs usually vary over more
     axes than the unmodified carry)."""
     def widen(val, ty):
-        want = getattr(ty, "vma", frozenset()) or frozenset()
-        have = getattr(jax_compat.typeof(val), "vma", frozenset()) \
-            or frozenset()
-        extra = tuple(want - have)
+        extra = tuple((ty.vma or frozenset()) - jax.typeof(val).vma)
         if extra:
-            val = jax_compat.pvary_cast(val, extra)
+            val = lax.pcast(val, extra, to="varying")
         return val
 
     return jax.tree.map(widen, tree, types)
@@ -95,12 +90,9 @@ def _grow_carry_vma(step_carry, carry0, max_rounds=None):
 
         def widen(init, sds):
             nonlocal changed
-            want = getattr(sds, "vma", frozenset()) or frozenset()
-            have = getattr(jax_compat.typeof(init), "vma", frozenset()) \
-                or frozenset()
-            extra = tuple(want - have)
+            extra = tuple((sds.vma or frozenset()) - jax.typeof(init).vma)
             if extra:
-                init = jax_compat.pvary_cast(init, extra)
+                init = lax.pcast(init, extra, to="varying")
                 changed = True
             return init
 
@@ -138,7 +130,7 @@ def gpipe_apply(stage_fn, stage_params, x, num_microbatches, axis=PIPE_AXIS,
     (leaves ``(M, ...)``).  Valid on every device via a psum over the
     stage axis.
     """
-    p = jax_compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = num_microbatches
     b = jax.tree.leaves(x)[0].shape[0]
@@ -248,7 +240,7 @@ def interleaved_gpipe_apply(stage_fn, chunk_params, x, num_microbatches,
     Backward is plain autodiff (scan + ring ppermute transpose cleanly),
     i.e. GPipe activation memory.
     """
-    p = jax_compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = num_microbatches
     v = int(virtual)
@@ -463,7 +455,7 @@ def pipeline_1f1b(stage_fn, stage_params, h, num_microbatches, last_fn,
     extras (replicated — nonzero contributions come only from the last /
     first stage respectively).
     """
-    p = jax_compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = num_microbatches
     b = h.shape[0]
@@ -526,7 +518,7 @@ def pipeline_1f1b(stage_fn, stage_params, h, num_microbatches, last_fn,
         # aux primal (stage_fns may return either an invariant constant
         # or a varying router loss)
         aux_cot = _pcast_like(jnp.asarray(aux_ct, aux2.dtype),
-                              jax_compat.typeof(aux2))
+                              jax.typeof(aux2))
         dparams, dx = vjp_fn((dh_in, aux_cot))
         gacc = jax.tree.map(
             lambda g, d: g + jnp.where(bvalid, d, jnp.zeros_like(d)),
@@ -650,7 +642,7 @@ def pipeline_interleaved_1f1b(stage_fn, chunk_params, h, num_microbatches,
     aux_ct / returns: exactly as :func:`pipeline_1f1b`, except
     ``stage_grads`` has the (v, ...) chunk leading axis.
     """
-    p = jax_compat.axis_size(axis)
+    p = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     m = num_microbatches
     v = int(virtual)
@@ -757,7 +749,7 @@ def pipeline_interleaved_1f1b(stage_fn, chunk_params, h, num_microbatches,
             (y2, aux2), vjp_fn = jax.vjp(
                 lambda pc, xx: stage_fn(pc, xx), params_c, x_st)
             aux_cot = _pcast_like(jnp.asarray(aux_ct, aux2.dtype),
-                                  jax_compat.typeof(aux2))
+                                  jax.typeof(aux2))
             dparams, dx = vjp_fn((dh_in, aux_cot))
             # accumulate into this chunk's grad slot
             cslot = jnp.clip(c_b, 0, v - 1)
@@ -1091,25 +1083,13 @@ def make_pp_train_step(mesh, cfg, num_microbatches, optimizer=None,
         if dp:
             loss = lax.pmean(loss, WORKER_AXIS)
             aux = lax.pmean(aux, WORKER_AXIS)
-            if jax_compat.HAS_VMA:
-                # params are worker-INVARIANT, data worker-varying: AD's
-                # implicit invariant->varying promotion transposes into
-                # a psum over workers, so the grads arrive already
-                # SUMMED — scale to the mean instead of collecting again
-                n = mesh.shape[WORKER_AXIS]
-                rest_g = jax.tree.map(lambda g: g / n, rest_g)
-                block_g = jax.tree.map(lambda g: g / n, block_g)
-            else:
-                # pre-vma jax runs this program with check_rep=False
-                # (the static inferencer rejects it, see
-                # jax_compat.shard_map), which also drops that implicit
-                # transpose psum: each worker column holds only ITS
-                # local-data gradient — reduce explicitly or the
-                # columns silently drift apart
-                rest_g = jax.tree.map(
-                    lambda g: lax.pmean(g, WORKER_AXIS), rest_g)
-                block_g = jax.tree.map(
-                    lambda g: lax.pmean(g, WORKER_AXIS), block_g)
+            # params are worker-INVARIANT, data worker-varying: AD's
+            # implicit invariant->varying promotion transposes into
+            # a psum over workers, so the grads arrive already
+            # SUMMED — scale to the mean instead of collecting again
+            n = mesh.shape[WORKER_AXIS]
+            rest_g = jax.tree.map(lambda g: g / n, rest_g)
+            block_g = jax.tree.map(lambda g: g / n, block_g)
         u_r, opt_rest = tx.update(rest_g, opt_rest, rest)
         rest = optax.apply_updates(rest, u_r)
         u_b, opt_blocks = tx.update(block_g, opt_blocks, blocks)
@@ -1145,11 +1125,7 @@ def make_pp_train_step(mesh, cfg, num_microbatches, optimizer=None,
     def step_factory(rest, blocks, opt_rest, opt_blocks):
         rs, bs, ors, obs, xs_spec = pp_step_specs(
             rest, blocks, opt_rest, opt_blocks)
-        # jax_compat.shard_map: composed-mesh (PP x DP) programs fail
-        # pre-vma jax's static replication inference — see the shim
-        from dist_keras_tpu.utils.jax_compat import shard_map
-
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(rs, bs, ors, obs, xs_spec, xs_spec),
             out_specs=(rs, bs, ors, obs, P(), P()),

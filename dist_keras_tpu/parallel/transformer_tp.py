@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from dist_keras_tpu.models.transformer import (
@@ -39,17 +39,6 @@ from dist_keras_tpu.models.transformer import (
 )
 from dist_keras_tpu.ops.attention import ring_attention
 from dist_keras_tpu.parallel.mesh import MODEL_AXIS, SEQ_AXIS, WORKER_AXIS, grid_mesh
-from dist_keras_tpu.utils import jax_compat
-
-# deliberately the raw import, NOT jax_compat.shard_map: that shim
-# disables check_rep on pre-vma jax, but this module's programs (the
-# TP forward, and the vma-path train step) pass the static replication
-# check and should keep it — the pre-vma TRAIN path instead
-# differentiates THROUGH shard_map (see make_tp_train_step)
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def make_tp_mesh(dp=1, tp=1, sp=1, devices=None):
@@ -205,32 +194,13 @@ def make_tp_train_step(mesh, cfg, optimizer=None, loss="softmax_xent",
 
     def step_fn_factory(params, opt_state):
         pspecs, ospecs, data_x, data_y = tp_step_specs(params, opt_state)
-        if jax_compat.HAS_VMA:
-            # grad INSIDE shard_map: the vma-aware transpose inserts the
-            # cross-axis psums and proves the output replication
-            return jax.jit(shard_map(
-                body, mesh=mesh,
-                in_specs=(pspecs, ospecs, data_x, data_y),
-                out_specs=(pspecs, ospecs, P()),
-            ))
-        # Pre-vma jax: its rep machinery can neither prove the updated
-        # params' replication (check_rep=True rejects the program) nor
-        # transpose the grad correctly with the check disabled (measured
-        # against the single-device oracle).  Differentiate THROUGH the
-        # shard_map primitive instead — its transpose derives the exact
-        # psums from the in/out specs — and update outside it under the
-        # same jit (GSPMD keeps the leaves sharded per spec).
-        fwd = shard_map(local_loss, mesh=mesh,
-                        in_specs=(pspecs, data_x, data_y), out_specs=P())
-
-        def step(params, opt_state, x, y):
-            loss_val, grads = jax.value_and_grad(
-                lambda p: fwd(p, x, y))(params)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            return new_params, new_opt, loss_val
-
-        return jax.jit(step)
+        # grad INSIDE shard_map: the vma-aware transpose inserts the
+        # cross-axis psums and proves the output replication
+        return jax.jit(shard_map(
+            body, mesh=mesh,
+            in_specs=(pspecs, ospecs, data_x, data_y),
+            out_specs=(pspecs, ospecs, P()),
+        ))
 
     return step_fn_factory, init_fn
 

@@ -95,10 +95,7 @@ from dist_keras_tpu.observability import slo as _slo
 from dist_keras_tpu.ops.pallas.decode_attention import (
     paged_attention_auto,
 )
-from dist_keras_tpu.ops.pallas.flash_attention import (
-    attention_auto,
-    use_pallas,
-)
+from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 from dist_keras_tpu.resilience.faults import fault_point
 from dist_keras_tpu.serving.engine import Overloaded
 from dist_keras_tpu.serving.kv_cache import PagedKVCache, PagesExhausted
@@ -314,13 +311,12 @@ class DecodeEngine:
         self._dh = d // h
         self._heads = h
         self._layers = int(cfg["n_layers"])
-        # donation keeps the pool update in place on TPU; CPU jax would
-        # warn-and-copy, so only donate where donation is real
-        donate = (1, 2) if use_pallas() else ()
+        # donation keeps the pool update in place: a dispatch consumes
+        # the replica's kp/vp and returns their successors
         self._prefill_jit = jax.jit(self._prefill_fn,
-                                    donate_argnums=donate)
+                                    donate_argnums=(1, 2))
         self._decode_jit = jax.jit(self._decode_fn,
-                                   donate_argnums=donate)
+                                   donate_argnums=(1, 2))
 
         if devices is None:
             devices = jax.devices()
@@ -406,11 +402,10 @@ class DecodeEngine:
         cache = PagedKVCache(self.num_pages, self.page_size)
         shape = (self._layers, self._heads, self.num_pages + 1,
                  self.page_size, self._dh)
-        kp = jnp.zeros(shape, jnp.float32)
-        vp = jnp.zeros(shape, jnp.float32)
-        if device is not None:
-            kp = jax.device_put(kp, device)
-            vp = jax.device_put(vp, device)
+        # allocated ON the replica's device: N pools staged through the
+        # default device would cost it N pools of peak memory
+        kp = jnp.zeros(shape, jnp.float32, device=device)
+        vp = jnp.zeros(shape, jnp.float32, device=device)
         return _DecodeReplica(index, device, self._host_params, cache,
                               kp, vp)
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dist_keras_tpu.parallel.collectives import tree_pmean_sync, tree_pvary
@@ -27,11 +28,6 @@ from dist_keras_tpu.trainers.chunking import (
     scan_units,
 )
 from dist_keras_tpu.utils.sync import drain
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 class AveragingTrainer(DistributedTrainer):

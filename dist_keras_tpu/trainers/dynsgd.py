@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dist_keras_tpu.parallel.collectives import tree_psum, tree_pvary
@@ -39,11 +40,6 @@ from dist_keras_tpu.comm import backend as comm
 from dist_keras_tpu.trainers.chunking import run_chunked
 from dist_keras_tpu.trainers.windowed import AsynchronousDistributedTrainer
 from dist_keras_tpu.utils.pytree import tree_merge_floats, tree_zeros_like
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def _make_body(step, window, num_workers, steps_per_epoch, T, streamed):

@@ -36,9 +36,6 @@ class SingleTrainer(Trainer):
         return tuple(jax.device_put(np.ascontiguousarray(a[0]))
                      for a in arrays)
 
-    def _to_device(self, x):
-        return jnp.asarray(x[0])
-
     def train(self, dataset, shuffle=False):
         model, loss_fn, tx = self._resolve()
         if shuffle:

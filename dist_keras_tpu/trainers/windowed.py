@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dist_keras_tpu.parallel.collectives import (
@@ -54,11 +55,6 @@ from dist_keras_tpu.utils.pytree import (
     tree_sub,
     tree_zeros_like,
 )
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 class AsynchronousDistributedTrainer(DistributedTrainer):

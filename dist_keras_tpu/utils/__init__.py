@@ -14,7 +14,7 @@ fall back to the module ``__getattr__``.
 import importlib
 
 _LAZY_MODULES = (
-    "jax_compat", "knobs", "misc", "profiling", "pytree",
+    "compile_cache", "knobs", "misc", "profiling", "pytree",
     "serialization", "sync",
 )
 
@@ -74,6 +74,6 @@ __all__ = [
     "uniform_weights", "to_host",
     "to_vector", "shuffle", "precache", "new_dataframe_row",
     "history_average_loss",
-    "jax_compat", "knobs", "misc", "profiling", "pytree",
+    "compile_cache", "knobs", "misc", "profiling", "pytree",
     "serialization", "sync",
 ]

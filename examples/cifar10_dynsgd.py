@@ -19,11 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":  # see examples/mnist.py
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 from dist_keras_tpu.data import (  # noqa: E402
     AccuracyEvaluator,
     Dataset,
@@ -36,6 +31,7 @@ from dist_keras_tpu.data import (  # noqa: E402
 from dist_keras_tpu.data.synthetic import synthetic_cifar10, to_csv  # noqa: E402
 from dist_keras_tpu.models import cifar10_convnet  # noqa: E402
 from dist_keras_tpu.trainers import DynSGD, SingleTrainer  # noqa: E402
+from dist_keras_tpu.utils import compile_cache  # noqa: E402
 
 DATA_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -71,6 +67,7 @@ def evaluate(model, test):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n-train", type=int, default=8192)
     ap.add_argument("--n-test", type=int, default=2048)

@@ -24,11 +24,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":  # see examples/mnist.py
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 from dist_keras_tpu.data import (  # noqa: E402
     AccuracyEvaluator,
     AUCEvaluator,
@@ -41,6 +36,7 @@ from dist_keras_tpu.data import (  # noqa: E402
 from dist_keras_tpu.data.synthetic import synthetic_higgs, to_csv  # noqa: E402
 from dist_keras_tpu.models import higgs_mlp  # noqa: E402
 from dist_keras_tpu.trainers import AEASGD, EAMSGD, SingleTrainer  # noqa: E402
+from dist_keras_tpu.utils import compile_cache  # noqa: E402
 
 DATA_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -77,6 +73,7 @@ def evaluate(model, test):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n-train", type=int, default=16384)
     ap.add_argument("--n-test", type=int, default=4096)

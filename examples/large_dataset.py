@@ -37,16 +37,15 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from dist_keras_tpu.data import Dataset
 from dist_keras_tpu.models import mnist_mlp
 from dist_keras_tpu.trainers import ADAG
 from dist_keras_tpu.utils.misc import one_hot
+from dist_keras_tpu.utils import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=200_000)
     ap.add_argument("--stream", type=int, default=8,

@@ -29,11 +29,6 @@ import urllib.request
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":  # see examples/mnist.py
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from dist_keras_tpu.checkpoint import Checkpointer  # noqa: E402
@@ -45,6 +40,7 @@ from dist_keras_tpu.serving import (  # noqa: E402
     ServingServer,
 )
 from dist_keras_tpu.trainers import SingleTrainer  # noqa: E402
+from dist_keras_tpu.utils import compile_cache  # noqa: E402
 
 
 def _post(url, rows):
@@ -57,6 +53,7 @@ def _post(url, rows):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=512)
     ap.add_argument("--clients", type=int, default=4)

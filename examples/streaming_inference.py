@@ -24,11 +24,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":  # see examples/mnist.py
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from dist_keras_tpu.data import (  # noqa: E402
@@ -39,9 +34,11 @@ from dist_keras_tpu.data import (  # noqa: E402
 from dist_keras_tpu.data.synthetic import synthetic_mnist  # noqa: E402
 from dist_keras_tpu.models import mnist_mlp  # noqa: E402
 from dist_keras_tpu.trainers import SingleTrainer  # noqa: E402
+from dist_keras_tpu.utils import compile_cache  # noqa: E402
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=2048)
     ap.add_argument("--batch", type=int, default=256)

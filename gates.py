@@ -4557,11 +4557,9 @@ def main():
         "pytest_exit_code": res["exit_code"],
         "seconds": res["seconds"],
         "environment": {
-            "harness": "8-virtual-device CPU mesh (tests/conftest.py) "
-                       "for multi-worker gates (a single TPU chip cannot "
-                       "host a worker mesh); 1-worker gates additionally "
-                       "run on the real chip — see each gate record's "
-                       "'platform' field (round 5: single_mnist_mlp_tpu)",
+            "harness": "8-virtual-device CPU mesh (tests/conftest.py); "
+                       "the chip is checked by chip_smoke.py, not from "
+                       "inside pytest",
             "platforms": sorted({g.get("platform", "cpu")
                                  for g in res["gates"]}),
             "python": platform.python_version(),

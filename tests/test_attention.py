@@ -4,14 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dist_keras_tpu.ops.attention import attention, ring_attention
 from dist_keras_tpu.parallel.mesh import SEQ_AXIS
-
-# jax_compat.shard_map: pre-vma jax needs check_rep=False on
-# composed-mesh programs (see dist_keras_tpu/utils/jax_compat.py)
-from dist_keras_tpu.utils.jax_compat import shard_map
 
 
 def _qkv(b=2, t=32, h=4, d=8, seed=0):

@@ -66,8 +66,8 @@ def test_sigterm_mid_run_flushes_parseable_record():
     try:
         # wait for the pre-config record line (bench emits one before
         # any jax/device touch) with a REAL deadline — a blocking
-        # readline would hang the test on exactly the wedged-backend
-        # scenario this hardening targets.  Binary pipes: non-blocking
+        # readline would hang the test if the bench never printed.
+        # Binary pipes: non-blocking
         # reads on a text wrapper raise on empty reads.
         os.set_blocking(proc.stdout.fileno(), False)
         # _emit prefixes a newline (line-boundary guarantee), so wait

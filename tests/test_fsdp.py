@@ -43,9 +43,7 @@ def _setup(seed=0):
 def test_fsdp_specs_shard_big_leaves_only():
     model, *_ = _setup()
     specs = fsdp_specs(model.params, axis_size=8)
-    from dist_keras_tpu.utils.jax_compat import leaves_with_path
-
-    flat = leaves_with_path(
+    flat = jax.tree.leaves_with_path(
         specs, is_leaf=lambda s: hasattr(s, "index"))
     # big mats sharded, biases/LN replicated
     by_path = {jax.tree_util.keystr(p): s for p, s in flat}

@@ -7,6 +7,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dist_keras_tpu.parallel.moe import (
@@ -16,10 +17,6 @@ from dist_keras_tpu.parallel.moe import (
     switch_moe_dense,
     switch_moe_ep,
 )
-
-# jax_compat.shard_map: pre-vma jax needs check_rep=False on
-# composed-mesh programs (see dist_keras_tpu/utils/jax_compat.py)
-from dist_keras_tpu.utils.jax_compat import shard_map
 
 
 D, FF, E = 16, 32, 8

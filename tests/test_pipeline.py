@@ -8,6 +8,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dist_keras_tpu.models.transformer import (
@@ -24,10 +25,6 @@ from dist_keras_tpu.parallel.pipeline import (
     pp_transformer_apply,
     stack_blocks,
 )
-
-# jax_compat.shard_map: pre-vma jax needs check_rep=False on
-# composed-mesh programs (see dist_keras_tpu/utils/jax_compat.py)
-from dist_keras_tpu.utils.jax_compat import shard_map
 
 
 def _mesh(n):

@@ -4,8 +4,8 @@ graduation (DK_FUSED_BWD selfcheck verdicts + routing), and compressed
 PS commit deltas (DK_PS_COMPRESS codecs + error feedback).
 
 The collectives edge cases here are the ones the overlap path newly
-leans on (ISSUE 15 satellite): ``tree_pmean_sync`` under the
-jax_compat shims, zero-size leaves, and mixed-dtype trees through the
+leans on (ISSUE 15 satellite): ``tree_pmean_sync`` inside
+shard_map, zero-size leaves, and mixed-dtype trees through the
 async merge.
 """
 
@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from dist_keras_tpu.data import Dataset
 from dist_keras_tpu.models import mnist_mlp
@@ -29,13 +31,6 @@ from dist_keras_tpu.resilience import faults
 from dist_keras_tpu.resilience.faults import FaultInjected
 from dist_keras_tpu.trainers import ADAG, AEASGD, DOWNPOUR, EAMSGD
 from dist_keras_tpu.utils.misc import one_hot
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-from jax.sharding import PartitionSpec as P
 
 
 def _model(seed=0):
@@ -134,7 +129,7 @@ def test_async_merge_phase_split_recorded():
 
 
 def test_tree_pmean_sync_zero_size_and_int_leaves_in_shard_map():
-    """tree_pmean_sync through the jax_compat shims with the edge
+    """tree_pmean_sync inside shard_map with the edge
     leaves the overlap path can carry: zero-size float arrays (pmean)
     and integer RNG counters (pmax, axis-invariant typed)."""
     mesh = worker_mesh(2)

@@ -57,10 +57,7 @@ def test_tp_forward_matches_oracle(dp, tp, sp):
     x, _ = _data()
 
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from dist_keras_tpu.parallel.mesh import SEQ_AXIS, WORKER_AXIS
     from dist_keras_tpu.parallel.transformer_tp import param_specs
 
