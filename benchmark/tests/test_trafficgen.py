@@ -1,0 +1,103 @@
+"""The traffic generator: deterministic per seed, the same sizes and
+arrival gaps for every seed in another order, the drawn distributions
+reported."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmark import trafficgen
+
+TRAFFIC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "traffic")
+
+
+def load(name):
+    with open(os.path.join(TRAFFIC, name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", ["chat"])
+def test_open_schedule_same_work_every_seed(name):
+    tr = load(name)
+    a = trafficgen.open_schedule(tr, 30.0, 50000, 5)
+    b = trafficgen.open_schedule(tr, 30.0, 50000, 5)
+    c = trafficgen.open_schedule(tr, 30.0, 50000, 2 ** 31 + 17)
+    assert [r["due"] for r in a] == [r["due"] for r in b]
+    assert all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, b))
+    assert [len(r["prompt"]) for r in a] != [len(r["prompt"]) for r in c]
+    for key in (lambda r: len(r["prompt"]), lambda r: r["max_new"]):
+        assert sorted(map(key, a)) == sorted(map(key, c))
+    gaps = lambda rs: np.sort(np.diff([r["due"] for r in rs] + [30.0]))
+    np.testing.assert_allclose(gaps(a), gaps(c), rtol=1e-9, atol=1e-12)
+    assert a[0]["due"] == 0.0 and a[-1]["due"] < 30.0
+    ahead = tr["in_flight_at_start"]
+    assert len(a) == ahead + round(tr["arrival"]["rate_per_s"] * 30.0)
+    assert [r["due"] for r in a[:ahead + 1]] == [0.0] * (ahead + 1)
+    assert a[ahead + 1]["due"] > 0.0
+
+
+def test_lengths_follow_the_file():
+    """The drawn lengths have the source's published means (to the few
+    percent that rounding and a finite draw leave), and a reply is cut
+    only to the positions its prompt leaves of a slot."""
+    tr = load("chat")
+    cls = tr["classes"][0]
+    reqs = trafficgen.open_schedule(tr, 2000.0, 50000, 1)
+    d = trafficgen.describe(reqs)
+    n = d["prompt_len"]["n"]
+    assert abs(d["prompt_len"]["sum"] / n - cls["prompt_len"]["mean"]) \
+        <= 0.03 * cls["prompt_len"]["mean"]
+    assert abs(d["output_len"]["sum"] / n - cls["output_len"]["mean"]) \
+        <= 0.03 * cls["output_len"]["mean"]
+    assert d["prompt_len"]["min"] >= 1 and d["output_len"]["min"] >= 1
+    assert all(len(r["prompt"]) + r["max_new"] <= tr["max_total"]
+               for r in reqs)
+    assert any(len(r["prompt"]) + r["max_new"] == tr["max_total"]
+               for r in reqs)
+
+
+def test_closed_pool_fits_a_slot():
+    tr = load("docbatch")
+    pool = trafficgen.requests(tr, tr["requests"], 50000, 3)
+    assert len(pool) == tr["requests"]
+    assert all(1024 <= len(r["prompt"]) <= 1920 for r in pool)
+    assert all(len(r["prompt"]) + r["max_new"] <= 2048 for r in pool)
+    assert all(r["prompt"].max() < 50000 for r in pool)
+    # every stretch of eight holds one request of each stratum of length
+    other = trafficgen.requests(tr, tr["requests"], 50000, 2 ** 31 + 9)
+    assert sorted(r["max_new"] for r in pool) == sorted(
+        r["max_new"] for r in other)
+    assert [r["max_new"] for r in pool] != [r["max_new"] for r in other]
+    bounds = sorted(r["max_new"] for r in pool)[::8] + [10 ** 9]
+    for reqs in (pool, other):
+        for i in range(0, 64, 8):
+            strata = sorted(
+                max(s for s in range(8) if r["max_new"] >= bounds[s])
+                for r in reqs[i:i + 8])
+            assert strata == list(range(8)), (i, strata)
+        sums = [sum(r["max_new"] for r in reqs[i:i + 8])
+                for i in range(0, 64, 8)]
+        assert max(sums) - min(sums) <= 0.1 * min(sums)
+
+
+def test_bursts_and_classes_need_only_data():
+    tr = {"arrival": {"process": "poisson", "rate_per_s": 8.0, "burst": 4},
+          "classes": [
+              {"share": 3, "prompt_len": {"dist": "uniform", "min": 10,
+                                          "max": 10},
+               "output_len": {"dist": "uniform", "min": 2, "max": 2}},
+              {"share": 1, "prompt_len": {"dist": "uniform", "min": 50,
+                                          "max": 60},
+               "output_len": {"dist": "uniform", "min": 3, "max": 3}}]}
+    reqs = trafficgen.open_schedule(tr, 10.0, 100, 9)
+    assert len(reqs) == 80
+    dues = [r["due"] for r in reqs]
+    assert all(len(set(dues[i:i + 4])) == 1 for i in range(0, 80, 4))
+    assert sum(r["cls"] == 1 for r in reqs) == 20
+
+
+def test_unknown_distribution_is_an_error():
+    with pytest.raises(ValueError):
+        trafficgen.quantile({"dist": "zipf"}, 0.5)
