@@ -112,24 +112,26 @@ def apply_block_aux(blk, h, attn_fn, causal, capacity_factor=1.25,
     ``moe_fn(moe_params, tokens_2d) -> (out_2d, aux)`` is injectable —
     the EP step swaps in ``switch_moe_ep``; default is the dense
     single-device mixture."""
-    y = _ln(blk["ln1"], h)
-    q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
-    k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
-    v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
-    a = attn_fn(q, k, v, causal=causal)
-    h = h + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
-    y = _ln(blk["ln2"], h)
-    if "moe" in blk:
-        if moe_fn is None:
-            from dist_keras_tpu.parallel.moe import switch_moe_dense
+    with jax.named_scope("attention"):
+        y = _ln(blk["ln1"], h)
+        q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
+        k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
+        v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
+        a = attn_fn(q, k, v, causal=causal)
+        h = h + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
+    with jax.named_scope("mlp"):
+        y = _ln(blk["ln2"], h)
+        if "moe" in blk:
+            if moe_fn is None:
+                from dist_keras_tpu.parallel.moe import switch_moe_dense
 
-            moe_fn = functools.partial(switch_moe_dense,
-                                       capacity_factor=capacity_factor)
-        b, t, d = y.shape
-        u, aux = moe_fn(blk["moe"], y.reshape(b * t, d))
-        return h + u.reshape(b, t, d), aux
-    u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
-    return h + u @ blk["w2"] + blk["b2"], jnp.float32(0.0)
+                moe_fn = functools.partial(
+                    switch_moe_dense, capacity_factor=capacity_factor)
+            b, t, d = y.shape
+            u, aux = moe_fn(blk["moe"], y.reshape(b * t, d))
+            return h + u.reshape(b, t, d), aux
+        u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
+        return h + u @ blk["w2"] + blk["b2"], jnp.float32(0.0)
 
 
 def apply_block(blk, h, attn_fn, causal):

@@ -15,8 +15,9 @@ that stalled instead of silence:
   signals, coordination votes/barriers with durations, dead-peer
   transitions, NaN-sentinel hits.
 - :mod:`~dist_keras_tpu.observability.metrics` — process-wide named
-  counters/gauges/histograms (the grown-up ``StepTimer``, which is now a
-  thin wrapper); snapshots ride the event stream at epoch boundaries.
+  counters/gauges/histograms, histogram samples stamped so a reader
+  can cut one interval out; snapshots ride the event stream at epoch
+  boundaries.
 - :mod:`~dist_keras_tpu.observability.spans` — distributed tracing:
   nested ``span(name)`` regions minting ``trace_id``/``span_id``/
   ``parent_id``, capturable/resumable across threads, propagated

@@ -1,8 +1,6 @@
 """Process-wide metrics registry — named counters, gauges, histograms.
 
-``utils/profiling.StepTimer`` sketched this in miniature (a list of
-per-call durations with summary stats); this module grows it into the
-registry every subsystem shares: trainers, ``comm.backend``,
+The registry every subsystem shares: trainers, ``comm.backend``,
 ``checkpoint``, ``resilience.retry`` and ``data.streaming`` register
 named instruments here, and the whole registry snapshots to JSON at
 epoch boundaries into the event stream (``events.py``), so a post-hoc
@@ -22,7 +20,7 @@ Design points:
   ``DK_OBS_DIR`` is set.
 - **Zero-length windows are guarded**: an empty histogram summarizes to
   ``count: 0`` with ``None`` stats instead of a numpy warning or a
-  raise — the same convention ``StepTimer.summary`` now follows.
+  raise.
 """
 
 from __future__ import annotations
@@ -105,6 +103,11 @@ class Histogram:
     snapshot cost stays O(window) instead of growing quadratically with
     run length.  A recent window is also the operationally useful
     percentile — "what do saves cost *now*", not diluted by hour-one.
+
+    Each retained sample carries a stamp on ``time.perf_counter()``
+    (``observe(..., at=)``; the moment of the observation when
+    omitted), so a reader can cut the samples of one interval out of a
+    histogram nobody reset: :meth:`samples_between`.
     """
 
     WINDOW = 4096
@@ -119,21 +122,30 @@ class Histogram:
         self._count = 0
         self._total = 0.0
         self._max = None
+        self._evicted_at = None  # newest stamp the window has dropped
         self._over = {}  # threshold -> cumulative count(value > thr)
         self._lock = threading.Lock()
 
-    def observe(self, value, exemplar=None):
+    def observe(self, value, exemplar=None, at=None):
         """Record one sample.  ``exemplar``: optional ``(trace_id,
         span_id)`` linking this observation to a trace; when omitted
         and the SLO plane is armed, the current span's ids are
         captured automatically (provider registered by ``spans.py``).
+        ``at``: the sample's stamp on ``time.perf_counter()`` (a timed
+        region passes its START); the moment of the call when omitted.
         """
         value = float(value)
+        if at is None:
+            at = time.perf_counter()
         if exemplar is None and _exemplar_provider is not None \
                 and _exemplars_enabled():
             exemplar = _exemplar_provider()
         with self._lock:
-            self._window.append(value)
+            if len(self._window) == self._window.maxlen:
+                gone = self._window[0][0]
+                if self._evicted_at is None or gone > self._evicted_at:
+                    self._evicted_at = gone
+            self._window.append((at, value))
             self._count += 1
             self._total += value
             if self._max is None or value > self._max:
@@ -175,13 +187,27 @@ class Histogram:
             self._count = 0
             self._total = 0.0
             self._max = None
+            self._evicted_at = None
             self._over = {thr: 0 for thr in self._over}
 
     @property
     def samples(self):
         """The retained (most recent) samples — the percentile window."""
         with self._lock:
-            return list(self._window)
+            return [v for _, v in self._window]
+
+    def samples_between(self, lo, hi):
+        """-> (``[(at, value), ...]`` of the retained samples stamped in
+        ``[lo, hi)``, in the order observed; ``truncated``).
+        ``truncated`` is True when the bounded window has already
+        dropped a sample stamped at or after ``lo``: the list is then
+        the interval's tail, not the interval, and a reader should
+        report nothing rather than a statistic of it."""
+        with self._lock:
+            window = list(self._window)
+            evicted = self._evicted_at
+        return ([(at, v) for at, v in window if lo <= at < hi],
+                evicted is not None and evicted >= lo)
 
     def totals(self):
         """-> {count, total, max} — the exact lifetime aggregates,
@@ -199,7 +225,7 @@ class Histogram:
         instead of raising from the percentile math."""
         with self._lock:
             count, total, mx = self._count, self._total, self._max
-            window = list(self._window)
+            window = [v for _, v in self._window]
         if count == 0:
             return {"count": 0, "mean": None, "p50": None, "p95": None,
                     "p99": None, "max": None, "total": 0.0}
@@ -322,6 +348,8 @@ KNOWN_METRICS = {
     "decode.tokens": "counter",
     "decode.ttft_s": "histogram",
     "decode.step_s": "histogram",
+    "decode.prefill_s": "histogram",
+    "decode.queue_wait_s": "histogram",
     "decode.active": "gauge",
     "decode.kv_used_pages": "gauge",
     # decode survivability plane (serving/decode.py): quarantine +

@@ -21,16 +21,20 @@ stay on in production (<5% of train wall, gated):
   the trainers' blocking loss retire (bytes fetched + the blocking
   wall, which on the streamed path is the documented backpressure
   barrier — the honest "host overlap wall").
-- **Per-phase step-time breakdown** — :func:`phase` wraps the dispatch
-  loop's host-side phases (``data`` / ``step`` / ``comm`` / ``ckpt``)
-  into always-on ``perf.phase.<name>`` registry histograms.  The time
-  domain rides the sampler's ``perf_sample`` events, NOT per-call span
-  events: phases run at per-chunk cadence, and two JSON lines per phase
-  per chunk is exactly the hot-loop emission volume the <5% overhead
-  contract forbids (measured: it tripled the obs gate's emit wall).
-  While a device trace is open the region still goes through
-  ``spans.span`` — so XProf annotations and the histograms share one
-  vocabulary when it matters, at a cadence an operator opted into.
+- **Per-phase step-time breakdown** — :func:`phase` wraps the host
+  loops' phases (the trainers' ``data`` / ``step`` / ``comm`` /
+  ``ckpt``, the decode worker's ``decode.*``) into always-on
+  ``perf.phase.<name>`` registry histograms, each sample stamped with
+  the region's start.  The time domain rides the sampler's
+  ``perf_sample`` events, NOT per-call span events: phases run at
+  per-chunk or per-token cadence, and two JSON lines per phase is
+  exactly the hot-loop emission volume the <5% overhead contract
+  forbids (measured: it tripled the obs gate's emit wall).  Every
+  region is also a ``jax.profiler.TraceAnnotation`` named
+  ``perf.<name>``: nothing while no profiler session is open, and a
+  host region on the device trace's clock in ANY session that is —
+  ``utils.profiling.trace``, a benchmark's own ``start_trace``, a
+  remote capture through ``jax.profiler.start_server``.
 
 Everything lands in the process metrics registry, so it rides the
 epoch-boundary snapshots, the ``MetricsSampler`` time series, the
@@ -40,11 +44,10 @@ plumbing.  No device profiler is ever required.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 
-from dist_keras_tpu.observability import metrics, spans
+from dist_keras_tpu.observability import metrics
 
 # one executable build per fire — the retrace proxy
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -59,7 +62,20 @@ _installed = False
 # async enqueue to comm_overlap and the deferred block_until_ready to
 # comm_blocked, so "how much of the collective hid under compute" is a
 # first-class histogram instead of a guess inside "comm"
-PHASES = ("data", "step", "comm", "comm_overlap", "comm_blocked", "ckpt")
+PHASES = ("data", "step", "comm", "comm_overlap", "comm_blocked", "ckpt",
+          # the decode worker loop (serving/decode.py), parents before
+          # their children: the locked scheduling pass, the idle park,
+          # one prompt's prefill and one slot set's token step, each
+          # split into building the host arrays, the transfers and the
+          # launch returning, the wait for the result, and (step) the
+          # token callbacks and exits
+          "decode.sched", "decode.park",
+          "decode.prefill", "decode.prefill.build",
+          "decode.prefill.dispatch", "decode.prefill.wait",
+          "decode.step", "decode.step.build", "decode.step.dispatch",
+          "decode.step.wait", "decode.step.emit")
+
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
 
 
 def _on_duration(name, duration_secs, **kw):
@@ -122,25 +138,39 @@ def d2h(nbytes, seconds):
     metrics.histogram("perf.d2h_s").observe(seconds)
 
 
-@contextlib.contextmanager
-def phase(name, **fields):
-    """Always-on timed phase: observes ``perf.phase.<name>`` (registry
-    histogram — a clock read + deque append, no I/O, per-chunk-cadence
-    safe).  Only while a device trace is open does the region also run
-    through ``spans.span`` (-> ``TraceAnnotation`` + span events), so
-    XProf and the histograms share a vocabulary without per-chunk JSON
-    emission on production runs."""
-    # dklint: spans=perf.*
-    cm = (spans.span(f"perf.{name}", **fields)
-          if spans.device_trace_active() else contextlib.nullcontext())
-    with cm:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            # dklint: metrics=perf.phase.*
-            metrics.histogram(f"perf.phase.{name}").observe(
-                time.perf_counter() - t0)
+class phase:
+    """Always-on timed region ``with perf.phase(name, **fields):`` —
+    the one hot-loop region primitive.  Observes ``perf.phase.<name>``
+    (registry histogram: a clock read + deque append, no I/O), the
+    sample stamped with the region's START, and brackets the region
+    with a ``jax.profiler.TraceAnnotation("perf.<name>", **fields)``
+    (a ``TraceMe``: about a microsecond with no profiler session open),
+    so whoever opens a session sees the host regions on the device's
+    clock with ``fields`` as their arguments.  No flag, no knob."""
+
+    __slots__ = ("_name", "_ann", "_t0")
+
+    def __init__(self, name, **fields):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._name = name
+        self._ann = _annotation(f"perf.{name}", **fields)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t0 = self._t0
+        # dklint: metrics=perf.phase.*
+        metrics.histogram(f"perf.phase.{self._name}").observe(
+            time.perf_counter() - t0, at=t0)
+        self._ann.__exit__(*exc)
+        return False
 
 
 def snapshot(snap=None):

@@ -37,7 +37,10 @@ show up inside the XProf/TensorBoard timeline — one annotation
 vocabulary for both the host-side event log and the device trace.
 ``utils.profiling.trace`` flips :func:`set_device_trace`; nothing here
 imports jax unless that flag is on, so spans stay usable in processes
-that never touch a device (the launcher, the report CLI).
+that never touch a device (the launcher, the report CLI).  The flag is
+for spans alone: the hot-loop regions of ``perf.phase`` are
+``TraceAnnotation``s unconditionally and reach any profiler session,
+whoever opened it.
 
 Zero-cost contract: with ``DK_OBS_DIR`` unset and no device trace, a
 span is ONE SHARED no-op context-manager object — no clock read, no id
@@ -85,7 +88,8 @@ KNOWN_SPANS = (
     "route.forward",
     # parameter-server commit apply (ps/server.py)
     "ps.commit",
-    # perf phases under an open device trace (observability/perf.py)
+    # perf.phase regions (observability/perf.py): TraceAnnotations
+    # named perf.<phase> in any open profiler session, never span events
     "perf.*",
 )
 
@@ -157,10 +161,6 @@ def set_device_trace(active):
     spans forward to ``TraceAnnotation`` only while it is."""
     global _device_trace_active
     _device_trace_active = bool(active)
-
-
-def device_trace_active():
-    return _device_trace_active
 
 
 def _prune_stacks_locked():

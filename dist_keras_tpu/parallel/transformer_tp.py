@@ -75,10 +75,11 @@ def param_specs(params):
 
 
 def _mlp_half(blk, h):
-    y = _ln(blk["ln2"], h)
-    u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])  # column-parallel
-    z = u @ blk["w2"]                           # row-parallel
-    return h + lax.psum(z, MODEL_AXIS) + blk["b2"]
+    with jax.named_scope("mlp"):
+        y = _ln(blk["ln2"], h)
+        u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])  # column-parallel
+        z = u @ blk["w2"]                           # row-parallel
+        return h + lax.psum(z, MODEL_AXIS) + blk["b2"]
 
 
 def _tp_block(blk, h, causal, remat_mlp=False):
@@ -92,15 +93,16 @@ def _tp_block(blk, h, causal, remat_mlp=False):
     forward instead of re-running the flash kernels + collectives that
     full-block remat pays (measured v5e, T=32k d768/L4: full remat 89.8k
     tokens/s vs mlp-only 112k+ at a fraction of full-remat's memory)."""
-    y = _ln(blk["ln1"], h)
-    # local heads only: wq/wk/wv are head-sharded over `model`
-    q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
-    k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
-    v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
-    a = ring_attention(q, k, v, axis=SEQ_AXIS, causal=causal)
-    # partial over local heads -> reduce over the model axis
-    o = jnp.einsum("bthk,hkd->btd", a, blk["wo"])
-    h = h + lax.psum(o, MODEL_AXIS)
+    with jax.named_scope("attention"):
+        y = _ln(blk["ln1"], h)
+        # local heads only: wq/wk/wv are head-sharded over `model`
+        q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
+        k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
+        v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
+        a = ring_attention(q, k, v, axis=SEQ_AXIS, causal=causal)
+        # partial over local heads -> reduce over the model axis
+        o = jnp.einsum("bthk,hkd->btd", a, blk["wo"])
+        h = h + lax.psum(o, MODEL_AXIS)
     mlp = jax.checkpoint(_mlp_half) if remat_mlp else _mlp_half
     return mlp(blk, h)
 
@@ -173,18 +175,21 @@ def make_tp_train_step(mesh, cfg, optimizer=None, loss="softmax_xent",
             x = x.astype(compute_dtype)
         logits = tp_transformer_forward(p, x, cfg, causal=causal,
                                         remat=remat)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        nll = -jnp.take_along_axis(
-            logp, y[:, None].astype(jnp.int32), axis=-1).mean()
-        # mean over the data-parallel axis -> AD emits the grad psums
-        return lax.pmean(nll, WORKER_AXIS)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            nll = -jnp.take_along_axis(
+                logp, y[:, None].astype(jnp.int32), axis=-1).mean()
+            # mean over the data-parallel axis -> AD emits the grad
+            # psums
+            return lax.pmean(nll, WORKER_AXIS)
 
     def body(params, opt_state, x, y):
         # x local block: (B/workers, T/seq, input_dim); y: (B/workers,)
         loss_val, grads = jax.value_and_grad(
             lambda p: local_loss(p, x, y))(params)
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, new_opt, loss_val
 
     def init_fn(seed=0):

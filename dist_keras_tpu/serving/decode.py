@@ -384,6 +384,8 @@ class DecodeEngine:
         self._reg_kv_leaked = metrics.counter("decode.kv_leaked")
         self._reg_ttft = metrics.histogram("decode.ttft_s")
         self._reg_step = metrics.histogram("decode.step_s")
+        self._reg_prefill = metrics.histogram("decode.prefill_s")
+        self._reg_queue_wait = metrics.histogram("decode.queue_wait_s")
         self._reg_active = metrics.gauge("decode.active")
         self._reg_kv = metrics.gauge("decode.kv_used_pages")
         perf.install()  # retrace listener: the ladder bound, verified
@@ -418,25 +420,34 @@ class DecodeEngine:
         routes them there) and never influence position ``length - 1``
         under the causal mask."""
         t = tokens.shape[0]
-        x = jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
-        hs = (x @ params["proj"] + params["pos"][:t])[None]
+        with jax.named_scope("embed"):
+            x = jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
+            hs = (x @ params["proj"] + params["pos"][:t])[None]
         for li, blk in enumerate(params["blocks"]):
-            y = layer_norm(blk["ln1"], hs)
-            q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
-            k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
-            v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
-            # scalar layer + page arrays are non-adjacent advanced
-            # indices: the update's broadcast dims lead -> (T, H, dh)
-            kp = kp.at[li, :, page_idx, page_off, :].set(k[0])
-            vp = vp.at[li, :, page_idx, page_off, :].set(v[0])
-            a = attention_auto(q, k, v, causal=True)
-            hs = hs + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
-            y = layer_norm(blk["ln2"], hs)
-            u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
-            hs = hs + u @ blk["w2"] + blk["b2"]
-        hf = layer_norm(params["ln_f"], hs)[0, length - 1]
-        logits = hf @ params["head"]["kernel"] + params["head"]["bias"]
-        return jnp.argmax(logits).astype(jnp.int32), kp, vp
+            with jax.named_scope("qkv"):
+                y = layer_norm(blk["ln1"], hs)
+                q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
+                k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
+                v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
+            with jax.named_scope("kv_write"):
+                # scalar layer + page arrays are non-adjacent advanced
+                # indices: the update's broadcast dims lead -> (T, H, dh)
+                kp = kp.at[li, :, page_idx, page_off, :].set(k[0])
+                vp = vp.at[li, :, page_idx, page_off, :].set(v[0])
+            with jax.named_scope("attend"):
+                a = attention_auto(q, k, v, causal=True)
+            with jax.named_scope("attn_out"):
+                hs = hs + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
+            with jax.named_scope("mlp"):
+                y = layer_norm(blk["ln2"], hs)
+                u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
+                hs = hs + u @ blk["w2"] + blk["b2"]
+        with jax.named_scope("head"):
+            hf = layer_norm(params["ln_f"], hs)[0, length - 1]
+            logits = (hf @ params["head"]["kernel"]
+                      + params["head"]["bias"])
+            first = jnp.argmax(logits).astype(jnp.int32)
+        return first, kp, vp
 
     def _decode_fn(self, params, kp, vp, tokens, positions, page_tables,
                    write_page, write_off, lengths):
@@ -444,25 +455,36 @@ class DecodeEngine:
         updated pools).  Padding slots carry ``length == 0`` and write
         to the scratch page; the paged attention's dead-row guard
         makes their output exact zeros (then discarded)."""
-        x = (jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
-             @ params["proj"] + params["pos"][positions])
-        hs = x
+        with jax.named_scope("embed"):
+            hs = (jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
+                  @ params["proj"] + params["pos"][positions])
         for li, blk in enumerate(params["blocks"]):
-            y = layer_norm(blk["ln1"], hs)
-            q = jnp.einsum("sd,dhk->shk", y, blk["wq"])
-            k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
-            v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
-            kp = kp.at[li, :, write_page, write_off, :].set(k)
-            vp = vp.at[li, :, write_page, write_off, :].set(v)
-            a = paged_attention_auto(q, kp[li], vp[li], page_tables,
-                                     lengths)
-            hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
-            y = layer_norm(blk["ln2"], hs)
-            u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
-            hs = hs + u @ blk["w2"] + blk["b2"]
-        hf = layer_norm(params["ln_f"], hs)
-        logits = hf @ params["head"]["kernel"] + params["head"]["bias"]
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
+            with jax.named_scope("qkv"):
+                y = layer_norm(blk["ln1"], hs)
+                q = jnp.einsum("sd,dhk->shk", y, blk["wq"])
+                k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
+                v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
+            with jax.named_scope("kv_write"):
+                kp = kp.at[li, :, write_page, write_off, :].set(k)
+                vp = vp.at[li, :, write_page, write_off, :].set(v)
+            with jax.named_scope("kv_slice"):
+                # the layer's pages out of the pool: a copy of a sixth
+                # of it per layer on the v5e, apart from the read below
+                kl, vl = kp[li], vp[li]
+            with jax.named_scope("attend"):
+                a = paged_attention_auto(q, kl, vl, page_tables, lengths)
+            with jax.named_scope("attn_out"):
+                hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
+            with jax.named_scope("mlp"):
+                y = layer_norm(blk["ln2"], hs)
+                u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
+                hs = hs + u @ blk["w2"] + blk["b2"]
+        with jax.named_scope("head"):
+            hf = layer_norm(params["ln_f"], hs)
+            logits = (hf @ params["head"]["kernel"]
+                      + params["head"]["bias"])
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, kp, vp
 
     # -- admission ------------------------------------------------------
     def _rung_for(self, n, ladder):
@@ -725,34 +747,39 @@ class DecodeEngine:
             return "length"
         return None
 
-    def _prefill(self, rep, seq):
-        """Run one admitted prompt through the prefill ladder; emits
+    def _prefill(self, rep, seq, rung):
+        """Run one admitted prompt through its prefill ``rung``; emits
         the first generated token (TTFT) or fails the sequence typed.
 
         A RECOVERED sequence (``seq.tokens`` longer than the prompt)
         replays the same prefill over the prompt only — its prediction
         is a token the stream already delivered, so it is discarded
         and the teacher-forced decode steps replay the rest."""
-        rung = self._rung_for(seq.prompt_len, self.prefill_ladder)
-        toks = np.zeros((rung,), np.int32)
-        toks[:seq.prompt_len] = seq.tokens[:seq.prompt_len]
-        scratch = rep.cache.scratch_page
-        page_idx = np.full((rung,), scratch, np.int32)
-        ps = self.page_size
-        for t in range(seq.prompt_len):
-            page_idx[t] = seq.pages[t // ps]
-        page_off = (np.arange(rung, dtype=np.int32) % ps)
+        with perf.phase("decode.prefill.build"):
+            toks = np.zeros((rung,), np.int32)
+            toks[:seq.prompt_len] = seq.tokens[:seq.prompt_len]
+            scratch = rep.cache.scratch_page
+            page_idx = np.full((rung,), scratch, np.int32)
+            ps = self.page_size
+            for t in range(seq.prompt_len):
+                page_idx[t] = seq.pages[t // ps]
+            page_off = (np.arange(rung, dtype=np.int32) % ps)
+        replay = len(seq.tokens) > seq.prompt_len
         t0 = time.perf_counter()
         tw0 = time.time()
+        if not replay:
+            self._reg_queue_wait.observe(time.monotonic() - seq.t, at=t0)
         if events.enabled():
             spans.span_at("serve.queue_wait", seq.ctx, seq.tw, tw0)
         try:
             perf.count_dispatch()
-            first, rep.kp, rep.vp = self._prefill_jit(
-                seq.params, rep.kp, rep.vp, jnp.asarray(toks),
-                jnp.int32(seq.prompt_len), jnp.asarray(page_idx),
-                jnp.asarray(page_off))
-            first = int(first)
+            with perf.phase("decode.prefill.dispatch"):
+                first, rep.kp, rep.vp = self._prefill_jit(
+                    seq.params, rep.kp, rep.vp, jnp.asarray(toks),
+                    jnp.int32(seq.prompt_len), jnp.asarray(page_idx),
+                    jnp.asarray(page_off))
+            with perf.phase("decode.prefill.wait"):
+                first = int(first)
         # dklint: ignore[broad-except] a failed prefill lands TYPED on its own future with pages reclaimed
         except Exception as e:
             with self._cond:
@@ -763,13 +790,13 @@ class DecodeEngine:
             self._resolve(seq, None, error=e)
             return
         dt = time.perf_counter() - t0
+        self._reg_prefill.observe(dt, at=t0)
         with self._cond:
             self._shapes.add(("prefill", rung))
             self._ewma_prefill = (
                 dt if self._ewma_prefill is None
                 else 0.8 * self._ewma_prefill + 0.2 * dt)
         seq.kv_len = seq.prompt_len
-        replay = len(seq.tokens) > seq.prompt_len
         if not replay:
             seq.ttft_s = time.monotonic() - seq.t
             seq.t_first = time.time()
@@ -800,9 +827,9 @@ class DecodeEngine:
                         steps=seq.steps)
             self._resolve(seq, finish)
 
-    def _step_group(self, rep, group):
+    def _step_group(self, rep, group, rung):
         """One decode step for ``group`` (same pinned params), padded
-        to a decode-ladder rung.  A failed dispatch retries IN PLACE
+        to its decode-ladder ``rung``.  A failed dispatch retries IN PLACE
         (``step_retries`` — safe: pools and ``kv_len`` only advance on
         success); past the retries the replica quarantines when a
         survivor exists (the group migrates and replays), else it
@@ -813,35 +840,37 @@ class DecodeEngine:
         recovered sequence catches back up (its predictions are
         discarded until ``kv_len`` reaches the frontier, so streams
         never see a duplicate)."""
-        rung = self._rung_for(len(group), self.decode_ladder)
-        scratch = rep.cache.scratch_page
-        ps = self.page_size
-        pmax = self.max_pages_per_seq
-        toks = np.zeros((rung,), np.int32)
-        positions = np.zeros((rung,), np.int32)
-        tables = np.zeros((rung, pmax), np.int32)
-        wpage = np.full((rung,), scratch, np.int32)
-        woff = np.zeros((rung,), np.int32)
-        lengths = np.zeros((rung,), np.int32)
-        for i, seq in enumerate(group):
-            toks[i] = seq.tokens[seq.kv_len]
-            positions[i] = seq.kv_len
-            tables[i, :len(seq.pages)] = seq.pages
-            wpage[i] = seq.pages[seq.kv_len // ps]
-            woff[i] = seq.kv_len % ps
-            lengths[i] = seq.kv_len + 1
+        with perf.phase("decode.step.build"):
+            scratch = rep.cache.scratch_page
+            ps = self.page_size
+            pmax = self.max_pages_per_seq
+            toks = np.zeros((rung,), np.int32)
+            positions = np.zeros((rung,), np.int32)
+            tables = np.zeros((rung, pmax), np.int32)
+            wpage = np.full((rung,), scratch, np.int32)
+            woff = np.zeros((rung,), np.int32)
+            lengths = np.zeros((rung,), np.int32)
+            for i, seq in enumerate(group):
+                toks[i] = seq.tokens[seq.kv_len]
+                positions[i] = seq.kv_len
+                tables[i, :len(seq.pages)] = seq.pages
+                wpage[i] = seq.pages[seq.kv_len // ps]
+                woff[i] = seq.kv_len % ps
+                lengths[i] = seq.kv_len + 1
         t0 = time.perf_counter()
         err = None
         for attempt in range(1 + self.step_retries):
             try:
                 fault_point("decode.step")
                 perf.count_dispatch()
-                nxt, rep.kp, rep.vp = self._decode_jit(
-                    group[0].params, rep.kp, rep.vp, jnp.asarray(toks),
-                    jnp.asarray(positions), jnp.asarray(tables),
-                    jnp.asarray(wpage), jnp.asarray(woff),
-                    jnp.asarray(lengths))
-                nxt = np.asarray(nxt)
+                with perf.phase("decode.step.dispatch"):
+                    nxt, rep.kp, rep.vp = self._decode_jit(
+                        group[0].params, rep.kp, rep.vp,
+                        jnp.asarray(toks), jnp.asarray(positions),
+                        jnp.asarray(tables), jnp.asarray(wpage),
+                        jnp.asarray(woff), jnp.asarray(lengths))
+                with perf.phase("decode.step.wait"):
+                    nxt = np.asarray(nxt)
                 err = None
                 break
             # dklint: ignore[broad-except] a failed step retries in place, then quarantines or lands TYPED
@@ -871,37 +900,38 @@ class DecodeEngine:
             return
         dt = time.perf_counter() - t0
         rep.steps += 1
-        self._m_step.observe(dt)
-        self._reg_step.observe(dt)
-        with self._cond:
-            self._shapes.add(("decode", rung))
-            self._ewma_step = (dt if self._ewma_step is None
-                               else 0.8 * self._ewma_step + 0.2 * dt)
-        events.emit("decode_step", replica=rep.index, rung=rung,
-                    n=len(group), duration_s=dt)
-        finished = []
-        for i, seq in enumerate(group):
-            seq.kv_len += 1
-            seq.steps += 1
-            if seq.kv_len < len(seq.tokens):
-                # replay catch-up: this prediction is a token the
-                # stream already delivered before the crash — discard
-                continue
-            self._emit_token(seq, int(nxt[i]))
-            finish = self._sequence_done(seq, int(nxt[i]))
-            if finish is not None:
-                finished.append((seq, finish))
-        if finished:
+        self._m_step.observe(dt, at=t0)
+        self._reg_step.observe(dt, at=t0)
+        with perf.phase("decode.step.emit"):
             with self._cond:
+                self._shapes.add(("decode", rung))
+                self._ewma_step = (dt if self._ewma_step is None
+                                   else 0.8 * self._ewma_step + 0.2 * dt)
+            events.emit("decode_step", replica=rep.index, rung=rung,
+                        n=len(group), duration_s=dt)
+            finished = []
+            for i, seq in enumerate(group):
+                seq.kv_len += 1
+                seq.steps += 1
+                if seq.kv_len < len(seq.tokens):
+                    # replay catch-up: this prediction is a token the
+                    # stream already delivered before the crash — discard
+                    continue
+                self._emit_token(seq, int(nxt[i]))
+                finish = self._sequence_done(seq, int(nxt[i]))
+                if finish is not None:
+                    finished.append((seq, finish))
+            if finished:
+                with self._cond:
+                    for seq, finish in finished:
+                        rep.active.remove(seq)
+                        self._finish_locked(rep, seq, finish)
                 for seq, finish in finished:
-                    rep.active.remove(seq)
-                    self._finish_locked(rep, seq, finish)
-            for seq, finish in finished:
-                events.emit("decode_complete", sid=seq.sid,
-                            finish=finish,
-                            generated=len(seq.generated()),
-                            steps=seq.steps)
-                self._resolve(seq, finish)
+                    events.emit("decode_complete", sid=seq.sid,
+                                finish=finish,
+                                generated=len(seq.generated()),
+                                steps=seq.steps)
+                    self._resolve(seq, finish)
 
     def _worker_main(self, rep):
         """Thread body: the scheduler loop plus the crash boundary.
@@ -932,43 +962,50 @@ class DecodeEngine:
                     # — every admit, cancel and both lifecycle exits
                     # notify this cond, and the predicate re-checks
                     # stop/retire/kill on wake
-                    # dklint: ignore[unbounded-wait] idle park; admission and lifecycle exits notify this cond
-                    self._cond.wait()
-                if self._stopped:
-                    break
-                if rep.killed:
-                    raise _ReplicaDead(Overloaded("replica_lost"))
-                if rep.retiring and not rep.queue and not rep.active:
-                    break
-                o_migrated, o_dropped = \
-                    self._try_place_orphans_locked()
-                # retire cancelled and deadline-expired actives, refill
-                # free slots — the continuous-batching seam: between
-                # iterations, never a batch barrier.  An expired
-                # deadline frees the slot HERE, between steps.
-                now = time.monotonic()
-                for seq in list(rep.active):
-                    fin = ("cancelled" if seq.cancelled else
-                           "deadline" if seq.deadline is not None
-                           and now > seq.deadline else None)
-                    if fin is not None:
-                        rep.active.remove(seq)
-                        self._finish_locked(rep, seq, fin)
-                        dropped.append((seq, fin))
-                while rep.queue and len(rep.active) < self.max_slots:
-                    seq = rep.queue.popleft()
-                    fin = ("cancelled" if seq.cancelled else
-                           "deadline" if seq.deadline is not None
-                           and now > seq.deadline else None)
-                    if fin is not None:
-                        self._finish_locked(rep, seq, fin)
-                        dropped.append((seq, fin))
-                        continue
-                    rep.active.append(seq)
-                # prefill candidates by state, not by admission order:
-                # a recovered sequence re-enters here with kv_len == 0
-                # and replays exactly like a fresh admission
-                prefills = [s for s in rep.active if s.kv_len == 0]
+                    with perf.phase("decode.park"):
+                        # dklint: ignore[unbounded-wait] idle park; admission and lifecycle exits notify this cond
+                        self._cond.wait()
+                with perf.phase("decode.sched", queued=len(rep.queue),
+                                active=len(rep.active)):
+                    if self._stopped:
+                        break
+                    if rep.killed:
+                        raise _ReplicaDead(Overloaded("replica_lost"))
+                    if rep.retiring and not rep.queue \
+                            and not rep.active:
+                        break
+                    o_migrated, o_dropped = \
+                        self._try_place_orphans_locked()
+                    # retire cancelled and deadline-expired actives,
+                    # refill free slots — the continuous-batching seam:
+                    # between iterations, never a batch barrier.  An
+                    # expired deadline frees the slot HERE, between
+                    # steps.
+                    now = time.monotonic()
+                    for seq in list(rep.active):
+                        fin = ("cancelled" if seq.cancelled else
+                               "deadline" if seq.deadline is not None
+                               and now > seq.deadline else None)
+                        if fin is not None:
+                            rep.active.remove(seq)
+                            self._finish_locked(rep, seq, fin)
+                            dropped.append((seq, fin))
+                    while rep.queue \
+                            and len(rep.active) < self.max_slots:
+                        seq = rep.queue.popleft()
+                        fin = ("cancelled" if seq.cancelled else
+                               "deadline" if seq.deadline is not None
+                               and now > seq.deadline else None)
+                        if fin is not None:
+                            self._finish_locked(rep, seq, fin)
+                            dropped.append((seq, fin))
+                            continue
+                        rep.active.append(seq)
+                    # prefill candidates by state, not by admission
+                    # order: a recovered sequence re-enters here with
+                    # kv_len == 0 and replays exactly like a fresh
+                    # admission
+                    prefills = [s for s in rep.active if s.kv_len == 0]
             for seq, target in o_migrated:
                 self._reg_recovered.inc()
                 events.emit("decode_recover", sid=seq.sid, src=None,
@@ -986,7 +1023,9 @@ class DecodeEngine:
                                 generated=len(seq.generated()))
                 self._resolve(seq, fin)
             for seq in prefills:
-                self._prefill(rep, seq)
+                rung = self._rung_for(seq.prompt_len, self.prefill_ladder)
+                with perf.phase("decode.prefill", sid=seq.sid, rung=rung):
+                    self._prefill(rep, seq, rung)
                 if rep.killed:
                     raise _ReplicaDead(Overloaded("replica_lost"))
             with self._cond:
@@ -999,7 +1038,9 @@ class DecodeEngine:
                     groups.setdefault(id(seq.params), []).append(seq)
                 work = list(groups.values())
             for group in work:
-                self._step_group(rep, group)
+                rung = self._rung_for(len(group), self.decode_ladder)
+                with perf.phase("decode.step", n=len(group), rung=rung):
+                    self._step_group(rep, group, rung)
                 if rep.killed:
                     raise _ReplicaDead(Overloaded("replica_lost"))
             self._maybe_self_check()

@@ -3,27 +3,21 @@
 The reference's only instrumentation is trainer wall-clock timing
 (``record_training_start/stop``, trainers.py:~60), which our Trainer base
 already reproduces.  This module adds the TPU-native layer on top:
-
-- ``trace(logdir)``: context manager around ``jax.profiler`` producing an
-  XProf/TensorBoard trace of everything inside (compiled steps, collectives,
-  transfers).  While it is open, ``observability.span`` regions forward
-  their names into the device trace as ``TraceAnnotation``s.
-- ``annotate(name)``: named region that shows up inside the trace.
-- ``StepTimer``: cheap host-side per-call timer with summary stats, for
-  loops the profiler would be too heavy for.  Since the observability PR
-  it is a thin wrapper over ``observability.metrics.Histogram`` — the
-  process-wide registry every subsystem shares — keeping its historical
-  context-manager API.
+``trace(logdir)``, a context manager around ``jax.profiler`` producing an
+XProf/TensorBoard trace of everything inside (compiled steps, collectives,
+transfers).  While it is open, ``observability.span`` regions forward
+their names into the device trace as ``TraceAnnotation``s; the hot-loop
+regions of ``observability.perf.phase`` are there in any session and need
+no help from here.  Host-side timing lives in the metrics registry
+(``observability.metrics.Histogram``).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 
 import jax
 
-from dist_keras_tpu.observability import metrics as _metrics
 from dist_keras_tpu.observability import spans as _spans
 
 
@@ -41,65 +35,3 @@ def trace(logdir):
     finally:
         _spans.set_device_trace(False)
         jax.profiler.stop_trace()
-
-
-def annotate(name):
-    """Named region inside a trace (jax.profiler.TraceAnnotation)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-class StepTimer:
-    """Per-call wall-clock timer: ``with timer: ...`` per step.
-
-    A named timer (``StepTimer(name="train.step")``) registers its
-    histogram in the process-wide metrics registry, so its samples ride
-    the epoch-boundary snapshots into the event stream; an anonymous
-    one keeps a private histogram (the historical behavior).
-    """
-
-    def __init__(self, name=None):
-        # dklint: ignore[metric-dynamic] caller-chosen instrument
-        # name: a named StepTimer registers under whatever vocabulary
-        # its owner uses (the registry cannot enumerate user names)
-        self._hist = (_metrics.histogram(name) if name
-                      else _metrics.Histogram())
-        self._t0 = None
-
-    @property
-    def times(self):
-        """The recorded durations (seconds) — historical list API."""
-        return self._hist.samples
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._hist.observe(time.perf_counter() - self._t0)
-        return False
-
-    def observe(self, seconds):
-        """Record an externally-measured duration."""
-        self._hist.observe(seconds)
-
-    def reset(self):
-        """Drop every recorded sample (windowed use: reset per epoch)."""
-        self._hist.reset()
-
-    def summary(self):
-        """-> {count, mean_s, p50_s, p95_s, p99_s, max_s, total_s}.
-
-        A zero-length window returns ``count: 0`` with ``None`` stats
-        (``total_s: 0.0``) — guarded the same way the metrics registry
-        and ``Trainer._emit_epoch_end`` guard their empty windows,
-        instead of raising from the percentile math."""
-        s = self._hist.summary()
-        return {
-            "count": s["count"],
-            "mean_s": s["mean"],
-            "p50_s": s["p50"],
-            "p95_s": s["p95"],
-            "p99_s": s["p99"],
-            "max_s": s["max"],
-            "total_s": s["total"],
-        }

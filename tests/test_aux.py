@@ -21,7 +21,8 @@ from dist_keras_tpu.comm import (
 )
 from dist_keras_tpu.launch import Job, Punchcard
 from dist_keras_tpu.models import mnist_mlp
-from dist_keras_tpu.utils.profiling import StepTimer, annotate, trace
+from dist_keras_tpu.observability import perf
+from dist_keras_tpu.utils.profiling import trace
 
 
 # ---------------------------------------------------------------- checkpoint
@@ -61,18 +62,9 @@ def test_checkpointer_resume_empty(tmp_path):
 
 
 # ---------------------------------------------------------------- profiling
-def test_step_timer():
-    t = StepTimer()
-    for _ in range(3):
-        with t:
-            pass
-    s = t.summary()
-    assert s["count"] == 3 and s["total_s"] >= 0
-
-
 def test_trace_smoke(tmp_path):
     with trace(tmp_path / "prof"):
-        with annotate("tiny"):
+        with perf.phase("step"):
             jnp.sum(jnp.ones((4, 4))).block_until_ready()
     # a trace directory with content must exist
     found = [f for _, _, fs in os.walk(tmp_path / "prof") for f in fs]

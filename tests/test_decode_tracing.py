@@ -1,0 +1,313 @@
+"""The decode worker loop measured from inside: ``perf.phase`` regions
+that reach any profiler session, window-stamped histograms, and named
+scopes on the jitted steps."""
+
+import ast
+import glob
+import inspect
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dist_keras_tpu.models.transformer import Transformer, transformer_config
+from dist_keras_tpu.observability import metrics, perf, spans
+from dist_keras_tpu.serving import decode
+from dist_keras_tpu.serving.decode import DecodeEngine
+
+DECODE_REGIONS = tuple(p for p in perf.PHASES if p.startswith("decode."))
+DECODE_SCOPES = ("embed", "qkv", "kv_write", "attend", "attn_out", "mlp",
+                 "head")      # and kv_slice, in the decode step alone
+
+
+def _engine(**kw):
+    cfg = transformer_config(input_dim=16, seq_len=32, d_model=16,
+                             n_heads=2, n_layers=2, n_classes=16)
+    return DecodeEngine(Transformer(cfg), replicas=1, prefill_ladder=(4, 8),
+                        decode_ladder=(1, 4), page_size=4, **kw)
+
+
+@pytest.fixture
+def engine():
+    metrics.reset()
+    eng = _engine()
+    yield eng
+    eng.close(drain=False)
+    metrics.reset()
+
+
+# ------------------------------------------------- regions in the trace
+@pytest.fixture(scope="module")
+def traced_lines(tmp_path_factory):
+    """A toy engine serving three requests under a profiler session that
+    nothing in the program knows of (no ``utils.profiling.trace``, no
+    flag) -> {line index: [(start, end, name, {field: value})]} of the
+    ``perf.*`` events on the host plane."""
+    from jax.profiler import ProfileData
+
+    logdir = str(tmp_path_factory.mktemp("prof"))
+    eng = _engine()
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=3)     # compiles outside
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        assert not spans._device_trace_active
+        jax.profiler.start_trace(logdir, profiler_options=options)
+        try:
+            with perf.phase("step"):                   # the main thread's
+                gens = [eng.submit_generate([1, 2, 3, 4, 5],
+                                            max_new_tokens=4)
+                        for _ in range(3)]
+                for g in gens:
+                    g.result(timeout=120)
+            # a region still open when the session closes never reaches
+            # the trace: let the worker leave its last step and park
+            time.sleep(0.3)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.close(drain=False)
+    (path,) = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                    dict(e.stats))
+                   for e in line.events if e.name.startswith("perf.")]
+            if evs:
+                lines[i] = evs
+    return lines
+
+
+def _worker_events(lines):
+    (worker,) = [evs for evs in lines.values()
+                 if any(n.startswith("perf.decode.") for _, _, n, _ in evs)]
+    return worker
+
+
+def test_regions_reach_a_session_nobody_announced(traced_lines):
+    names = {n for _, _, n, _ in _worker_events(traced_lines)}
+    for region in DECODE_REGIONS:
+        if region != "decode.park":      # a busy engine may never park
+            assert f"perf.{region}" in names, region
+
+
+def test_regions_sit_on_the_worker_thread_alone(traced_lines):
+    worker = _worker_events(traced_lines)
+    others = [evs for evs in traced_lines.values() if evs is not worker]
+    # the load's own region is on another line, and holds no decode region
+    assert any(n == "perf.step" for evs in others for _, _, n, _ in evs)
+    assert all(n.startswith("perf.decode.") for _, _, n, _ in worker)
+
+
+@pytest.mark.parametrize("parent", ["decode.step", "decode.prefill"])
+def test_children_lie_inside_their_parents(traced_lines, parent):
+    worker = _worker_events(traced_lines)
+    parents = [(a, b) for a, b, n, _ in worker if n == f"perf.{parent}"]
+    children = [(a, b, n) for a, b, n, _ in worker
+                if n.startswith(f"perf.{parent}.")]
+    assert parents and children
+    kinds = {n for _, _, n in children}
+    assert kinds == {f"perf.{r}" for r in DECODE_REGIONS
+                     if r.startswith(parent + ".")}
+    for a, b, n in children:
+        assert any(pa <= a and b <= pb for pa, pb in parents), n
+    # one child of each kind in each parent that ran to its end
+    assert len(children) == len(kinds) * len(parents)
+
+
+def test_fields_reach_the_trace_as_arguments(traced_lines):
+    worker = _worker_events(traced_lines)
+    steps = [f for _, _, n, f in worker if n == "perf.decode.step"]
+    assert steps and all(f["rung"] in (1, 4) and 1 <= f["n"] <= f["rung"]
+                         for f in steps)
+    prefills = [f for _, _, n, f in worker if n == "perf.decode.prefill"]
+    assert len(prefills) == 3 and all(f["rung"] == 8 for f in prefills)
+    assert len({f["sid"] for f in prefills}) == 3
+    sched = [f for _, _, n, f in worker if n == "perf.decode.sched"]
+    assert all(set(f) == {"queued", "active"} for f in sched)
+
+
+def test_every_decode_region_is_a_listed_phase():
+    """The regions opened in ``serving/decode.py`` and ``perf.PHASES`` name
+    the same set."""
+    opened = set()
+    for node in ast.walk(ast.parse(inspect.getsource(decode))):
+        if isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr == "phase" and node.args:
+            opened.add(node.args[0].value)
+    assert opened == set(DECODE_REGIONS)
+
+
+# ------------------------------------------------ the region primitive
+def test_phase_stamps_the_start_and_survives_a_raise():
+    metrics.reset()
+    before = time.perf_counter()
+    with pytest.raises(KeyError):
+        with perf.phase("decode.step.build"):
+            time.sleep(0.01)
+            raise KeyError("x")
+    after = time.perf_counter()
+    hist = metrics.histogram("perf.phase.decode.step.build")
+    ((at, value),), truncated = hist.samples_between(before, after)
+    assert not truncated
+    assert value >= 0.01 and at + value <= after   # the START, not the end
+    metrics.reset()
+
+
+def test_ten_thousand_phases_with_no_session_stay_in_budget():
+    """Ten region entries and two observes a worker iteration are to cost
+    under 50 us on the chip's host; a shared CPU core here gets five times
+    that."""
+    metrics.reset()
+    for _ in range(200):
+        with perf.phase("decode.step", n=8, rung=8):
+            pass
+    t0 = time.perf_counter()
+    for _ in range(10_000):
+        with perf.phase("decode.step", n=8, rung=8):
+            pass
+    each = (time.perf_counter() - t0) / 10_000
+    assert metrics.histogram("perf.phase.decode.step").totals()["count"] \
+        == 10_200
+    assert 10 * each < 250e-6, each
+    metrics.reset()
+
+
+# ------------------------------------------------- stamped histograms
+def test_samples_between_selects_by_stamp():
+    h = metrics.Histogram()
+    for i in range(10):
+        h.observe(float(i), at=100.0 + i)
+    pairs, truncated = h.samples_between(103.0, 107.0)
+    assert pairs == [(103.0, 3.0), (104.0, 4.0), (105.0, 5.0),
+                     (106.0, 6.0)] and not truncated
+    assert h.samples_between(200.0, 300.0) == ([], False)
+    # what was there behaves as before
+    assert h.samples == [float(i) for i in range(10)]
+    s = h.summary()
+    assert s["count"] == 10 and s["total"] == 45.0 and s["p50"] == 4.5
+    assert h.totals() == {"count": 10, "total": 45.0, "max": 9.0}
+
+
+def test_samples_between_reports_truncation(monkeypatch):
+    monkeypatch.setattr(metrics.Histogram, "WINDOW", 4)
+    h = metrics.Histogram()
+    for i in range(10):
+        h.observe(float(i), at=100.0 + i)
+    # the window holds stamps 106-109; 105 is the newest it dropped
+    assert h.samples_between(106.0, 200.0) == (
+        [(106.0, 6.0), (107.0, 7.0), (108.0, 8.0), (109.0, 9.0)], False)
+    pairs, truncated = h.samples_between(105.0, 200.0)
+    assert truncated and len(pairs) == 4
+    assert h.samples_between(0.0, 200.0)[1]
+    h.reset()
+    h.observe(1.0, at=50.0)
+    assert h.samples_between(0.0, 200.0) == ([(50.0, 1.0)], False)
+
+
+def test_an_unstamped_observe_is_stamped_now():
+    h = metrics.Histogram()
+    lo = time.perf_counter()
+    h.observe(2.5)
+    hi = time.perf_counter()
+    (pair,), truncated = h.samples_between(lo, hi + 1e-9)
+    assert pair[1] == 2.5 and lo <= pair[0] <= hi and not truncated
+
+
+# ------------------------------------------- counts at the boundaries
+def test_prefill_and_queue_wait_count_one_sample_a_request(engine):
+    lo = time.perf_counter()
+    gens = [engine.submit_generate([1, 2, 3, 4, 5], max_new_tokens=4)
+            for _ in range(5)]
+    for g in gens:
+        g.result(timeout=120)
+    hi = time.perf_counter()
+    for name in ("decode.prefill_s", "decode.queue_wait_s"):
+        pairs, truncated = metrics.histogram(name).samples_between(lo, hi)
+        assert len(pairs) == 5 and not truncated, name
+        assert all(v >= 0 for _, v in pairs)
+    # a prefill's stamp is its start: each lies inside the run, in order
+    stamps = [at for at, _ in metrics.histogram(
+        "decode.prefill_s").samples_between(lo, hi)[0]]
+    assert stamps == sorted(stamps)
+
+
+def test_step_histogram_keeps_its_meaning_and_gains_the_stamp(engine):
+    lo = time.perf_counter()
+    engine.generate([1, 2, 3], max_new_tokens=6)
+    hi = time.perf_counter()
+    steps, _ = metrics.histogram("decode.step_s").samples_between(lo, hi)
+    assert len(steps) == 5       # the first token is the prefill's
+    for region in ("dispatch", "wait", "build", "emit"):
+        inner, _ = metrics.histogram(
+            f"perf.phase.decode.step.{region}").samples_between(lo, hi)
+        assert len(inner) == len(steps), region
+    # decode.step_s runs from after the arrays are built to the tokens on
+    # the host: dispatch and wait lie inside it, sample for sample
+    disp, _ = metrics.histogram(
+        "perf.phase.decode.step.dispatch").samples_between(lo, hi)
+    wait, _ = metrics.histogram(
+        "perf.phase.decode.step.wait").samples_between(lo, hi)
+    for (at, whole), (d_at, d), (w_at, w) in zip(steps, disp, wait):
+        assert at <= d_at and d_at + d <= w_at + 1e-6
+        assert w_at + w <= at + whole + 1e-6
+    assert engine.stats()["step_s"]["count"] == 5
+
+
+# ------------------------------------------------------- named scopes
+def _scopes_of(lowered, fn_name):
+    text = lowered.as_text(debug_info=True)
+    return set(re.findall(r'"jit\(%s\)/(?:[a-z_]+\([a-z_(]*)?([a-z_]+)\)*/'
+                          % re.escape(fn_name), text)), text
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_serving_steps_carry_their_scopes(engine, phase):
+    rep = engine._replicas[0]
+    i32 = jnp.int32
+    if phase == "decode":
+        lowered = engine._decode_jit.lower(
+            rep.params, rep.kp, rep.vp, jnp.zeros((4,), i32),
+            jnp.zeros((4,), i32), jnp.zeros((4, 8), i32),
+            jnp.zeros((4,), i32), jnp.zeros((4,), i32),
+            jnp.zeros((4,), i32))
+    else:
+        lowered = engine._prefill_jit.lower(
+            rep.params, rep.kp, rep.vp, jnp.zeros((8,), i32), i32(3),
+            jnp.zeros((8,), i32), jnp.zeros((8,), i32))
+    found, _ = _scopes_of(lowered, f"_{phase}_fn")
+    assert set(DECODE_SCOPES) <= found, found
+    assert ("kv_slice" in found) == (phase == "decode")
+
+
+def test_train_step_carries_its_scopes():
+    import optax
+
+    from dist_keras_tpu.parallel.transformer_tp import (
+        make_tp_mesh,
+        make_tp_train_step,
+    )
+
+    cfg = transformer_config(input_dim=8, seq_len=16, d_model=16,
+                             n_heads=2, n_layers=2, n_classes=4)
+    mesh = make_tp_mesh(1, 1, 1, devices=jax.devices()[:1])
+    factory, init = make_tp_train_step(mesh, cfg, optimizer=optax.adam(1e-3),
+                                       causal=True)
+    params, opt_state = init(0)
+    step = factory(params, opt_state)
+    text = step.lower(params, opt_state, jnp.zeros((2, 16, 8)),
+                      jnp.zeros((2,), jnp.int32)).as_text(debug_info=True)
+    for scope in ("attention", "mlp", "loss", "optimizer"):
+        # forward operations sit under the scope itself, the backward's
+        # under transpose(jvp(<scope>))
+        assert re.search(r'[/(]%s[/)]' % scope, text), scope
+    assert re.search(r'transpose\(jvp\(mlp\)\)', text)
+    assert "/optimizer/" in text

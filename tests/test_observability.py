@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from dist_keras_tpu.observability import events, metrics, report, spans
-from dist_keras_tpu.utils.profiling import StepTimer
 
 
 @pytest.fixture
@@ -136,38 +135,6 @@ def test_snapshot_rides_event_stream(obs_dir):
     (ev,) = _read_events(obs_dir)
     assert ev["kind"] == "metrics" and ev["epoch"] == 4
     assert ev["counters"]["job.rsync.retries"] == 2
-
-
-# ---------------------------------------------------------------- StepTimer
-def test_steptimer_summary_has_p99_max_and_reset():
-    t = StepTimer()
-    for _ in range(4):
-        with t:
-            pass
-    s = t.summary()
-    assert s["count"] == 4
-    for key in ("mean_s", "p50_s", "p95_s", "p99_s", "max_s", "total_s"):
-        assert s[key] is not None and s[key] >= 0
-    assert s["max_s"] >= s["p99_s"] >= s["p50_s"]
-    assert len(t.times) == 4
-    t.reset()
-    assert t.summary()["count"] == 0 and t.times == []
-
-
-def test_steptimer_zero_length_window_guarded():
-    s = StepTimer().summary()
-    assert s == {"count": 0, "mean_s": None, "p50_s": None,
-                 "p95_s": None, "p99_s": None, "max_s": None,
-                 "total_s": 0.0}
-
-
-def test_named_steptimer_registers_in_registry():
-    metrics.reset()
-    t = StepTimer(name="train.step")
-    with t:
-        pass
-    assert metrics.snapshot()["histograms"]["train.step"]["count"] == 1
-    metrics.reset()
 
 
 # ---------------------------------------------------------------- spans
