@@ -512,7 +512,7 @@ def _paged_case(slots, heads, d, page_size, n_pages):
     rng = np.random.default_rng(0)
     pool = slots * n_pages + 1
     q = jnp.asarray(rng.normal(size=(slots, heads, d)), jnp.float32)
-    kp, vp = (jnp.asarray(rng.normal(size=(heads, pool, page_size, d)),
+    kp, vp = (jnp.asarray(rng.normal(size=(pool, page_size, heads, d)),
                           jnp.float32) for _ in range(2))
     table = jnp.asarray(rng.integers(0, pool, (slots, n_pages)), jnp.int32)
     t = n_pages * page_size

@@ -13,14 +13,15 @@ dispatch predicate) rather than re-deriving any of it:
   table, mask positions at/after each sequence's length, softmax.  The
   DEFAULT serving path on every backend, and the parity baseline.
 - :func:`paged_attention_kernel` — the Pallas kernel.  Grid ``(slots,
-  heads, pages)`` with the page dim innermost carrying the online-
-  softmax scratch; the page table and per-slot lengths ride as
+  pages)`` with the page dim innermost carrying the online-softmax
+  scratch of all heads; the page table and per-slot lengths ride as
   SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``) so each
   grid step's K/V block index is computed from the page table before
   the DMA issues — the pool is never gathered, each program streams
-  exactly the pages its slot owns.  Fully-masked slots (padding in a
-  fixed-shape decode rung, ``length == 0``) produce exact zeros via
-  the same dead-row guards as the flash forward.
+  exactly the pages its slot owns, one whole page (all heads) a step.
+  Fully-masked slots (padding in a fixed-shape decode rung, ``length
+  == 0``) produce exact zeros via the same dead-row guards as the
+  flash forward.
 - :func:`graduate` — the round-19 exact-parity graduation pattern
   (``fused_bwd_experimental``): ``DK_DECODE_KERNEL=1`` routes
   :func:`paged_attention_auto` through the kernel only after a cached
@@ -32,12 +33,18 @@ dispatch predicate) rather than re-deriving any of it:
   coherence games here, unlike the fused backward, so interpret parity
   is meaningful and the CPU gates exercise the real kernel body).
 
-Shapes: ``q (S, H, D)``; pools ``k/v (H, P, page_size, D)`` — the head
-axis leads so a grid step DMAs one ``(page_size, D)`` tile per page
-without transposing the pool; ``page_table (S, max_pages) int32``
-(entries past a slot's allocation must hold any valid page id — masked
-by ``lengths``); ``lengths (S,) int32`` = valid KV positions per slot,
-INCLUDING the current token (its k/v is written before attention).
+Shapes: ``q (S, H, D)``; pools ``k/v (P, page_size, H, D)`` — PAGE-MAJOR,
+the one layout this module knows: a page is one contiguous ``(page_size,
+H, D)`` block, so the writer's scatter over (page, offset) indexes the
+major dimensions (what the TPU compiler runs in place; a head-major pool
+is converted, whole, on the way in and out of every step) and a reader
+fetches a page with one block copy.  ``P`` may span several layers' pages
+(the decode engine passes its whole pool viewed flat over (layer, page)
+and offsets the page ids; a per-layer slice would be a copy).
+``page_table (S, max_pages) int32`` (entries past a slot's allocation
+must hold any valid page id — masked by ``lengths``); ``lengths (S,)
+int32`` = valid KV positions per slot, INCLUDING the current token (its
+k/v is written before attention).
 """
 
 from __future__ import annotations
@@ -62,21 +69,19 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
                               *, scale=None):
     """Pure-jnp oracle and default serving path.
 
-    Gathers each slot's pages into a contiguous ``(S, T, H, D)`` view
-    (T = max_pages * page_size), masks positions past ``lengths``, and
-    softmaxes — with the flash dead-row guards so a ``length == 0``
-    padding slot yields exact zeros, not NaN.
+    Gathers each slot's pages, whole ``(page_size, H, D)`` blocks by
+    page id, into a contiguous ``(S, T, H, D)`` view (T = max_pages *
+    page_size), masks positions past ``lengths``, and softmaxes — with
+    the flash dead-row guards so a ``length == 0`` padding slot yields
+    exact zeros, not NaN.
     """
     s, h, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
-    ps = k_pages.shape[2]
-    # (H, S, max_pages, ps, D) -> (S, H, T, D)
-    k = jnp.moveaxis(k_pages[:, page_table], 0, 1)
-    v = jnp.moveaxis(v_pages[:, page_table], 0, 1)
-    t = k.shape[2] * ps
-    k = k.reshape(s, h, t, d)
-    v = v.reshape(s, h, t, d)
-    logits = (jnp.einsum("shd,shtd->sht", q, k)
+    # (S, max_pages, ps, H, D) -> (S, T, H, D)
+    k = k_pages[page_table].reshape(s, -1, h, d)
+    v = v_pages[page_table].reshape(s, -1, h, d)
+    t = k.shape[1]
+    logits = (jnp.einsum("shd,sthd->sht", q, k)
               .astype(jnp.float32) * scale)
     kpos = jnp.arange(t, dtype=jnp.int32)
     mask = kpos[None, None, :] < lengths.astype(jnp.int32)[:, None, None]
@@ -84,7 +89,7 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - jnp.where(m <= _NEG_INF / 2, 0.0, m))
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = (jnp.einsum("sht,shtd->shd", p, v)
+    out = (jnp.einsum("sht,sthd->shd", p, v)
            / jnp.maximum(l, 1e-30))
     return out.astype(q.dtype)
 
@@ -94,8 +99,8 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
 # ---------------------------------------------------------------------------
 def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, page_size, scale):
-    s, j = pl.program_id(0), pl.program_id(2)
-    nj = pl.num_programs(2)
+    s, j = pl.program_id(0), pl.program_id(1)
+    nj = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -104,32 +109,31 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[s]
-    q = q_ref[0, 0]                                 # (1, D)
-    k = k_ref[0, 0]                                 # (ps, D)
-    v = v_ref[0, 0]                                 # (ps, D)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (1, ps)
+    q = q_ref[0].astype(jnp.float32)                # (H, D)
+    k = k_ref[0].astype(jnp.float32)                # (ps, H, D)
+    v = v_ref[0].astype(jnp.float32)                # (ps, H, D)
+    # one query row a head: the products are elementwise with a lane
+    # reduction, every head at once; the page's positions stay the
+    # leading (untiled) dimension throughout
+    logits = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
     kpos = (j * page_size
-            + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1))
-    logits = jnp.where(kpos < length, logits, _NEG_INF)
-    m_prev = m_scr[...]                             # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
+            + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0))
+    logits = jnp.where(kpos < length, logits, _NEG_INF)     # (ps, H, 1)
+    m_prev = m_scr[...]                             # (H, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=0))
     # same dead-row shift as the flash forward: a fully-masked tile
     # (page past length / padding slot) contributes exactly nothing
     safe_m = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(logits - safe_m)
+    p = jnp.exp(logits - safe_m[None])              # (ps, H, 1)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, -1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=0)
+    acc_scr[...] = acc_scr[...] * corr + jnp.sum(p * v, axis=0)
     m_scr[...] = m_new
 
     @pl.when(j == nj - 1)
     def _emit():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
@@ -138,41 +142,38 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
     contract).  Callers route through :func:`paged_attention_auto`,
     which gates this on the graduation verdict."""
     s, h, d = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[1]
     n_pages = page_table.shape[1]
     scale = (d ** -0.5) if scale is None else scale
     kernel = functools.partial(_decode_kernel, page_size=ps, scale=scale)
     # index maps see (*grid_indices, *scalar_prefetch_refs): the page
     # table picks each grid step's K/V page BEFORE its DMA issues
-    kv_map = lambda si, hi, j, pt, ln: (hi, pt[si, j], 0, 0)  # noqa: E731
-    # q/out ride as (S, H, 1, D): mosaic wants a block's last two dims
-    # (8k, 128k) or full-dim, which a (1, D) row over (..., 1, D) is
-    q_map = lambda si, hi, j, pt, ln: (si, hi, 0, 0)          # noqa: E731
+    kv_map = lambda si, j, pt, ln: (pt[si, j], 0, 0, 0)       # noqa: E731
+    q_map = lambda si, j, pt, ln: (si, 0, 0)                  # noqa: E731
     extra = ({} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))})
+        dimension_semantics=("parallel", "arbitrary"))})
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, h, n_pages),
+        grid=(s, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, d), q_map),
-            pl.BlockSpec((1, 1, ps, d), kv_map),
-            pl.BlockSpec((1, 1, ps, d), kv_map),
+            pl.BlockSpec((1, h, d), q_map),
+            pl.BlockSpec((1, ps, h, d), kv_map),
+            pl.BlockSpec((1, ps, h, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, d), q_map),
-        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, h, d), q_map),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, d), jnp.float32)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_sds((s, h, 1, d), q.dtype, q),
+        out_shape=_sds((s, h, d), q.dtype, q),
         interpret=interpret,
         name=_kernel_name("paged_decode"),
         **extra,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q[:, :, None, :], k_pages, v_pages)
-    return out[:, :, 0, :]
+      q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -199,9 +200,9 @@ def selfcheck(slots=4, heads=2, head_dim=64, page_size=8, n_pages=4,
     pool = n_pages * slots + 1          # +1 scratch-style spare
     q = jnp.asarray(rng.normal(size=(slots, heads, head_dim)), dtype)
     kp = jnp.asarray(
-        rng.normal(size=(heads, pool, page_size, head_dim)), dtype)
+        rng.normal(size=(pool, page_size, heads, head_dim)), dtype)
     vp = jnp.asarray(
-        rng.normal(size=(heads, pool, page_size, head_dim)), dtype)
+        rng.normal(size=(pool, page_size, heads, head_dim)), dtype)
     pt = jnp.asarray(
         rng.integers(0, pool, size=(slots, n_pages)), jnp.int32)
     t = n_pages * page_size
@@ -268,7 +269,7 @@ def paged_attention_auto(q, k_pages, v_pages, page_table, lengths,
     if knobs.get("DK_DECODE_KERNEL"):
         s, h, d = q.shape
         interpret = not use_pallas()
-        v = graduate(s, h, d, k_pages.shape[2], page_table.shape[1],
+        v = graduate(s, h, d, k_pages.shape[1], page_table.shape[1],
                      q.dtype, interpret=interpret)
         if v.status == "exact":
             return paged_attention_kernel(

@@ -22,8 +22,11 @@ Design points (each mirrors an existing engine contract):
   (x replica devices, inherent) — the same no-retrace contract
   ``ServingEngine.stats()["retrace_count"]`` verifies, reported the
   same way.
-- **Paged KV.**  Each replica owns one KV pool array of shape
-  ``(layers, heads, num_pages + 1, page_size, head_dim)`` and a
+- **Paged KV.**  Each replica owns one K and one V pool array of shape
+  :attr:`DecodeEngine.pool_shape`, ``(layers, num_pages + 1, page_size,
+  heads, head_dim)`` — page-major, so that the steps' scatters over
+  (page, offset) update the donated pools in place and the attention
+  reads whole pages with no copy of the pool or of a layer of it — and a
   :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache` allocator.
   Admission reserves a sequence's WORST-CASE page count up front, so
   decode never stalls mid-sequence on KV: exhaustion is a typed
@@ -307,10 +310,6 @@ class DecodeEngine:
             num_pages = self.max_slots * self.max_pages_per_seq
         self.num_pages = int(num_pages)
 
-        d, h = cfg["d_model"], cfg["n_heads"]
-        self._dh = d // h
-        self._heads = h
-        self._layers = int(cfg["n_layers"])
         # donation keeps the pool update in place: a dispatch consumes
         # the replica's kp/vp and returns their successors
         self._prefill_jit = jax.jit(self._prefill_fn,
@@ -398,16 +397,26 @@ class DecodeEngine:
             t.start()
 
     # -- model math (jitted once per ladder rung) -----------------------
+    @property
+    def pool_shape(self):
+        """The shape of a replica's K pool and of its V pool (float32),
+        page-major: ``(layers, num_pages + 1, page_size, heads,
+        head_dim)``; page index ``num_pages`` is the scratch page.  The
+        one statement of the layout: replicas allocate from it and the
+        jitted steps read everything else off the pools they are given.
+        """
+        heads = self.cfg["n_heads"]
+        return (self.cfg["n_layers"], self.num_pages + 1, self.page_size,
+                heads, self.cfg["d_model"] // heads)
+
     def _make_replica(self, index):
         devs = self._devices
         device = devs[index % len(devs)] if devs else None
         cache = PagedKVCache(self.num_pages, self.page_size)
-        shape = (self._layers, self._heads, self.num_pages + 1,
-                 self.page_size, self._dh)
         # allocated ON the replica's device: N pools staged through the
         # default device would cost it N pools of peak memory
-        kp = jnp.zeros(shape, jnp.float32, device=device)
-        vp = jnp.zeros(shape, jnp.float32, device=device)
+        kp = jnp.zeros(self.pool_shape, jnp.float32, device=device)
+        vp = jnp.zeros(self.pool_shape, jnp.float32, device=device)
         return _DecodeReplica(index, device, self._host_params, cache,
                               kp, vp)
 
@@ -430,10 +439,10 @@ class DecodeEngine:
                 k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
                 v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
             with jax.named_scope("kv_write"):
-                # scalar layer + page arrays are non-adjacent advanced
-                # indices: the update's broadcast dims lead -> (T, H, dh)
-                kp = kp.at[li, :, page_idx, page_off, :].set(k[0])
-                vp = vp.at[li, :, page_idx, page_off, :].set(v[0])
+                # the scattered dimensions are the pool's major ones:
+                # in place on the donated pools, update (T, H, dh)
+                kp = kp.at[li, page_idx, page_off].set(k[0])
+                vp = vp.at[li, page_idx, page_off].set(v[0])
             with jax.named_scope("attend"):
                 a = attention_auto(q, k, v, causal=True)
             with jax.named_scope("attn_out"):
@@ -465,14 +474,16 @@ class DecodeEngine:
                 k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
                 v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
             with jax.named_scope("kv_write"):
-                kp = kp.at[li, :, write_page, write_off, :].set(k)
-                vp = vp.at[li, :, write_page, write_off, :].set(v)
-            with jax.named_scope("kv_slice"):
-                # the layer's pages out of the pool: a copy of a sixth
-                # of it per layer on the v5e, apart from the read below
-                kl, vl = kp[li], vp[li]
+                kp = kp.at[li, write_page, write_off].set(k)
+                vp = vp.at[li, write_page, write_off].set(v)
             with jax.named_scope("attend"):
-                a = paged_attention_auto(q, kl, vl, page_tables, lengths)
+                # the whole pool viewed flat over (layer, page), the
+                # page ids offset to this layer's: ``kp[li]`` would
+                # materialise the layer's pages before the read
+                a = paged_attention_auto(
+                    q, kp.reshape(-1, *kp.shape[2:]),
+                    vp.reshape(-1, *vp.shape[2:]),
+                    page_tables + li * kp.shape[1], lengths)
             with jax.named_scope("attn_out"):
                 hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
             with jax.named_scope("mlp"):

@@ -147,6 +147,19 @@ def test_kv_scratch_page_outside_pool():
     assert all(p != c.scratch_page for p in held[0])
 
 
+def test_pool_shape_is_page_major_and_what_replicas_hold(engine_and_model):
+    eng, m = engine_and_model
+    heads = m.cfg["n_heads"]
+    assert eng.pool_shape == (m.cfg["n_layers"], eng.num_pages + 1,
+                              eng.page_size, heads,
+                              m.cfg["d_model"] // heads)
+    for rep in eng._replicas:
+        assert rep.cache.scratch_page == eng.pool_shape[1] - 1
+        for pool in (rep.kp, rep.vp):
+            assert pool.shape == eng.pool_shape
+            assert pool.dtype == jnp.float32
+
+
 # -- engine vs oracle --------------------------------------------------
 def test_greedy_decode_matches_oracle(engine_and_model):
     eng, m = engine_and_model
@@ -733,23 +746,23 @@ def test_paged_attention_reference_matches_dense():
     heads, dh, ps, npg = 2, 8, 4, 3
     pool = 7
     q = jnp.asarray(rng.normal(size=(2, heads, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(heads, pool, ps, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(heads, pool, ps, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(pool, ps, heads, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(pool, ps, heads, dh)), jnp.float32)
     pt = jnp.asarray(rng.integers(0, pool, size=(2, npg)), jnp.int32)
     lengths = jnp.asarray([5, 12], jnp.int32)
     got = decode_attention.paged_attention_reference(q, kp, vp, pt,
                                                      lengths)
     for s in range(2):
         t = int(lengths[s])
-        k = np.concatenate([np.asarray(kp[:, pt[s, j]])
-                            for j in range(npg)], axis=1)[:, :t]
-        v = np.concatenate([np.asarray(vp[:, pt[s, j]])
-                            for j in range(npg)], axis=1)[:, :t]
+        k = np.concatenate([np.asarray(kp[pt[s, j]])
+                            for j in range(npg)], axis=0)[:t]
+        v = np.concatenate([np.asarray(vp[pt[s, j]])
+                            for j in range(npg)], axis=0)[:t]
         for h in range(heads):
-            logits = np.asarray(q[s, h]) @ k[h].T * dh ** -0.5
+            logits = np.asarray(q[s, h]) @ k[:, h].T * dh ** -0.5
             w = np.exp(logits - logits.max())
             w /= w.sum()
-            want = w @ v[h]
+            want = w @ v[:, h]
             assert np.allclose(np.asarray(got[s, h]), want, atol=1e-5)
 
 
@@ -768,8 +781,8 @@ def test_kernel_parity_every_decode_ladder_shape(slots):
 def test_paged_attention_auto_uses_reference_off_tpu():
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.normal(size=(1, 2, 8)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(2, 5, 4, 8)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(2, 5, 4, 8)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(5, 4, 2, 8)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(5, 4, 2, 8)), jnp.float32)
     pt = jnp.asarray([[0, 1, 2]], jnp.int32)
     lengths = jnp.asarray([9], jnp.int32)
     auto = decode_attention.paged_attention_auto(q, kp, vp, pt, lengths)

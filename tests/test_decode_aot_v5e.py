@@ -1,0 +1,140 @@
+"""The serving steps, compiled for a v5e from the CPU, touch the KV pool
+only through their in-place scatters.
+
+The TPU's compiler is installed and compiles for a chip that is described
+and not attached (the recipe of ``benchmark/tests/test_aot_v5e.py``);
+nothing runs.  At the widths of galactica-6.7b cut to six layers, eight
+slots of 2048 positions (the benchmark's serving cells), the page-major
+pool of ``DecodeEngine.pool_shape`` must leave the compiled decode and
+prefill programs without a copy of the pool and without a per-layer
+slice of it: a head-major pool cost four whole-pool copies a step and
+twelve layer-sized slice fusions (PERF.md, PR 25).
+"""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dist_keras_tpu.models.transformer import (
+    init_transformer_params,
+    transformer_config,
+)
+from dist_keras_tpu.serving.decode import DecodeEngine
+
+GB = 1e9
+VOCAB, SEQ, SLOTS, PAGE = 50000, 2048, 8, 8
+CFG = transformer_config(input_dim=VOCAB, seq_len=SEQ, d_model=4096,
+                         n_heads=32, n_layers=6, d_ff=16384,
+                         n_classes=VOCAB)
+PAGES_PER_SEQ = SEQ // PAGE
+# results that may be as large as the pool: the arguments, views of them
+# that move nothing, and the scatters that update them in place
+FREE = {"parameter", "bitcast", "get-tuple-element", "tuple"}
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = \(?[a-z0-9]+\[([0-9,]*)\]\S* "
+    r"([\w\-]+)\(")
+
+
+def _instructions(text):
+    """(computation, name, elements, opcode, line) of every instruction
+    with an array result; a tuple result counts by its first element."""
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            yield comp, m.group(1), math.prod(dims), m.group(3), line
+
+
+def _roots(text):
+    """computation -> the opcode of its ROOT instruction."""
+    roots = {}
+    for comp, _, _, opcode, line in _instructions(text):
+        if line.lstrip().startswith("ROOT "):
+            roots[comp] = opcode
+    return roots
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The program picks its Pallas kernels by ``jax.default_backend()``,
+    which still says cpu here; and a compile for a described chip can be
+    written to the persistent cache but never read back without it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("phase,rung", [("decode", SLOTS), ("prefill", 128),
+                                        ("prefill", 2048)])
+def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    # the two step bodies and the pool's shape only: no weights are made
+    engine = DecodeEngine.__new__(DecodeEngine)
+    engine.cfg, engine.vocab = CFG, VOCAB
+    engine.page_size, engine.num_pages = PAGE, SLOTS * PAGES_PER_SEQ
+    pool_shape = engine.pool_shape
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_transformer_params(k, CFG),
+                       jax.random.PRNGKey(0)))
+    pool = S(pool_shape, jnp.float32)
+    if phase == "decode":
+        fn, args = engine._decode_fn, (
+            S((rung,)), S((rung,)), S((rung, PAGES_PER_SEQ)), S((rung,)),
+            S((rung,)), S((rung,)))
+    else:
+        fn, args = engine._prefill_fn, (
+            S((rung,)), S(()), S((rung,)), S((rung,)))
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool, pool, *args).compile()
+    text = compiled.as_text()
+
+    pool_elems = math.prod(pool_shape)
+    layer_elems = pool_elems // pool_shape[0]
+    roots = _roots(text)
+    scatters, offenders = 0, []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in (pool_elems, layer_elems) or opcode in FREE:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        in_place = opcode == "scatter" or (
+            opcode == "fusion" and called
+            and roots.get(called.group(1)) == "scatter")
+        if elems == pool_elems and in_place:
+            scatters += opcode == "scatter"     # one at each fusion's root
+            continue
+        offenders.append(f"{comp}: %{name} = {opcode} of "
+                         f"{'pool' if elems == pool_elems else 'layer'} size")
+    assert not offenders, offenders
+    # the parse saw the program: K and V are written once a layer
+    assert scatters == 2 * CFG["n_layers"], scatters
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * 4 * pool_elems      # both donated
+    assert m.temp_size_in_bytes < 1.0 * GB, m.temp_size_in_bytes

@@ -20,7 +20,7 @@ from dist_keras_tpu.serving.decode import DecodeEngine
 
 DECODE_REGIONS = tuple(p for p in perf.PHASES if p.startswith("decode."))
 DECODE_SCOPES = ("embed", "qkv", "kv_write", "attend", "attn_out", "mlp",
-                 "head")      # and kv_slice, in the decode step alone
+                 "head")
 
 
 def _engine(**kw):
@@ -285,7 +285,6 @@ def test_serving_steps_carry_their_scopes(engine, phase):
             jnp.zeros((8,), i32), jnp.zeros((8,), i32))
     found, _ = _scopes_of(lowered, f"_{phase}_fn")
     assert set(DECODE_SCOPES) <= found, found
-    assert ("kv_slice" in found) == (phase == "decode")
 
 
 def test_train_step_carries_its_scopes():
