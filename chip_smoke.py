@@ -311,8 +311,7 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
     try:
         # each replica on its own device, pools and params included
         for rep in eng._replicas:
-            on = {d for leaf in jax.tree.leaves((rep.kp, rep.vp,
-                                                 rep.params))
+            on = {d for leaf in jax.tree.leaves((rep.pools, rep.params))
                   for d in leaf.devices()}
             _check(on == {rep.device},
                    f"stage C: replica {rep.index} holds arrays on "
@@ -325,11 +324,11 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
         vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
         top, slots = prefill_ladder[-1], decode_ladder[-1]
         prefill_text = eng._prefill_jit.lower(
-            rep0.params, rep0.kp, rep0.vp, vec(top),
+            rep0.params, *rep0.pools, vec(top),
             jax.ShapeDtypeStruct((), jnp.int32), vec(top),
             vec(top)).as_text()
         decode_text = eng._decode_jit.lower(
-            rep0.params, rep0.kp, rep0.vp, vec(slots), vec(slots),
+            rep0.params, *rep0.pools, vec(slots), vec(slots),
             jax.ShapeDtypeStruct((slots, eng.max_pages_per_seq),
                                  jnp.int32),
             vec(slots), vec(slots), vec(slots)).as_text()

@@ -79,6 +79,19 @@ def _fans(shape):
 
 
 # --------------------------------------------------------------------------
+# expert selection (parallel/moe.py at k = 1, models/mla_moe.py at k > 1)
+# --------------------------------------------------------------------------
+
+def select_top_k(scores, bias, k):
+    """Choose ``k`` experts a token by ``scores + bias`` -> (ids (N, k)
+    int32, the chosen ``scores`` WITHOUT the bias (N, k)); of equal
+    scores the first wins."""
+    biased = scores if bias is None else scores + bias
+    _, idx = lax.top_k(biased, k)
+    return idx.astype(jnp.int32), jnp.take_along_axis(scores, idx, -1)
+
+
+# --------------------------------------------------------------------------
 # layer base + registry
 # --------------------------------------------------------------------------
 

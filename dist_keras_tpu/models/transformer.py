@@ -24,6 +24,10 @@ import numpy as np
 
 from dist_keras_tpu.models.layers import glorot_uniform
 from dist_keras_tpu.ops.attention import attention  # noqa: F401 (oracle)
+from dist_keras_tpu.ops.pallas.decode_attention import paged_attention_auto
+from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
+
+FAMILY = "transformer"
 
 
 def transformer_config(input_dim, seq_len, d_model=64, n_heads=4,
@@ -190,6 +194,116 @@ def transformer_apply(params, x, cfg, *, causal=False, attn_fn=None,
     logits, _ = transformer_apply_with_aux(
         params, x, cfg, causal=causal, attn_fn=attn_fn, remat=remat)
     return logits
+
+
+# -- what ``serving.decode.DecodeEngine`` takes from a block family -----
+# (``models/mla_moe.py`` has the same five names)
+def vocab(cfg):
+    """The vocabulary a decoder of ``cfg`` reads and writes; a config
+    this family cannot decode is refused here."""
+    if cfg.get("moe_experts", 0):
+        raise ValueError(
+            "a Transformer with Switch-MoE blocks (moe_experts > 0) "
+            "has no decode step: its top-1 routing drops tokens over "
+            "capacity.  The engine decodes dense Transformer blocks "
+            "and the models.mla_moe family (top-k experts, no drops)")
+    if cfg["input_dim"] != cfg["n_classes"]:
+        raise ValueError(
+            "a Transformer decodes with token-in == logit-out (its "
+            "embedding is one_hot(tokens) @ proj): "
+            f"input_dim={cfg['input_dim']} != "
+            f"n_classes={cfg['n_classes']}.  The models.mla_moe "
+            "family embeds by row gather and needs no such match")
+    return int(cfg["n_classes"])
+
+
+def cache_entry_shapes(cfg):
+    """The trailing shape of each pool a replica holds: a K and a V pool
+    of ``heads x head_dim`` entries."""
+    heads = cfg["n_heads"]
+    return ((heads, cfg["d_model"] // heads),) * 2
+
+
+def prefill_step(cfg, params, kp, vp, tokens, length, page_idx, page_off):
+    """One padded prompt -> (first generated token, updated pools).
+
+    ``tokens (T,) int32`` padded to a prefill rung; positions past
+    ``length`` write their K/V to the scratch page (``page_idx``
+    routes them there) and never influence position ``length - 1``
+    under the causal mask."""
+    t = tokens.shape[0]
+    with jax.named_scope("embed"):
+        x = jax.nn.one_hot(tokens, cfg["n_classes"], dtype=kp.dtype)
+        hs = (x @ params["proj"] + params["pos"][:t])[None]
+    for li, blk in enumerate(params["blocks"]):
+        with jax.named_scope("qkv"):
+            y = layer_norm(blk["ln1"], hs)
+            q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
+            k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
+            v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
+        with jax.named_scope("kv_write"):
+            # the scattered dimensions are the pool's major ones:
+            # in place on the donated pools, update (T, H, dh)
+            kp = kp.at[li, page_idx, page_off].set(k[0])
+            vp = vp.at[li, page_idx, page_off].set(v[0])
+        with jax.named_scope("attend"):
+            a = attention_auto(q, k, v, causal=True)
+        with jax.named_scope("attn_out"):
+            hs = hs + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
+        with jax.named_scope("mlp"):
+            y = layer_norm(blk["ln2"], hs)
+            u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
+            hs = hs + u @ blk["w2"] + blk["b2"]
+    with jax.named_scope("head"):
+        hf = layer_norm(params["ln_f"], hs)[0, length - 1]
+        logits = (hf @ params["head"]["kernel"]
+                  + params["head"]["bias"])
+        first = jnp.argmax(logits).astype(jnp.int32)
+    return first, kp, vp
+
+
+def decode_step(cfg, params, kp, vp, tokens, positions, page_tables,
+                write_page, write_off, lengths):
+    """One token step for a padded slot set -> (next tokens,
+    updated pools).  Padding slots carry ``length == 0`` and write
+    to the scratch page; the paged attention's dead-row guard
+    makes their output exact zeros (then discarded)."""
+    with jax.named_scope("embed"):
+        hs = (jax.nn.one_hot(tokens, cfg["n_classes"], dtype=kp.dtype)
+              @ params["proj"] + params["pos"][positions])
+    for li, blk in enumerate(params["blocks"]):
+        with jax.named_scope("qkv"):
+            y = layer_norm(blk["ln1"], hs)
+            q = jnp.einsum("sd,dhk->shk", y, blk["wq"])
+            k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
+            v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
+        with jax.named_scope("kv_write"):
+            kp = kp.at[li, write_page, write_off].set(k)
+            vp = vp.at[li, write_page, write_off].set(v)
+        with jax.named_scope("attend"):
+            # the whole pool viewed flat over (layer, page), the
+            # page ids offset to this layer's: ``kp[li]`` would
+            # materialise the layer's pages before the read
+            a = paged_attention_auto(
+                q, kp.reshape(-1, *kp.shape[2:]),
+                vp.reshape(-1, *vp.shape[2:]),
+                page_tables + li * kp.shape[1], lengths)
+        with jax.named_scope("attn_out"):
+            hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
+        with jax.named_scope("mlp"):
+            y = layer_norm(blk["ln2"], hs)
+            u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
+            hs = hs + u @ blk["w2"] + blk["b2"]
+    with jax.named_scope("head"):
+        hf = layer_norm(params["ln_f"], hs)
+        logits = (hf @ params["head"]["kernel"]
+                  + params["head"]["bias"])
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt, kp, vp
+
+
+# this family's steps send no counts behind their tokens
+observe_step = None
 
 
 class Transformer:

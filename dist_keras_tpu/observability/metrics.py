@@ -352,6 +352,15 @@ KNOWN_METRICS = {
     "decode.queue_wait_s": "histogram",
     "decode.active": "gauge",
     "decode.kv_used_pages": "gauge",
+    # the latent-attention, sparse-expert family's steps
+    # (models/mla_moe.py): routing counts that come off the device behind
+    # a step's tokens; the histograms one sample a decode step, stamped
+    # like decode.step_s
+    "decode.moe.pairs_total": "counter",
+    "decode.moe.pairs_held": "counter",
+    "decode.moe.load_max_over_mean": "histogram",
+    "decode.moe.experts_hit": "histogram",
+    "decode.latent.live_positions": "histogram",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

@@ -12,6 +12,9 @@ dispatch predicate) rather than re-deriving any of it:
 - :func:`paged_attention_reference` — pure-jnp oracle: gather the page
   table, mask positions at/after each sequence's length, softmax.  The
   DEFAULT serving path on every backend, and the parity baseline.
+- :func:`latent_attention_reference` — the same read over a LATENT pool
+  (``models/mla_moe.py``): every head's absorbed query against one shared
+  row a position, whose leading values are also the "values".
 - :func:`paged_attention_kernel` — the Pallas kernel.  Grid ``(slots,
   pages)`` with the page dim innermost carrying the online-softmax
   scratch of all heads; the page table and per-slot lengths ride as
@@ -92,6 +95,45 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
     out = (jnp.einsum("sht,sthd->shd", p, v)
            / jnp.maximum(l, 1e-30))
     return out.astype(q.dtype)
+
+
+def latent_attention_reference(q, latent_pages, page_table, lengths, *,
+                               rank, scale):
+    """The read of a LATENT pool, absorbed form, pure ``jnp``.
+
+    One row a cached position, shared by every head: its first ``rank``
+    values are the normalised latent (key part and "values" alike), the
+    rest the rotated shared key and zeros up to whole lanes.  ``q (S, H,
+    R)`` are the absorbed queries (``q_nope w_uk^T | q_pe``, zeros
+    behind), ``latent_pages (P, page_size, R)`` the pool (page-major, any
+    number of layers' pages flat),
+    ``page_table`` / ``lengths`` as for :func:`paged_attention_reference`
+    -> ``sum p c`` ``(S, H, rank)``; a ``length == 0`` padding slot
+    yields exact zeros (the same dead-row guard).
+
+    Like the K/V reference it gathers each slot's whole table whatever
+    its length (ROADMAP S1).  The gathered rows pass an optimisation
+    barrier: without it the compiler moves the products' rounding of
+    their operands ahead of the gather and rounds the WHOLE pool, every
+    layer of it, once a read (10.9 ms of a 12.7 ms read at 32 slots x
+    6656 positions x 9 layers on a v5e, PR 27).
+    """
+    s, h, r = q.shape
+    rows = jax.lax.optimization_barrier(
+        latent_pages[page_table].reshape(s, -1, r))
+    t = rows.shape[1]
+    logits = (jnp.einsum("shr,str->sht", q, rows)
+              .astype(jnp.float32) * scale)
+    kpos = jnp.arange(t, dtype=jnp.int32)
+    mask = kpos[None, None, :] < lengths.astype(jnp.int32)[:, None, None]
+    logits = jnp.where(mask, logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.exp(logits - jnp.where(m <= _NEG_INF / 2, 0.0, m))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    # over the whole row, the rotated key's columns dropped afterwards: a
+    # slice of the gathered rows would be a second copy of them
+    out = jnp.einsum("sht,str->shr", p, rows)[..., :rank]
+    return (out / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

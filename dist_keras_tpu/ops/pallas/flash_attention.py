@@ -183,9 +183,12 @@ def _kv_index_map(causal, block_q, block_k, q_offset, kv_offset):
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, q_offset,
               kv_offset, interpret):
-    """q: (BH, Tq, D), k/v: (BH, Tk, D) -> (out (BH,Tq,D), lse (BH,Tq))."""
+    """q: (BH, Tq, D), k: (BH, Tk, D), v: (BH, Tk, Dv) -> (out (BH, Tq,
+    Dv), lse (BH, Tq, 1)).  The values may be narrower or wider than the
+    queries and keys (latent attention: 192 and 128); the backward is
+    written for ``Dv == D`` only."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
@@ -196,21 +199,21 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, q_offset,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             # lse rides as (BH, T, 1): mosaic wants last-two block dims
             # (8k, 128k) or full-dim, which (block_q, 1) satisfies
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _sds((bh, tq, d), q.dtype, q),
+            _sds((bh, tq, dv), q.dtype, q),
             _sds((bh, tq, 1), jnp.float32, q),
         ],
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, d), jnp.float32)],
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
         interpret=interpret,
         name=_kernel_name("flash_fwd"),
         **_compiler_params(interpret),
@@ -487,6 +490,13 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     if bq is None or bk is None:
         return _ref_with_lse(q, k, v, causal=causal, scale=scale,
                              q_offset=q_offset, kv_offset=kv_offset)
+    if v.shape[-1] != d:
+        # values of another width than queries and keys: forward only
+        # (the custom backward assumes one head_dim)
+        out, lse = _fwd_call(_to_bh(q), _to_bh(k), _to_bh(v), causal,
+                             scale, bq, bk, int(q_offset), int(kv_offset),
+                             interpret)
+        return _from_bh(out, b, h), lse.reshape(b, h, tq)
     out, lse = _flash_core(_to_bh(q), _to_bh(k), _to_bh(v), causal, scale,
                            bq, bk, int(q_offset), int(kv_offset), interpret)
     return _from_bh(out, b, h), lse.reshape(b, h, tq)  # lse (BH, T, 1)

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from dist_keras_tpu.models.layers import glorot_uniform
+from dist_keras_tpu.models.layers import glorot_uniform, select_top_k
 
 EXPERT_AXIS = "experts"
 
@@ -66,8 +66,10 @@ def _route(params, x, num_experts, capacity):
     """
     logits = x @ params["router"]                      # (N, E)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate = jnp.max(probs, axis=-1)                     # (N,)
-    expert = jnp.argmax(probs, axis=-1)                # (N,)
+    # the one top-k selection the repo has (models/layers.py), at k = 1
+    # and with no selection bias: the first of equal scores wins, as
+    # argmax chose
+    expert, gate = (a[:, 0] for a in select_top_k(probs, None, 1))
     # queue position of each token within its chosen expert — int32
     # cumsum: exact for any token count (float32 cumsum loses exactness
     # past 2^24 tokens and would silently corrupt capacity assignment)

@@ -22,12 +22,18 @@ Design points (each mirrors an existing engine contract):
   (x replica devices, inherent) — the same no-retrace contract
   ``ServingEngine.stats()["retrace_count"]`` verifies, reported the
   same way.
-- **Paged KV.**  Each replica owns one K and one V pool array of shape
-  :attr:`DecodeEngine.pool_shape`, ``(layers, num_pages + 1, page_size,
-  heads, head_dim)`` — page-major, so that the steps' scatters over
-  (page, offset) update the donated pools in place and the attention
-  reads whole pages with no copy of the pool or of a layer of it — and a
-  :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache` allocator.
+- **Paged KV.**  Each replica owns the pools of its model's block
+  family (its ``cache_entry_shapes``: one K and one V pool of ``heads x
+  head_dim`` entries for ``models/transformer.py``, ONE pool of ``latent
+  + rope`` wide entries for ``models/mla_moe.py``), each of shape
+  ``(layers, num_pages + 1, page_size, *entry)`` — page-major, so that
+  the steps' scatters over (page, offset) update the donated pools in
+  place and the attention reads whole pages with no copy of the pool or
+  of a layer of it — and a
+  :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache` allocator.  The
+  family is named by the model's ``cfg`` and taken once, at construction
+  (``_FAMILIES``: its two step functions and its cache entry); the
+  scheduler, the allocator and recovery's replay see no family.
   Admission reserves a sequence's WORST-CASE page count up front, so
   decode never stalls mid-sequence on KV: exhaustion is a typed
   ``Overloaded(reason="kv_exhausted")`` strictly at the door (rejected,
@@ -92,13 +98,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models.transformer import layer_norm
+from dist_keras_tpu.models import mla_moe, transformer
 from dist_keras_tpu.observability import events, metrics, perf, spans
 from dist_keras_tpu.observability import slo as _slo
-from dist_keras_tpu.ops.pallas.decode_attention import (
-    paged_attention_auto,
-)
-from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 from dist_keras_tpu.resilience.faults import fault_point
 from dist_keras_tpu.serving.engine import Overloaded
 from dist_keras_tpu.serving.kv_cache import PagedKVCache, PagesExhausted
@@ -189,18 +191,29 @@ class Generation:
         return self.future.done()
 
 
-class _DecodeReplica:
-    """One replica: pinned device, params swap point, its KV pool."""
+# A block family is a module under ``models/`` with ``FAMILY`` (the name a
+# model's ``cfg["family"]`` gives; a cfg that names none is a
+# ``Transformer``'s), ``vocab(cfg)`` (which also refuses what the family
+# cannot decode), ``cache_entry_shapes(cfg)`` (the trailing shape of each
+# pool: what one cached position of one layer is), ``prefill_step`` /
+# ``decode_step`` (``(cfg, params, *pools, ...) -> (int32 array,
+# *pools)``: the tokens first, then whatever counts the family sends
+# along) and ``observe_step(counts, at, live_positions=None)`` for those
+# counts (None when the family sends none).
+_FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe)}
 
-    def __init__(self, index, device, params, cache, kp, vp):
+
+class _DecodeReplica:
+    """One replica: pinned device, params swap point, its KV pools."""
+
+    def __init__(self, index, device, params, cache, pools):
         self.index = index
         self.device = device
         self.params_host = params
         self.params = (jax.device_put(params, device)
                        if device is not None else params)
         self.cache = cache
-        self.kp = kp
-        self.vp = vp
+        self.pools = tuple(pools)
         self.queue = collections.deque()
         self.active = []
         self.retiring = False
@@ -231,14 +244,16 @@ class _DecodeReplica:
 
 
 class DecodeEngine:
-    """Continuous-batching decode over the causal Transformer.
+    """Continuous-batching decode over a causal decoder.
 
     Args:
-      keras_model: a ``models.transformer.Transformer`` (or anything
-        the serialization layer round-trips to one).  Decode needs
-        token in == logit out, so the config must have
-        ``input_dim == n_classes`` (the vocabulary); MoE configs are
-        rejected.
+      keras_model: a ``models.transformer.Transformer`` or a
+        ``models.mla_moe.LatentMoEDecoder`` (or anything the
+        serialization layer round-trips to one); its ``cfg`` names the
+        block family.  A ``Transformer`` decodes with token in == logit
+        out, so its config must have ``input_dim == n_classes`` (the
+        vocabulary) and dense feed-forwards (its Switch-MoE blocks drop
+        tokens over capacity and have no decode step).
       replicas: replica count (default: one per visible device).
       prefill_ladder: ascending fixed PROMPT shapes; a prompt runs
         padded to the smallest rung that fits (``ValueError`` past the
@@ -273,18 +288,15 @@ class DecodeEngine:
         cfg = getattr(model, "cfg", None)
         if cfg is None:
             raise ValueError(
-                "DecodeEngine needs the causal Transformer model "
-                "contract (a cfg dict); got "
+                "DecodeEngine needs a decoder's model contract (a cfg "
+                "dict: models.transformer.Transformer or "
+                f"models.mla_moe.LatentMoEDecoder); got "
                 f"{type(model).__name__}")
-        if cfg.get("moe_experts", 0):
-            raise ValueError("MoE configs are not decodable here")
-        if cfg["input_dim"] != cfg["n_classes"]:
-            raise ValueError(
-                "causal decode needs token-in == logit-out: "
-                f"input_dim={cfg['input_dim']} != "
-                f"n_classes={cfg['n_classes']}")
         self.cfg = cfg
-        self.vocab = int(cfg["n_classes"])
+        # the model's block family, looked up once: everything below
+        # this line sees pools and steps, no family
+        self._family = _FAMILIES[cfg.get("family", transformer.FAMILY)]
+        self.vocab = self._family.vocab(cfg)
         self.seq_len = int(cfg["seq_len"])
         self._host_params = model.params
 
@@ -311,11 +323,13 @@ class DecodeEngine:
         self.num_pages = int(num_pages)
 
         # donation keeps the pool update in place: a dispatch consumes
-        # the replica's kp/vp and returns their successors
+        # the replica's pools and returns their successors
+        donated = tuple(
+            range(1, 1 + len(self._family.cache_entry_shapes(cfg))))
         self._prefill_jit = jax.jit(self._prefill_fn,
-                                    donate_argnums=(1, 2))
+                                    donate_argnums=donated)
         self._decode_jit = jax.jit(self._decode_fn,
-                                   donate_argnums=(1, 2))
+                                   donate_argnums=donated)
 
         if devices is None:
             devices = jax.devices()
@@ -396,18 +410,31 @@ class DecodeEngine:
         for t in self._workers:
             t.start()
 
-    # -- model math (jitted once per ladder rung) -----------------------
+    # -- the family's model math (jitted once per ladder rung) ----------
+    def _prefill_fn(self, params, *args):
+        """``(params, *pools, tokens, length, page_idx, page_off)``: one
+        padded prompt -> (int32 array, the first token in front, *updated
+        pools)."""
+        return self._family.prefill_step(self.cfg, params, *args)
+
+    def _decode_fn(self, params, *args):
+        """``(params, *pools, tokens, positions, page_tables, write_page,
+        write_off, lengths)``: one token step for a padded slot set ->
+        (int32 array, the next tokens in front, *updated pools)."""
+        return self._family.decode_step(self.cfg, params, *args)
+
     @property
-    def pool_shape(self):
-        """The shape of a replica's K pool and of its V pool (float32),
-        page-major: ``(layers, num_pages + 1, page_size, heads,
-        head_dim)``; page index ``num_pages`` is the scratch page.  The
-        one statement of the layout: replicas allocate from it and the
-        jitted steps read everything else off the pools they are given.
-        """
-        heads = self.cfg["n_heads"]
-        return (self.cfg["n_layers"], self.num_pages + 1, self.page_size,
-                heads, self.cfg["d_model"] // heads)
+    def pool_shapes(self):
+        """The shape of each pool a replica holds (float32), page-major:
+        ``(layers, num_pages + 1, page_size, *entry)`` for every entry of
+        the family's ``cache_entry_shapes``; page index ``num_pages`` is
+        the scratch page.  The one statement of the layout: replicas
+        allocate from it and the jitted steps read everything else off
+        the pools they are given."""
+        return tuple(
+            (self.cfg["n_layers"], self.num_pages + 1, self.page_size)
+            + tuple(entry)
+            for entry in self._family.cache_entry_shapes(self.cfg))
 
     def _make_replica(self, index):
         devs = self._devices
@@ -415,87 +442,10 @@ class DecodeEngine:
         cache = PagedKVCache(self.num_pages, self.page_size)
         # allocated ON the replica's device: N pools staged through the
         # default device would cost it N pools of peak memory
-        kp = jnp.zeros(self.pool_shape, jnp.float32, device=device)
-        vp = jnp.zeros(self.pool_shape, jnp.float32, device=device)
+        pools = [jnp.zeros(shape, jnp.float32, device=device)
+                 for shape in self.pool_shapes]
         return _DecodeReplica(index, device, self._host_params, cache,
-                              kp, vp)
-
-    def _prefill_fn(self, params, kp, vp, tokens, length, page_idx,
-                    page_off):
-        """One padded prompt -> (first generated token, updated pools).
-
-        ``tokens (T,) int32`` padded to a prefill rung; positions past
-        ``length`` write their K/V to the scratch page (``page_idx``
-        routes them there) and never influence position ``length - 1``
-        under the causal mask."""
-        t = tokens.shape[0]
-        with jax.named_scope("embed"):
-            x = jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
-            hs = (x @ params["proj"] + params["pos"][:t])[None]
-        for li, blk in enumerate(params["blocks"]):
-            with jax.named_scope("qkv"):
-                y = layer_norm(blk["ln1"], hs)
-                q = jnp.einsum("btd,dhk->bthk", y, blk["wq"])
-                k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
-                v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
-            with jax.named_scope("kv_write"):
-                # the scattered dimensions are the pool's major ones:
-                # in place on the donated pools, update (T, H, dh)
-                kp = kp.at[li, page_idx, page_off].set(k[0])
-                vp = vp.at[li, page_idx, page_off].set(v[0])
-            with jax.named_scope("attend"):
-                a = attention_auto(q, k, v, causal=True)
-            with jax.named_scope("attn_out"):
-                hs = hs + jnp.einsum("bthk,hkd->btd", a, blk["wo"])
-            with jax.named_scope("mlp"):
-                y = layer_norm(blk["ln2"], hs)
-                u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
-                hs = hs + u @ blk["w2"] + blk["b2"]
-        with jax.named_scope("head"):
-            hf = layer_norm(params["ln_f"], hs)[0, length - 1]
-            logits = (hf @ params["head"]["kernel"]
-                      + params["head"]["bias"])
-            first = jnp.argmax(logits).astype(jnp.int32)
-        return first, kp, vp
-
-    def _decode_fn(self, params, kp, vp, tokens, positions, page_tables,
-                   write_page, write_off, lengths):
-        """One token step for a padded slot set -> (next tokens,
-        updated pools).  Padding slots carry ``length == 0`` and write
-        to the scratch page; the paged attention's dead-row guard
-        makes their output exact zeros (then discarded)."""
-        with jax.named_scope("embed"):
-            hs = (jax.nn.one_hot(tokens, self.vocab, dtype=kp.dtype)
-                  @ params["proj"] + params["pos"][positions])
-        for li, blk in enumerate(params["blocks"]):
-            with jax.named_scope("qkv"):
-                y = layer_norm(blk["ln1"], hs)
-                q = jnp.einsum("sd,dhk->shk", y, blk["wq"])
-                k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
-                v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
-            with jax.named_scope("kv_write"):
-                kp = kp.at[li, write_page, write_off].set(k)
-                vp = vp.at[li, write_page, write_off].set(v)
-            with jax.named_scope("attend"):
-                # the whole pool viewed flat over (layer, page), the
-                # page ids offset to this layer's: ``kp[li]`` would
-                # materialise the layer's pages before the read
-                a = paged_attention_auto(
-                    q, kp.reshape(-1, *kp.shape[2:]),
-                    vp.reshape(-1, *vp.shape[2:]),
-                    page_tables + li * kp.shape[1], lengths)
-            with jax.named_scope("attn_out"):
-                hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
-            with jax.named_scope("mlp"):
-                y = layer_norm(blk["ln2"], hs)
-                u = jax.nn.gelu(y @ blk["w1"] + blk["b1"])
-                hs = hs + u @ blk["w2"] + blk["b2"]
-        with jax.named_scope("head"):
-            hf = layer_norm(params["ln_f"], hs)
-            logits = (hf @ params["head"]["kernel"]
-                      + params["head"]["bias"])
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, kp, vp
+                              pools)
 
     # -- admission ------------------------------------------------------
     def _rung_for(self, n, ladder):
@@ -785,11 +735,13 @@ class DecodeEngine:
         try:
             perf.count_dispatch()
             with perf.phase("decode.prefill.dispatch"):
-                first, rep.kp, rep.vp = self._prefill_jit(
-                    seq.params, rep.kp, rep.vp, jnp.asarray(toks),
+                first, *rep.pools = self._prefill_jit(
+                    seq.params, *rep.pools, jnp.asarray(toks),
                     jnp.int32(seq.prompt_len), jnp.asarray(page_idx),
                     jnp.asarray(page_off))
             with perf.phase("decode.prefill.wait"):
+                # the token, then whatever counts the family sends along
+                first, *counts = np.asarray(first).reshape(-1)
                 first = int(first)
         # dklint: ignore[broad-except] a failed prefill lands TYPED on its own future with pages reclaimed
         except Exception as e:
@@ -802,6 +754,8 @@ class DecodeEngine:
             return
         dt = time.perf_counter() - t0
         self._reg_prefill.observe(dt, at=t0)
+        if self._family.observe_step is not None:
+            self._family.observe_step(counts, t0)
         with self._cond:
             self._shapes.add(("prefill", rung))
             self._ewma_prefill = (
@@ -875,8 +829,8 @@ class DecodeEngine:
                 fault_point("decode.step")
                 perf.count_dispatch()
                 with perf.phase("decode.step.dispatch"):
-                    nxt, rep.kp, rep.vp = self._decode_jit(
-                        group[0].params, rep.kp, rep.vp,
+                    nxt, *rep.pools = self._decode_jit(
+                        group[0].params, *rep.pools,
                         jnp.asarray(toks), jnp.asarray(positions),
                         jnp.asarray(tables), jnp.asarray(wpage),
                         jnp.asarray(woff), jnp.asarray(lengths))
@@ -913,6 +867,11 @@ class DecodeEngine:
         rep.steps += 1
         self._m_step.observe(dt, at=t0)
         self._reg_step.observe(dt, at=t0)
+        if self._family.observe_step is not None:
+            # the counts came off the device behind the tokens, in the
+            # one array the wait already fetched
+            self._family.observe_step(nxt[rung:], t0,
+                                      live_positions=int(lengths.sum()))
         with perf.phase("decode.step.emit"):
             with self._cond:
                 self._shapes.add(("decode", rung))

@@ -1,10 +1,10 @@
 """Paged KV-cache allocator — fixed-size pages, free list, exact accounting.
 
 The decode engine (``serving/decode.py``) keeps each replica's attention
-keys/values in a page pool: a K and a V device array per replica of
-shape ``DecodeEngine.pool_shape``, ``(layers, num_pages + 1, page_size,
-heads, head_dim)`` — page-major: a page is one contiguous ``(page_size,
-heads, head_dim)`` block of one layer.  This module owns the HOST-side
+keys/values in page pools of the shapes ``DecodeEngine.pool_shapes``: for
+a ``Transformer`` a K and a V device array per replica of ``(layers,
+num_pages + 1, page_size, heads, head_dim)`` — page-major: a page is one
+contiguous ``(page_size, heads, head_dim)`` block of one layer.  This module owns the HOST-side
 accounting for that pool — which pages are free, which sequence holds
 which pages — so the device arrays never need compaction and a
 sequence's KV never moves once written (vLLM's PagedAttention layout,

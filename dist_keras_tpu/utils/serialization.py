@@ -50,6 +50,12 @@ def deserialize_model(d):
         model = Transformer(cfg=arch["config"])
         model.set_weights(d["weights"])
         return model
+    if arch.get("class_name") == "LatentMoEDecoder":
+        from dist_keras_tpu.models.mla_moe import LatentMoEDecoder
+
+        model = LatentMoEDecoder(cfg=arch["config"])
+        model.set_weights(d["weights"])
+        return model
     if arch.get("class_name") == "Sequential" and "layers" in arch and all(
             "class_name" in spec for spec in arch["layers"]):
         try:

@@ -150,13 +150,13 @@ def test_kv_scratch_page_outside_pool():
 def test_pool_shape_is_page_major_and_what_replicas_hold(engine_and_model):
     eng, m = engine_and_model
     heads = m.cfg["n_heads"]
-    assert eng.pool_shape == (m.cfg["n_layers"], eng.num_pages + 1,
-                              eng.page_size, heads,
-                              m.cfg["d_model"] // heads)
+    shape = (m.cfg["n_layers"], eng.num_pages + 1, eng.page_size, heads,
+             m.cfg["d_model"] // heads)
+    assert eng.pool_shapes == (shape, shape)    # K and V
     for rep in eng._replicas:
-        assert rep.cache.scratch_page == eng.pool_shape[1] - 1
-        for pool in (rep.kp, rep.vp):
-            assert pool.shape == eng.pool_shape
+        assert rep.cache.scratch_page == shape[1] - 1
+        assert tuple(pool.shape for pool in rep.pools) == eng.pool_shapes
+        for pool in rep.pools:
             assert pool.dtype == jnp.float32
 
 

@@ -5,7 +5,7 @@ The TPU's compiler is installed and compiles for a chip that is described
 and not attached (the recipe of ``benchmark/tests/test_aot_v5e.py``);
 nothing runs.  At the widths of galactica-6.7b cut to six layers, eight
 slots of 2048 positions (the benchmark's serving cells), the page-major
-pool of ``DecodeEngine.pool_shape`` must leave the compiled decode and
+pool of ``DecodeEngine.pool_shapes`` must leave the compiled decode and
 prefill programs without a copy of the pool and without a per-layer
 slice of it: a head-major pool cost four whole-pool copies a step and
 twelve layer-sized slice fusions (PERF.md, PR 25).
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from dist_keras_tpu.models import mla_moe, transformer
 from dist_keras_tpu.models.transformer import (
     init_transformer_params,
     transformer_config,
@@ -97,9 +98,9 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
 
     # the two step bodies and the pool's shape only: no weights are made
     engine = DecodeEngine.__new__(DecodeEngine)
-    engine.cfg, engine.vocab = CFG, VOCAB
+    engine.cfg, engine._family = CFG, transformer
     engine.page_size, engine.num_pages = PAGE, SLOTS * PAGES_PER_SEQ
-    pool_shape = engine.pool_shape
+    pool_shape, _ = engine.pool_shapes
     params = jax.tree.map(
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(lambda k: init_transformer_params(k, CFG),
@@ -138,3 +139,95 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == 2 * 4 * pool_elems      # both donated
     assert m.temp_size_in_bytes < 1.0 * GB, m.temp_size_in_bytes
+
+
+# -- the latent-attention, sparse-expert family (models/mla_moe.py) ------
+# kimi-vl-a3b-instruct as the benchmark cuts it: every width as published,
+# 1 dense + 8 expert layers, 8 of 64 experts held, 32 slots of 6656
+# positions in pages of 16
+L_SLOTS, L_POSITIONS, L_PAGE = 32, 6656, 16
+
+
+def _latent_cfg():
+    from dist_keras_tpu.models.mla_moe import mla_moe_config
+
+    return mla_moe_config(
+        vocab_size=163840, seq_len=L_POSITIONS, d_model=2048, n_heads=16,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=512, d_ff=11264, moe_d_ff=1408, n_routed_experts=64,
+        n_shared_experts=2, top_k=6, n_layers=9,
+        held_experts=list(range(8)), routed_scaling_factor=2.446,
+        rope_theta=800000.0)
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("decode", L_SLOTS, 0.5), ("prefill", 2560, 0.8), ("prefill", 6144, 1.5)])
+def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
+                                              temp_gb):
+    """The new family's steps hold no copy of the latent pool (rows of
+    whole lanes: at the entry's own 576 values the v5e's default layout
+    puts the pages minor and every step converts the pool twice, 4.4 GB
+    each way) and no vocabulary-sized temporary (the head's norm weight
+    folded into the head); their temporaries stay under ``temp_gb``: the
+    gathered pages of one layer in bfloat16 (0.27 GB) for the decode
+    step, the held experts' hidden rows of one layer for a prefill."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = _latent_cfg()
+    engine = DecodeEngine.__new__(DecodeEngine)
+    engine.cfg, engine._family = cfg, mla_moe
+    engine.page_size = L_PAGE
+    engine.num_pages = L_SLOTS * L_POSITIONS // L_PAGE
+    (pool_shape,) = engine.pool_shapes
+    assert pool_shape == (9, engine.num_pages + 1, L_PAGE, 640)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(mla_moe.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    pool = S(pool_shape, jnp.float32)
+    if phase == "decode":
+        fn, args = engine._decode_fn, (
+            S((rung,)), S((rung,)), S((rung, L_POSITIONS // L_PAGE)),
+            S((rung,)), S((rung,)), S((rung,)))
+    else:
+        fn, args = engine._prefill_fn, (
+            S((rung,)), S(()), S((rung,)), S((rung,)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    text = compiled.as_text()
+
+    pool_elems = math.prod(pool_shape)
+    big = {pool_elems: "pool", pool_elems // pool_shape[0]: "layer",
+           cfg["vocab_size"] * cfg["d_model"]: "vocabulary"}
+    roots = _roots(text)
+    scatters, offenders = 0, []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in big or opcode in FREE:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = roots.get(called.group(1)) if called else None
+        if opcode == "fusion" and called.group(1).startswith("bitcast"):
+            continue                                  # a view: moves nothing
+        if big[elems] == "vocabulary" and "fus" in comp:
+            # inside a fusion nothing of this size is written: a prefill's
+            # one row times the head is a multiply feeding its own reduce
+            continue
+        if big[elems] == "pool" and (opcode == "scatter" or (
+                opcode == "fusion" and root == "scatter")):
+            scatters += opcode == "scatter"
+            continue
+        offenders.append(f"{comp}: %{name} = {opcode} of {big[elems]} size")
+    assert not offenders, offenders
+    assert scatters >= cfg["n_layers"], scatters     # one write a layer
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * pool_elems          # donated
+    assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
+    if phase == "prefill":
+        assert "flash_fwd" in text

@@ -275,13 +275,13 @@ def test_serving_steps_carry_their_scopes(engine, phase):
     i32 = jnp.int32
     if phase == "decode":
         lowered = engine._decode_jit.lower(
-            rep.params, rep.kp, rep.vp, jnp.zeros((4,), i32),
+            rep.params, *rep.pools, jnp.zeros((4,), i32),
             jnp.zeros((4,), i32), jnp.zeros((4, 8), i32),
             jnp.zeros((4,), i32), jnp.zeros((4,), i32),
             jnp.zeros((4,), i32))
     else:
         lowered = engine._prefill_jit.lower(
-            rep.params, rep.kp, rep.vp, jnp.zeros((8,), i32), i32(3),
+            rep.params, *rep.pools, jnp.zeros((8,), i32), i32(3),
             jnp.zeros((8,), i32), jnp.zeros((8,), i32))
     found, _ = _scopes_of(lowered, f"_{phase}_fn")
     assert set(DECODE_SCOPES) <= found, found

@@ -1,0 +1,444 @@
+"""The latent-attention, sparse-expert decoder family (``models/mla_moe.py``)
+at a small size on the CPU, against the plain reference the benchmark
+keeps (``benchmark/reference/mla_moe_ref.py``): whole-sequence forward,
+prefill then decoding through ``DecodeEngine``'s paged latent pool, the
+routing case table, the share test, the pool's layout and the routing
+counters.  Logits are compared, not tokens.
+
+Tolerances.  Everything here is float32 on the CPU, the program under
+``jax.default_matmul_precision("highest")`` where it is compared (the
+reference sets it product by product), so program and reference differ by
+the order of float32 sums only: logits of magnitude up to 3 agree to
+about 3e-6, and the limit is 1e-4 (30 times the reading, five hundred
+times under the 0.05 by which a bfloat16 product moves such a logit).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.families import mla_moe as family
+from benchmark.reference import mla_moe_ref as ref
+from dist_keras_tpu.models import mla_moe, transformer
+from dist_keras_tpu.models.layers import select_top_k
+from dist_keras_tpu.models.transformer import Transformer, transformer_config
+from dist_keras_tpu.observability import metrics
+from dist_keras_tpu.serving import DecodeEngine
+
+TOL = 1e-4
+VOCAB = 128
+SIZES = dict(vocab_size=VOCAB, seq_len=48, d_model=64, n_heads=4,
+             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+             kv_lora_rank=32, d_ff=96, moe_d_ff=48, n_routed_experts=8,
+             n_shared_experts=1, top_k=3, n_layers=3, first_k_dense=1,
+             routed_scaling_factor=2.446, rope_theta=800000.0)
+
+
+def config(held=(2, 3, 4), **kw):
+    return mla_moe.mla_moe_config(**{**SIZES, "held_experts": list(held),
+                                     **kw})
+
+
+def weights_for(cfg, seed=2 ** 31 + 7):
+    """The benchmark's seeded weights: the ones a chip run hands to the
+    program and to the reference alike."""
+    from benchmark import weights
+
+    return family.tree(weights.base_key(seed), cfg)
+
+
+def reference_logits(params, tokens, cfg, **kw):
+    return ref.forward(params, jnp.asarray(tokens),
+                       family.reference_config(cfg),
+                       tuple(cfg["held_experts"]), **kw)
+
+
+def engine_for(cfg, params, **kw):
+    model = mla_moe.LatentMoEDecoder(cfg=cfg)
+    model.set_params(params)
+    kw.setdefault("replicas", 1)
+    kw.setdefault("prefill_ladder", (8, 16, 32))
+    kw.setdefault("decode_ladder", (1, 4))
+    kw.setdefault("page_size", 4)
+    return DecodeEngine(model, **kw)
+
+
+@pytest.fixture
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# -- (1) whole-sequence forward ----------------------------------------
+@pytest.mark.parametrize("seed", [1, 2 ** 31 + 7])
+def test_forward_equals_the_reference(highest, seed):
+    cfg = config()
+    params = weights_for(cfg, seed)
+    tokens = np.random.default_rng(seed).integers(0, VOCAB, 40)
+    got = mla_moe.forward(params, jnp.asarray(tokens), cfg)
+    want = reference_logits(params, tokens, cfg)
+    assert got.shape == (40, VOCAB)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def test_benchmark_weights_are_in_the_programs_layout():
+    cfg = config()
+    mine = jax.eval_shape(lambda: weights_for(cfg))
+    theirs = jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg))
+    assert jax.tree.structure(mine) == jax.tree.structure(theirs)
+    assert [a.shape for a in jax.tree.leaves(mine)] == \
+        [a.shape for a in jax.tree.leaves(theirs)]
+
+
+def test_serialization_round_trip_holds_no_second_set_of_weights():
+    from dist_keras_tpu.utils.serialization import (
+        deserialize_model,
+        serialize_model,
+    )
+
+    cfg = config()
+    model = mla_moe.LatentMoEDecoder(cfg=cfg, seed=3)
+    back = deserialize_model(serialize_model(model))
+    assert isinstance(back, mla_moe.LatentMoEDecoder) and back.cfg == cfg
+    for a, b in zip(jax.tree.leaves(model.params),
+                    jax.tree.leaves(back.params)):
+        np.testing.assert_array_equal(a, b)
+    fresh = mla_moe.LatentMoEDecoder(cfg=cfg)
+    assert fresh._params is None          # made on first use, not before
+    fresh.set_weights(model.get_weights())
+    assert fresh._params is not None
+
+
+# -- (2) prefill, then decoding through the paged latent pool ----------
+def test_steps_over_the_pool_equal_the_reference_at_every_position(highest):
+    """Teacher-forced: two sequences prefilled into scattered pages, then
+    stepped together on a 4-slot rung whose other two slots are padding;
+    the first crosses a page boundary (positions 6..13, pages of 4).  The
+    logits of every step equal the reference's full forward."""
+    cfg = config()
+    params = weights_for(cfg)
+    rng = np.random.default_rng(5)
+    seqs = [rng.integers(0, VOCAB, 14), rng.integers(0, VOCAB, 17)]
+    prompts = [6, 9]
+    ps, n_pages = 4, 12
+    pool = jnp.zeros((cfg["n_layers"], n_pages + 1, ps)
+                     + mla_moe.cache_entry_shapes(cfg)[0])
+    pages = [[7, 2, 9, 4, 0], [5, 11, 1, 8, 3]]       # scratch page is 12
+    for toks, n, mine in zip(seqs, prompts, pages):
+        rung = 16
+        padded = np.zeros((rung,), np.int32)
+        padded[:n] = toks[:n]
+        page_idx = np.full((rung,), n_pages, np.int32)
+        page_idx[:n] = [mine[t // ps] for t in range(n)]
+        out, pool = mla_moe.prefill_step(
+            cfg, params, pool, jnp.asarray(padded), jnp.int32(n),
+            jnp.asarray(page_idx), jnp.arange(rung, dtype=jnp.int32) % ps)
+        want = reference_logits(params, toks[:n], cfg)[-1]
+        assert int(out[0]) == int(jnp.argmax(want))
+    wants = [reference_logits(params, toks, cfg) for toks in seqs]
+    for step in range(8):
+        at = [n + step for n in prompts]
+        tables = np.zeros((4, 5), np.int32)
+        tables[0], tables[1] = pages
+        lengths = np.array([at[0] + 1, at[1] + 1, 0, 0], np.int32)
+        hs, counts, pool = mla_moe._decode_layers(
+            cfg, params, pool,
+            jnp.asarray([seqs[0][at[0]], seqs[1][at[1]], 0, 0], jnp.int32),
+            jnp.asarray(at + [0, 0], jnp.int32), jnp.asarray(tables),
+            jnp.asarray([pages[0][at[0] // ps], pages[1][at[1] // ps],
+                         n_pages, n_pages], jnp.int32),
+            jnp.asarray([at[0] % ps, at[1] % ps, 0, 0], jnp.int32),
+            jnp.asarray(lengths))
+        got = mla_moe._logits(params, hs, cfg)
+        for slot in (0, 1):
+            np.testing.assert_allclose(got[slot], wants[slot][at[slot]],
+                                       atol=TOL, rtol=0)
+        # padding slots reached no expert: two real tokens, two layers
+        assert int(counts[-1]) == 2 * cfg["top_k"] * 2
+
+
+def test_engine_tokens_are_the_references_own(highest):
+    """Through ``DecodeEngine`` itself: three requests of different
+    lengths on the 4-slot rung (one padding slot), replies that cross
+    page boundaries; every served token's logit is the reference's best
+    to within the tolerance."""
+    cfg = config()
+    params = weights_for(cfg)
+    rng = np.random.default_rng(11)
+    with engine_for(cfg, params) as eng:
+        gens = [eng.submit_generate(rng.integers(0, VOCAB, n).tolist(),
+                                    max_new_tokens=m)
+                for n, m in ((7, 12), (13, 9), (3, 14))]
+        docs = [g.result(timeout=600) for g in gens]
+        assert ("decode", 4) in eng.stats()["shapes_dispatched"]
+    for doc in docs:
+        z = np.asarray(reference_logits(params, doc["tokens"][:-1], cfg))
+        z = z[doc["prompt_len"] - 1:]
+        gap = z.max(axis=1) - z[np.arange(len(z)), doc["generated"]]
+        assert gap.max() <= TOL, gap
+    eng.assert_no_leaks()
+
+
+# -- (3) the two forms of the attention --------------------------------
+@pytest.mark.parametrize("q_block", [None, 16])
+def test_absorbed_and_unabsorbed_attention_agree_in_the_reference(q_block):
+    cfg = config()
+    conf = family.reference_config(cfg)
+    blk = weights_for(cfg)["blocks"][1]
+    y = jax.random.normal(jax.random.PRNGKey(3), (40, cfg["d_model"]))
+    plain = ref.attention(blk, y, conf, q_block=q_block)
+    absorbed = ref.attention(blk, y, conf, q_block=q_block, absorbed=True)
+    # the same numbers in exact arithmetic; float32 sums in another order
+    np.testing.assert_allclose(plain, absorbed, atol=1e-5, rtol=0)
+
+
+# -- (4) routing --------------------------------------------------------
+def _moe_with(router, bias, cfg):
+    moe = weights_for(cfg)["blocks"][1]["moe"]
+    return {**moe, "router": jnp.asarray(router, jnp.float32),
+            "router_bias": jnp.asarray(bias, jnp.float32)}
+
+
+def _logit(p):
+    return float(np.log(p / (1 - p)))
+
+
+ROUTING = {
+    # scores s by expert, selection bias b -> the chosen experts
+    "by_score": ([.9, .8, .7, .6, .5, .4, .3, .2], [0] * 8, [0, 1, 2]),
+    "bias_lifts_a_lower_score": ([.9, .8, .7, .6, .5, .4, .3, .2],
+                                 [0, 0, 0, 0, 0, 0, 0, .65], [0, 1, 7]),
+    "bias_sinks_the_best": ([.9, .8, .7, .6, .5, .4, .3, .2],
+                            [-.5, 0, 0, 0, 0, 0, 0, 0], [1, 2, 3]),
+    "first_of_equals": ([.5] * 8, [0] * 8, [0, 1, 2]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTING))
+def test_routing_selects_by_score_plus_bias_and_weighs_by_score(case):
+    scores, bias, chosen = ROUTING[case]
+    cfg = config(d_model=8, n_heads=1)
+    # x = e_0, router row 0 = logit(s): the scores are exactly ``scores``
+    router = np.zeros((8, 8), np.float32)
+    router[0] = [_logit(p) for p in scores]
+    x = jnp.zeros((1, 8)).at[0, 0].set(1.0)
+    idx, w = mla_moe.route(_moe_with(router, bias, cfg), x, cfg)
+    assert sorted(np.asarray(idx[0]).tolist()) == chosen
+    s = np.asarray(scores)[np.asarray(idx[0])]
+    # weights from s alone (no bias), normalised, times the scaling factor
+    np.testing.assert_allclose(
+        w[0], s / s.sum() * cfg["routed_scaling_factor"], rtol=1e-5)
+    want_idx, want_w = ref.routing(_moe_with(router, bias, cfg), x,
+                                   family.reference_config(cfg))
+    assert sorted(np.asarray(want_idx[0]).tolist()) == chosen
+    np.testing.assert_allclose(np.sort(w[0]), np.sort(want_w[0]),
+                               rtol=1e-6)
+
+
+def test_every_token_to_one_expert_drops_none(highest):
+    """No capacity: 24 tokens whose three choices are all the same three
+    experts, two of them held; every pair is computed."""
+    cfg = config(held=(2, 3))
+    conf = family.reference_config(cfg)
+    moe = weights_for(cfg)["blocks"][1]["moe"]
+    moe = {**moe, "router": jnp.zeros_like(moe["router"]),
+           "router_bias": jnp.zeros((8,)).at[jnp.asarray([1, 2, 3])].set(1.)}
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, cfg["d_model"]))
+    got, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((24,), bool))
+    assert counts.tolist() == [24, 24, 2, 72]
+    want = ref.expert_layer(moe, x, conf, (2, 3))
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+    # padding tokens reach no expert and count nowhere
+    valid = jnp.arange(24) < 10
+    _, counts = mla_moe.moe_layer(moe, x, cfg, valid)
+    assert counts.tolist() == [10, 10, 2, 30]
+
+
+def test_switch_router_is_the_one_selection_at_k_1():
+    """``parallel/moe.py:_route`` chooses through ``select_top_k``: the
+    first of equal scores, as argmax did."""
+    from dist_keras_tpu.parallel.moe import _route, init_moe_params
+
+    params = init_moe_params(jax.random.PRNGKey(0), 16, 32, 4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (12, 16))
+    dispatch, combine, _ = _route(params, x, 4, 12)
+    probs = jax.nn.softmax(x @ params["router"], -1)
+    np.testing.assert_array_equal(dispatch.sum(-1).argmax(-1),
+                                  probs.argmax(-1))
+    np.testing.assert_allclose(combine.sum((-1, -2)), probs.max(-1),
+                               rtol=1e-6)
+    idx, s = select_top_k(jnp.ones((3, 5)), None, 1)
+    assert idx.tolist() == [[0]] * 3 and s.tolist() == [[1.0]] * 3
+
+
+# -- (5) the share -------------------------------------------------------
+@pytest.mark.parametrize("per_share", [1, 2, 4])
+def test_shares_add_up_to_the_uncut_layer(highest, per_share):
+    """The parts all the shares give, the shared expert counted once, add
+    up to the uncut layer of the reference."""
+    whole = config(held=range(8))
+    conf = family.reference_config(whole)
+    moe = weights_for(whole)["blocks"][2]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(9), (20, whole["d_model"]))
+    valid = jnp.ones((20,), bool)
+    uncut = ref.expert_layer(moe, x, conf, tuple(range(8)))
+    shared = mla_moe.swiglu(moe["shared"], x)
+    total, pairs = jnp.zeros_like(uncut), 0
+    for first in range(0, 8, per_share):
+        held = tuple(range(first, first + per_share))
+        mine = {**moe, "experts": jax.tree.map(
+            lambda leaf: leaf[first:first + per_share], moe["experts"])}
+        part, counts = mla_moe.moe_layer(mine, x, config(held=held), valid)
+        # the program's share is the reference's share
+        np.testing.assert_allclose(
+            part, ref.expert_layer(mine, x, conf, held), atol=TOL, rtol=0)
+        total = total + (part - shared)
+        pairs += int(counts[:per_share].sum())
+    np.testing.assert_allclose(total + shared, uncut, atol=TOL, rtol=0)
+    assert pairs == 20 * whole["top_k"]     # every chosen pair, once
+
+
+# -- (6) the pool ---------------------------------------------------------
+def test_pool_shape_is_one_latent_pool_and_the_old_family_is_unchanged():
+    cfg = config()
+    width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    lanes = -(-width // mla_moe.LANES) * mla_moe.LANES
+    assert mla_moe.cache_entry_shapes(cfg) == ((lanes,),)
+    with engine_for(cfg, weights_for(cfg), num_pages=20) as eng:
+        assert eng.pool_shapes == ((cfg["n_layers"], 21, 4, lanes),)
+        (pool,) = eng._replicas[0].pools
+        assert (pool.shape,) == eng.pool_shapes
+        assert pool.dtype == jnp.float32
+        # a mixed run: short and long prompts, one cancelled mid-way
+        rng = np.random.default_rng(2)
+        gens = [eng.submit_generate(rng.integers(0, VOCAB, n).tolist(),
+                                    max_new_tokens=m)
+                for n, m in ((3, 4), (20, 6), (9, 10))]
+        gens[2].cancel()
+        for g in gens:
+            g.result(timeout=600)
+        for rep in eng._replicas:
+            rep.cache.assert_balanced()
+            assert rep.cache.used_pages() == 0
+        # the rows' lanes past the entry stay zero (the steps donated the
+        # pool looked at above: this is its successor)
+        (pool,) = eng._replicas[0].pools
+        assert float(jnp.abs(pool[..., width:]).max()) == 0.0
+    old = transformer_config(input_dim=16, seq_len=32, d_model=16, n_heads=2,
+                             n_layers=2, n_classes=16)
+    assert transformer.cache_entry_shapes(old) == ((2, 8), (2, 8))
+    with DecodeEngine(Transformer(old), replicas=1, prefill_ladder=(4,),
+                      decode_ladder=(1,), page_size=4) as eng:
+        assert eng.pool_shapes == ((2, eng.num_pages + 1, 4, 2, 8),) * 2
+
+
+@pytest.mark.parametrize("cfg_kw,match", [
+    (dict(moe_experts=4), "Switch-MoE"),
+    (dict(n_classes=8), "token-in == logit-out"),
+])
+def test_what_the_engine_cannot_decode_is_refused_with_what_it_can(
+        cfg_kw, match):
+    kw = dict(input_dim=16, seq_len=32, d_model=16, n_heads=2, n_layers=1,
+              n_classes=16)
+    kw.update(cfg_kw)
+    with pytest.raises(ValueError, match=match) as e:
+        DecodeEngine(Transformer(transformer_config(**kw)), replicas=1)
+    assert "mla_moe" in str(e.value)
+
+
+# -- (8) the counters ------------------------------------------------------
+HISTOGRAMS = ("decode.moe.load_max_over_mean", "decode.moe.experts_hit",
+              "decode.latent.live_positions")
+PAIRS = ("decode.moe.pairs_total", "decode.moe.pairs_held")
+
+
+def test_routing_counters_exist_and_are_stamped():
+    import time
+
+    cfg = config(held=(6, 7), n_routed_experts=16)
+    for name in PAIRS + HISTOGRAMS:
+        assert name in metrics.KNOWN_METRICS
+    before = [metrics.counter(n).value for n in PAIRS]
+    lo = time.perf_counter()
+    rng = np.random.default_rng(4)
+    with engine_for(cfg, weights_for(cfg), decode_ladder=(4,)) as eng:
+        gens = [eng.submit_generate(rng.integers(0, VOCAB, 30).tolist(),
+                                    max_new_tokens=16) for _ in range(4)]
+        docs = [g.result(timeout=600) for g in gens]
+    hi = time.perf_counter()
+    total, held = (metrics.counter(n).value - b
+                   for n, b in zip(PAIRS, before))
+    # every real token of every prefill and step, in both expert layers
+    tokens = sum(d["prompt_len"] + len(d["generated"]) - 1 for d in docs)
+    assert total == tokens * cfg["top_k"] * 2
+    assert 0 < held < total
+    # one sample a decode step, stamped with the step's start like
+    # decode.step_s, so that a reader can cut a window out
+    steps = metrics.histogram("decode.step_s").samples_between(lo, hi)[0]
+    for name in HISTOGRAMS:
+        pairs, truncated = metrics.histogram(name).samples_between(lo, hi)
+        assert not truncated and pairs, name
+        assert {at for at, _ in pairs} <= {at for at, _ in steps}, name
+    live = [v for _, v in metrics.histogram(
+        "decode.latent.live_positions").samples_between(lo, hi)[0]]
+    assert min(live) >= 31 and max(live) <= 4 * 46
+    hit = [v for _, v in metrics.histogram(
+        "decode.moe.experts_hit").samples_between(lo, hi)[0]]
+    assert all(0 <= v <= 2 * 2 for v in hit)
+
+
+def test_held_share_reads_an_eighth_on_uniform_routing():
+    """16 experts, 2 held, top 3: with router columns of one length, no
+    bias and isotropic tokens every expert is as likely as another, and
+    an eighth of the chosen pairs is held, within sampling (4096 tokens:
+    a standard deviation of 0.003)."""
+    cfg = config(held=(6, 7), n_routed_experts=16)
+    moe = weights_for(cfg)["blocks"][1]["moe"]
+    router = jax.random.normal(jax.random.PRNGKey(1), moe["router"].shape)
+    moe = {**moe, "router_bias": jnp.zeros((16,)),
+           "router": router / jnp.linalg.norm(router, axis=0)}
+    x = jax.random.normal(jax.random.PRNGKey(2), (4096, cfg["d_model"]))
+    _, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((4096,), bool))
+    before = [metrics.counter(n).value for n in PAIRS]
+    mla_moe.observe_step(counts, at=0.0)
+    total, held = (metrics.counter(n).value - b
+                   for n, b in zip(PAIRS, before))
+    assert total == 4096 * 3
+    assert abs(held / total - 1 / 8) < 0.015, held / total
+
+
+# -- scopes ------------------------------------------------------------------
+SCOPES = {"decode": ("embed", "mla_q", "latent_write", "attend_latent",
+                     "attn_out", "moe_route", "moe_experts", "moe_shared",
+                     "mlp", "head"),
+          "prefill": ("embed", "mla_q", "latent_write", "attend", "attn_out",
+                      "moe_route", "moe_experts", "moe_shared", "mlp",
+                      "head")}
+
+
+@pytest.mark.parametrize("phase", sorted(SCOPES))
+def test_steps_carry_their_names_and_scopes(phase):
+    """The engine's jitted steps are ``_prefill_fn`` / ``_decode_fn``
+    whatever the family (a trace's programs carry the names) and every
+    part of this family's lies under a named scope."""
+    cfg = config()
+    i32 = jnp.int32
+    with engine_for(cfg, weights_for(cfg)) as eng:
+        rep = eng._replicas[0]
+        if phase == "decode":
+            lowered = eng._decode_jit.lower(
+                rep.params, *rep.pools, jnp.zeros((4,), i32),
+                jnp.zeros((4,), i32), jnp.zeros((4, 12), i32),
+                jnp.zeros((4,), i32), jnp.zeros((4,), i32),
+                jnp.zeros((4,), i32))
+        else:
+            lowered = eng._prefill_jit.lower(
+                rep.params, *rep.pools, jnp.zeros((8,), i32), i32(3),
+                jnp.zeros((8,), i32), jnp.zeros((8,), i32))
+    text = lowered.as_text(debug_info=True)
+    assert f"jit__{phase}_fn" in text
+    for scope in SCOPES[phase]:
+        assert f"jit(_{phase}_fn)/{scope}/" in text, scope
