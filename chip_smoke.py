@@ -34,6 +34,7 @@ success the LAST stdout line is one JSON object::
 
 from __future__ import annotations
 
+import functools
 import importlib.metadata
 import json
 import sys
@@ -505,6 +506,12 @@ def _flash_case(bh, t, d, block, dtype):
             "ref_both": ref_both}
 
 
+def _case_lengths(slots, page_size, n_pages):
+    """A padding slot, one position, a page boundary, the full extent."""
+    return jnp.asarray([(0, 1, page_size, n_pages * page_size)[i % 4]
+                        for i in range(slots)], jnp.int32)
+
+
 def _paged_case(slots, heads, d, page_size, n_pages):
     """float32, like the engine's pools; lengths cover a padding slot, a
     partial page, a page boundary and the full extent."""
@@ -514,10 +521,29 @@ def _paged_case(slots, heads, d, page_size, n_pages):
     kp, vp = (jnp.asarray(rng.normal(size=(pool, page_size, heads, d)),
                           jnp.float32) for _ in range(2))
     table = jnp.asarray(rng.integers(0, pool, (slots, n_pages)), jnp.int32)
-    t = n_pages * page_size
-    lengths = jnp.asarray([(0, 1, page_size, t)[i % 4]
-                           for i in range(slots)], jnp.int32)
-    return (q, kp, vp, table, lengths)
+    return (q, kp, vp, table, _case_lengths(slots, page_size, n_pages))
+
+
+# the latent pool's read at the published widths (rank 512 + rope 64 in a
+# row of 640 lanes, scores scaled for 128 + 64 wide keys)
+_LATENT = {"rank": 512, "scale": 192 ** -0.5}
+
+
+def _latent_case(slots, heads, page_size, n_pages, width=640, used=576):
+    """float32 rows of whole lanes, like the engine's latent pool, two
+    layers of it flat with the page ids offset to the second; lengths as
+    in :func:`_paged_case`, the extent past the kernel's first block."""
+    rng = np.random.default_rng(1)
+    per_layer = slots * n_pages + 1
+    live = np.arange(width) < used
+    q = jnp.asarray(rng.normal(size=(slots, heads, width)) * live,
+                    jnp.float32)
+    pool = jnp.asarray(
+        rng.normal(size=(2 * per_layer, page_size, width)) * live,
+        jnp.float32)
+    table = jnp.asarray(per_layer + rng.permutation(per_layer)[
+        :slots * n_pages].reshape(slots, n_pages), jnp.int32)
+    return (q, pool, table, _case_lengths(slots, page_size, n_pages))
 
 
 def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
@@ -538,6 +564,10 @@ def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
         decode_attention.paged_attention_kernel,
         _paged_case(slots, n_heads, head_dim, page_size,
                     -(-seq // page_size)))
+    cases["latent_decode/f32"] = (
+        functools.partial(decode_attention.latent_attention_kernel,
+                          **_LATENT),
+        _latent_case(slots, n_heads, page_size, -(-seq // page_size)))
     return cases, flash
 
 
@@ -562,6 +592,8 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
         q, kp, vp, table, lengths = cases["paged_decode/f32"][1]
         paged_ref = decode_attention.paged_attention_reference(
             q, kp, vp, table, lengths)
+        latent_ref = decode_attention.latent_attention_reference(
+            *cases["latent_decode/f32"][1], **_LATENT)
 
     for tag, c in flash.items():
         ref_out, ref_grads = refs[tag]
@@ -578,6 +610,8 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
             max(_rel_err(g, r) for g, r in zip(fused, ref_grads)), None)
     report["paged_decode/f32"] = (
         _rel_err(run("paged_decode/f32"), paged_ref), TOLERANCE)
+    report["latent_decode/f32"] = (
+        _rel_err(run("latent_decode/f32"), latent_ref), TOLERANCE)
     for name, (err, tol) in report.items():
         _check(np.isfinite(err) and (tol is None or err <= tol),
                f"stage D: {name} rel err {err:.3g} > tolerance {tol}")

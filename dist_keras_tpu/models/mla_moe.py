@@ -63,7 +63,8 @@ import numpy as np
 
 from dist_keras_tpu.models.layers import glorot_uniform, select_top_k
 from dist_keras_tpu.ops.pallas.decode_attention import (
-    latent_attention_reference,
+    latent_attention_auto,
+    latent_walked_positions,
 )
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
@@ -392,7 +393,7 @@ def _decode_layers(cfg, params, pool, tokens, positions, page_tables,
         with jax.named_scope("attend_latent"):
             # the whole pool viewed flat over (layer, page), the page ids
             # offset to this layer's: ``pool[li]`` would copy the layer
-            o = latent_attention_reference(
+            o = latent_attention_auto(
                 q, pool.reshape(-1, *pool.shape[2:]),
                 page_tables + li * pool.shape[1], lengths, rank=rank,
                 scale=scale)
@@ -416,21 +417,25 @@ def decode_step(cfg, params, pool, tokens, positions, page_tables,
     return jnp.concatenate([nxt, counts]), pool
 
 
-def observe_step(counts, at, live_positions=None):
+def observe_step(counts, at, lengths=None, page_size=None):
     """The counts behind a step's tokens -> the registry.  A decode step
-    passes ``live_positions`` (the cached positions its read had to walk)
-    and adds one sample to each per-step histogram, stamped ``at`` like
-    ``decode.step_s``; a prefill only adds its pairs."""
+    passes its slots' ``lengths`` (host values, zeros for padding) and
+    the ``page_size`` and adds one sample to each per-step histogram,
+    stamped ``at`` like ``decode.step_s``: the live cached positions,
+    and the positions the read's blocks of pages fetch for them; a
+    prefill only adds its pairs."""
     from dist_keras_tpu.observability import metrics
 
     counts = np.asarray(counts)
     held, hit, total = counts[:-N_COUNTS], counts[-2], counts[-1]
     metrics.counter("decode.moe.pairs_total").inc(int(total))
     metrics.counter("decode.moe.pairs_held").inc(int(held.sum()))
-    if live_positions is None:
+    if lengths is None:
         return
     metrics.histogram("decode.latent.live_positions").observe(
-        live_positions, at=at)
+        int(lengths.sum()), at=at)
+    metrics.histogram("decode.latent.walked_positions").observe(
+        latent_walked_positions(lengths, page_size), at=at)
     metrics.histogram("decode.moe.experts_hit").observe(int(hit), at=at)
     if held.sum() > 0:
         metrics.histogram("decode.moe.load_max_over_mean").observe(

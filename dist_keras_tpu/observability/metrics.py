@@ -361,6 +361,7 @@ KNOWN_METRICS = {
     "decode.moe.load_max_over_mean": "histogram",
     "decode.moe.experts_hit": "histogram",
     "decode.latent.live_positions": "histogram",
+    "decode.latent.walked_positions": "histogram",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

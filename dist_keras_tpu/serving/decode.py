@@ -198,8 +198,8 @@ class Generation:
 # pool: what one cached position of one layer is), ``prefill_step`` /
 # ``decode_step`` (``(cfg, params, *pools, ...) -> (int32 array,
 # *pools)``: the tokens first, then whatever counts the family sends
-# along) and ``observe_step(counts, at, live_positions=None)`` for those
-# counts (None when the family sends none).
+# along) and ``observe_step(counts, at, lengths=None, page_size=None)``
+# for those counts (None when the family sends none).
 _FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe)}
 
 
@@ -870,8 +870,8 @@ class DecodeEngine:
         if self._family.observe_step is not None:
             # the counts came off the device behind the tokens, in the
             # one array the wait already fetched
-            self._family.observe_step(nxt[rung:], t0,
-                                      live_positions=int(lengths.sum()))
+            self._family.observe_step(nxt[rung:], t0, lengths=lengths,
+                                      page_size=self.page_size)
         with perf.phase("decode.step.emit"):
             with self._cond:
                 self._shapes.add(("decode", rung))

@@ -69,7 +69,7 @@ def chip_kernel_cases():
 @pytest.mark.parametrize("name", [
     "flash_fwd/bf16_train", "flash_bwd/bf16_train", "fused_bwd/bf16_train",
     "flash_fwd/f32_prefill", "flash_bwd/f32_prefill",
-    "fused_bwd/f32_prefill", "paged_decode/f32"])
+    "fused_bwd/f32_prefill", "paged_decode/f32", "latent_decode/f32"])
 def test_kernel_lowers_for_tpu(chip_kernel_cases, name):
     fn, args = chip_kernel_cases[name]
     text = jax.jit(fn).trace(*args).lower(

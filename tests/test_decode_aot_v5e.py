@@ -161,7 +161,8 @@ def _latent_cfg():
 
 
 @pytest.mark.parametrize("phase,rung,temp_gb", [
-    ("decode", L_SLOTS, 0.5), ("prefill", 2560, 0.8), ("prefill", 6144, 1.5)])
+    ("decode", L_SLOTS, 0.1), ("decode", 8, 0.1), ("prefill", 2560, 0.8),
+    ("prefill", 6144, 1.5)])
 def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
                                               temp_gb):
     """The new family's steps hold no copy of the latent pool (rows of
@@ -169,8 +170,10 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
     puts the pages minor and every step converts the pool twice, 4.4 GB
     each way) and no vocabulary-sized temporary (the head's norm weight
     folded into the head); their temporaries stay under ``temp_gb``: the
-    gathered pages of one layer in bfloat16 (0.27 GB) for the decode
-    step, the held experts' hidden rows of one layer for a prefill."""
+    held experts' hidden rows of one layer for a prefill, next to nothing
+    for a decode step, whose read of the pool is one Mosaic kernel a
+    layer that walks the live pages where they lie (no gathered rows:
+    0.27 GB of them in bfloat16 until PR 28, under 0.5 GB then)."""
     import functools
 
     from jax.sharding import SingleDeviceSharding
@@ -231,3 +234,18 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
     assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
     if phase == "prefill":
         assert "flash_fwd" in text
+        return
+    # the read: one kernel a layer over the flat float32 pool itself, and
+    # nothing of the size of the slots' whole tables, in any type
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "latent_decode" in line]
+    assert len(calls) == cfg["n_layers"], len(calls)
+    flat = f"f32[{math.prod(pool_shape[:2])},{L_PAGE},640]"
+    assert all(flat in line for line in calls), calls[0]
+    table_rows = rung * (L_POSITIONS // L_PAGE)
+    gathered = [
+        f"{comp}: %{name} = {opcode}"
+        for comp, name, elems, opcode, _ in _instructions(text)
+        if elems >= table_rows * L_PAGE * 512 and elems not in big
+        and opcode not in FREE]
+    assert not gathered, gathered

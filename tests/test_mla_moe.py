@@ -390,6 +390,61 @@ def test_routing_counters_exist_and_are_stamped():
     assert all(0 <= v <= 2 * 2 for v in hit)
 
 
+def test_walked_positions_are_stamped_once_a_step_with_the_block_arithmetic():
+    """``decode.latent.walked_positions``: one sample a decode step,
+    stamped like ``decode.step_s``, equal to what the latent kernel's
+    blocks of pages fetch for that step's lengths (each live slot's length
+    rounded up to ``LATENT_BLOCK_PAGES`` pages): host arithmetic on the
+    lengths the worker already has."""
+    import time
+
+    from dist_keras_tpu.ops.pallas.decode_attention import (
+        LATENT_BLOCK_PAGES,
+    )
+
+    assert metrics.KNOWN_METRICS["decode.latent.walked_positions"] == \
+        "histogram"
+    walked_h = metrics.histogram("decode.latent.walked_positions")
+    page = 4
+    block = LATENT_BLOCK_PAGES * page               # 128 positions
+    # known lengths: a padding slot, one position, a block to the last
+    # position, one past it, and three blocks and a bit
+    lengths = np.asarray([0, 1, block, block + 1, 3 * block + 7], np.int32)
+    counts = np.zeros((3 + mla_moe.N_COUNTS,), np.int32)
+    at = time.perf_counter()
+    mla_moe.observe_step(counts, at, lengths=lengths, page_size=page)
+    assert walked_h.samples_between(at, at + 1e-6)[0] == [
+        (at, (0 + 1 + 1 + 2 + 4) * block)]
+    assert metrics.histogram("decode.latent.live_positions").samples_between(
+        at, at + 1e-6)[0] == [(at, int(lengths.sum()))]
+    # a prefill passes no lengths and stamps nothing
+    mla_moe.observe_step(counts, at + 1e-3)
+    assert walked_h.samples_between(at + 1e-3, at + 2e-3)[0] == []
+
+    # through the engine: once a decode step, with the step's own stamp.
+    # Prompts whose block counts stay 1, 2 and 3 for all 11 steps
+    cfg = config(seq_len=400)
+    rng = np.random.default_rng(9)
+    lo = time.perf_counter()
+    with engine_for(cfg, weights_for(cfg), decode_ladder=(4,),
+                    prefill_ladder=(8, 256, 384), page_size=page) as eng:
+        gens = [eng.submit_generate(rng.integers(0, VOCAB, n).tolist(),
+                                    max_new_tokens=12)
+                for n in (5, 130, 260)]
+        for g in gens:
+            g.result(timeout=600)
+    hi = time.perf_counter()
+    steps = metrics.histogram("decode.step_s").samples_between(lo, hi)[0]
+    walked = walked_h.samples_between(lo, hi)[0]
+    live = metrics.histogram("decode.latent.live_positions").samples_between(
+        lo, hi)[0]
+    assert [at for at, _ in walked] == [at for at, _ in steps]
+    assert len(walked) == len(live) >= 11
+    for (_, w), (_, n) in zip(walked, live):
+        assert w in [k * block for k in range(1, 7)] and n <= w
+    assert max(w for _, w in walked) == 6 * block     # all three at once
+
+
 def test_held_share_reads_an_eighth_on_uniform_routing():
     """16 experts, 2 held, top 3: with router columns of one length, no
     bias and isotropic tokens every expert is as likely as another, and
