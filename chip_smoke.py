@@ -323,15 +323,12 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
         rep0 = eng._replicas[0]
         vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
         top, slots = prefill_ladder[-1], decode_ladder[-1]
+        # a dispatch hands over ONE packed int32 array (serving/decode.py)
         prefill_text = eng._prefill_jit.lower(
-            rep0.params, *rep0.pools, vec(top),
-            jax.ShapeDtypeStruct((), jnp.int32), vec(top),
-            vec(top)).as_text()
+            rep0.params, *rep0.pools, vec(3 * top + 1)).as_text()
         decode_text = eng._decode_jit.lower(
-            rep0.params, *rep0.pools, vec(slots), vec(slots),
-            jax.ShapeDtypeStruct((slots, eng.max_pages_per_seq),
-                                 jnp.int32),
-            vec(slots), vec(slots), vec(slots)).as_text()
+            rep0.params, *rep0.pools,
+            vec(slots * (eng.max_pages_per_seq + 5))).as_text()
         kernels = _kernel_counts(prefill_text, ("flash_fwd",))
         _check_kernels_traced("C", kernels)
         kernels.update(_kernel_counts(decode_text, ("paged_decode",)))

@@ -17,7 +17,8 @@ stay on in production (<5% of train wall, gated):
   dispatch *granularity the framework chose*, which is exactly the knob
   chunk plans and batch ladders turn.
 - **H2D / D2H bytes + walls** — :func:`h2d` at the ``ChunkFeed``
-  transfer (bytes shipped + the async enqueue wall) and :func:`d2h` at
+  transfer and at the decode worker's one packed transfer a dispatch
+  (bytes shipped + the async enqueue wall) and :func:`d2h` at
   the trainers' blocking loss retire (bytes fetched + the blocking
   wall, which on the streamed path is the documented backpressure
   barrier — the honest "host overlap wall").
@@ -66,9 +67,11 @@ PHASES = ("data", "step", "comm", "comm_overlap", "comm_blocked", "ckpt",
           # the decode worker loop (serving/decode.py), parents before
           # their children: the locked scheduling pass, the idle park,
           # one prompt's prefill and one slot set's token step, each
-          # split into building the host arrays, the transfers and the
-          # launch returning, the wait for the result, and (step) the
-          # token callbacks and exits
+          # split into building the ONE packed host array, its transfer
+          # (counted by h2d) and the launch returning, the wait for the
+          # result, and (step) the token callbacks and exits.  Leaves
+          # stay leaves: a trace's reader credits an idle gap to the
+          # innermost region by its exact name
           "decode.sched", "decode.park",
           "decode.prefill", "decode.prefill.build",
           "decode.prefill.dispatch", "decode.prefill.wait",

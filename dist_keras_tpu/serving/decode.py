@@ -203,6 +203,45 @@ class Generation:
 _FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe)}
 
 
+def _step_views(packed, pmax):
+    """The six arrays of a decode step inside its ONE packed int32 array,
+    in ``decode_step``'s order.  ``packed`` holds ``rung * (pmax + 5)``
+    values: the page tables row by row (``pmax`` entries a slot), then
+    the tokens, positions, write pages, write offsets and lengths, a rung
+    of each.  The layout's one statement: on the host these are the
+    writable views ``_step_group`` fills, inside the compiled step the
+    same slices cut the device's copy apart.  Flat with the tables first
+    because the compiled step is then no longer than with six arguments
+    (one copy into fast memory, whole-tile slices; the latent kernel
+    wants its tables flat anyway), and no slower on the chip: PERF.md,
+    PR 31."""
+    rung = packed.shape[0] // (pmax + 5)
+    tables = packed[:rung * pmax].reshape(rung, pmax)
+    toks, positions, wpage, woff, lengths = \
+        packed[rung * pmax:].reshape(5, rung)
+    return toks, positions, tables, wpage, woff, lengths
+
+
+def _prefill_views(packed):
+    """The four arrays of a prefill inside its ONE packed int32 array, in
+    ``prefill_step``'s order: ``3 * rung + 1`` values, the tokens, page
+    indices and page offsets a rung each, then the prompt's length (on
+    the host a copy, not a view: ``_prefill`` writes ``packed[-1]``)."""
+    toks, page_idx, page_off = packed[:-1].reshape(3, -1)
+    return toks, packed[-1], page_idx, page_off
+
+
+def _to_device(packed):
+    """A dispatch's ONE transfer to the device, counted: ``perf.h2d_bytes``
+    grows by the array's size and ``perf.h2d_s`` gains the enqueue's wall,
+    which is the transfer's part of the dispatch region around it (the
+    rest is the launch)."""
+    t0 = time.perf_counter()
+    on_device = jnp.asarray(packed)
+    perf.h2d(packed.nbytes, time.perf_counter() - t0)
+    return on_device
+
+
 class _DecodeReplica:
     """One replica: pinned device, params swap point, its KV pools."""
 
@@ -326,9 +365,9 @@ class DecodeEngine:
         # the replica's pools and returns their successors
         donated = tuple(
             range(1, 1 + len(self._family.cache_entry_shapes(cfg))))
-        self._prefill_jit = jax.jit(self._prefill_fn,
+        self._prefill_jit = jax.jit(self._packed_prefill_fn,
                                     donate_argnums=donated)
-        self._decode_jit = jax.jit(self._decode_fn,
+        self._decode_jit = jax.jit(self._packed_decode_fn,
                                    donate_argnums=donated)
 
         if devices is None:
@@ -414,14 +453,35 @@ class DecodeEngine:
     def _prefill_fn(self, params, *args):
         """``(params, *pools, tokens, length, page_idx, page_off)``: one
         padded prompt -> (int32 array, the first token in front, *updated
-        pools)."""
+        pools).  The family's step with its four integer arrays apart;
+        what is dispatched is :meth:`_packed_prefill_fn` around it."""
         return self._family.prefill_step(self.cfg, params, *args)
 
     def _decode_fn(self, params, *args):
         """``(params, *pools, tokens, positions, page_tables, write_page,
         write_off, lengths)``: one token step for a padded slot set ->
-        (int32 array, the next tokens in front, *updated pools)."""
+        (int32 array, the next tokens in front, *updated pools).  The
+        family's step with its six integer arrays apart; what is
+        dispatched is :meth:`_packed_decode_fn` around it."""
         return self._family.decode_step(self.cfg, params, *args)
+
+    # What crosses to the device a dispatch: ONE int32 array, cut back
+    # into the step's arrays inside the compiled program (a transfer
+    # costs the host about as much for 54 KB as for 128 B, and the device
+    # waits through each).  The programs keep the family functions' names
+    # in theirs: a trace's reader finds a step's program by ``_decode_fn``.
+    def _packed_prefill_fn(self, params, *args):
+        """``(params, *pools, packed)``: :meth:`_prefill_fn` on the four
+        arrays :func:`_prefill_views` cuts out of ``packed``."""
+        *pools, packed = args
+        return self._prefill_fn(params, *pools, *_prefill_views(packed))
+
+    def _packed_decode_fn(self, params, *args):
+        """``(params, *pools, packed)``: :meth:`_decode_fn` on the six
+        arrays :func:`_step_views` cuts out of ``packed``."""
+        *pools, packed = args
+        return self._decode_fn(
+            params, *pools, *_step_views(packed, self.max_pages_per_seq))
 
     @property
     def pool_shapes(self):
@@ -712,19 +772,25 @@ class DecodeEngine:
         """Run one admitted prompt through its prefill ``rung``; emits
         the first generated token (TTFT) or fails the sequence typed.
 
+        One crossing each way: the prompt's four integer arrays go to
+        the device as ONE packed int32 array (:func:`_prefill_views`, cut
+        apart inside the compiled step), the token comes back in the one
+        array the wait region fetches.
+
         A RECOVERED sequence (``seq.tokens`` longer than the prompt)
         replays the same prefill over the prompt only — its prediction
         is a token the stream already delivered, so it is discarded
         and the teacher-forced decode steps replay the rest."""
         with perf.phase("decode.prefill.build"):
-            toks = np.zeros((rung,), np.int32)
-            toks[:seq.prompt_len] = seq.tokens[:seq.prompt_len]
-            scratch = rep.cache.scratch_page
-            page_idx = np.full((rung,), scratch, np.int32)
-            ps = self.page_size
-            for t in range(seq.prompt_len):
-                page_idx[t] = seq.pages[t // ps]
-            page_off = (np.arange(rung, dtype=np.int32) % ps)
+            n, ps = seq.prompt_len, self.page_size
+            packed = np.zeros((3 * rung + 1,), np.int32)
+            toks, _, page_idx, page_off = _prefill_views(packed)
+            toks[:n] = seq.tokens[:n]
+            # position t goes to page t // ps; the padding to the scratch
+            page_idx[:n] = np.repeat(seq.pages, ps)[:n]
+            page_idx[n:] = rep.cache.scratch_page
+            page_off[:] = np.arange(rung) % ps
+            packed[-1] = n
         replay = len(seq.tokens) > seq.prompt_len
         t0 = time.perf_counter()
         tw0 = time.time()
@@ -736,9 +802,7 @@ class DecodeEngine:
             perf.count_dispatch()
             with perf.phase("decode.prefill.dispatch"):
                 first, *rep.pools = self._prefill_jit(
-                    seq.params, *rep.pools, jnp.asarray(toks),
-                    jnp.int32(seq.prompt_len), jnp.asarray(page_idx),
-                    jnp.asarray(page_off))
+                    seq.params, *rep.pools, _to_device(packed))
             with perf.phase("decode.prefill.wait"):
                 # the token, then whatever counts the family sends along
                 first, *counts = np.asarray(first).reshape(-1)
@@ -800,21 +864,27 @@ class DecodeEngine:
         survivor exists (the group migrates and replays), else it
         fails exactly this group's sequences, typed, pages reclaimed.
 
+        One crossing each way: the step's six integer arrays (tokens,
+        positions, page tables, write pages, write offsets, lengths) are
+        views of ONE packed int32 host array (:func:`_step_views`; 54 KB
+        at 32 slots of 416 pages) that goes to the device in one transfer
+        and is cut apart inside the compiled step; the next tokens come
+        back in the one array the wait region fetches (asked for while
+        the device still runs: a ``copy_to_host_async`` at the launch
+        took nothing off the step, PERF.md, PR 31).
+
         The input token is ``seq.tokens[seq.kv_len]`` — the last token
         in steady state, a teacher-forced KNOWN token while a
         recovered sequence catches back up (its predictions are
         discarded until ``kv_len`` reaches the frontier, so streams
         never see a duplicate)."""
         with perf.phase("decode.step.build"):
-            scratch = rep.cache.scratch_page
             ps = self.page_size
             pmax = self.max_pages_per_seq
-            toks = np.zeros((rung,), np.int32)
-            positions = np.zeros((rung,), np.int32)
-            tables = np.zeros((rung, pmax), np.int32)
-            wpage = np.full((rung,), scratch, np.int32)
-            woff = np.zeros((rung,), np.int32)
-            lengths = np.zeros((rung,), np.int32)
+            packed = np.zeros((rung * (pmax + 5),), np.int32)
+            toks, positions, tables, wpage, woff, lengths = \
+                _step_views(packed, pmax)
+            wpage[:] = rep.cache.scratch_page
             for i, seq in enumerate(group):
                 toks[i] = seq.tokens[seq.kv_len]
                 positions[i] = seq.kv_len
@@ -830,10 +900,7 @@ class DecodeEngine:
                 perf.count_dispatch()
                 with perf.phase("decode.step.dispatch"):
                     nxt, *rep.pools = self._decode_jit(
-                        group[0].params, *rep.pools,
-                        jnp.asarray(toks), jnp.asarray(positions),
-                        jnp.asarray(tables), jnp.asarray(wpage),
-                        jnp.asarray(woff), jnp.asarray(lengths))
+                        group[0].params, *rep.pools, _to_device(packed))
                 with perf.phase("decode.step.wait"):
                     nxt = np.asarray(nxt)
                 err = None

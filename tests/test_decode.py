@@ -19,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from dist_keras_tpu.models import mla_moe
 from dist_keras_tpu.models.transformer import (
     Transformer,
     apply_block,
@@ -159,6 +160,145 @@ def test_pool_shape_is_page_major_and_what_replicas_hold(engine_and_model):
         assert tuple(pool.shape for pool in rep.pools) == eng.pool_shapes
         for pool in rep.pools:
             assert pool.dtype == jnp.float32
+
+
+# -- one packed array a dispatch ---------------------------------------
+def _family_engine(family, decode_ladder):
+    if family == "transformer":
+        return _engine(decode_ladder=decode_ladder, prefill_ladder=(8, 16))
+    cfg = mla_moe.mla_moe_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=4,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        kv_lora_rank=32, d_ff=96, moe_d_ff=48, n_routed_experts=8,
+        n_shared_experts=1, top_k=3, n_layers=3, held_experts=[2, 3, 4],
+        routed_scaling_factor=2.446, rope_theta=800000.0)
+    return DecodeEngine(mla_moe.LatentMoEDecoder(cfg=cfg, seed=1),
+                        replicas=1, prefill_ladder=(8, 16),
+                        decode_ladder=decode_ladder, page_size=4)
+
+
+def _filled_pools(eng, seed):
+    """Pools with something in every position, so a wrong table, write
+    page or length shows in the tokens or in the pools."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(eng.pool_shapes))
+    return [jax.random.normal(k, shape, jnp.float32)
+            for k, shape in zip(keys, eng.pool_shapes)]
+
+
+def _assert_same_step(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("rung", [4, 8])
+@pytest.mark.parametrize("family", ["transformer", "mla_moe"])
+def test_packed_decode_step_is_the_familys_own(family, rung):
+    """The dispatched program, handed the worker's ONE packed array, gives
+    bit for bit the tokens and pools of the family's ``decode_step`` on
+    the six arrays apart; the rung's last slots are padding."""
+    from dist_keras_tpu.serving.decode import _step_views
+
+    with _family_engine(family, (1, 4, 8)) as eng:
+        rep, ps, pmax = eng._replicas[0], eng.page_size, \
+            eng.max_pages_per_seq
+        rng = np.random.default_rng(rung)
+        live = rung - 2
+        packed = np.zeros((rung * (pmax + 5),), np.int32)
+        toks, positions, tables, wpage, woff, lengths = \
+            _step_views(packed, pmax)
+        assert tables.shape == (rung, pmax) and toks.shape == (rung,)
+        wpage[:] = rep.cache.scratch_page
+        pages = rng.permutation(eng.num_pages).astype(np.int32)
+        for i in range(live):
+            at = int(rng.integers(1, eng.seq_len - 1))
+            mine = pages[i * pmax:(i + 1) * pmax]
+            toks[i] = rng.integers(0, eng.vocab)
+            positions[i] = at
+            tables[i] = mine
+            wpage[i], woff[i] = mine[at // ps], at % ps
+            lengths[i] = at + 1
+        assert np.count_nonzero(packed) > live * pmax     # views, not copies
+        want = jax.jit(functools.partial(eng._family.decode_step, eng.cfg))(
+            rep.params, *_filled_pools(eng, 5), *map(
+                jnp.asarray, (toks, positions, tables, wpage, woff,
+                              lengths)))
+        got = eng._decode_jit(rep.params, *_filled_pools(eng, 5),
+                              jnp.asarray(packed))
+        _assert_same_step(got, want)
+
+
+@pytest.mark.parametrize("rung,n", [(8, 5), (16, 13)])
+@pytest.mark.parametrize("family", ["transformer", "mla_moe"])
+def test_packed_prefill_is_the_familys_own(family, rung, n):
+    """The same for a prompt of ``n`` tokens padded to its rung: the four
+    arrays of ``prefill_step`` out of one."""
+    from dist_keras_tpu.serving.decode import _prefill_views
+
+    with _family_engine(family, (1, 4)) as eng:
+        rep, ps = eng._replicas[0], eng.page_size
+        rng = np.random.default_rng(rung)
+        packed = np.zeros((3 * rung + 1,), np.int32)
+        toks, _, page_idx, page_off = _prefill_views(packed)
+        pages = rng.permutation(eng.num_pages)[:-(-n // ps)]
+        toks[:n] = rng.integers(0, eng.vocab, n)
+        page_idx[:] = rep.cache.scratch_page
+        for t in range(n):        # the loop the worker's np.repeat replaced
+            page_idx[t] = pages[t // ps]
+        page_off[:] = np.arange(rung) % ps
+        packed[-1] = n
+        want = jax.jit(functools.partial(eng._family.prefill_step, eng.cfg))(
+            rep.params, *_filled_pools(eng, 6), jnp.asarray(toks),
+            jnp.int32(n), jnp.asarray(page_idx), jnp.asarray(page_off))
+        got = eng._prefill_jit(rep.params, *_filled_pools(eng, 6),
+                               jnp.asarray(packed))
+        _assert_same_step(got, want)
+
+
+def test_worker_packs_what_the_per_token_loops_built():
+    """Through a real ``generate`` (a 6-token prompt over pages of 4, then
+    5 steps): every array the worker hands to the device is what the
+    per-token loops it replaced would build from the sequence's pages."""
+    from dist_keras_tpu.serving.decode import _prefill_views, _step_views
+
+    with _engine(max_new_default=6) as eng:
+        seen = {"prefill": [], "decode": []}
+
+        def recording(phase, jitted):
+            def call(*args):
+                seen[phase].append(np.array(args[-1]))
+                return jitted(*args)
+            return call
+
+        eng._prefill_jit = recording("prefill", eng._prefill_jit)
+        eng._decode_jit = recording("decode", eng._decode_jit)
+        prompt = [3, 1, 4, 1, 5, 9]
+        out = eng.generate(prompt)["tokens"]
+        ps, pmax = eng.page_size, eng.max_pages_per_seq
+        scratch = eng._replicas[0].cache.scratch_page
+    assert len(seen["prefill"]) == 1 and len(seen["decode"]) == 5
+    # the sequence's pages: row 0 of any step's table (12 positions' worth)
+    pages = _step_views(seen["decode"][0], pmax)[2][0][:3]
+    assert len(set(pages.tolist())) == 3 and scratch not in pages
+
+    rung = 8
+    toks, length, page_idx, page_off = _prefill_views(seen["prefill"][0])
+    assert toks.tolist() == prompt + [0, 0] and length == 6
+    assert page_idx.tolist() == [pages[t // ps] for t in range(6)] \
+        + [scratch] * 2
+    assert page_off.tolist() == [t % ps for t in range(rung)]
+
+    for k, packed in enumerate(seen["decode"]):
+        assert packed.dtype == np.int32 and packed.shape == (pmax + 5,)
+        toks, positions, tables, wpage, woff, lengths = \
+            _step_views(packed, pmax)
+        at = 6 + k
+        assert (toks.tolist(), positions.tolist(), lengths.tolist()) == \
+            ([out[at]], [at], [at + 1])
+        assert tables[0].tolist() == pages.tolist() + [0] * (pmax - 3)
+        assert (wpage.tolist(), woff.tolist()) == \
+            ([pages[at // ps]], [at % ps])
 
 
 # -- engine vs oracle --------------------------------------------------

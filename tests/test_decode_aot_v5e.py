@@ -86,8 +86,26 @@ def as_tpu(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-@pytest.mark.parametrize("phase,rung", [("decode", SLOTS), ("prefill", 128),
-                                        ("prefill", 2048)])
+def _step_and_args(engine, phase, rung, pages_per_seq, S):
+    """A step of ``phase`` and its integer arguments' shapes: the family's
+    function on its arrays apart, or (``packed_*``) the program the worker
+    dispatches, on the ONE packed array (which cuts the tables out by the
+    engine's ``max_pages_per_seq``, set here: the engine is a bare one)."""
+    engine.max_pages_per_seq = pages_per_seq
+    if phase == "decode":
+        return engine._decode_fn, (
+            S((rung,)), S((rung,)), S((rung, pages_per_seq)), S((rung,)),
+            S((rung,)), S((rung,)))
+    if phase == "packed_decode":
+        return engine._packed_decode_fn, (S((rung * (pages_per_seq + 5),)),)
+    if phase == "packed_prefill":
+        return engine._packed_prefill_fn, (S((3 * rung + 1,)),)
+    return engine._prefill_fn, (S((rung,)), S(()), S((rung,)), S((rung,)))
+
+
+@pytest.mark.parametrize("phase,rung", [
+    ("decode", SLOTS), ("prefill", 128), ("prefill", 2048),
+    ("packed_decode", SLOTS), ("packed_prefill", 2048)])
 def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
     from jax.sharding import SingleDeviceSharding
 
@@ -106,13 +124,7 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
         jax.eval_shape(lambda k: init_transformer_params(k, CFG),
                        jax.random.PRNGKey(0)))
     pool = S(pool_shape, jnp.float32)
-    if phase == "decode":
-        fn, args = engine._decode_fn, (
-            S((rung,)), S((rung,)), S((rung, PAGES_PER_SEQ)), S((rung,)),
-            S((rung,)), S((rung,)))
-    else:
-        fn, args = engine._prefill_fn, (
-            S((rung,)), S(()), S((rung,)), S((rung,)))
+    fn, args = _step_and_args(engine, phase, rung, PAGES_PER_SEQ, S)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
         params, pool, pool, *args).compile()
     text = compiled.as_text()
@@ -162,7 +174,7 @@ def _latent_cfg():
 
 @pytest.mark.parametrize("phase,rung,temp_gb", [
     ("decode", L_SLOTS, 0.1), ("decode", 8, 0.1), ("prefill", 2560, 0.8),
-    ("prefill", 6144, 1.5)])
+    ("prefill", 6144, 1.5), ("packed_decode", L_SLOTS, 0.1)])
 def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
                                               temp_gb):
     """The new family's steps hold no copy of the latent pool (rows of
@@ -195,13 +207,7 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         jax.eval_shape(functools.partial(mla_moe.init_params, cfg=cfg),
                        jax.random.PRNGKey(0)))
     pool = S(pool_shape, jnp.float32)
-    if phase == "decode":
-        fn, args = engine._decode_fn, (
-            S((rung,)), S((rung,)), S((rung, L_POSITIONS // L_PAGE)),
-            S((rung,)), S((rung,)), S((rung,)))
-    else:
-        fn, args = engine._prefill_fn, (
-            S((rung,)), S(()), S((rung,)), S((rung,)))
+    fn, args = _step_and_args(engine, phase, rung, L_POSITIONS // L_PAGE, S)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *args).compile()
     text = compiled.as_text()
@@ -235,6 +241,15 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
     if phase == "prefill":
         assert "flash_fwd" in text
         return
+    if phase == "packed_decode":
+        # the worker's one array crosses into the step whole: nothing
+        # else of its type comes in, and the program is still found by
+        # the family function's name
+        ints = [a for a in jax.tree.leaves(compiled.args_info)
+                if a.dtype == jnp.int32]
+        assert [a.shape for a in ints] == [
+            (rung * (L_POSITIONS // L_PAGE + 5),)]
+        assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
     # the read: one kernel a layer over the flat float32 pool itself, and
     # nothing of the size of the slots' whole tables, in any type
     calls = [line for line in text.splitlines()

@@ -262,6 +262,39 @@ def test_step_histogram_keeps_its_meaning_and_gains_the_stamp(engine):
     assert engine.stats()["step_s"]["count"] == 5
 
 
+def test_one_transfer_a_dispatch_is_counted(engine):
+    """``perf.h2d_s`` gains exactly one sample a decode step and one a
+    prefill, and ``perf.h2d_bytes`` grows by the packed arrays' sizes:
+    how many transfers a dispatch made, and what their enqueue cost, read
+    off the registry without a region of their own."""
+    engine.generate([1, 2, 3], max_new_tokens=2)      # compiles outside
+    before = metrics.counter("perf.h2d_bytes").value
+    lo = time.perf_counter()
+    engine.generate([1, 2, 3, 4, 5], max_new_tokens=4)
+    hi = time.perf_counter()
+    steps, _ = metrics.histogram("decode.step_s").samples_between(lo, hi)
+    prefills, _ = metrics.histogram(
+        "decode.prefill_s").samples_between(lo, hi)
+    assert (len(prefills), len(steps)) == (1, 3)
+    puts, truncated = metrics.histogram("perf.h2d_s").samples_between(lo, hi)
+    assert len(puts) == len(steps) + len(prefills) and not truncated
+    assert all(v >= 0 for _, v in puts)
+    # one prompt of 5 on the 8 rung, then three steps alone on rung 1
+    grown = metrics.counter("perf.h2d_bytes").value - before
+    assert grown == 4 * (3 * 8 + 1) + 3 * 4 * (engine.max_pages_per_seq + 5)
+
+
+def test_dispatch_and_wait_hold_no_child_region():
+    """A trace's reader credits an idle gap to the INNERMOST
+    ``perf.decode.*`` region by its exact name: a region inside a
+    dispatch or a wait would blind ``idle_in_dispatch_pct`` /
+    ``idle_in_wait_pct``."""
+    for parent in ("decode.step.dispatch", "decode.step.wait",
+                   "decode.prefill.dispatch", "decode.prefill.wait"):
+        assert parent in perf.PHASES
+        assert not [p for p in perf.PHASES if p.startswith(parent + ".")]
+
+
 # ------------------------------------------------------- named scopes
 def _scopes_of(lowered, fn_name):
     text = lowered.as_text(debug_info=True)
@@ -269,22 +302,43 @@ def _scopes_of(lowered, fn_name):
                           % re.escape(fn_name), text)), text
 
 
+def _lowered_step(engine, phase):
+    """The engine's jitted step of ``phase`` lowered the way the worker
+    calls it: params, pools, ONE packed int32 array."""
+    rep = engine._replicas[0]
+    size = (4 * (engine.max_pages_per_seq + 5) if phase == "decode"
+            else 3 * 8 + 1)
+    jitted = engine._decode_jit if phase == "decode" else engine._prefill_jit
+    return jitted.lower(rep.params, *rep.pools,
+                        jax.ShapeDtypeStruct((size,), jnp.int32))
+
+
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
 def test_serving_steps_carry_their_scopes(engine, phase):
-    rep = engine._replicas[0]
-    i32 = jnp.int32
-    if phase == "decode":
-        lowered = engine._decode_jit.lower(
-            rep.params, *rep.pools, jnp.zeros((4,), i32),
-            jnp.zeros((4,), i32), jnp.zeros((4, 8), i32),
-            jnp.zeros((4,), i32), jnp.zeros((4,), i32),
-            jnp.zeros((4,), i32))
-    else:
-        lowered = engine._prefill_jit.lower(
-            rep.params, *rep.pools, jnp.zeros((8,), i32), i32(3),
-            jnp.zeros((8,), i32), jnp.zeros((8,), i32))
-    found, _ = _scopes_of(lowered, f"_{phase}_fn")
+    found, _ = _scopes_of(_lowered_step(engine, phase),
+                          f"_packed_{phase}_fn")
     assert set(DECODE_SCOPES) <= found, found
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_one_int32_array_crosses_and_the_program_keeps_its_name(engine,
+                                                                phase):
+    """Besides the params and the pools a dispatched step takes exactly
+    one array, the packed int32 one; and its program's name still holds
+    the family function's (``_decode_fn`` / ``_prefill_fn``): a trace's
+    reader finds the step's program by it."""
+    lowered = _lowered_step(engine, phase)
+    rep = engine._replicas[0]
+    args = jax.tree.leaves(lowered.args_info)
+    ints = [a for a in args if a.dtype == jnp.int32]
+    assert len(ints) == 1 and len(ints[0].shape) == 1
+    assert len(args) == (len(jax.tree.leaves(rep.params))
+                         + len(rep.pools) + 1)
+    (module,) = re.findall(r"^module @(\S+)", lowered.as_text(),
+                           flags=re.M)
+    assert re.search(f"_{phase}_fn", module), module
+    other = "prefill" if phase == "decode" else "decode"
+    assert not re.search(f"_{other}_fn", module), module
 
 
 def test_train_step_carries_its_scopes():

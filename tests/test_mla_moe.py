@@ -476,24 +476,23 @@ SCOPES = {"decode": ("embed", "mla_q", "latent_write", "attend_latent",
 
 @pytest.mark.parametrize("phase", sorted(SCOPES))
 def test_steps_carry_their_names_and_scopes(phase):
-    """The engine's jitted steps are ``_prefill_fn`` / ``_decode_fn``
-    whatever the family (a trace's programs carry the names) and every
-    part of this family's lies under a named scope."""
+    """The engine's jitted steps are ``_packed_prefill_fn`` /
+    ``_packed_decode_fn`` whatever the family (a trace's programs carry
+    the names, ``_decode_fn`` within them) and every part of this
+    family's lies under a named scope."""
     cfg = config()
     i32 = jnp.int32
     with engine_for(cfg, weights_for(cfg)) as eng:
         rep = eng._replicas[0]
+        # what the worker hands over: ONE packed int32 array a dispatch
+        # (4 slots of 12 pages, 5 more values a slot; an 8-token prompt)
         if phase == "decode":
             lowered = eng._decode_jit.lower(
-                rep.params, *rep.pools, jnp.zeros((4,), i32),
-                jnp.zeros((4,), i32), jnp.zeros((4, 12), i32),
-                jnp.zeros((4,), i32), jnp.zeros((4,), i32),
-                jnp.zeros((4,), i32))
+                rep.params, *rep.pools, jnp.zeros((4 * (12 + 5),), i32))
         else:
             lowered = eng._prefill_jit.lower(
-                rep.params, *rep.pools, jnp.zeros((8,), i32), i32(3),
-                jnp.zeros((8,), i32), jnp.zeros((8,), i32))
+                rep.params, *rep.pools, jnp.zeros((3 * 8 + 1,), i32))
     text = lowered.as_text(debug_info=True)
-    assert f"jit__{phase}_fn" in text
+    assert f"jit__packed_{phase}_fn" in text
     for scope in SCOPES[phase]:
-        assert f"jit(_{phase}_fn)/{scope}/" in text, scope
+        assert f"jit(_packed_{phase}_fn)/{scope}/" in text, scope
