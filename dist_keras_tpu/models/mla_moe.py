@@ -5,7 +5,7 @@ entered three ways over the same functions: :func:`forward` (a whole
 sequence, no cache: parity tests, a later training step),
 :func:`prefill_step` (one padded prompt, writes the latent pool) and
 :func:`decode_step` (one token a slot, reads it).  ``DecodeEngine`` takes
-the two steps and :func:`cache_entry_shapes` from here when the model's
+the two steps and :func:`cache_pools` from here when the model's
 ``cfg["family"]`` says ``"mla_moe"``.
 
 Per layer ``h <- h + Attn(RMSNorm(h))``, ``h <- h + FFN(RMSNorm(h))``;
@@ -131,6 +131,13 @@ def cache_entry_shapes(cfg):
     return ((-(-width // LANES) * LANES,),)
 
 
+def cache_pools(cfg):
+    """What the engine allocates: the one latent pool, paged, a row a
+    cached position in every layer."""
+    return tuple((cfg["n_layers"], "page", entry)
+                 for entry in cache_entry_shapes(cfg))
+
+
 def _pad_lanes(x, cfg):
     """``(..., rank + rope)`` -> the pool's row width, zeros behind."""
     pad = cache_entry_shapes(cfg)[0][0] - x.shape[-1]
@@ -220,12 +227,15 @@ def swiglu(p, x):
 
 def route(moe, x, cfg):
     """-> (expert ids (N, k), weights (N, k) float32) over ALL the routed
-    experts, held here or not."""
+    experts, held here or not.  The chosen scores are divided by their
+    sum plus ``cfg["route_norm_eps"]`` (this family's published code:
+    1e-20, the default; ``models/lfm2_moe.py`` states its own 1e-6)."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), moe["router"].astype(jnp.float32),
         precision="highest"))
     idx, w = select_top_k(s, moe["router_bias"], cfg["top_k"])
-    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    w = w / (jnp.sum(w, -1, keepdims=True)
+             + cfg.get("route_norm_eps", 1e-20))
     return idx, w * cfg["routed_scaling_factor"]
 
 
@@ -257,14 +267,16 @@ def held_experts(experts, x, idx, w, first_held, valid):
 
 
 def moe_layer(moe, x, cfg, valid):
-    """-> (the layer's output for ``x (N, d)``, routing counts)."""
+    """-> (the layer's output for ``x (N, d)``, routing counts).  A
+    layer whose ``moe`` holds no ``"shared"`` has no shared expert."""
     with jax.named_scope("moe_route"):
         idx, w = route(moe, x, cfg)
     with jax.named_scope("moe_experts"):
         y, sizes = held_experts(moe["experts"], x, idx, w,
                                 cfg["held_experts"][0], valid)
-    with jax.named_scope("moe_shared"):
-        y = y + swiglu(moe["shared"], x)
+    if "shared" in moe:
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(moe["shared"], x)
     total = jnp.sum(valid, dtype=jnp.int32) * cfg["top_k"]
     return y, jnp.concatenate([
         sizes, jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32), total])])
@@ -417,6 +429,25 @@ def decode_step(cfg, params, pool, tokens, positions, page_tables,
     return jnp.concatenate([nxt, counts]), pool
 
 
+def observe_routing(counts, at, decode):
+    """The routing counts behind a step's tokens -> the ``decode.moe.*``
+    instruments: the pairs of every step and prefill, and for a
+    ``decode`` step one sample of each per-step histogram, stamped ``at``
+    like ``decode.step_s``."""
+    from dist_keras_tpu.observability import metrics
+
+    counts = np.asarray(counts)
+    held, hit, total = counts[:-N_COUNTS], counts[-2], counts[-1]
+    metrics.counter("decode.moe.pairs_total").inc(int(total))
+    metrics.counter("decode.moe.pairs_held").inc(int(held.sum()))
+    if not decode:
+        return
+    metrics.histogram("decode.moe.experts_hit").observe(int(hit), at=at)
+    if held.sum() > 0:
+        metrics.histogram("decode.moe.load_max_over_mean").observe(
+            held.max() / held.mean(), at=at)
+
+
 def observe_step(counts, at, lengths=None, page_size=None):
     """The counts behind a step's tokens -> the registry.  A decode step
     passes its slots' ``lengths`` (host values, zeros for padding) and
@@ -426,20 +457,13 @@ def observe_step(counts, at, lengths=None, page_size=None):
     prefill only adds its pairs."""
     from dist_keras_tpu.observability import metrics
 
-    counts = np.asarray(counts)
-    held, hit, total = counts[:-N_COUNTS], counts[-2], counts[-1]
-    metrics.counter("decode.moe.pairs_total").inc(int(total))
-    metrics.counter("decode.moe.pairs_held").inc(int(held.sum()))
+    observe_routing(counts, at, decode=lengths is not None)
     if lengths is None:
         return
     metrics.histogram("decode.latent.live_positions").observe(
         int(lengths.sum()), at=at)
     metrics.histogram("decode.latent.walked_positions").observe(
         latent_walked_positions(lengths, page_size), at=at)
-    metrics.histogram("decode.moe.experts_hit").observe(int(hit), at=at)
-    if held.sum() > 0:
-        metrics.histogram("decode.moe.load_max_over_mean").observe(
-            held.max() / held.mean(), at=at)
 
 
 class LatentMoEDecoder:

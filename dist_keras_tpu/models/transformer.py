@@ -199,7 +199,7 @@ def transformer_apply(params, x, cfg, *, causal=False, attn_fn=None,
 
 
 # -- what ``serving.decode.DecodeEngine`` takes from a block family -----
-# (``models/mla_moe.py`` has the same five names)
+# (``models/mla_moe.py`` and ``models/lfm2_moe.py`` have the same names)
 def vocab(cfg):
     """The vocabulary a decoder of ``cfg`` reads and writes; a config
     this family cannot decode is refused here."""
@@ -224,6 +224,13 @@ def cache_entry_shapes(cfg):
     of ``heads x head_dim`` entries."""
     heads = cfg["n_heads"]
     return ((heads, cfg["d_model"] // heads),) * 2
+
+
+def cache_pools(cfg):
+    """What the engine allocates: the K and the V pool, paged, an entry
+    a cached position in every layer."""
+    return tuple((cfg["n_layers"], "page", entry)
+                 for entry in cache_entry_shapes(cfg))
 
 
 def prefill_step(cfg, params, kp, vp, tokens, length, page_idx, page_off):

@@ -362,6 +362,8 @@ KNOWN_METRICS = {
     "decode.moe.experts_hit": "histogram",
     "decode.latent.live_positions": "histogram",
     "decode.latent.walked_positions": "histogram",
+    "decode.kv.live_positions": "histogram",
+    "decode.state_rows_used": "gauge",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

@@ -161,8 +161,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = lse.astype(lse_ref.dtype)   # (BQ, 1)
 
 
-def _kv_index_map(causal, block_q, block_k, q_offset, kv_offset):
+def _kv_index_map(causal, block_q, block_k, q_offset, kv_offset, group=1):
     """K/V index map for (bh, q_blocks, kv_blocks=innermost) grids.
+    With ``group`` query heads to a K/V head (grouped-query attention)
+    query problem ``b`` reads K/V problem ``b // group``: both are
+    (batch x heads) flat, heads minor, so the batch carries over.
 
     For causal attention, tiles strictly above the diagonal are skipped
     by ``pl.when`` — but Pallas still DMAs each grid step's blocks into
@@ -171,28 +174,35 @@ def _kv_index_map(causal, block_q, block_k, q_offset, kv_offset):
     step re-address the block already in VMEM; Pallas elides the copy
     when the index is unchanged, so masked tiles cost no HBM traffic."""
     if not causal:
-        return lambda b, i, j: (b, j, 0)
+        index = lambda b, i, j: (b, j, 0)                     # noqa: E731
+    else:
+        def index(b, i, j):
+            jmax = jnp.maximum(
+                (q_offset + (i + 1) * block_q - 1 - kv_offset) // block_k,
+                0)
+            return (b, jnp.minimum(j, jmax), 0)
 
-    def index(b, i, j):
-        jmax = jnp.maximum(
-            (q_offset + (i + 1) * block_q - 1 - kv_offset) // block_k, 0)
-        return (b, jnp.minimum(j, jmax), 0)
-
-    return index
+    if group == 1:
+        return index
+    return lambda b, i, j: index(b // group, i, j)
 
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, q_offset,
               kv_offset, interpret):
-    """q: (BH, Tq, D), k: (BH, Tk, D), v: (BH, Tk, Dv) -> (out (BH, Tq,
-    Dv), lse (BH, Tq, 1)).  The values may be narrower or wider than the
-    queries and keys (latent attention: 192 and 128); the backward is
-    written for ``Dv == D`` only."""
+    """q: (BH, Tq, D), k: (BHkv, Tk, D), v: (BHkv, Tk, Dv) -> (out (BH,
+    Tq, Dv), lse (BH, Tq, 1)).  The values may be narrower or wider than
+    the queries and keys (latent attention: 192 and 128), and there may
+    be fewer K/V heads than query heads (grouped-query attention: ``BH /
+    BHkv`` consecutive query heads read one K/V head, fetched through the
+    index map, never repeated in memory); the backward is written for
+    ``Dv == D`` and ``BHkv == BH`` only."""
     bh, tq, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
-    kv_map = _kv_index_map(causal, block_q, block_k, q_offset, kv_offset)
+    kv_map = _kv_index_map(causal, block_q, block_k, q_offset, kv_offset,
+                           group=bh // k.shape[0])
     return pl.pallas_call(
         kernel,
         grid=(bh, tq // block_q, tk // block_k),
@@ -443,7 +453,8 @@ def _from_bh(x, b, h):
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              block_q=1024, block_k=1024, q_offset=0,
                              kv_offset=0, interpret=False):
-    """q,k,v: (B, T, H, D) -> (out (B,T,H,D), lse (B,H,T) float32).
+    """q,k,v: (B, T, H, D) -> (out (B,T,H,D), lse (B,H,T) float32); k
+    and v may have fewer heads, a divisor of H (grouped-query attention).
 
     Falls back to the jnp reference when T doesn't tile evenly (rare;
     tests and ragged tails).  Offsets shift the *global* positions of the
@@ -455,11 +466,13 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     bq = _fit_block(tq, block_q)
     bk = _fit_block(tk, block_k)
     if bq is None or bk is None:
-        return _ref_with_lse(q, k, v, causal=causal, scale=scale,
-                             q_offset=q_offset, kv_offset=kv_offset)
-    if v.shape[-1] != d:
-        # values of another width than queries and keys: forward only
-        # (the custom backward assumes one head_dim)
+        return _ref_with_lse(q, *repeat_kv_heads(h, k, v), causal=causal,
+                             scale=scale, q_offset=q_offset,
+                             kv_offset=kv_offset)
+    if v.shape[-1] != d or k.shape[2] != h:
+        # values of another width than queries and keys, or fewer K/V
+        # heads than query heads: forward only (the custom backward
+        # assumes one head_dim and one head count)
         out, lse = _fwd_call(_to_bh(q), _to_bh(k), _to_bh(v), causal,
                              scale, bq, bk, int(q_offset), int(kv_offset),
                              interpret)
@@ -467,6 +480,17 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     out, lse = _flash_core(_to_bh(q), _to_bh(k), _to_bh(v), causal, scale,
                            bq, bk, int(q_offset), int(kv_offset), interpret)
     return _from_bh(out, b, h), lse.reshape(b, h, tq)  # lse (BH, T, 1)
+
+
+def repeat_kv_heads(h, k, v):
+    """K and V ``(B, T, Hkv, D)`` with each head repeated to ``h`` query
+    heads (query heads ``g*i .. g*i + g - 1`` read K/V head ``i``): what
+    the ``jnp`` references take, which know one head count.  The arrays
+    themselves when the counts are equal."""
+    group = h // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=1024,
@@ -488,4 +512,5 @@ def attention_auto(q, k, v, causal=False, scale=None, block_q=1024,
                                block_q=block_q, block_k=block_k)
     from dist_keras_tpu.ops.attention import attention
 
-    return attention(q, k, v, causal=causal, scale=scale)
+    return attention(q, *repeat_kv_heads(q.shape[2], k, v), causal=causal,
+                     scale=scale)

@@ -22,23 +22,30 @@ Design points (each mirrors an existing engine contract):
   (x replica devices, inherent) — the same no-retrace contract
   ``ServingEngine.stats()["retrace_count"]`` verifies, reported the
   same way.
-- **Paged KV.**  Each replica owns the pools of its model's block
-  family (its ``cache_entry_shapes``: one K and one V pool of ``heads x
-  head_dim`` entries for ``models/transformer.py``, ONE pool of ``latent
-  + rope`` wide entries for ``models/mla_moe.py``), each of shape
+- **Paged KV, and per-sequence state beside it.**  Each replica owns
+  the pools of its model's block family.  The family states each pool
+  (its ``cache_pools``) as ``(layers it spans, "page" or "sequence", the
+  entry's shape)``: one K and one V pool of ``heads x head_dim`` entries
+  over every layer for ``models/transformer.py``, ONE pool of ``latent +
+  rope`` wide entries over every layer for ``models/mla_moe.py``, and for
+  ``models/lfm2_moe.py`` one paged pool of ``v | k`` rows over its
+  attention layers only and one pool of convolution state, a row a
+  SEQUENCE, over its convolution layers only.  A paged pool is
   ``(layers, num_pages + 1, page_size, *entry)`` — page-major, so that
   the steps' scatters over (page, offset) update the donated pools in
   place and the attention reads whole pages with no copy of the pool or
-  of a layer of it — and a
-  :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache` allocator.  The
-  family is named by the model's ``cfg`` and taken once, at construction
-  (``_FAMILIES``: its two step functions and its cache entry); the
-  scheduler, the allocator and recovery's replay see no family.
+  of a layer of it; a per-sequence pool is ``(layers, state_rows + 1,
+  *entry)``.  One :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache`
+  allocator hands out both: a sequence's row is reserved with its pages
+  and returned with them.  The family is named by the model's ``cfg`` and
+  taken once, at construction (``_FAMILIES``: its two step functions and
+  its pools); the scheduler, the allocator and recovery's replay see no
+  family.
   Admission reserves a sequence's WORST-CASE page count up front, so
   decode never stalls mid-sequence on KV: exhaustion is a typed
   ``Overloaded(reason="kv_exhausted")`` strictly at the door (rejected,
   not lost), and completion/cancel/error all reclaim through the one
-  allocator path (zero leaked pages — the chaos tests assert it).
+  allocator path (zero leaked pages or rows — the chaos tests assert it).
 - **Hot reload never drops a sequence.**  ``submit_generate`` pins the
   replica's CURRENT params reference into the sequence; a
   ``set_params`` (CheckpointWatcher promotion, blue/green cutover)
@@ -55,7 +62,8 @@ Design points (each mirrors an existing engine contract):
   fault past the in-place retry) QUARANTINES that replica: its KV
   pages free, its in-flight sequences re-admit onto surviving
   replicas and REPLAY — prefill over the prompt, then teacher-forced
-  decode steps over the already-generated tokens (the canonical
+  decode steps over the already-generated tokens, which rebuild a
+  per-sequence state as they rebuild the KV (the canonical
   ``seq.tokens`` are kept; replayed predictions are discarded, so
   streaming callbacks resume exactly where they stopped and the
   final doc is bit-identical to an undisturbed greedy run).  Futures
@@ -98,7 +106,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models import mla_moe, transformer
+from dist_keras_tpu.models import lfm2_moe, mla_moe, transformer
 from dist_keras_tpu.observability import events, metrics, perf, spans
 from dist_keras_tpu.observability import slo as _slo
 from dist_keras_tpu.resilience.faults import fault_point
@@ -126,12 +134,13 @@ class _Sequence:
 
     __slots__ = ("sid", "tokens", "prompt_len", "max_new", "eos_id",
                  "future", "on_token", "t", "tw", "ctx", "params",
-                 "params_host", "pages", "kv_len", "steps", "cancelled",
+                 "params_host", "pages", "row", "kv_len", "steps",
+                 "cancelled",
                  "ttft_s", "t_first", "deadline", "priority",
                  "recoveries", "finished")
 
     def __init__(self, sid, tokens, max_new, eos_id, on_token, params,
-                 params_host, pages, deadline=None,
+                 params_host, pages, row, deadline=None,
                  priority="interactive"):
         self.sid = sid
         self.tokens = list(tokens)
@@ -146,6 +155,7 @@ class _Sequence:
         self.params = params      # pinned: reloads never touch us
         self.params_host = params_host  # host ref: re-pin on recovery
         self.pages = pages
+        self.row = row            # its per-sequence state row, or None
         self.kv_len = 0           # KV positions written so far
         self.steps = 0            # decode iterations consumed
         self.cancelled = False
@@ -194,16 +204,19 @@ class Generation:
 # A block family is a module under ``models/`` with ``FAMILY`` (the name a
 # model's ``cfg["family"]`` gives; a cfg that names none is a
 # ``Transformer``'s), ``vocab(cfg)`` (which also refuses what the family
-# cannot decode), ``cache_entry_shapes(cfg)`` (the trailing shape of each
-# pool: what one cached position of one layer is), ``prefill_step`` /
-# ``decode_step`` (``(cfg, params, *pools, ...) -> (int32 array,
-# *pools)``: the tokens first, then whatever counts the family sends
-# along) and ``observe_step(counts, at, lengths=None, page_size=None)``
-# for those counts (None when the family sends none).
-_FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe)}
+# cannot decode), ``cache_pools(cfg)`` (for each pool ``(layers, rows,
+# entry)``: how many layers it spans, whether its rows are ``"page"``s of
+# cached positions or one ``"sequence"`` each, and the trailing shape of
+# one entry), ``prefill_step`` / ``decode_step`` (``(cfg, params, *pools,
+# ...) -> (int32 array, *pools)``: the tokens first, then whatever counts
+# the family sends along; a family with a per-sequence pool is also
+# handed the state rows, last) and ``observe_step(counts, at,
+# lengths=None, page_size=None)`` for those counts (None when the family
+# sends none).
+_FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe, lfm2_moe)}
 
 
-def _step_views(packed, pmax):
+def _step_views(packed, pmax, state=False):
     """The six arrays of a decode step inside its ONE packed int32 array,
     in ``decode_step``'s order.  ``packed`` holds ``rung * (pmax + 5)``
     values: the page tables row by row (``pmax`` entries a slot), then
@@ -214,19 +227,27 @@ def _step_views(packed, pmax):
     because the compiled step is then no longer than with six arguments
     (one copy into fast memory, whole-tile slices; the latent kernel
     wants its tables flat anyway), and no slower on the chip: PERF.md,
-    PR 31."""
-    rung = packed.shape[0] // (pmax + 5)
+    PR 31.  For a family that holds per-sequence ``state`` a seventh
+    array follows, the slots' state rows (``rung * (pmax + 6)`` values);
+    every other family is handed exactly the six."""
+    arrays = 6 if state else 5
+    rung = packed.shape[0] // (pmax + arrays)
     tables = packed[:rung * pmax].reshape(rung, pmax)
-    toks, positions, wpage, woff, lengths = \
-        packed[rung * pmax:].reshape(5, rung)
-    return toks, positions, tables, wpage, woff, lengths
+    toks, positions, wpage, woff, lengths, *rows = \
+        packed[rung * pmax:].reshape(arrays, rung)
+    return (toks, positions, tables, wpage, woff, lengths, *rows)
 
 
-def _prefill_views(packed):
+def _prefill_views(packed, state=False):
     """The four arrays of a prefill inside its ONE packed int32 array, in
     ``prefill_step``'s order: ``3 * rung + 1`` values, the tokens, page
     indices and page offsets a rung each, then the prompt's length (on
-    the host a copy, not a view: ``_prefill`` writes ``packed[-1]``)."""
+    the host a copy, not a view: ``_prefill`` writes ``packed[-1]``).
+    For a family that holds per-sequence ``state`` the sequence's state
+    row follows the length (``3 * rung + 2`` values)."""
+    if state:
+        toks, page_idx, page_off = packed[:-2].reshape(3, -1)
+        return toks, packed[-2], page_idx, page_off, packed[-1]
     toks, page_idx, page_off = packed[:-1].reshape(3, -1)
     return toks, packed[-1], page_idx, page_off
 
@@ -286,8 +307,9 @@ class DecodeEngine:
     """Continuous-batching decode over a causal decoder.
 
     Args:
-      keras_model: a ``models.transformer.Transformer`` or a
-        ``models.mla_moe.LatentMoEDecoder`` (or anything the
+      keras_model: a ``models.transformer.Transformer``, a
+        ``models.mla_moe.LatentMoEDecoder`` or a
+        ``models.lfm2_moe.Lfm2MoeDecoder`` (or anything the
         serialization layer round-trips to one); its ``cfg`` names the
         block family.  A ``Transformer`` decodes with token in == logit
         out, so its config must have ``input_dim == n_classes`` (the
@@ -303,6 +325,10 @@ class DecodeEngine:
       num_pages: pool pages per replica.  Default sizes the pool so a
         full slot set of maximum-length sequences fits.
       max_queue: admission bound on admitted-but-unresolved sequences.
+      state_rows: per-sequence state rows per replica, for a family that
+        keeps such state (ignored otherwise).  Default: one for every
+        sequence the door can admit (``min(max_queue, num_pages)``), so
+        that pages and the queue bound refuse before rows do.
       max_new_default: ``max_new_tokens`` when a request omits it.
       eos_id: default stop token (None = length-only stopping).
       devices: explicit device list (default ``jax.devices()``).
@@ -321,15 +347,16 @@ class DecodeEngine:
                  page_size=8, num_pages=None, max_queue=256,
                  max_new_default=16, eos_id=None, devices=None,
                  step_retries=1, shed_watermark=None,
-                 self_check_interval_s=1.0):
+                 self_check_interval_s=1.0, state_rows=None):
         self.serialized = serialize_model(keras_model)
         model = deserialize_model(self.serialized)
         cfg = getattr(model, "cfg", None)
         if cfg is None:
             raise ValueError(
                 "DecodeEngine needs a decoder's model contract (a cfg "
-                "dict: models.transformer.Transformer or "
-                f"models.mla_moe.LatentMoEDecoder); got "
+                "dict: models.transformer.Transformer, "
+                "models.mla_moe.LatentMoEDecoder or "
+                f"models.lfm2_moe.Lfm2MoeDecoder); got "
                 f"{type(model).__name__}")
         self.cfg = cfg
         # the model's block family, looked up once: everything below
@@ -360,11 +387,21 @@ class DecodeEngine:
         if num_pages is None:
             num_pages = self.max_slots * self.max_pages_per_seq
         self.num_pages = int(num_pages)
+        self._pools = tuple(self._family.cache_pools(cfg))
+        # a family with a pool of per-sequence rows: every sequence holds
+        # one, and a dispatch names the rows in one more packed column
+        self._state = any(rows == "sequence" for _, rows, _ in self._pools)
+        if not self._state:
+            state_rows = 0
+        elif state_rows is None:
+            state_rows = min(self.max_queue, self.num_pages)
+        self.state_rows = int(state_rows)
+        if self._state and self.state_rows < 1:
+            raise ValueError(f"state_rows={state_rows} must be >= 1")
 
         # donation keeps the pool update in place: a dispatch consumes
         # the replica's pools and returns their successors
-        donated = tuple(
-            range(1, 1 + len(self._family.cache_entry_shapes(cfg))))
+        donated = tuple(range(1, 1 + len(self._pools)))
         self._prefill_jit = jax.jit(self._packed_prefill_fn,
                                     donate_argnums=donated)
         self._decode_jit = jax.jit(self._packed_decode_fn,
@@ -440,6 +477,7 @@ class DecodeEngine:
         self._reg_queue_wait = metrics.histogram("decode.queue_wait_s")
         self._reg_active = metrics.gauge("decode.active")
         self._reg_kv = metrics.gauge("decode.kv_used_pages")
+        self._reg_rows = metrics.gauge("decode.state_rows_used")
         perf.install()  # retrace listener: the ladder bound, verified
 
         self._workers = [threading.Thread(
@@ -453,7 +491,8 @@ class DecodeEngine:
     def _prefill_fn(self, params, *args):
         """``(params, *pools, tokens, length, page_idx, page_off)``: one
         padded prompt -> (int32 array, the first token in front, *updated
-        pools).  The family's step with its four integer arrays apart;
+        pools).  The family's step with its four integer arrays apart (a
+        fifth, the state row, for a family with per-sequence state);
         what is dispatched is :meth:`_packed_prefill_fn` around it."""
         return self._family.prefill_step(self.cfg, params, *args)
 
@@ -461,7 +500,8 @@ class DecodeEngine:
         """``(params, *pools, tokens, positions, page_tables, write_page,
         write_off, lengths)``: one token step for a padded slot set ->
         (int32 array, the next tokens in front, *updated pools).  The
-        family's step with its six integer arrays apart; what is
+        family's step with its six integer arrays apart (a seventh, the
+        state rows, for a family with per-sequence state); what is
         dispatched is :meth:`_packed_decode_fn` around it."""
         return self._family.decode_step(self.cfg, params, *args)
 
@@ -474,32 +514,38 @@ class DecodeEngine:
         """``(params, *pools, packed)``: :meth:`_prefill_fn` on the four
         arrays :func:`_prefill_views` cuts out of ``packed``."""
         *pools, packed = args
-        return self._prefill_fn(params, *pools, *_prefill_views(packed))
+        return self._prefill_fn(
+            params, *pools, *_prefill_views(packed, self._state))
 
     def _packed_decode_fn(self, params, *args):
         """``(params, *pools, packed)``: :meth:`_decode_fn` on the six
         arrays :func:`_step_views` cuts out of ``packed``."""
         *pools, packed = args
         return self._decode_fn(
-            params, *pools, *_step_views(packed, self.max_pages_per_seq))
+            params, *pools,
+            *_step_views(packed, self.max_pages_per_seq, self._state))
 
     @property
     def pool_shapes(self):
-        """The shape of each pool a replica holds (float32), page-major:
-        ``(layers, num_pages + 1, page_size, *entry)`` for every entry of
-        the family's ``cache_entry_shapes``; page index ``num_pages`` is
-        the scratch page.  The one statement of the layout: replicas
-        allocate from it and the jitted steps read everything else off
-        the pools they are given."""
+        """The shape of each pool a replica holds (float32), from the
+        family's ``cache_pools``: a paged pool is page-major, ``(layers,
+        num_pages + 1, page_size, *entry)``, page index ``num_pages`` the
+        scratch page; a per-sequence pool is ``(layers, state_rows + 1,
+        *entry)``, row index ``state_rows`` the scratch row.  The one
+        statement of the layout: replicas allocate from it and the
+        jitted steps read everything else off the pools they are
+        given."""
         return tuple(
-            (self.cfg["n_layers"], self.num_pages + 1, self.page_size)
+            (layers,) + ((self.num_pages + 1, self.page_size)
+                         if rows == "page" else (self.state_rows + 1,))
             + tuple(entry)
-            for entry in self._family.cache_entry_shapes(self.cfg))
+            for layers, rows, entry in self._pools)
 
     def _make_replica(self, index):
         devs = self._devices
         device = devs[index % len(devs)] if devs else None
-        cache = PagedKVCache(self.num_pages, self.page_size)
+        cache = PagedKVCache(self.num_pages, self.page_size,
+                             self.state_rows)
         # allocated ON the replica's device: N pools staged through the
         # default device would cost it N pools of peak memory
         pools = [jnp.zeros(shape, jnp.float32, device=device)
@@ -520,9 +566,13 @@ class DecodeEngine:
 
     def _pick_replica(self, needed_pages):
         """Most free pages wins (KV is the scarce resource), round-robin
-        on ties; retiring and quarantined replicas are out of rotation.
-        Caller holds the lock."""
+        on ties; retiring and quarantined replicas are out of rotation,
+        and so is one whose state rows are all held (a family that keeps
+        per-sequence state).  Caller holds the lock."""
         live = self._live_replicas_locked()
+        if self._state:
+            live = [r for r in live
+                    if r.cache.used_rows() < r.cache.state_rows]
         if not live:
             return None, 0
         frees = [r.cache.stats()["free_pages"] for r in live]
@@ -650,7 +700,7 @@ class DecodeEngine:
             pages = rep.cache.alloc(sid, total)
             seq = _Sequence(
                 sid, toks, max_new, eos, on_token, rep.params,
-                rep.params_host, pages,
+                rep.params_host, pages, self._row_of(rep, sid),
                 deadline=(None if deadline_s is None
                           else time.monotonic() + deadline_s),
                 priority=priority)
@@ -658,12 +708,19 @@ class DecodeEngine:
             self._outstanding += 1
             self._n_admitted += 1
             self._reg_active.set(self._outstanding)
+            if self._state:
+                self._reg_rows.set(sum(r.cache.used_rows()
+                                       for r in self._replicas))
             self._cond.notify_all()
         self._reg_admitted.inc()
         events.emit("decode_admit", sid=sid, prompt_len=len(toks),
                     max_new=max_new, replica=rep.index,
                     pages=len(pages))
         return Generation(self, seq)
+
+    def _row_of(self, rep, sid):
+        """The state row ``alloc`` reserved with a sequence's pages."""
+        return rep.cache.state_row(sid) if self._state else None
 
     def generate(self, tokens, max_new_tokens=None, eos_id=None,
                  timeout_s=None):
@@ -747,6 +804,9 @@ class DecodeEngine:
         self._reg_active.set(self._outstanding)
         self._reg_kv.set(sum(r.cache.used_pages()
                              for r in self._replicas))
+        if self._state:
+            self._reg_rows.set(sum(r.cache.used_rows()
+                                   for r in self._replicas))
         self._cond.notify_all()
 
     def _emit_token(self, seq, token):
@@ -772,10 +832,11 @@ class DecodeEngine:
         """Run one admitted prompt through its prefill ``rung``; emits
         the first generated token (TTFT) or fails the sequence typed.
 
-        One crossing each way: the prompt's four integer arrays go to
-        the device as ONE packed int32 array (:func:`_prefill_views`, cut
-        apart inside the compiled step), the token comes back in the one
-        array the wait region fetches.
+        One crossing each way: the prompt's four integer arrays (and
+        the sequence's state row, where the family keeps such state) go
+        to the device as ONE packed int32 array (:func:`_prefill_views`,
+        cut apart inside the compiled step), the token comes back in the
+        one array the wait region fetches.
 
         A RECOVERED sequence (``seq.tokens`` longer than the prompt)
         replays the same prefill over the prompt only — its prediction
@@ -783,14 +844,18 @@ class DecodeEngine:
         and the teacher-forced decode steps replay the rest."""
         with perf.phase("decode.prefill.build"):
             n, ps = seq.prompt_len, self.page_size
-            packed = np.zeros((3 * rung + 1,), np.int32)
-            toks, _, page_idx, page_off = _prefill_views(packed)
+            packed = np.zeros((3 * rung + 1 + self._state,), np.int32)
+            toks, _, page_idx, page_off, *_ = _prefill_views(
+                packed, self._state)
             toks[:n] = seq.tokens[:n]
             # position t goes to page t // ps; the padding to the scratch
             page_idx[:n] = np.repeat(seq.pages, ps)[:n]
             page_idx[n:] = rep.cache.scratch_page
             page_off[:] = np.arange(rung) % ps
-            packed[-1] = n
+            if self._state:
+                packed[-2:] = n, seq.row
+            else:
+                packed[-1] = n
         replay = len(seq.tokens) > seq.prompt_len
         t0 = time.perf_counter()
         tw0 = time.time()
@@ -881,10 +946,15 @@ class DecodeEngine:
         with perf.phase("decode.step.build"):
             ps = self.page_size
             pmax = self.max_pages_per_seq
-            packed = np.zeros((rung * (pmax + 5),), np.int32)
-            toks, positions, tables, wpage, woff, lengths = \
-                _step_views(packed, pmax)
+            packed = np.zeros((rung * (pmax + 5 + self._state),),
+                              np.int32)
+            toks, positions, tables, wpage, woff, lengths, *rows = \
+                _step_views(packed, pmax, self._state)
             wpage[:] = rep.cache.scratch_page
+            if self._state:
+                # a padding slot's state goes to the scratch row
+                rows[0][:] = rep.cache.scratch_row
+                rows[0][:len(group)] = [seq.row for seq in group]
             for i, seq in enumerate(group):
                 toks[i] = seq.tokens[seq.kv_len]
                 positions[i] = seq.kv_len
@@ -1117,8 +1187,9 @@ class DecodeEngine:
         for rep in live:
             try:
                 seq.pages = rep.cache.alloc(seq.sid, total)
-            except PagesExhausted:
+            except PagesExhausted:      # pages or state rows
                 continue
+            seq.row = self._row_of(rep, seq.sid)
             return rep
         return None
 
@@ -1434,6 +1505,8 @@ class DecodeEngine:
             "num_pages": total,
             "used_pages": used,
             "peak_pages": sum(p["peak_pages"] for p in per),
+            "state_rows": sum(p["state_rows"] for p in per),
+            "used_rows": sum(p["used_rows"] for p in per),
             "occupancy": (used / total) if total else 0.0,
             "sequences": sum(p["sequences"] for p in per),
             "replicas": per,
@@ -1448,11 +1521,11 @@ class DecodeEngine:
             idle = self._outstanding == 0
         if idle:
             for rep in self._replicas:
-                used = rep.cache.used_pages()
-                if used:
+                used, rows = rep.cache.used_pages(), rep.cache.used_rows()
+                if used or rows:
                     raise AssertionError(
-                        f"replica {rep.index} leaked {used} KV pages "
-                        "with no sequence outstanding")
+                        f"replica {rep.index} leaked {used} KV pages and "
+                        f"{rows} state rows with no sequence outstanding")
 
     def stats(self):
         """JSON-ready engine counters — the ``/metricsz`` payload core

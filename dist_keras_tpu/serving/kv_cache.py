@@ -29,6 +29,18 @@ Contract (the decode engine's admission story depends on every clause):
   jitted scatter, and the scratch page absorbs them.  It is never
   allocated, never read (masked by per-sequence lengths), and never
   counted.
+- **State rows: the second kind of cache, the same manager.**  A block
+  family may keep, beside its paged entries, a fixed-size state a
+  SEQUENCE (``models/lfm2_moe.py``: the last columns of a short
+  convolution's input), in a pool addressed by row, not by (page,
+  offset).  With ``state_rows > 0`` every ``alloc`` also reserves one
+  row for the sequence and every ``free`` returns it: one call each, so
+  a row travels every path a page does (admission, completion, cancel,
+  error, quarantine, the self-check) and ``assert_balanced`` covers
+  both.  A pool with no row left refuses with :class:`StateRowsExhausted`
+  (a :class:`PagesExhausted`: the same typed door), side-effect free.
+  Row index ``state_rows`` is the scratch row, the padding slots' spill
+  target, as the scratch page is.
 
 Thread safety: the allocator has its own lock, but the decode engine
 additionally serializes alloc/free per replica under its scheduler
@@ -60,25 +72,41 @@ class PagesExhausted(RuntimeError):
             f"{self.free} free of {self.capacity}")
 
 
+class StateRowsExhausted(PagesExhausted):
+    """Typed allocation failure: every per-sequence state row is held
+    (``needed`` is 1).  The same door as its base class; nothing is
+    allocated on this path."""
+
+    def __init__(self, free, capacity):
+        RuntimeError.__init__(
+            self, f"state rows exhausted: {free} free of {capacity}")
+        self.needed, self.free, self.capacity = 1, int(free), int(capacity)
+
+
 class PagedKVCache:
-    """Free-list page allocator over a ``num_pages`` pool.
+    """Free-list page allocator over a ``num_pages`` pool, and over
+    ``state_rows`` per-sequence rows where the family keeps such state.
 
     Pure host-side accounting — the device pool arrays live with the
-    replica that owns them (the engine threads page ids from here into
-    the jitted prefill/decode scatters).
+    replica that owns them (the engine threads page and row ids from
+    here into the jitted prefill/decode scatters).
     """
 
-    def __init__(self, num_pages, page_size):
+    def __init__(self, num_pages, page_size, state_rows=0):
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        if self.num_pages < 1 or self.page_size < 1:
+        self.state_rows = int(state_rows)
+        if self.num_pages < 1 or self.page_size < 1 or self.state_rows < 0:
             raise ValueError(
                 f"PagedKVCache(num_pages={num_pages}, "
-                f"page_size={page_size}): both must be >= 1")
+                f"page_size={page_size}, state_rows={state_rows}): pages "
+                "and page size must be >= 1, state rows >= 0")
         # LIFO free list: a just-freed page is the next handed out, so
         # a steady workload touches a small working set of pages
         self._free = list(range(self.num_pages - 1, -1, -1))
         self._held = {}      # seq_id -> [page ids]
+        self._free_rows = list(range(self.state_rows - 1, -1, -1))
+        self._rows = {}      # seq_id -> state row
         self._peak = 0
         self._allocs = 0
         self._frees = 0
@@ -89,16 +117,23 @@ class PagedKVCache:
         """The write-only spill page index (one past the pool)."""
         return self.num_pages
 
+    @property
+    def scratch_row(self):
+        """The write-only spill row index (one past the state rows)."""
+        return self.state_rows
+
     def pages_for(self, tokens):
         """Pages needed to hold ``tokens`` KV positions."""
         t = int(tokens)
         return max(1, -(-t // self.page_size))
 
     def alloc(self, seq_id, tokens):
-        """Reserve every page ``tokens`` positions need; -> page-id
-        list.  Raises :class:`PagesExhausted` (side-effect free) when
-        the free list cannot cover it, ``ValueError`` on a duplicate
-        ``seq_id`` (an accounting bug, not load)."""
+        """Reserve every page ``tokens`` positions need, and the
+        sequence's state row where the pool has any; -> page-id list
+        (the row: :meth:`state_row`).  Raises :class:`PagesExhausted`
+        (side-effect free) when either free list cannot cover it,
+        ``ValueError`` on a duplicate ``seq_id`` (an accounting bug, not
+        load)."""
         fault_point("decode.kv_alloc")
         n = self.pages_for(tokens)
         with self._lock:
@@ -107,6 +142,10 @@ class PagedKVCache:
                     f"sequence {seq_id!r} already holds pages")
             if n > len(self._free):
                 raise PagesExhausted(n, len(self._free), self.num_pages)
+            if self.state_rows:
+                if not self._free_rows:
+                    raise StateRowsExhausted(0, self.state_rows)
+                self._rows[seq_id] = self._free_rows.pop()
             pages = [self._free.pop() for _ in range(n)]
             self._held[seq_id] = pages
             self._allocs += 1
@@ -115,21 +154,33 @@ class PagedKVCache:
             return list(pages)
 
     def free(self, seq_id):
-        """Return every page ``seq_id`` holds to the free list — the
-        single reclamation path for completion, cancel, error and
-        engine shutdown.  Idempotent-hostile by design: freeing an
-        unknown sequence raises ``KeyError`` (callers own exactly-once
-        reclamation; a silent second free would hide a leak of the
-        OPPOSITE sign)."""
+        """Return every page ``seq_id`` holds, and its state row, to
+        the free lists — the single reclamation path for completion,
+        cancel, error and engine shutdown; -> pages returned.
+        Idempotent-hostile by design: freeing an unknown sequence raises
+        ``KeyError`` (callers own exactly-once reclamation; a silent
+        second free would hide a leak of the OPPOSITE sign)."""
         with self._lock:
             pages = self._held.pop(seq_id)
             self._free.extend(pages)
+            if self.state_rows:
+                self._free_rows.append(self._rows.pop(seq_id))
             self._frees += 1
             return len(pages)
 
     def holds(self, seq_id):
         with self._lock:
             return seq_id in self._held
+
+    def state_row(self, seq_id):
+        """The state row ``seq_id`` holds (``KeyError`` for a sequence
+        that holds none: a pool without state rows, or a stranger)."""
+        with self._lock:
+            return self._rows[seq_id]
+
+    def used_rows(self):
+        with self._lock:
+            return self.state_rows - len(self._free_rows)
 
     def sequence_ids(self):
         """Sequence ids currently holding pages — the engine's
@@ -156,6 +207,14 @@ class PagedKVCache:
                     f"({sorted(self._held)} live)")
             if len(set(self._free)) != free:
                 raise AssertionError("KV free list holds duplicates")
+            rows = sorted([*self._rows.values(), *self._free_rows])
+            if rows != list(range(self.state_rows)) or (
+                    self.state_rows and set(self._rows) != set(self._held)):
+                raise AssertionError(
+                    f"state row leak: {len(self._rows)} held + "
+                    f"{len(self._free_rows)} free of {self.state_rows} "
+                    f"rows; rows of {sorted(self._rows)}, pages of "
+                    f"{sorted(self._held)}")
 
     def stats(self):
         """JSON-ready pool counters (occupancy is the bench's
@@ -170,6 +229,8 @@ class PagedKVCache:
                 "peak_pages": self._peak,
                 "occupancy": used / self.num_pages,
                 "sequences": len(self._held),
+                "state_rows": self.state_rows,
+                "used_rows": self.state_rows - len(self._free_rows),
                 "allocs": self._allocs,
                 "frees": self._frees,
             }

@@ -56,6 +56,12 @@ def deserialize_model(d):
         model = LatentMoEDecoder(cfg=arch["config"])
         model.set_weights(d["weights"])
         return model
+    if arch.get("class_name") == "Lfm2MoeDecoder":
+        from dist_keras_tpu.models.lfm2_moe import Lfm2MoeDecoder
+
+        model = Lfm2MoeDecoder(cfg=arch["config"])
+        model.set_weights(d["weights"])
+        return model
     if arch.get("class_name") == "Sequential" and "layers" in arch and all(
             "class_name" in spec for spec in arch["layers"]):
         try:

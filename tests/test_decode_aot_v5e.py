@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import mla_moe, transformer
+from dist_keras_tpu.models import lfm2_moe, mla_moe, transformer
 from dist_keras_tpu.models.transformer import (
     init_transformer_params,
     transformer_config,
@@ -34,6 +34,7 @@ PAGES_PER_SEQ = SEQ // PAGE
 # results that may be as large as the pool: the arguments, views of them
 # that move nothing, and the scatters that update them in place
 FREE = {"parameter", "bitcast", "get-tuple-element", "tuple"}
+WRITES = {"scatter", "dynamic-update-slice"}
 
 _INSTRUCTION = re.compile(
     r"^\s*(?:ROOT )?%([\w.\-]+) = \(?[a-z0-9]+\[([0-9,]*)\]\S* "
@@ -86,21 +87,37 @@ def as_tpu(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+def _bare_engine(cfg, family, page_size, num_pages, state_rows=0):
+    """The step bodies and the pools' shapes only: no weights are made,
+    no worker runs."""
+    engine = DecodeEngine.__new__(DecodeEngine)
+    engine.cfg, engine._family = cfg, family
+    engine.page_size, engine.num_pages = page_size, num_pages
+    engine._pools = tuple(family.cache_pools(cfg))
+    engine._state = bool(state_rows)
+    engine.state_rows = state_rows
+    return engine
+
+
 def _step_and_args(engine, phase, rung, pages_per_seq, S):
     """A step of ``phase`` and its integer arguments' shapes: the family's
     function on its arrays apart, or (``packed_*``) the program the worker
     dispatches, on the ONE packed array (which cuts the tables out by the
-    engine's ``max_pages_per_seq``, set here: the engine is a bare one)."""
+    engine's ``max_pages_per_seq``, set here: the engine is a bare one).
+    A family with per-sequence state takes its rows as one array more."""
     engine.max_pages_per_seq = pages_per_seq
+    rows = int(engine._state)
     if phase == "decode":
         return engine._decode_fn, (
             S((rung,)), S((rung,)), S((rung, pages_per_seq)), S((rung,)),
-            S((rung,)), S((rung,)))
+            S((rung,)), S((rung,))) + (S((rung,)),) * rows
     if phase == "packed_decode":
-        return engine._packed_decode_fn, (S((rung * (pages_per_seq + 5),)),)
+        return engine._packed_decode_fn, (
+            S((rung * (pages_per_seq + 5 + rows),)),)
     if phase == "packed_prefill":
-        return engine._packed_prefill_fn, (S((3 * rung + 1,)),)
-    return engine._prefill_fn, (S((rung,)), S(()), S((rung,)), S((rung,)))
+        return engine._packed_prefill_fn, (S((3 * rung + 1 + rows,)),)
+    return engine._prefill_fn, (
+        S((rung,)), S(()), S((rung,)), S((rung,))) + (S(()),) * rows
 
 
 @pytest.mark.parametrize("phase,rung", [
@@ -114,10 +131,7 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung):
     def S(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    # the two step bodies and the pool's shape only: no weights are made
-    engine = DecodeEngine.__new__(DecodeEngine)
-    engine.cfg, engine._family = CFG, transformer
-    engine.page_size, engine.num_pages = PAGE, SLOTS * PAGES_PER_SEQ
+    engine = _bare_engine(CFG, transformer, PAGE, SLOTS * PAGES_PER_SEQ)
     pool_shape, _ = engine.pool_shapes
     params = jax.tree.map(
         lambda a: S(a.shape, a.dtype),
@@ -196,10 +210,8 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     cfg = _latent_cfg()
-    engine = DecodeEngine.__new__(DecodeEngine)
-    engine.cfg, engine._family = cfg, mla_moe
-    engine.page_size = L_PAGE
-    engine.num_pages = L_SLOTS * L_POSITIONS // L_PAGE
+    engine = _bare_engine(cfg, mla_moe, L_PAGE,
+                          L_SLOTS * L_POSITIONS // L_PAGE)
     (pool_shape,) = engine.pool_shapes
     assert pool_shape == (9, engine.num_pages + 1, L_PAGE, 640)
     params = jax.tree.map(
@@ -264,6 +276,111 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         if elems >= table_rows * L_PAGE * 512 and elems not in big
         and opcode not in FREE]
     assert not gathered, gathered
+
+
+# -- the convolution, grouped-query, expert family (models/lfm2_moe.py) ---
+# lfm2-8b-a1b as the benchmark cuts it: every width as published, layers
+# 0-7 (6 convolutions, 2 attentions, 2 dense and 6 expert layers, all 32
+# experts held), 64 slots of 1280 positions in pages of 16, 256 state rows
+C_SLOTS, C_POSITIONS, C_PAGE, C_ROWS = 64, 1280, 16, 256
+
+
+def _conv_cfg():
+    return lfm2_moe.lfm2_moe_config(
+        vocab_size=65536, seq_len=C_POSITIONS, d_model=2048, n_heads=32,
+        n_kv_heads=8, d_ff=7168, moe_d_ff=1792, n_routed_experts=32,
+        top_k=4, layer_types=["conv", "conv", "full_attention", "conv",
+                              "conv", "conv", "full_attention", "conv"])
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("packed_decode", C_SLOTS, 0.1), ("decode", 16, 0.1),
+    ("packed_prefill", 768, 0.6), ("prefill", 256, 0.3)])
+def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
+                                                     rung, temp_gb):
+    """The third family's steps hold no copy of the ``v | k`` pool (rows
+    of 1,024 lanes over the two attention layers only) nor of the state
+    pool (a row a sequence over the six convolution layers only), no
+    expert-layer-sized or vocabulary-sized temporary, and their
+    temporaries stay under ``temp_gb``; decoding reads the K/V with one
+    ``latent_decode`` Mosaic kernel an attention layer over the flat
+    float32 pool itself, the prefill attends with one ``flash_fwd`` each
+    (32 query heads over 8 K/V heads of 64: the kernel's K/V operands
+    have 8 heads, nothing repeats them)."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = _conv_cfg()
+    pages_per_seq = C_POSITIONS // C_PAGE
+    engine = _bare_engine(cfg, lfm2_moe, C_PAGE, C_SLOTS * pages_per_seq,
+                          state_rows=C_ROWS)
+    kv_shape, state_shape = engine.pool_shapes
+    assert kv_shape == (2, engine.num_pages + 1, C_PAGE, 1024)
+    assert state_shape == (6, C_ROWS + 1, 2, 2048)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(lfm2_moe.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, S(kv_shape, jnp.float32), S(state_shape, jnp.float32),
+        *args).compile()
+    text = compiled.as_text()
+
+    kv_elems, state_elems = math.prod(kv_shape), math.prod(state_shape)
+    big = {kv_elems: "K/V pool", kv_elems // kv_shape[0]: "K/V layer",
+           state_elems: "state pool",
+           cfg["vocab_size"] * cfg["d_model"]: "vocabulary",
+           32 * 2048 * 1792: "expert matrix"}
+    roots = _roots(text)
+    scatters, offenders = {"K/V pool": 0, "state pool": 0}, []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in big or opcode in FREE:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = roots.get(called.group(1)) if called else None
+        if opcode == "fusion" and called.group(1).startswith("bitcast"):
+            continue                                  # a view: moves nothing
+        if big[elems] == "vocabulary" and "fus" in comp:
+            # a prefill's one row times the tied table: a multiply feeding
+            # its own reduce, nothing of this size is written
+            continue
+        # in place on the donated pool: a scatter over pages or rows, or
+        # (a prefill's ONE row) a dynamic-update-slice
+        if big[elems] in scatters and (opcode in WRITES or (
+                opcode == "fusion" and root in WRITES)):
+            scatters[big[elems]] += opcode in WRITES
+            continue
+        offenders.append(f"{comp}: %{name} = {opcode} of {big[elems]} size")
+    assert not offenders, offenders
+    # each layer writes its own pool once, in place
+    assert scatters == {"K/V pool": 2, "state pool": 6}, scatters
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * (kv_elems + state_elems)  # donated
+    assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    if "prefill" in phase:
+        assert len(kernels) == 2 and all("flash_fwd" in k for k in kernels)
+        # q of 32 heads, k and v of 8: fetched through the index map
+        assert all(f"f32[32,{rung},64]" in k and f"f32[8,{rung},64]" in k
+                   for k in kernels), kernels[0]
+        return
+    assert len(kernels) == 2 and all("latent_decode" in k for k in kernels)
+    flat = f"f32[{math.prod(kv_shape[:2])},{C_PAGE},1024]"
+    assert all(flat in k for k in kernels), kernels[0]
+    if phase == "packed_decode":
+        # one array crosses: the six of every family and this one's rows
+        ints = [a for a in jax.tree.leaves(compiled.args_info)
+                if a.dtype == jnp.int32]
+        assert [a.shape for a in ints] == [(rung * (pages_per_seq + 6),)]
+        assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
 
 
 # -- the train step (parallel/transformer_tp.py) --------------------------

@@ -1,0 +1,108 @@
+"""The two block families that were served before PR 32 compile to the
+programs they compiled to then.
+
+PR 32 widened the family seam (a pool states the layers it spans and
+whether its rows are pages or sequences; a family with per-sequence state
+gets one more column in the packed array) for a third family.  The three
+serving cells of the two older families spread close to their bounds, so
+that PR was held to leaving their compiled programs exactly as they were:
+the lowered text (StableHLO, no source locations) of each family's packed
+prefill and decode programs at a toy size is hashed here and compared with
+the hash recorded from the parent commit (9317672, PR 31), by this file's
+own ``lowered_hash`` run in a checkout of that commit.
+
+A change that means to alter one of these programs records the new hash
+and says so; a change that does not, and fails here, has moved a
+benchmark cell's program.
+"""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dist_keras_tpu.models import mla_moe
+from dist_keras_tpu.models.transformer import Transformer, transformer_config
+from dist_keras_tpu.serving import DecodeEngine
+
+LADDERS = dict(replicas=1, prefill_ladder=(8, 16), decode_ladder=(1, 4),
+               page_size=4)
+
+
+def _transformer():
+    return Transformer(transformer_config(
+        input_dim=16, seq_len=32, d_model=16, n_heads=2, n_layers=2,
+        n_classes=16))
+
+
+def _mla_moe():
+    return mla_moe.LatentMoEDecoder(cfg=mla_moe.mla_moe_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=4,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        kv_lora_rank=32, d_ff=96, moe_d_ff=48, n_routed_experts=8,
+        n_shared_experts=1, top_k=3, n_layers=3, held_experts=[2, 3, 4],
+        routed_scaling_factor=2.446, rope_theta=800000.0), seed=1)
+
+
+MODELS = {"transformer": _transformer, "mla_moe": _mla_moe}
+
+# recorded on the parent commit (9317672): sha256 of the lowered text, for
+# the CPU (the ``jnp`` references serve) and for a TPU (the Pallas kernels
+# do: ``use_pallas()`` asks ``jax.default_backend()``, patched here)
+PARENT = {
+    ("transformer", "prefill", "cpu"):
+        "fe59c3565961787bc3f1f6bbe9e7c7f6ffdaeeb0d16971e055c1c7bbeee7549a",
+    ("transformer", "decode", "cpu"):
+        "7fb2059ea6c174d6dcbf2961483989959122b6be1f5fd2e067fcfe07b6532ffe",
+    ("mla_moe", "prefill", "cpu"):
+        "e3ee6957027c2cdc8d3239a7007edb69d5e90c757b04248f8f17e061f3ea60af",
+    ("mla_moe", "decode", "cpu"):
+        "7400b558c2856e76acf39683fb2b6a7e9dbc111c892a5bb0c78a2cae95d6f119",
+    ("transformer", "prefill", "tpu"):
+        "cf0c89111d800675d3dd1168210c8a2edf951a349621233e69f2e127dede0ed3",
+    ("transformer", "decode", "tpu"):
+        "7fb2059ea6c174d6dcbf2961483989959122b6be1f5fd2e067fcfe07b6532ffe",
+    ("mla_moe", "prefill", "tpu"):
+        "d0ee9c0422cd0fd5708a6c3a56a0795d0b657947d10ffd2e0b6c075839fe6e5c",
+    ("mla_moe", "decode", "tpu"):
+        "1aa96e97a60bfa1c40b531fe95dc29ea31a901a2cb1bae3529047ab56c505940",
+}
+
+
+def lowered_hash(family, phase, platform):
+    """sha256 of the lowered text of one family's packed ``phase``
+    program: what the worker dispatches, at the top rung of the toy
+    ladders, lowered for ``platform`` and the zero-filled packed array."""
+    real = jax.default_backend
+    # a Pallas kernel's serialized body carries the call stack of its
+    # trace; with no frames kept, the text does not depend on who calls
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    with DecodeEngine(MODELS[family](), **LADDERS) as eng:
+        rep = eng._replicas[0]
+        pmax = eng.max_pages_per_seq
+        if phase == "decode":
+            step, n = eng._decode_jit, 4 * (pmax + 5)
+        else:
+            step, n = eng._prefill_jit, 3 * 16 + 1
+        jax.default_backend = lambda: platform
+        try:
+            lowered = step.trace(
+                rep.params, *rep.pools, jnp.zeros((n,), jnp.int32)).lower(
+                lowering_platforms=(platform,))
+        finally:
+            jax.default_backend = real
+            jax.config.update("jax_traceback_in_locations_limit", frames)
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("family,phase,platform", sorted(PARENT))
+def test_lowered_program_is_the_parents(family, phase, platform):
+    assert lowered_hash(family, phase, platform) == \
+        PARENT[(family, phase, platform)]
+
+
+if __name__ == "__main__":
+    for key in sorted(PARENT):
+        print(key, lowered_hash(*key))
