@@ -323,11 +323,13 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
         rep0 = eng._replicas[0]
         vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
         top, slots = prefill_ladder[-1], decode_ladder[-1]
-        # a dispatch hands over ONE packed int32 array (serving/decode.py)
+        # a dispatch hands over ONE packed int32 array; a decode step also
+        # takes the output of the step before it, which never left the
+        # device (serving/decode.py)
         prefill_text = eng._prefill_jit.lower(
             rep0.params, *rep0.pools, vec(3 * top + 1)).as_text()
         decode_text = eng._decode_jit.lower(
-            rep0.params, *rep0.pools,
+            rep0.params, *rep0.pools, rep0.no_tokens,
             vec(slots * (eng.max_pages_per_seq + 5))).as_text()
         kernels = _kernel_counts(prefill_text, ("flash_fwd",))
         _check_kernels_traced("C", kernels)

@@ -348,6 +348,11 @@ KNOWN_METRICS = {
     "decode.tokens": "counter",
     "decode.ttft_s": "histogram",
     "decode.step_s": "histogram",
+    # the step in flight: whether a step was launched under its
+    # predecessor (one stamped sample a step), and the slots computed
+    # for a sequence whose end was seen a step late
+    "decode.step_overlapped": "histogram",
+    "decode.tokens_discarded": "counter",
     "decode.prefill_s": "histogram",
     "decode.queue_wait_s": "histogram",
     "decode.active": "gauge",

@@ -69,7 +69,12 @@ PHASES = ("data", "step", "comm", "comm_overlap", "comm_blocked", "ckpt",
           # one prompt's prefill and one slot set's token step, each
           # split into building the ONE packed host array, its transfer
           # (counted by h2d) and the launch returning, the wait for the
-          # result, and (step) the token callbacks and exits.  Leaves
+          # result, and (step) the token callbacks and exits.  A step
+          # stays in flight: a ``decode.step`` parent holds the launch
+          # of one step (build, dispatch) and the landing of the one
+          # before it (wait, emit), each child once a step; a
+          # ``decode.prefill`` parent closes around the launch and opens
+          # again around the wait when such a pass runs between.  Leaves
           # stay leaves: a trace's reader credits an idle gap to the
           # innermost region by its exact name
           "decode.sched", "decode.park",

@@ -52,6 +52,27 @@ Design points (each mirrors an existing engine contract):
   swaps the replica reference only — in-flight sequences finish on the
   params they started with, decode iterations simply group active
   sequences by params generation (at most a couple in flight).
+- **A decode step stays in flight.**  The worker launches step n+1
+  while step n still runs, and fetches, emits and schedules behind the
+  running step: the device always has its successor queued, and the
+  host's part of an iteration (build, transfer, launch, the tokens'
+  way back, callbacks, the locked scheduling pass) costs the loop only
+  what does not fit under a step.  Step n+1's input tokens never visit
+  the host: the compiled step takes its predecessor's output, still on
+  the device, and the tokens view of the packed array says where each
+  slot's token comes from (:func:`_step_views` states the encoding).
+  Everything else a step needs the host knows without the token
+  (``kv_len + 1`` for a sequence of the step in flight).  The canonical
+  ``seq.tokens`` / ``kv_len`` advance only when a step LANDS, so
+  recovery, retries and deadlines read what they always read.  A
+  sequence that reaches its count with step n is left out of n+1; one
+  that ends on ``eos_id`` is known a step late, and its slot of n+1 is
+  computed and discarded (``decode.tokens_discarded``).  A prefill
+  stays synchronous (its first token goes to the host) and is launched
+  between two steps without draining them (:meth:`DecodeEngine._prefill`).
+  No option and no second loop: with nothing in flight the same code launches on
+  host-known tokens alone.  Why pages may be freed under a step that
+  still names them: :meth:`DecodeEngine._step_group`.
 - **Typed errors, never hangs.**  The ``decode.admit`` /
   ``decode.kv_alloc`` / ``decode.step`` / ``decode.recover`` fault
   points cover admission, page reservation, the step dispatch and the
@@ -96,6 +117,7 @@ surfaces (``observability/slo.py``).
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import threading
 import time
@@ -229,7 +251,16 @@ def _step_views(packed, pmax, state=False):
     wants its tables flat anyway), and no slower on the chip: PERF.md,
     PR 31.  For a family that holds per-sequence ``state`` a seventh
     array follows, the slots' state rows (``rung * (pmax + 6)`` values);
-    every other family is handed exactly the six."""
+    every other family is handed exactly the six.
+
+    The tokens view names each slot's token SOURCE: an entry ``>= 0`` is
+    the token itself, one the host knows (a prefill's first token, a
+    recovered sequence's teacher-forced token, the first step with
+    nothing in flight); an entry ``-(j + 1)`` means "slot ``j`` of the
+    step in flight's output", which is still on the device.  The
+    engine's compiled wrapper (``_packed_decode_fn``) resolves it before
+    the family's step sees the tokens; no other view depends on a
+    token."""
     arrays = 6 if state else 5
     rung = packed.shape[0] // (pmax + arrays)
     tables = packed[:rung * pmax].reshape(rung, pmax)
@@ -263,10 +294,29 @@ def _to_device(packed):
     return on_device
 
 
-class _DecodeReplica:
-    """One replica: pinned device, params swap point, its KV pools."""
+class _Flight:
+    """One decode step launched and not yet landed: what its landing
+    needs besides the host's canonical state, which it has not touched."""
 
-    def __init__(self, index, device, params, cache, pools):
+    __slots__ = ("group", "slot", "rung", "out", "lengths", "t0",
+                 "overlapped", "attempt")
+
+    def __init__(self, group, rung, out, lengths, t0, overlapped, attempt):
+        self.group = group            # the sequences, slot by slot
+        self.slot = {seq: i for i, seq in enumerate(group)}
+        self.rung = rung
+        self.out = out                # device: tokens to the top rung, counts
+        self.lengths = lengths        # host view, for the family's counts
+        self.t0 = t0                  # perf_counter at the launch
+        self.overlapped = overlapped  # launched under its predecessor
+        self.attempt = attempt        # failures this step had before
+
+
+class _DecodeReplica:
+    """One replica: pinned device, params swap point, its KV pools, and
+    the decode step it has in flight."""
+
+    def __init__(self, index, device, params, cache, pools, no_tokens):
         self.index = index
         self.device = device
         self.params_host = params
@@ -280,6 +330,10 @@ class _DecodeReplica:
         self.killed = False       # crash requested (kill_replica seam)
         self.dead = False         # quarantined: out of service for good
         self.steps = 0
+        self.flight = None        # the launched, unlanded step (worker's)
+        self.no_tokens = no_tokens  # what a step carries with none in flight
+        self.landed_at = 0.0      # perf_counter: the last tokens on the host
+        self.attempt = 0          # failures of the step to launch next
         self._pinned = {}         # id(params_host) -> device params
 
     def put_params(self, params):
@@ -399,8 +453,13 @@ class DecodeEngine:
         if self._state and self.state_rows < 1:
             raise ValueError(f"state_rows={state_rows} must be >= 1")
 
+        # the width of a decode step's output, whatever rung ran it: the
+        # tokens padded to the top rung, then the family's counts
+        self._out_width = self._decode_out_width(model.params)
+
         # donation keeps the pool update in place: a dispatch consumes
-        # the replica's pools and returns their successors
+        # the replica's pools and returns their successors (the output a
+        # step carries from its predecessor is read, never donated)
         donated = tuple(range(1, 1 + len(self._pools)))
         self._prefill_jit = jax.jit(self._packed_prefill_fn,
                                     donate_argnums=donated)
@@ -473,6 +532,8 @@ class DecodeEngine:
         self._reg_kv_leaked = metrics.counter("decode.kv_leaked")
         self._reg_ttft = metrics.histogram("decode.ttft_s")
         self._reg_step = metrics.histogram("decode.step_s")
+        self._reg_overlapped = metrics.histogram("decode.step_overlapped")
+        self._reg_discarded = metrics.counter("decode.tokens_discarded")
         self._reg_prefill = metrics.histogram("decode.prefill_s")
         self._reg_queue_wait = metrics.histogram("decode.queue_wait_s")
         self._reg_active = metrics.gauge("decode.active")
@@ -518,12 +579,43 @@ class DecodeEngine:
             params, *pools, *_prefill_views(packed, self._state))
 
     def _packed_decode_fn(self, params, *args):
-        """``(params, *pools, packed)``: :meth:`_decode_fn` on the six
-        arrays :func:`_step_views` cuts out of ``packed``."""
-        *pools, packed = args
-        return self._decode_fn(
-            params, *pools,
-            *_step_views(packed, self.max_pages_per_seq, self._state))
+        """``(params, *pools, carried, packed)``: :meth:`_decode_fn` on
+        the six arrays :func:`_step_views` cuts out of ``packed``, the
+        tokens resolved first: a slot whose entry is ``-(j + 1)`` takes
+        slot ``j`` of ``carried``, the previous step's output, which never
+        left the device.  The output has ONE width whatever the rung (the
+        tokens padded to the top rung, then the family's counts), so a
+        step carries any rung's output into the one program of its own."""
+        *pools, carried, packed = args
+        toks, *rest = _step_views(packed, self.max_pages_per_seq,
+                                  self._state)
+        top, rung = self.max_slots, toks.shape[0]
+        with jax.named_scope("carried_tokens"):
+            toks = jnp.where(
+                toks < 0, carried[jnp.clip(-toks - 1, 0, top - 1)], toks)
+        out, *pools = self._decode_fn(params, *pools, toks, *rest)
+        if rung < top:
+            out = jnp.concatenate(
+                [out[:rung], jnp.zeros((top - rung,), out.dtype),
+                 out[rung:]])
+        return (out, *pools)
+
+    def _decode_out_width(self, params):
+        """How many int32 values a decode step of this family puts out,
+        the tokens padded to the top rung: known from the step's shapes
+        alone (nothing runs), and what a step carries when none is in
+        flight has to have it."""
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        top = self.max_slots
+        out, *_ = jax.eval_shape(
+            self._decode_fn, params,
+            *(jax.ShapeDtypeStruct(shape, jnp.float32)
+              for shape in self.pool_shapes),
+            ints(top), ints(top), ints(top, self.max_pages_per_seq),
+            *(ints(top),) * (3 + self._state))
+        return out.shape[0]
 
     @property
     def pool_shapes(self):
@@ -550,8 +642,9 @@ class DecodeEngine:
         # default device would cost it N pools of peak memory
         pools = [jnp.zeros(shape, jnp.float32, device=device)
                  for shape in self.pool_shapes]
+        no_tokens = jnp.zeros((self._out_width,), jnp.int32, device=device)
         return _DecodeReplica(index, device, self._host_params, cache,
-                              pools)
+                              pools, no_tokens)
 
     # -- admission ------------------------------------------------------
     def _rung_for(self, n, ladder):
@@ -828,7 +921,7 @@ class DecodeEngine:
             return "length"
         return None
 
-    def _prefill(self, rep, seq, rung):
+    def _prefill(self, rep, seq, rung, leads):
         """Run one admitted prompt through its prefill ``rung``; emits
         the first generated token (TTFT) or fails the sequence typed.
 
@@ -838,49 +931,82 @@ class DecodeEngine:
         cut apart inside the compiled step), the token comes back in the
         one array the wait region fetches.
 
+        A prefill is synchronous: its first token goes to the host, and
+        the sequence joins the first step launched after that, on a token
+        the host knows.  The running sequences do not wait for it: the
+        prefill's launch queues behind the step in flight, and before
+        this thread blocks on the prefill's token it runs one pass of the
+        loop's steps (:meth:`_advance`: the next step is launched behind
+        the prefill, the step in flight lands and its tokens go out), so
+        the device goes from step to prefill to step with nothing
+        drained.  Only the prefill that ``leads`` a scheduling pass has a
+        step launched behind it; the others of a burst only land what is
+        in flight and then run back to back, so that a burst of
+        admissions is decoding together after one step, not after one
+        step each (the benchmark's warm-up counts on a rung's worth of
+        short requests meeting in one step).  The ``decode.prefill``
+        region closes around the launch and opens again around the wait
+        when such a pass runs between (its regions lie there, under their
+        own names), and ``decode.prefill_s`` runs from the later of the
+        launch and the landed step's tokens on the host.
+
         A RECOVERED sequence (``seq.tokens`` longer than the prompt)
         replays the same prefill over the prompt only — its prediction
         is a token the stream already delivered, so it is discarded
         and the teacher-forced decode steps replay the rest."""
-        with perf.phase("decode.prefill.build"):
-            n, ps = seq.prompt_len, self.page_size
-            packed = np.zeros((3 * rung + 1 + self._state,), np.int32)
-            toks, _, page_idx, page_off, *_ = _prefill_views(
-                packed, self._state)
-            toks[:n] = seq.tokens[:n]
-            # position t goes to page t // ps; the padding to the scratch
-            page_idx[:n] = np.repeat(seq.pages, ps)[:n]
-            page_idx[n:] = rep.cache.scratch_page
-            page_off[:] = np.arange(rung) % ps
-            if self._state:
-                packed[-2:] = n, seq.row
-            else:
-                packed[-1] = n
-        replay = len(seq.tokens) > seq.prompt_len
-        t0 = time.perf_counter()
-        tw0 = time.time()
-        if not replay:
-            self._reg_queue_wait.observe(time.monotonic() - seq.t, at=t0)
-        if events.enabled():
-            spans.span_at("serve.queue_wait", seq.ctx, seq.tw, tw0)
-        try:
-            perf.count_dispatch()
-            with perf.phase("decode.prefill.dispatch"):
-                first, *rep.pools = self._prefill_jit(
-                    seq.params, *rep.pools, _to_device(packed))
-            with perf.phase("decode.prefill.wait"):
-                # the token, then whatever counts the family sends along
-                first, *counts = np.asarray(first).reshape(-1)
-                first = int(first)
-        # dklint: ignore[broad-except] a failed prefill lands TYPED on its own future with pages reclaimed
-        except Exception as e:
-            with self._cond:
-                rep.active.remove(seq)
-                self._finish_locked(rep, seq, "error")
-            events.emit("decode_error", sid=seq.sid, where="prefill",
-                        error=type(e).__name__)
-            self._resolve(seq, None, error=e)
-            return
+        with contextlib.ExitStack() as region:
+            region.enter_context(
+                perf.phase("decode.prefill", sid=seq.sid, rung=rung))
+            with perf.phase("decode.prefill.build"):
+                n, ps = seq.prompt_len, self.page_size
+                packed = np.zeros((3 * rung + 1 + self._state,), np.int32)
+                toks, _, page_idx, page_off, *_ = _prefill_views(
+                    packed, self._state)
+                toks[:n] = seq.tokens[:n]
+                # position t goes to page t // ps; the padding to the
+                # scratch
+                page_idx[:n] = np.repeat(seq.pages, ps)[:n]
+                page_idx[n:] = rep.cache.scratch_page
+                page_off[:] = np.arange(rung) % ps
+                if self._state:
+                    packed[-2:] = n, seq.row
+                else:
+                    packed[-1] = n
+            replay = len(seq.tokens) > seq.prompt_len
+            t0 = time.perf_counter()
+            tw0 = time.time()
+            if not replay:
+                self._reg_queue_wait.observe(time.monotonic() - seq.t,
+                                             at=t0)
+            if events.enabled():
+                spans.span_at("serve.queue_wait", seq.ctx, seq.tw, tw0)
+            try:
+                perf.count_dispatch()
+                with perf.phase("decode.prefill.dispatch"):
+                    first, *rep.pools = self._prefill_jit(
+                        seq.params, *rep.pools, _to_device(packed))
+                if rep.flight is not None or (leads and any(
+                        other.kv_len for other in rep.active)):
+                    region.close()
+                    self._advance(rep, launch=leads)
+                    t0 = max(t0, rep.landed_at)
+                    region.enter_context(perf.phase(
+                        "decode.prefill", sid=seq.sid, rung=rung))
+                with perf.phase("decode.prefill.wait"):
+                    # the token, then whatever counts the family sends
+                    first, *counts = np.asarray(first).reshape(-1)
+                    first = int(first)
+            except _ReplicaDead:
+                raise
+            # dklint: ignore[broad-except] a failed prefill lands TYPED on its own future with pages reclaimed
+            except Exception as e:
+                with self._cond:
+                    rep.active.remove(seq)
+                    self._finish_locked(rep, seq, "error")
+                events.emit("decode_error", sid=seq.sid, where="prefill",
+                            error=type(e).__name__)
+                self._resolve(seq, None, error=e)
+                return
         dt = time.perf_counter() - t0
         self._reg_prefill.observe(dt, at=t0)
         if self._family.observe_step is not None:
@@ -921,28 +1047,102 @@ class DecodeEngine:
                         steps=seq.steps)
             self._resolve(seq, finish)
 
-    def _step_group(self, rep, group, rung):
-        """One decode step for ``group`` (same pinned params), padded
-        to its decode-ladder ``rung``.  A failed dispatch retries IN PLACE
-        (``step_retries`` — safe: pools and ``kv_len`` only advance on
-        success); past the retries the replica quarantines when a
-        survivor exists (the group migrates and replays), else it
-        fails exactly this group's sequences, typed, pages reclaimed.
+    def _step_group(self, rep, group):
+        """Launch one decode step for ``group`` (same pinned params)
+        behind the step in flight, THEN land that step: fetch its tokens,
+        emit them, settle finishes.  The one primitive of the loop: an
+        empty ``group`` only lands, and with nothing in flight the launch
+        is all there is (its tokens all host-known), so the drained case
+        is this code with nothing carried.
 
-        One crossing each way: the step's six integer arrays (tokens,
-        positions, page tables, write pages, write offsets, lengths) are
-        views of ONE packed int32 host array (:func:`_step_views`; 54 KB
-        at 32 slots of 416 pages) that goes to the device in one transfer
-        and is cut apart inside the compiled step; the next tokens come
-        back in the one array the wait region fetches (asked for while
-        the device still runs: a ``copy_to_host_async`` at the launch
-        took nothing off the step, PERF.md, PR 31).
+        What the launch reads is the host's state as it will be once the
+        step in flight lands, which the host knows without its tokens: a
+        sequence of that step sits at ``kv_len + 1``, and its input token
+        is the step's output for its slot, still on the device
+        (:func:`_step_views`: entry ``-(j + 1)``), unless a recovered
+        sequence is still catching up on tokens the host has (its
+        predictions are discarded until ``kv_len`` reaches the frontier,
+        so streams never see a duplicate).  A sequence that reaches
+        ``max_new`` with the step in flight is left out: no step is spent
+        on it.  One that ends on ``eos_id`` is known only when its step
+        lands, with its successor already launched: that slot's result is
+        discarded at ITS landing (``seq.finished``; never appended, never
+        streamed, counted on ``decode.tokens_discarded``), as is the
+        slot of a sequence cancelled or expired under its step.
 
-        The input token is ``seq.tokens[seq.kv_len]`` — the last token
-        in steady state, a teacher-forced KNOWN token while a
-        recovered sequence catches back up (its predictions are
-        discarded until ``kv_len`` reaches the frontier, so streams
-        never see a duplicate)."""
+        **Why pages are freed at once.**  A sequence retired while a
+        launched step still names its pages (or its state row) returns
+        them to the allocator immediately, and the next admission may
+        take them.  Every program that touches a replica's pools takes
+        the pools its predecessor put out (they are donated), so the
+        device runs them in launch order: a new owner's prefill, and all
+        its later steps, write after the stale step's one write, and a
+        sequence reads only positions it wrote itself since it got the
+        page.  The stale write lands in a page nobody reads before
+        rewriting; nothing waits for the landing.
+
+        **Failures.**  ``seq.tokens`` / ``kv_len`` advance only when a
+        step lands, so a failed step retries IN PLACE from them
+        (``step_retries``).  ``fault_point("decode.step")`` fires once a
+        launch; a device failure surfaces at the fetch, with the
+        successor already launched on poisoned inputs: both are dropped
+        and the failure counts once, against the step that was fetched.
+        Past the retries the replica quarantines when a survivor exists
+        (the sequences migrate and replay), else exactly the dropped
+        steps' sequences fail, typed, pages reclaimed."""
+        prev, rep.flight = rep.flight, None
+        if prev is not None:
+            group = [seq for seq in group
+                     if not self._ends_with(prev, seq)]
+        if not group and prev is None:
+            return
+        rung = (self._rung_for(len(group), self.decode_ladder) if group
+                else prev.rung)
+        err, blamed = None, ()
+        with perf.phase("decode.step", n=len(group or prev.group),
+                        rung=rung):
+            if group:
+                try:
+                    rep.flight = self._launch(rep, group, rung, prev)
+                # dklint: ignore[broad-except] a failed launch retries in place, then quarantines or lands TYPED
+                except Exception as e:
+                    err, blamed = e, group
+                    rep.attempt += 1
+            if prev is not None:
+                try:
+                    with perf.phase("decode.step.wait"):
+                        out = np.asarray(prev.out)
+                # dklint: ignore[broad-except] a failed fetch drops the step and its successor, then retries, quarantines or lands TYPED
+                except Exception as e:
+                    err = e
+                    blamed = prev.group + [seq for seq in group
+                                           if seq not in prev.slot]
+                    rep.attempt = prev.attempt + 1
+                    rep.flight = None
+                else:
+                    self._land(rep, prev, out)
+        if err is not None:
+            self._step_failed(rep, blamed, err)
+
+    @staticmethod
+    def _ends_with(flight, seq):
+        """Will ``seq`` reach its ``max_new`` when ``flight`` lands?  Known
+        from its count alone (the step's prediction is a NEW token unless
+        a recovered sequence is still catching up)."""
+        return (seq in flight.slot
+                and seq.kv_len + 1 >= len(seq.tokens)
+                and len(seq.tokens) + 1 - seq.prompt_len >= seq.max_new)
+
+    def _launch(self, rep, group, rung, prev):
+        """Build and dispatch one step for ``group`` on ``prev``'s output
+        (None: nothing in flight) -> its :class:`_Flight`.
+
+        One crossing: the step's six integer arrays (tokens or their
+        sources, positions, page tables, write pages, write offsets,
+        lengths) are views of ONE packed int32 host array
+        (:func:`_step_views`; 54 KB at 32 slots of 416 pages) that goes
+        to the device in one transfer and is cut apart inside the
+        compiled step; what the step carries is there already."""
         with perf.phase("decode.step.build"):
             ps = self.page_size
             pmax = self.max_pages_per_seq
@@ -955,79 +1155,79 @@ class DecodeEngine:
                 # a padding slot's state goes to the scratch row
                 rows[0][:] = rep.cache.scratch_row
                 rows[0][:len(group)] = [seq.row for seq in group]
+            ahead = prev.slot if prev is not None else {}
             for i, seq in enumerate(group):
-                toks[i] = seq.tokens[seq.kv_len]
-                positions[i] = seq.kv_len
+                j = ahead.get(seq)
+                at = seq.kv_len + (j is not None)
+                toks[i] = (seq.tokens[at] if at < len(seq.tokens)
+                           else -(j + 1))
+                positions[i] = at
                 tables[i, :len(seq.pages)] = seq.pages
-                wpage[i] = seq.pages[seq.kv_len // ps]
-                woff[i] = seq.kv_len % ps
-                lengths[i] = seq.kv_len + 1
+                wpage[i] = seq.pages[at // ps]
+                woff[i] = at % ps
+                lengths[i] = at + 1
         t0 = time.perf_counter()
-        err = None
-        for attempt in range(1 + self.step_retries):
-            try:
-                fault_point("decode.step")
-                perf.count_dispatch()
-                with perf.phase("decode.step.dispatch"):
-                    nxt, *rep.pools = self._decode_jit(
-                        group[0].params, *rep.pools, _to_device(packed))
-                with perf.phase("decode.step.wait"):
-                    nxt = np.asarray(nxt)
-                err = None
-                break
-            # dklint: ignore[broad-except] a failed step retries in place, then quarantines or lands TYPED
-            except Exception as e:
-                err = e
-                if attempt < self.step_retries:
-                    events.emit("decode_error", where="step_retry",
-                                n=len(group), replica=rep.index,
-                                attempt=attempt,
-                                error=type(e).__name__)
-        if err is not None:
-            with self._cond:
-                survivors = [r for r in self._live_replicas_locked()
-                             if r is not rep]
-            if survivors:
-                # a peer can hold this work: quarantine this replica,
-                # migrate + replay — the futures never see the failure
-                raise _ReplicaDead(err)
-            with self._cond:
-                for seq in group:
-                    rep.active.remove(seq)
-                    self._finish_locked(rep, seq, "error")
-            events.emit("decode_error", where="step", n=len(group),
-                        replica=rep.index, error=type(err).__name__)
-            for seq in group:
-                self._resolve(seq, None, error=err)
-            return
-        dt = time.perf_counter() - t0
+        fault_point("decode.step")
+        perf.count_dispatch()
+        with perf.phase("decode.step.dispatch"):
+            out, *rep.pools = self._decode_jit(
+                group[0].params, *rep.pools,
+                rep.no_tokens if prev is None else prev.out,
+                _to_device(packed))
+        flight = _Flight(group, rung, out, lengths, t0, prev is not None,
+                         rep.attempt)
+        rep.attempt = 0
+        return flight
+
+    def _land(self, rep, flight, out):
+        """``flight``'s output is on the host: account the step, emit its
+        tokens, settle finishes.  ``decode.step_s`` reads what the step
+        cost the loop: from the later of its launch and its
+        predecessor's tokens on the host to its own (dispatch + wait with
+        nothing in flight; under overlap the period between two steps'
+        tokens), one sample a step, stamped with that start, as are
+        ``decode.step_overlapped`` and the family's counts."""
+        now = time.perf_counter()
+        t0 = max(flight.t0, rep.landed_at)
+        dt = now - t0
+        rep.landed_at = now
         rep.steps += 1
         self._m_step.observe(dt, at=t0)
         self._reg_step.observe(dt, at=t0)
+        self._reg_overlapped.observe(float(flight.overlapped), at=t0)
         if self._family.observe_step is not None:
             # the counts came off the device behind the tokens, in the
             # one array the wait already fetched
-            self._family.observe_step(nxt[rung:], t0, lengths=lengths,
+            self._family.observe_step(out[self.max_slots:], t0,
+                                      lengths=flight.lengths,
                                       page_size=self.page_size)
         with perf.phase("decode.step.emit"):
             with self._cond:
-                self._shapes.add(("decode", rung))
+                self._shapes.add(("decode", flight.rung))
                 self._ewma_step = (dt if self._ewma_step is None
                                    else 0.8 * self._ewma_step + 0.2 * dt)
-            events.emit("decode_step", replica=rep.index, rung=rung,
-                        n=len(group), duration_s=dt)
-            finished = []
-            for i, seq in enumerate(group):
+            events.emit("decode_step", replica=rep.index, rung=flight.rung,
+                        n=len(flight.group), duration_s=dt)
+            finished, discarded = [], 0
+            for i, seq in enumerate(flight.group):
+                if seq.finished:
+                    # ended under this step (an eos seen a step late, a
+                    # cancel, a deadline): computed and thrown away
+                    discarded += 1
+                    continue
                 seq.kv_len += 1
                 seq.steps += 1
                 if seq.kv_len < len(seq.tokens):
                     # replay catch-up: this prediction is a token the
                     # stream already delivered before the crash — discard
                     continue
-                self._emit_token(seq, int(nxt[i]))
-                finish = self._sequence_done(seq, int(nxt[i]))
+                token = int(out[i])
+                self._emit_token(seq, token)
+                finish = self._sequence_done(seq, token)
                 if finish is not None:
                     finished.append((seq, finish))
+            if discarded:
+                self._reg_discarded.inc(discarded)
             if finished:
                 with self._cond:
                     for seq, finish in finished:
@@ -1039,6 +1239,33 @@ class DecodeEngine:
                                 generated=len(seq.generated()),
                                 steps=seq.steps)
                     self._resolve(seq, finish)
+
+    def _step_failed(self, rep, blamed, err):
+        """A launch or a fetch failed and nothing is in flight any more:
+        leave the retry to the loop's next pass, or past ``step_retries``
+        quarantine (a peer can hold the work: migrate and replay, the
+        futures never see the failure) or fail ``blamed`` typed."""
+        if rep.attempt <= self.step_retries:
+            events.emit("decode_error", where="step_retry",
+                        n=len(blamed), replica=rep.index,
+                        attempt=rep.attempt - 1,
+                        error=type(err).__name__)
+            return
+        rep.attempt = 0
+        with self._cond:
+            survivors = [r for r in self._live_replicas_locked()
+                         if r is not rep]
+        if survivors:
+            raise _ReplicaDead(err)
+        blamed = [seq for seq in blamed if not seq.finished]
+        with self._cond:
+            for seq in blamed:
+                rep.active.remove(seq)
+                self._finish_locked(rep, seq, "error")
+        events.emit("decode_error", where="step", n=len(blamed),
+                    replica=rep.index, error=type(err).__name__)
+        for seq in blamed:
+            self._resolve(seq, None, error=err)
 
     def _worker_main(self, rep):
         """Thread body: the scheduler loop plus the crash boundary.
@@ -1062,7 +1289,7 @@ class DecodeEngine:
                 # has its whole (homogeneous) pool free, so the next
                 # placement pass below always lands them
                 while (not rep.queue and not rep.active
-                       and not self._orphans
+                       and rep.flight is None and not self._orphans
                        and not self._stopped and not rep.retiring
                        and not rep.killed):
                     # the scheduler's idle park: deliberately unbounded
@@ -1129,28 +1356,36 @@ class DecodeEngine:
                                 phase="expiry",
                                 generated=len(seq.generated()))
                 self._resolve(seq, fin)
-            for seq in prefills:
-                rung = self._rung_for(seq.prompt_len, self.prefill_ladder)
-                with perf.phase("decode.prefill", sid=seq.sid, rung=rung):
-                    self._prefill(rep, seq, rung)
+            for i, seq in enumerate(prefills):
+                self._prefill(rep, seq, self._rung_for(
+                    seq.prompt_len, self.prefill_ladder), leads=i == 0)
                 if rep.killed:
                     raise _ReplicaDead(Overloaded("replica_lost"))
+            self._advance(rep)
+            self._maybe_self_check()
+        # stopped or retired: nothing is launched any more, and the step
+        # in flight lands (a retired replica's holds discarded slots only)
+        self._step_group(rep, [])
+
+    def _advance(self, rep, launch=True):
+        """One pass of decode steps over the replica's prefilled
+        sequences, grouped by pinned params generation (a hot reload
+        means at most a couple of groups until old sequences drain):
+        each group's step launches behind whatever is in flight and
+        lands it, so two generations simply alternate; with no group
+        left, or nothing to ``launch``, the last step still has to
+        land."""
+        groups = {}
+        if launch:
             with self._cond:
-                # group by pinned params generation: a hot reload means
-                # at most a couple of groups until old sequences drain
-                groups = {}
                 for seq in rep.active:
                     if seq.kv_len == 0:
-                        continue  # not prefilled yet: next pass
+                        continue  # not prefilled yet: it joins the next pass
                     groups.setdefault(id(seq.params), []).append(seq)
-                work = list(groups.values())
-            for group in work:
-                rung = self._rung_for(len(group), self.decode_ladder)
-                with perf.phase("decode.step", n=len(group), rung=rung):
-                    self._step_group(rep, group, rung)
-                if rep.killed:
-                    raise _ReplicaDead(Overloaded("replica_lost"))
-            self._maybe_self_check()
+        for group in list(groups.values()) or [[]]:
+            self._step_group(rep, group)
+            if rep.killed:
+                raise _ReplicaDead(Overloaded("replica_lost"))
 
     # -- survivability: quarantine + sequence-level recovery ------------
     def kill_replica(self, index):
@@ -1248,6 +1483,7 @@ class DecodeEngine:
             rep.killed = True
             rep.dead = True
             rep.retiring = True     # out of _pick_replica rotation
+            rep.flight = None       # dropped: the replay starts from what LANDED
             orphans = list(rep.active) + list(rep.queue)
             del rep.active[:]
             rep.queue.clear()

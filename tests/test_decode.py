@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models import mla_moe
+from dist_keras_tpu.models import lfm2_moe, mla_moe
 from dist_keras_tpu.models.transformer import (
     Transformer,
     apply_block,
@@ -65,18 +65,22 @@ def _engine(model=None, **kw):
 
 
 # -- the oracle: full forward over the growing sequence ----------------
-def _oracle_next(params, cfg, tokens):
-    """Greedy next token by the same shared-block math the engine's
-    incremental KV path must reproduce bit-for-bit."""
+def _oracle_logits(params, cfg, tokens):
+    """The logits at every position by the same shared-block math the
+    engine's incremental KV path must reproduce bit-for-bit."""
     from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
     x = jax.nn.one_hot(jnp.asarray([tokens]), cfg["input_dim"])
     h = x @ params["proj"] + params["pos"][None, :len(tokens)]
     for blk in params["blocks"]:
         h = apply_block(blk, h, attention_auto, True)
-    hs = layer_norm(params["ln_f"], h)[0, -1]
-    logits = hs @ params["head"]["kernel"] + params["head"]["bias"]
-    return int(jnp.argmax(logits))
+    hs = layer_norm(params["ln_f"], h)[0]
+    return hs @ params["head"]["kernel"] + params["head"]["bias"]
+
+
+def _oracle_next(params, cfg, tokens):
+    """Greedy next token: a full forward over the growing sequence."""
+    return int(jnp.argmax(_oracle_logits(params, cfg, tokens)[-1]))
 
 
 def _oracle_generate(params, cfg, tokens, max_new, eos_id=None):
@@ -166,6 +170,14 @@ def test_pool_shape_is_page_major_and_what_replicas_hold(engine_and_model):
 def _family_engine(family, decode_ladder):
     if family == "transformer":
         return _engine(decode_ladder=decode_ladder, prefill_ladder=(8, 16))
+    if family == "lfm2_moe":
+        cfg = lfm2_moe.lfm2_moe_config(
+            vocab_size=128, seq_len=48, d_model=64, n_heads=8, n_kv_heads=2,
+            d_ff=96, moe_d_ff=48, n_routed_experts=8, top_k=2,
+            layer_types=["conv", "conv", "full_attention", "conv"] * 2)
+        return DecodeEngine(lfm2_moe.Lfm2MoeDecoder(cfg=cfg, seed=1),
+                            replicas=1, prefill_ladder=(8, 16),
+                            decode_ladder=decode_ladder, page_size=4)
     cfg = mla_moe.mla_moe_config(
         vocab_size=128, seq_len=48, d_model=64, n_heads=4,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
@@ -195,9 +207,13 @@ def _assert_same_step(got, want):
 @pytest.mark.parametrize("rung", [4, 8])
 @pytest.mark.parametrize("family", ["transformer", "mla_moe"])
 def test_packed_decode_step_is_the_familys_own(family, rung):
-    """The dispatched program, handed the worker's ONE packed array, gives
-    bit for bit the tokens and pools of the family's ``decode_step`` on
-    the six arrays apart; the rung's last slots are padding."""
+    """The dispatched program, handed the worker's ONE packed array and
+    the output of the step before it, gives bit for bit the tokens and
+    pools of the family's ``decode_step`` on the six arrays apart; the
+    rung's last slots are padding.  Half of the live slots name their
+    token's SOURCE, a slot of the carried output, in place of the token;
+    and the output is as wide at every rung (tokens to the top rung, then
+    the family's counts)."""
     from dist_keras_tpu.serving.decode import _step_views
 
     with _family_engine(family, (1, 4, 8)) as eng:
@@ -205,6 +221,8 @@ def test_packed_decode_step_is_the_familys_own(family, rung):
             eng.max_pages_per_seq
         rng = np.random.default_rng(rung)
         live = rung - 2
+        carried = rng.integers(0, eng.vocab, eng._out_width).astype(np.int32)
+        assert rep.no_tokens.shape == carried.shape
         packed = np.zeros((rung * (pmax + 5),), np.int32)
         toks, positions, tables, wpage, woff, lengths = \
             _step_views(packed, pmax)
@@ -220,13 +238,26 @@ def test_packed_decode_step_is_the_familys_own(family, rung):
             wpage[i], woff[i] = mine[at // ps], at % ps
             lengths[i] = at + 1
         assert np.count_nonzero(packed) > live * pmax     # views, not copies
-        want = jax.jit(functools.partial(eng._family.decode_step, eng.cfg))(
-            rep.params, *_filled_pools(eng, 5), *map(
-                jnp.asarray, (toks, positions, tables, wpage, woff,
-                              lengths)))
+        # (done before the views change below: on the CPU ``jnp.asarray``
+        # may alias the host array, and the launch does not wait)
+        want = jax.block_until_ready(
+            jax.jit(functools.partial(eng._family.decode_step, eng.cfg))(
+                rep.params, *_filled_pools(eng, 5), *map(
+                    jnp.asarray, (toks, positions, tables, wpage, woff,
+                                  lengths))))
+        # every other live slot: "slot j of the carried output", j any
+        # slot of the top rung
+        for i in range(0, live, 2):
+            j = int(rng.integers(0, 8))
+            carried[j] = toks[i]
+            toks[i] = -(j + 1)
         got = eng._decode_jit(rep.params, *_filled_pools(eng, 5),
-                              jnp.asarray(packed))
-        _assert_same_step(got, want)
+                              jnp.asarray(carried), jnp.asarray(packed))
+        out, *pools = got
+        assert out.shape == (eng._out_width,)
+        assert not np.asarray(out[rung:8]).any()
+        _assert_same_step(
+            [jnp.concatenate([out[:rung], out[8:]]), *pools], want)
 
 
 @pytest.mark.parametrize("rung,n", [(8, 5), (16, 13)])
@@ -294,8 +325,10 @@ def test_worker_packs_what_the_per_token_loops_built():
         toks, positions, tables, wpage, woff, lengths = \
             _step_views(packed, pmax)
         at = 6 + k
+        # the first step's token is the prefill's, which the host knows;
+        # every later one is slot 0 of the step in flight's output
         assert (toks.tolist(), positions.tolist(), lengths.tolist()) == \
-            ([out[at]], [at], [at + 1])
+            ([out[at] if k == 0 else -1], [at], [at + 1])
         assert tables[0].tolist() == pages.tolist() + [0] * (pmax - 3)
         assert (wpage.tolist(), woff.tolist()) == \
             ([pages[at // ps]], [at % ps])
@@ -340,6 +373,229 @@ def test_eos_stops_early(engine_and_model):
                        timeout_s=300)
     assert doc["generated"] == want
     assert doc["finish"] == "eos"
+
+
+# -- a decode step in flight -------------------------------------------
+def _family_logits(eng, tokens):
+    """The oracle of the engine's own family: one full forward over the
+    whole sequence, no cache -> the logits at every position."""
+    params, cfg = eng._host_params, eng.cfg
+    if eng._family.FAMILY == "transformer":
+        return _oracle_logits(params, cfg, tokens)
+    return eng._family.forward(params, jnp.asarray(tokens), cfg)
+
+
+def _assert_greedy(eng, doc):
+    """Every generated token is the oracle's greedy choice after the
+    tokens before it (the families are causal: position ``t``'s logits
+    are those of a forward over ``tokens[:t + 1]`` alone)."""
+    best = np.asarray(jnp.argmax(_family_logits(eng, doc["tokens"][:-1]), -1))
+    assert doc["generated"] == best[doc["prompt_len"] - 1:].tolist()
+
+
+def _held_until_all_are_in(eng, requests):
+    """Submit ``requests`` (``submit_generate`` keywords; ``on_token`` is
+    the test's own list's ``append``) so that the worker sees them all in
+    ONE scheduling pass after the first one's prefill: that prefill's
+    token callback waits for the last submit.  From then on no prefill
+    cuts in between two steps, and which step had a successor is known
+    from the counts alone."""
+    all_in = threading.Event()
+    streams = [[] for _ in requests]
+
+    def first(t):
+        streams[0].append(t)
+        assert all_in.wait(60)
+
+    gens = [eng.submit_generate(
+        **req, on_token=first if i == 0 and not streams[0] else
+        streams[i].append) for i, req in enumerate(requests)]
+    all_in.set()
+    return gens, streams
+
+
+@pytest.mark.parametrize("family", ["transformer", "mla_moe", "lfm2_moe"])
+def test_overlapped_steps_return_the_oracles_tokens(family):
+    """Mixed-length concurrent requests, some ending on ``eos_id`` and
+    some on ``max_new``, with a step in flight all the way: every reply
+    is the per-token oracle's (each request alone first, held to a full
+    forward over its tokens; then all together, held to those replies cut
+    at their ``eos``), no callback fires after an ``eos``, and
+    ``decode.tokens_discarded`` counts exactly the ``eos`` endings that
+    had a successor step launched (an ``eos`` that is also the count's
+    last token had none: the sequence was left out of that step)."""
+    discarded = _metrics.counter("decode.tokens_discarded")
+    rng = np.random.default_rng(5)
+    with _family_engine(family, (1, 4, 8)) as eng:
+        plans = []
+        for n, max_new, stop in ((3, 9, None), (7, 6, 4), (5, 8, 8),
+                                 (2, 7, 3), (6, 5, None), (4, 9, 6)):
+            prompt = rng.integers(0, eng.vocab, n).tolist()
+            alone = eng.generate(prompt, max_new_tokens=max_new,
+                                 timeout_s=600)
+            _assert_greedy(eng, alone)
+            free, eos = alone["generated"], None
+            if stop is not None:
+                # the first token from ``stop`` on that did not occur
+                # before: the reply ends there, at the latest on its count
+                at = next((k for k in range(stop - 1, max_new)
+                           if free[k] not in free[:k]), None)
+                eos = None if at is None else free[at]
+            want = free if eos is None else free[:free.index(eos) + 1]
+            plans.append((prompt, max_new, eos, want))
+        before = discarded.value
+        gens, streams = _held_until_all_are_in(eng, [
+            dict(tokens=p, max_new_tokens=m, eos_id=e)
+            for p, m, e, _ in plans])
+        docs = [g.result(timeout=600) for g in gens]
+        late = 0
+        for doc, stream, (_, max_new, eos, want) in zip(docs, streams, plans):
+            assert doc["generated"] == want == stream
+            ended = eos is not None and want[-1] == eos
+            assert doc["finish"] == ("eos" if ended else "length")
+            late += ended and len(want) < max_new
+        assert any(d["finish"] == "eos" for d in docs) and late
+        eng.drain(timeout_s=60)       # the last discarded slot has landed
+        assert discarded.value - before == late
+        eng.assert_no_leaks()
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline", "eos"])
+def test_pages_reused_under_a_step_in_flight(how):
+    """A sequence leaves mid-decode while a launched step still names its
+    pages, and the pool is sized so that the next admission MUST take
+    them: the pages are free at once, the newcomer's prefill is ordered
+    behind the stale step by the pools it takes, and its reply is the
+    oracle's."""
+    m = _model()
+    # 3 pages of 4: exactly one reservation of 4 + 8 tokens
+    eng = _engine(m, num_pages=3, decode_ladder=(1, 4))
+    discarded = _metrics.counter("decode.tokens_discarded")
+    try:
+        first, second = [1, 2, 3, 4], [9, 8, 7, 6]
+        free = _oracle_generate(m.params, m.cfg, first, 8)
+        eos = free[3] if how == "eos" and free[3] not in free[:3] else None
+        if how == "eos":
+            assert eos is not None
+        before = discarded.value
+        seen, gen = [], []
+
+        def on_token(t):
+            seen.append(t)
+            if len(seen) == 4 and how == "cancel":
+                # inside the landing of a step whose successor is out
+                assert gen[0].cancel()
+            if len(seen) == 4 and how == "deadline":
+                gen[0]._seq.deadline = time.monotonic() - 1.0   # ran out
+
+        gen.append(eng.submit_generate(first, max_new_tokens=8, eos_id=eos,
+                                       on_token=on_token))
+        pages = set(gen[0]._seq.pages)
+        doc = gen[0].result(timeout=300)
+        assert doc["finish"] == ("cancelled" if how == "cancel" else how)
+        assert doc["generated"] == seen == free[:4]
+        newcomer = None
+        deadline = time.monotonic() + 60
+        while newcomer is None and time.monotonic() < deadline:
+            try:
+                newcomer = eng.submit_generate(second, max_new_tokens=8)
+            except Overloaded as e:       # not retired yet: its pages
+                assert e.reason == "kv_exhausted"
+                time.sleep(0.002)
+        assert set(newcomer._seq.pages) == pages
+        assert newcomer.result(timeout=300)["generated"] == \
+            _oracle_generate(m.params, m.cfg, second, 8)
+        # the step launched on the leaver's fourth token was thrown away
+        assert discarded.value - before == 1
+        eng.assert_no_leaks()
+        assert eng.self_check() == 0
+    finally:
+        eng.close(drain=True)
+
+
+def test_set_params_during_traffic_keeps_generations_apart():
+    """Two params generations decode side by side (their steps alternate,
+    each launched behind the other's): neither sequence sees the other's
+    params, token for token."""
+    m = _model()
+    eng = _engine(m, num_pages=32, decode_ladder=(1, 4))
+    try:
+        old = jax.tree.map(np.asarray, m.params)
+        new = jax.tree.map(lambda a: np.asarray(a) * 0.5, m.params)
+        under_way, swapped = threading.Event(), threading.Event()
+        first = []
+
+        def on_token(t):
+            first.append(t)
+            if len(first) == 3:
+                under_way.set()
+                assert swapped.wait(60)
+
+        a = eng.submit_generate([5, 3, 1], max_new_tokens=12,
+                                on_token=on_token)
+        assert under_way.wait(300)
+        eng.set_params({"params": new}, step=1)
+        second = []
+        b = eng.submit_generate([5, 3, 1], max_new_tokens=9,
+                                on_token=second.append)
+        c = eng.submit_generate([2, 6], max_new_tokens=7)
+        swapped.set()
+        want_a = _oracle_generate(old, m.cfg, [5, 3, 1], 12)
+        want_b = _oracle_generate(new, m.cfg, [5, 3, 1], 9)
+        assert want_a[:9] != want_b           # the two generations differ
+        assert a.result(timeout=300)["generated"] == want_a == first
+        assert b.result(timeout=300)["generated"] == want_b == second
+        assert c.result(timeout=300)["generated"] == \
+            _oracle_generate(new, m.cfg, [2, 6], 7)
+        eng.assert_no_leaks()
+    finally:
+        eng.close(drain=True)
+
+
+def _recording_steps(eng):
+    """Every packed array the engine launches a decode step on."""
+    from dist_keras_tpu.serving.decode import _step_views
+
+    real, sent = eng._decode_jit, []
+
+    def step(*args):
+        sent.append(_step_views(np.array(args[-1]), eng.max_pages_per_seq))
+        return real(*args)
+
+    eng._decode_jit = step
+    return sent
+
+
+def test_steps_overlapped_and_no_step_for_a_sequence_past_its_count():
+    """A lone 6-token request runs 5 decode steps, 4 of them launched
+    under their predecessor; and with no ``eos`` in play no step is spent
+    on a sequence that ended on its count: the live slots of all launched
+    steps are exactly the tokens the steps delivered."""
+    discarded = _metrics.counter("decode.tokens_discarded")
+    overlapped = _metrics.histogram("decode.step_overlapped")
+    with _engine(decode_ladder=(1, 4)) as eng:
+        eng.generate([1, 2], max_new_tokens=2)          # compiles outside
+        sent = _recording_steps(eng)
+        lo = time.perf_counter()
+        doc = eng.generate([3, 1, 4], max_new_tokens=6, timeout_s=300)
+        hi = time.perf_counter()
+        flags = [v for _, v in overlapped.samples_between(lo, hi)[0]]
+        assert doc["steps"] == 5 and flags == [0.0, 1.0, 1.0, 1.0, 1.0]
+        # a step's token is the host's only with nothing in flight
+        assert [int(toks[0]) < 0 for toks, *_ in sent] == \
+            [False, True, True, True, True]
+
+        del sent[:]
+        before = discarded.value
+        gens, _ = _held_until_all_are_in(eng, [
+            dict(tokens=[7, i], max_new_tokens=n)
+            for i, n in enumerate((3, 5, 7, 2))])
+        docs = [g.result(timeout=300) for g in gens]
+        assert [len(d["generated"]) for d in docs] == [3, 5, 7, 2]
+        live = sum(int((lengths > 0).sum()) for *_, lengths in sent)
+        assert live == sum(d["steps"] for d in docs) == 2 + 4 + 6 + 1
+        assert discarded.value == before
+        eng.assert_no_leaks()
 
 
 # -- admission control -------------------------------------------------
@@ -487,15 +743,54 @@ def test_fault_points_typed(engine_and_model):
     eng.assert_no_leaks()
 
 
-def test_step_fault_absorbed_by_retry(engine_and_model):
-    # one transient step failure: the dispatch retries in place and
-    # the caller never notices (pools and kv_len advance only on
-    # success, so the retry is sound)
+class _Poisoned:
+    """A step's output that fails when it is fetched, as an asynchronous
+    device failure does: launched fine, raises at ``np.asarray``."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("device failure surfaced at the fetch")
+
+
+def _poison_fetches(monkeypatch, eng, which):
+    """The engine's decode program, its ``which``-th outputs (counting
+    the launches that ran) poisoned.  The pools move on as they do; a
+    successor handed the poisoned output fails at its launch."""
+    real, ran = eng._decode_jit, []
+
+    def step(*args):
+        out, *pools = real(*args)
+        ran.append(1)
+        return (_Poisoned() if len(ran) in which else out, *pools)
+
+    monkeypatch.setattr(eng, "_decode_jit", step)
+
+
+def _arm_step_fault(monkeypatch, eng, where, times):
+    """``times`` consecutive failures of one step: at the ``launch`` of
+    the first step (nothing in flight), at a launch ``under_flight``
+    (the third dispatch, its predecessor running), or at the ``fetch`` of
+    the second step's tokens with the third already launched on them."""
+    if where == "fetch":
+        _poison_fetches(monkeypatch, eng, set(range(2, 2 + times)))
+    else:
+        faults.inject("decode.step", at=0 if where == "launch" else 2,
+                      times=times)
+
+
+@pytest.mark.parametrize("where", ["launch", "under_flight", "fetch"])
+def test_step_fault_absorbed_by_retry(engine_and_model, monkeypatch, where):
+    # one transient step failure: the step retries in place and the
+    # caller never notices (pools and kv_len advance only when a step
+    # LANDS, so the retry is sound; a failed fetch drops the successor
+    # launched on its tokens with it, and counts once)
     eng, m = engine_and_model
-    with faults.armed("decode.step", times=1):
-        doc = eng.generate([2, 4, 6], max_new_tokens=4, timeout_s=300)
-    assert doc["generated"] == _oracle_generate(m.params, m.cfg,
-                                                [2, 4, 6], 4)
+    _arm_step_fault(monkeypatch, eng, where, times=1)
+    seen = []
+    doc = eng.submit_generate([2, 4, 6], max_new_tokens=6,
+                              on_token=seen.append).result(timeout=300)
+    want = _oracle_generate(m.params, m.cfg, [2, 4, 6], 6)
+    assert doc["generated"] == want and seen == want
+    assert doc["recoveries"] == 0 and doc["steps"] == 5
     eng.assert_no_leaks()
 
 
@@ -566,10 +861,13 @@ def test_kill_replica_racing_prefill_bit_identical():
         eng.close(drain=True)
 
 
-def test_kill_replica_mid_decode_bit_identical():
-    # the kill fires from the token stream itself after two tokens —
-    # squarely between decode steps on the owning replica; the replay
-    # is teacher-forced so the stream resumes exactly where it stopped
+@pytest.mark.parametrize("after", [1, 2, 4])
+def test_kill_replica_mid_decode_bit_identical(after):
+    # the kill fires from the token stream itself: after the prefill's
+    # token (nothing in flight), or after a step's, when its successor is
+    # already launched on the device's tokens and is dropped with the
+    # replica; the replay starts from what LANDED and is teacher-forced,
+    # so the stream resumes exactly where it stopped
     m = _model()
     eng = _engine(m, replicas=2, num_pages=32)
     try:
@@ -578,7 +876,7 @@ def test_kill_replica_mid_decode_bit_identical():
 
         def on_token(t):
             seen.append(t)
-            if len(seen) == 2:
+            if len(seen) == after:
                 eng.kill_replica(0)
 
         g = eng.submit_generate(prompt, max_new_tokens=6,
@@ -597,19 +895,22 @@ def test_kill_replica_mid_decode_bit_identical():
         eng.close(drain=True)
 
 
-def test_step_fault_past_retry_quarantines_and_recovers():
-    # decode.step fails twice (beats the 1 in-place retry) on the
-    # owning replica; a survivor exists, so the replica quarantines
-    # and the sequence replays to a bit-identical doc — the caller
-    # never sees FaultInjected
+@pytest.mark.parametrize("where", ["launch", "under_flight", "fetch"])
+def test_step_fault_past_retry_quarantines_and_recovers(monkeypatch, where):
+    # one step fails twice (beats the 1 in-place retry) on the owning
+    # replica, at its launch or at the fetch of its tokens; a survivor
+    # exists, so the replica quarantines and the sequence replays to a
+    # bit-identical doc — the caller never sees the failure
     m = _model()
     eng = _engine(m, replicas=2, num_pages=32)
     try:
         prompt = [5, 3]
-        with faults.armed("decode.step", times=2):
-            doc = eng.generate(prompt, max_new_tokens=5, timeout_s=300)
-        assert doc["generated"] == _oracle_generate(m.params, m.cfg,
-                                                    prompt, 5)
+        _arm_step_fault(monkeypatch, eng, where, times=2)
+        seen = []
+        doc = eng.submit_generate(prompt, max_new_tokens=5,
+                                  on_token=seen.append).result(timeout=300)
+        assert doc["generated"] == seen == _oracle_generate(
+            m.params, m.cfg, prompt, 5)
         assert doc["recoveries"] == 1
         st = eng.stats()
         assert st["quarantines"] == 1

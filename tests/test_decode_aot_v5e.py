@@ -99,13 +99,17 @@ def _bare_engine(cfg, family, page_size, num_pages, state_rows=0):
     return engine
 
 
-def _step_and_args(engine, phase, rung, pages_per_seq, S):
+def _step_and_args(engine, phase, rung, pages_per_seq, S, counts=0):
     """A step of ``phase`` and its integer arguments' shapes: the family's
     function on its arrays apart, or (``packed_*``) the program the worker
-    dispatches, on the ONE packed array (which cuts the tables out by the
-    engine's ``max_pages_per_seq``, set here: the engine is a bare one).
-    A family with per-sequence state takes its rows as one array more."""
+    dispatches, on the output of the step before it (as wide as this
+    rung's own: ``rung`` stands for the top of the ladder, and ``counts``
+    says how many values the family sends behind its tokens) and the ONE
+    packed array (which cuts the tables out by the engine's
+    ``max_pages_per_seq``, set here: the engine is a bare one).  A family
+    with per-sequence state takes its rows as one array more."""
     engine.max_pages_per_seq = pages_per_seq
+    engine.max_slots = rung
     rows = int(engine._state)
     if phase == "decode":
         return engine._decode_fn, (
@@ -113,7 +117,7 @@ def _step_and_args(engine, phase, rung, pages_per_seq, S):
             S((rung,)), S((rung,))) + (S((rung,)),) * rows
     if phase == "packed_decode":
         return engine._packed_decode_fn, (
-            S((rung * (pages_per_seq + 5 + rows),)),)
+            S((rung + counts,)), S((rung * (pages_per_seq + 5 + rows),)))
     if phase == "packed_prefill":
         return engine._packed_prefill_fn, (S((3 * rung + 1 + rows,)),)
     return engine._prefill_fn, (
@@ -219,7 +223,8 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         jax.eval_shape(functools.partial(mla_moe.init_params, cfg=cfg),
                        jax.random.PRNGKey(0)))
     pool = S(pool_shape, jnp.float32)
-    fn, args = _step_and_args(engine, phase, rung, L_POSITIONS // L_PAGE, S)
+    fn, args = _step_and_args(engine, phase, rung, L_POSITIONS // L_PAGE, S,
+                              counts=len(cfg["held_experts"]) + 2)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *args).compile()
     text = compiled.as_text()
@@ -259,8 +264,10 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         # the family function's name
         ints = [a for a in jax.tree.leaves(compiled.args_info)
                 if a.dtype == jnp.int32]
+        # the carried output (the tokens and the family's ten counts),
+        # then the one array that crosses
         assert [a.shape for a in ints] == [
-            (rung * (L_POSITIONS // L_PAGE + 5),)]
+            (rung + 10,), (rung * (L_POSITIONS // L_PAGE + 5),)]
         assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
     # the read: one kernel a layer over the flat float32 pool itself, and
     # nothing of the size of the slots' whole tables, in any type
@@ -327,7 +334,8 @@ def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(functools.partial(lfm2_moe.init_params, cfg=cfg),
                        jax.random.PRNGKey(0)))
-    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S)
+    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S,
+                              counts=cfg["n_routed_experts"] + 2)
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
         params, S(kv_shape, jnp.float32), S(state_shape, jnp.float32),
         *args).compile()
@@ -379,7 +387,8 @@ def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
         # one array crosses: the six of every family and this one's rows
         ints = [a for a in jax.tree.leaves(compiled.args_info)
                 if a.dtype == jnp.int32]
-        assert [a.shape for a in ints] == [(rung * (pages_per_seq + 6),)]
+        assert [a.shape for a in ints] == [
+            (rung + 34,), (rung * (pages_per_seq + 6),)]
         assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
 
 

@@ -108,6 +108,13 @@ def test_regions_sit_on_the_worker_thread_alone(traced_lines):
 
 @pytest.mark.parametrize("parent", ["decode.step", "decode.prefill"])
 def test_children_lie_inside_their_parents(traced_lines, parent):
+    """A step's ``build`` and ``dispatch`` lie in the parent region of the
+    pass that launched it, its ``wait`` and ``emit`` in the pass that
+    landed it (the next, with the successor's launch before them); a
+    prefill's parent closes around its launch and opens again around its
+    wait when a step in flight lands between.  So a parent holds at most
+    one child of a kind, every child lies in one, and each kind counts
+    one a step (one a prefill)."""
     worker = _worker_events(traced_lines)
     parents = [(a, b) for a, b, n, _ in worker if n == f"perf.{parent}"]
     children = [(a, b, n) for a, b, n, _ in worker
@@ -116,10 +123,18 @@ def test_children_lie_inside_their_parents(traced_lines, parent):
     kinds = {n for _, _, n in children}
     assert kinds == {f"perf.{r}" for r in DECODE_REGIONS
                      if r.startswith(parent + ".")}
+    held = {p: [] for p in parents}
     for a, b, n in children:
-        assert any(pa <= a and b <= pb for pa, pb in parents), n
-    # one child of each kind in each parent that ran to its end
-    assert len(children) == len(kinds) * len(parents)
+        (mine,) = [p for p in parents if p[0] <= a and b <= p[1]]
+        held[mine].append(n)
+    for names in held.values():
+        assert names and len(names) == len(set(names)), names
+    per_kind = {k: sum(n == k for _, _, n in children) for k in kinds}
+    assert len(set(per_kind.values())) == 1, per_kind
+    # three requests of four tokens: a prefill each, and three to nine
+    # steps by how they were batched
+    (count,) = set(per_kind.values())
+    assert count == 3 if parent == "decode.prefill" else 3 <= count <= 9
 
 
 def test_fields_reach_the_trace_as_arguments(traced_lines):
@@ -127,8 +142,10 @@ def test_fields_reach_the_trace_as_arguments(traced_lines):
     steps = [f for _, _, n, f in worker if n == "perf.decode.step"]
     assert steps and all(f["rung"] in (1, 4) and 1 <= f["n"] <= f["rung"]
                          for f in steps)
+    # a prefill's region opens twice (around its launch, around its wait)
+    # when a pass of steps runs between
     prefills = [f for _, _, n, f in worker if n == "perf.decode.prefill"]
-    assert len(prefills) == 3 and all(f["rung"] == 8 for f in prefills)
+    assert 3 <= len(prefills) <= 6 and all(f["rung"] == 8 for f in prefills)
     assert len({f["sid"] for f in prefills}) == 3
     sched = [f for _, _, n, f in worker if n == "perf.decode.sched"]
     assert all(set(f) == {"queued", "active"} for f in sched)
@@ -240,26 +257,79 @@ def test_prefill_and_queue_wait_count_one_sample_a_request(engine):
     assert stamps == sorted(stamps)
 
 
+def _window(name, lo, hi):
+    pairs, truncated = metrics.histogram(name).samples_between(lo, hi)
+    assert not truncated, name
+    return pairs
+
+
 def test_step_histogram_keeps_its_meaning_and_gains_the_stamp(engine):
+    """``decode.step_s`` has one sample a step and reads what the step
+    cost the loop: from the later of its launch and its predecessor's
+    tokens on the host to its own tokens on the host.  With nothing in
+    flight that is dispatch + wait, as it was; under overlap consecutive
+    samples tile the wall between the tokens' arrivals: each starts where
+    its predecessor ended and ends where its own wait did."""
     lo = time.perf_counter()
     engine.generate([1, 2, 3], max_new_tokens=6)
     hi = time.perf_counter()
-    steps, _ = metrics.histogram("decode.step_s").samples_between(lo, hi)
+    steps = _window("decode.step_s", lo, hi)
     assert len(steps) == 5       # the first token is the prefill's
     for region in ("dispatch", "wait", "build", "emit"):
-        inner, _ = metrics.histogram(
-            f"perf.phase.decode.step.{region}").samples_between(lo, hi)
-        assert len(inner) == len(steps), region
-    # decode.step_s runs from after the arrays are built to the tokens on
-    # the host: dispatch and wait lie inside it, sample for sample
-    disp, _ = metrics.histogram(
-        "perf.phase.decode.step.dispatch").samples_between(lo, hi)
-    wait, _ = metrics.histogram(
-        "perf.phase.decode.step.wait").samples_between(lo, hi)
-    for (at, whole), (d_at, d), (w_at, w) in zip(steps, disp, wait):
-        assert at <= d_at and d_at + d <= w_at + 1e-6
-        assert w_at + w <= at + whole + 1e-6
+        assert len(_window(f"perf.phase.decode.step.{region}", lo, hi)) \
+            == len(steps), region
+    disp = _window("perf.phase.decode.step.dispatch", lo, hi)
+    wait = _window("perf.phase.decode.step.wait", lo, hi)
+    flags = [v for _, v in _window("decode.step_overlapped", lo, hi)]
+    assert flags == [0.0, 1.0, 1.0, 1.0, 1.0]
+    # step k's tokens reach the host at the end of the k-th wait
+    landed = [w_at + w for w_at, w in wait]
+    for k, ((at, whole), (d_at, d)) in enumerate(zip(steps, disp)):
+        assert abs(at + whole - landed[k]) < 1e-4, k
+        if not flags[k]:
+            # nothing in flight: from before the dispatch, as ever
+            assert at <= d_at and d_at + d <= wait[k][0] + 1e-6
+        else:
+            # launched under its predecessor, before that one's tokens
+            # were fetched: the sample starts when they were
+            assert d_at < landed[k - 1]
+            assert abs(at - landed[k - 1]) < 1e-4, k
+    # no overlap and no hole from the second sample on
+    for (at, whole), (nxt_at, _) in zip(steps[1:], steps[2:]):
+        assert abs(at + whole - nxt_at) < 1e-4
     assert engine.stats()["step_s"]["count"] == 5
+
+
+def test_overlap_and_discards_are_counted(engine):
+    """``decode.step_overlapped`` gains one stamped sample a step, 1.0
+    when the step was launched with its predecessor in flight (stamped as
+    ``decode.step_s`` is); ``decode.tokens_discarded`` counts the slots
+    computed for a sequence that had already ended: here the one step
+    launched before the ``eos`` of the step in front of it was seen."""
+    free = engine.generate([2, 7, 2], max_new_tokens=8)["generated"]
+    eos = free[2]
+    assert eos not in free[:2]
+    discarded = metrics.counter("decode.tokens_discarded")
+    before = discarded.value
+    lo = time.perf_counter()
+    seen = []
+    doc = engine.submit_generate([2, 7, 2], max_new_tokens=8, eos_id=eos,
+                                 on_token=seen.append).result(timeout=120)
+    # the future resolves when the eos LANDS; its successor lands after
+    deadline = time.monotonic() + 60
+    while discarded.value == before and time.monotonic() < deadline:
+        time.sleep(0.01)
+    hi = time.perf_counter()
+    assert doc["finish"] == "eos" and doc["generated"] == free[:3] == seen
+    steps = _window("decode.step_s", lo, hi)
+    flags = _window("decode.step_overlapped", lo, hi)
+    # tokens two and three are steps' (the eos the second's); the third
+    # step was in flight when the eos landed, and lands for nothing
+    assert len(steps) == len(flags) == 3
+    assert [at for at, _ in flags] == [at for at, _ in steps]
+    assert [v for _, v in flags] == [0.0, 1.0, 1.0]
+    assert discarded.value - before == 1
+    assert doc["steps"] == 2
 
 
 def test_one_transfer_a_dispatch_is_counted(engine):
@@ -306,11 +376,13 @@ def _lowered_step(engine, phase):
     """The engine's jitted step of ``phase`` lowered the way the worker
     calls it: params, pools, ONE packed int32 array."""
     rep = engine._replicas[0]
-    size = (4 * (engine.max_pages_per_seq + 5) if phase == "decode"
-            else 3 * 8 + 1)
-    jitted = engine._decode_jit if phase == "decode" else engine._prefill_jit
-    return jitted.lower(rep.params, *rep.pools,
-                        jax.ShapeDtypeStruct((size,), jnp.int32))
+    packed = jax.ShapeDtypeStruct(
+        (4 * (engine.max_pages_per_seq + 5) if phase == "decode"
+         else 3 * 8 + 1,), jnp.int32)
+    if phase == "decode":       # and the output of the step before it
+        return engine._decode_jit.lower(rep.params, *rep.pools,
+                                        rep.no_tokens, packed)
+    return engine._prefill_jit.lower(rep.params, *rep.pools, packed)
 
 
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
@@ -324,16 +396,21 @@ def test_serving_steps_carry_their_scopes(engine, phase):
 def test_one_int32_array_crosses_and_the_program_keeps_its_name(engine,
                                                                 phase):
     """Besides the params and the pools a dispatched step takes exactly
-    one array, the packed int32 one; and its program's name still holds
-    the family function's (``_decode_fn`` / ``_prefill_fn``): a trace's
-    reader finds the step's program by it."""
+    one array from the host, the packed int32 one (a decode step also the
+    output of the step before it, as wide as the top rung whatever rung
+    ran: it is on the device already, ``test_one_transfer_a_dispatch_is_
+    counted``); and its program's name still holds the family
+    function's (``_decode_fn`` / ``_prefill_fn``): a trace's reader finds
+    the step's program by it."""
     lowered = _lowered_step(engine, phase)
     rep = engine._replicas[0]
     args = jax.tree.leaves(lowered.args_info)
-    ints = [a for a in args if a.dtype == jnp.int32]
-    assert len(ints) == 1 and len(ints[0].shape) == 1
+    ints = [a.shape for a in args if a.dtype == jnp.int32]
+    carried = [(engine.max_slots,)] if phase == "decode" else []
+    assert len(ints) == 1 + len(carried) and ints[:-1] == carried
+    assert len(ints[-1]) == 1
     assert len(args) == (len(jax.tree.leaves(rep.params))
-                         + len(rep.pools) + 1)
+                         + len(rep.pools) + len(ints))
     (module,) = re.findall(r"^module @(\S+)", lowered.as_text(),
                            flags=re.M)
     assert re.search(f"_{phase}_fn", module), module

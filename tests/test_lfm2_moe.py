@@ -712,7 +712,8 @@ def test_steps_carry_their_names_and_scopes(phase):
         rep = eng._replicas[0]
         if phase == "decode":
             lowered = eng._decode_jit.lower(
-                rep.params, *rep.pools, jnp.zeros((4 * (12 + 6),), i32))
+                rep.params, *rep.pools, rep.no_tokens,
+                jnp.zeros((4 * (12 + 6),), i32))
         else:
             lowered = eng._prefill_jit.lower(
                 rep.params, *rep.pools, jnp.zeros((3 * 8 + 2,), i32))

@@ -1,5 +1,5 @@
 """The two block families that were served before PR 32 compile to the
-programs they compiled to then.
+programs recorded here.
 
 PR 32 widened the family seam (a pool states the layers it spans and
 whether its rows are pages or sequences; a family with per-sequence state
@@ -8,8 +8,18 @@ serving cells of the two older families spread close to their bounds, so
 that PR was held to leaving their compiled programs exactly as they were:
 the lowered text (StableHLO, no source locations) of each family's packed
 prefill and decode programs at a toy size is hashed here and compared with
-the hash recorded from the parent commit (9317672, PR 31), by this file's
-own ``lowered_hash`` run in a checkout of that commit.
+a recorded hash, by this file's own ``lowered_hash``.
+
+**PR 35 meant to change the four decode entries, and only those.**  A
+decode step now takes the output of the step before it as one more device
+argument and resolves, inside the program, the slots whose token is named
+by its source (``serving/decode.py:_step_views``), and its output is as
+wide as the top rung at every rung: an integer select over at most a rung
+of values in front of the family's step, which is handed what it was
+handed before.  Their hashes were recorded anew on that PR's tree.  The
+four prefill entries are still those recorded from commit 9317672 (PR 31),
+in a checkout of that commit: a prefill is synchronous and its program
+did not move.
 
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
@@ -47,26 +57,27 @@ def _mla_moe():
 
 MODELS = {"transformer": _transformer, "mla_moe": _mla_moe}
 
-# recorded on the parent commit (9317672): sha256 of the lowered text, for
-# the CPU (the ``jnp`` references serve) and for a TPU (the Pallas kernels
-# do: ``use_pallas()`` asks ``jax.default_backend()``, patched here)
-PARENT = {
+# sha256 of the lowered text, for the CPU (the ``jnp`` references serve)
+# and for a TPU (the Pallas kernels do: ``use_pallas()`` asks
+# ``jax.default_backend()``, patched here): the prefills' recorded on
+# commit 9317672 (PR 31), the decode steps' on PR 35's tree
+RECORDED = {
     ("transformer", "prefill", "cpu"):
         "fe59c3565961787bc3f1f6bbe9e7c7f6ffdaeeb0d16971e055c1c7bbeee7549a",
     ("transformer", "decode", "cpu"):
-        "7fb2059ea6c174d6dcbf2961483989959122b6be1f5fd2e067fcfe07b6532ffe",
+        "5e5df36b3903704085261d2964f3cd8d0de7519fadd38ec8e3b19d90f327b5ec",
     ("mla_moe", "prefill", "cpu"):
         "e3ee6957027c2cdc8d3239a7007edb69d5e90c757b04248f8f17e061f3ea60af",
     ("mla_moe", "decode", "cpu"):
-        "7400b558c2856e76acf39683fb2b6a7e9dbc111c892a5bb0c78a2cae95d6f119",
+        "747fae6db7e70fb990ebd203437755c284129cbb3ef915740aed3ed6924d8e8f",
     ("transformer", "prefill", "tpu"):
         "cf0c89111d800675d3dd1168210c8a2edf951a349621233e69f2e127dede0ed3",
     ("transformer", "decode", "tpu"):
-        "7fb2059ea6c174d6dcbf2961483989959122b6be1f5fd2e067fcfe07b6532ffe",
+        "5e5df36b3903704085261d2964f3cd8d0de7519fadd38ec8e3b19d90f327b5ec",
     ("mla_moe", "prefill", "tpu"):
         "d0ee9c0422cd0fd5708a6c3a56a0795d0b657947d10ffd2e0b6c075839fe6e5c",
     ("mla_moe", "decode", "tpu"):
-        "1aa96e97a60bfa1c40b531fe95dc29ea31a901a2cb1bae3529047ab56c505940",
+        "e2591b9341c56b9da03fda179174e2a5be7d531be252bcd8a9163c4090676c8c",
 }
 
 
@@ -83,13 +94,16 @@ def lowered_hash(family, phase, platform):
         rep = eng._replicas[0]
         pmax = eng.max_pages_per_seq
         if phase == "decode":
-            step, n = eng._decode_jit, 4 * (pmax + 5)
+            # behind the pools the output of the step before it
+            step, n, carried = eng._decode_jit, 4 * (pmax + 5), \
+                (rep.no_tokens,)
         else:
-            step, n = eng._prefill_jit, 3 * 16 + 1
+            step, n, carried = eng._prefill_jit, 3 * 16 + 1, ()
         jax.default_backend = lambda: platform
         try:
             lowered = step.trace(
-                rep.params, *rep.pools, jnp.zeros((n,), jnp.int32)).lower(
+                rep.params, *rep.pools, *carried,
+                jnp.zeros((n,), jnp.int32)).lower(
                 lowering_platforms=(platform,))
         finally:
             jax.default_backend = real
@@ -97,12 +111,12 @@ def lowered_hash(family, phase, platform):
     return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
 
-@pytest.mark.parametrize("family,phase,platform", sorted(PARENT))
+@pytest.mark.parametrize("family,phase,platform", sorted(RECORDED))
 def test_lowered_program_is_the_parents(family, phase, platform):
     assert lowered_hash(family, phase, platform) == \
-        PARENT[(family, phase, platform)]
+        RECORDED[(family, phase, platform)]
 
 
 if __name__ == "__main__":
-    for key in sorted(PARENT):
+    for key in sorted(RECORDED):
         print(key, lowered_hash(*key))

@@ -488,7 +488,8 @@ def test_steps_carry_their_names_and_scopes(phase):
         # (4 slots of 12 pages, 5 more values a slot; an 8-token prompt)
         if phase == "decode":
             lowered = eng._decode_jit.lower(
-                rep.params, *rep.pools, jnp.zeros((4 * (12 + 5),), i32))
+                rep.params, *rep.pools, rep.no_tokens,
+                jnp.zeros((4 * (12 + 5),), i32))
         else:
             lowered = eng._prefill_jit.lower(
                 rep.params, *rep.pools, jnp.zeros((3 * 8 + 1,), i32))
