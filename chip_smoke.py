@@ -59,7 +59,9 @@ from dist_keras_tpu.models.transformer import (
     transformer_config,
 )
 from dist_keras_tpu.ops.attention import attention_with_lse
+from dist_keras_tpu.ops import gated_delta
 from dist_keras_tpu.ops.pallas import decode_attention
+from dist_keras_tpu.ops.pallas import gated_delta as pallas_gated_delta
 from dist_keras_tpu.ops.pallas.flash_attention import (
     _bwd_call,
     _fwd_call,
@@ -539,6 +541,26 @@ def _latent_case(slots, heads, page_size, n_pages, width=640, used=576):
     return (q, pool, table, _case_lengths(slots, page_size, n_pages))
 
 
+def _state_step_case(slots, heads, dk=96, dv=192):
+    """A flat pool of two layers' per-sequence matrices (``slots + 1`` rows
+    a layer, the published key and value widths), the slots' rows of the
+    second layer in another order, and one position's q, k, v, g, beta."""
+    rng = np.random.default_rng(2)
+    rows = slots + 1
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    k = normal(slots, heads, dk)
+    return (normal(2 * rows, heads, dk, dv),
+            jnp.asarray(rows + rng.permutation(slots), jnp.int32),
+            normal(slots, heads, dk) * dk ** -0.5,
+            k / jnp.linalg.norm(k, axis=-1, keepdims=True),
+            normal(slots, heads, dv),
+            -jnp.abs(normal(slots, heads)),
+            2.0 * jax.nn.sigmoid(normal(slots, heads)))
+
+
 def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
     """name -> (fn, args): every ``pl.pallas_call`` in ``ops/pallas/`` at
     the geometry stages B and C dispatch.  The one table stage D runs on
@@ -560,6 +582,9 @@ def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
         functools.partial(decode_attention.latent_attention_kernel,
                           **_LATENT),
         _latent_case(slots, n_heads, page_size, -(-seq // page_size)))
+    cases["gdn_state_step/f32"] = (
+        pallas_gated_delta.state_step_kernel,
+        _state_step_case(slots, n_heads))
     return cases, flash
 
 
@@ -586,6 +611,10 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
             q, kp, vp, table, lengths)
         latent_ref = decode_attention.latent_attention_reference(
             *cases["latent_decode/f32"][1], **_LATENT)
+        states, rows, *position = cases["gdn_state_step/f32"][1]
+        step_o, step_rows = gated_delta.gated_delta_step(states[rows],
+                                                         *position)
+        step_ref = (step_o, states.at[rows].set(step_rows))
 
     for tag, c in flash.items():
         ref_out, ref_grads = refs[tag]
@@ -599,6 +628,11 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
         _rel_err(run("paged_decode/f32"), paged_ref), TOLERANCE)
     report["latent_decode/f32"] = (
         _rel_err(run("latent_decode/f32"), latent_ref), TOLERANCE)
+    # the output and the WHOLE pool: the rows no slot names are untouched
+    report["gdn_state_step/f32"] = (
+        max(_rel_err(g, r)
+            for g, r in zip(run("gdn_state_step/f32"), step_ref)),
+        TOLERANCE)
     for name, (err, tol) in report.items():
         _check(np.isfinite(err) and err <= tol,
                f"stage D: {name} rel err {err:.3g} > tolerance {tol}")

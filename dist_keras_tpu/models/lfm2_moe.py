@@ -75,7 +75,10 @@ from dist_keras_tpu.models.mla_moe import (
     observe_routing,
     rms_norm,
 )
-from dist_keras_tpu.ops.pallas.decode_attention import latent_attention_auto
+from dist_keras_tpu.ops.pallas.decode_attention import (
+    LATENT_BLOCK_PAGES,
+    latent_attention_auto,
+)
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
 FAMILY = "lfm2_moe"
@@ -212,6 +215,32 @@ def rope_halves(x, positions, theta):
                            -1).astype(x.dtype)
 
 
+def causal_taps(kernel, u, length):
+    """A depthwise causal convolution over one whole sequence, and what it
+    needs of the past afterwards: ``kernel (channels, L)``, ``u (T,
+    channels)`` -> (``v_t = sum_j kernel[:, j] u_{t - (L-1) + j}`` with
+    ``u`` zero before the sequence's start, ``u`` at positions ``length -
+    L + 1 .. length - 1``, zeros on the left of a sequence shorter than
+    that).  Shared by this family's gated short convolution and
+    ``models/olmo_hybrid.py``'s three."""
+    taps = kernel.shape[1]
+    t = u.shape[0]
+    padded = jnp.pad(u, ((taps - 1, 0), (0, 0)))
+    v = sum(kernel[:, j] * padded[j:j + t] for j in range(taps))
+    # position p is row p + taps - 1 of ``padded``
+    return v, jax.lax.dynamic_slice_in_dim(padded, length, taps - 1)
+
+
+def causal_taps_token(kernel, u, state):
+    """The same convolution one position on, a slot each: ``u (S,
+    channels)``, ``state (S, L - 1, channels)`` -> (``v (S, channels)``,
+    the window ``(S, L, channels)`` it was taken over: ``window[:, 1:]``
+    is the state one position on)."""
+    window = jnp.concatenate([state, u[:, None]], 1)         # (S, L, d)
+    v = sum(kernel[:, j] * window[:, j] for j in range(window.shape[1]))
+    return v, window
+
+
 def _conv_gates(conv, y):
     """-> (``u = B * X``, ``C``), each ``(T, d)``."""
     with jax.named_scope("conv_in"):
@@ -228,15 +257,9 @@ def _conv_sequence(conv, y, length):
     """The operator over one whole sequence ``y (T, d)`` -> (``o (T,
     d)``, the state after position ``length - 1``: ``u`` at the last
     ``L - 1`` positions, zeros before the sequence's start)."""
-    taps = conv["kernel"].shape[1]
     u, c = _conv_gates(conv, y)
     with jax.named_scope("conv_mix"):
-        t = u.shape[0]
-        padded = jnp.pad(u, ((taps - 1, 0), (0, 0)))
-        v = sum(conv["kernel"][:, j] * padded[j:j + t]
-                for j in range(taps))
-        # position p is row p + taps - 1 of ``padded``
-        state = jax.lax.dynamic_slice_in_dim(padded, length, taps - 1)
+        v, state = causal_taps(conv["kernel"], u, length)
     return _conv_out(conv, c, v), state
 
 
@@ -245,9 +268,7 @@ def _conv_token(conv, y, state):
     (S, d)``, the state one position on)."""
     u, c = _conv_gates(conv, y)
     with jax.named_scope("conv_mix"):
-        window = jnp.concatenate([state, u[:, None]], 1)     # (S, L, d)
-        v = sum(conv["kernel"][:, j] * window[:, j]
-                for j in range(window.shape[1]))
+        v, window = causal_taps_token(conv["kernel"], u, state)
     return _conv_out(conv, c, v), window[:, 1:]
 
 
@@ -266,26 +287,31 @@ def _qkv(attn, y, positions, cfg):
     return q, jnp.concatenate([v.reshape(t, -1), k.reshape(t, -1)], -1)
 
 
-def _attend_sequence(q, entry, cfg):
-    """Causal grouped-query attention of one whole sequence over its own
-    positions -> (T, H, hd)."""
-    t, hk = q.shape[0], cfg["n_kv_heads"]
+def attend_entries(q, entry, hk):
+    """Causal attention of one whole sequence over its own ``v | k``
+    entries, ``hk`` K/V heads serving the query heads -> (T, H, hd).
+    ``models/olmo_hybrid.py`` attends through it too (as many K/V heads as
+    query heads)."""
+    t = q.shape[0]
     v, k = jnp.split(entry, 2, axis=-1)
     return attention_auto(q[None], k.reshape(1, t, hk, -1),
                           v.reshape(1, t, hk, -1), causal=True)[0]
 
 
-def _attend_pool(q, pool_rows, page_tables, lengths, cfg):
-    """One query a slot over the paged ``v | k`` rows -> (S, H, hd)."""
+def attend_rows(q, pool_rows, page_tables, lengths, hk,
+                block_pages=LATENT_BLOCK_PAGES):
+    """One query a slot over the paged ``v | k`` rows of ``hk`` K/V heads
+    -> (S, H, hd); ``block_pages`` is the read kernel's (a family whose
+    rows are wider than this one's states fewer)."""
     s, h, hd = q.shape
-    hk = cfg["n_kv_heads"]
     mine = (jnp.arange(h)[:, None] // (h // hk)
             == jnp.arange(hk)[None]).astype(q.dtype)            # (H, Hkv)
     # head h's query in the lanes of its own K/V head's keys
     wide = (q[:, :, None, :] * mine[None, :, :, None]).reshape(s, h, -1)
     wide = jnp.concatenate([jnp.zeros_like(wide), wide], -1)
     o = latent_attention_auto(wide, pool_rows, page_tables, lengths,
-                              rank=hk * hd, scale=hd ** -0.5)
+                              rank=hk * hd, scale=hd ** -0.5,
+                              block_pages=block_pages)
     # and of the summed values' row its own K/V head's lanes
     return jnp.einsum("shkd,hk->shd", o.reshape(s, h, hk, hd), mine)
 
@@ -298,7 +324,7 @@ def _logits(params, hs, cfg):
             params["norm_f"], hs, cfg["rms_norm_eps"])) @ params["embed"].T
 
 
-def _pool_layer(cfg, layer):
+def pool_layer(cfg, layer):
     """Which layer of its pool layer ``layer`` writes: its ordinal among
     the layers of its kind."""
     kind = cfg["layer_types"][layer]
@@ -318,12 +344,12 @@ def _sequence_layers(params, tokens, length, cfg, write_kv, write_state):
         y = rms_norm(blk["op_norm"], hs, cfg["rms_norm_eps"])
         if "conv" in blk:
             o, state = _conv_sequence(blk["conv"], y, length)
-            write_state(_pool_layer(cfg, li), state)
+            write_state(pool_layer(cfg, li), state)
         else:
             q, entry = _qkv(blk["attn"], y, positions, cfg)
-            write_kv(_pool_layer(cfg, li), entry)
+            write_kv(pool_layer(cfg, li), entry)
             with jax.named_scope("attend"):
-                a = _attend_sequence(q, entry, cfg)
+                a = attend_entries(q, entry, cfg["n_kv_heads"])
             with jax.named_scope("attn_out"):
                 o = jnp.einsum("thk,hkd->td", a, blk["attn"]["wo"])
         hs, counts = ffn(blk, hs + o, cfg, valid, counts)
@@ -378,7 +404,7 @@ def _decode_layers(cfg, params, kv, state, tokens, positions, page_tables,
     counts = _zero_counts(cfg)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, eps)
-        at = _pool_layer(cfg, li)
+        at = pool_layer(cfg, li)
         if "conv" in blk:
             with jax.named_scope("state_read"):
                 old = state[at, rows]
@@ -392,9 +418,9 @@ def _decode_layers(cfg, params, kv, state, tokens, positions, page_tables,
             with jax.named_scope("attend_pool"):
                 # the whole pool viewed flat over (layer, page), the page
                 # ids offset to this layer's: ``kv[at]`` would copy it
-                a = _attend_pool(q, kv.reshape(-1, *kv.shape[2:]),
-                                 page_tables + at * kv.shape[1], lengths,
-                                 cfg)
+                a = attend_rows(q, kv.reshape(-1, *kv.shape[2:]),
+                                page_tables + at * kv.shape[1], lengths,
+                                cfg["n_kv_heads"])
             with jax.named_scope("attn_out"):
                 o = jnp.einsum("shk,hkd->sd", a, blk["attn"]["wo"])
         hs, counts = ffn(blk, hs + o, cfg, valid, counts)

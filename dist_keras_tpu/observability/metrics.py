@@ -369,6 +369,12 @@ KNOWN_METRICS = {
     "decode.latent.walked_positions": "histogram",
     "decode.kv.live_positions": "histogram",
     "decode.state_rows_used": "gauge",
+    # the gated-delta-rule family's steps (models/olmo_hybrid.py): the
+    # rows a decode step read and wrote, and what a prefill's scan
+    # covered against what its rung made of it
+    "decode.state.live_rows": "histogram",
+    "prefill.scan_positions": "histogram",
+    "prefill.scan_padded_positions": "histogram",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

@@ -427,11 +427,14 @@ def latent_attention_kernel(q, latent_pages, page_table, lengths, *, rank,
 
 
 def latent_attention_auto(q, latent_pages, page_table, lengths, *, rank,
-                          scale):
+                          scale, block_pages=LATENT_BLOCK_PAGES):
     """Trace-time dispatch, the rule of ``flash_attention.attention_auto``:
-    the kernel on a TPU, the ``jnp`` reference elsewhere."""
+    the kernel on a TPU, the ``jnp`` reference elsewhere.  ``block_pages``
+    is the kernel's: a family whose rows are wide states a smaller block
+    (two buffers of it must fit VMEM)."""
     if use_pallas():
         return latent_attention_kernel(q, latent_pages, page_table, lengths,
-                                       rank=rank, scale=scale)
+                                       rank=rank, scale=scale,
+                                       block_pages=block_pages)
     return latent_attention_reference(q, latent_pages, page_table, lengths,
                                       rank=rank, scale=scale)

@@ -27,17 +27,29 @@ Design points (each mirrors an existing engine contract):
   (its ``cache_pools``) as ``(layers it spans, "page" or "sequence", the
   entry's shape)``: one K and one V pool of ``heads x head_dim`` entries
   over every layer for ``models/transformer.py``, ONE pool of ``latent +
-  rope`` wide entries over every layer for ``models/mla_moe.py``, and for
+  rope`` wide entries over every layer for ``models/mla_moe.py``, for
   ``models/lfm2_moe.py`` one paged pool of ``v | k`` rows over its
   attention layers only and one pool of convolution state, a row a
-  SEQUENCE, over its convolution layers only.  A paged pool is
+  SEQUENCE, over its convolution layers only, and for
+  ``models/olmo_hybrid.py`` one paged pool of ``v | k`` rows over its
+  attention layers and TWO per-sequence pools over its linear-attention
+  layers (the convolutions' last inputs; the recurrent matrices).  A
+  family may hold any number of pools of either kind: they are all
+  addressed by the ONE page table and the ONE row a sequence has, and the
+  steps take and return them in ``cache_pools``' order.  A paged pool is
   ``(layers, num_pages + 1, page_size, *entry)`` — page-major, so that
   the steps' scatters over (page, offset) update the donated pools in
   place and the attention reads whole pages with no copy of the pool or
   of a layer of it; a per-sequence pool is ``(layers, state_rows + 1,
   *entry)``.  One :class:`~dist_keras_tpu.serving.kv_cache.PagedKVCache`
   allocator hands out both: a sequence's row is reserved with its pages
-  and returned with them.  The family is named by the model's ``cfg`` and
+  and returned with them, before its future resolves, so that a caller
+  who answers a result with a request finds the row free.  How many rows
+  a replica holds is ``state_rows``: by default one for every sequence the
+  door can admit, which suits a row of kilobytes; where a row is megabytes
+  (``olmo_hybrid``: 14 MB at the published widths) the count is part of
+  sizing the replica, as pages are, and the door refuses typed when every
+  row is held.  The family is named by the model's ``cfg`` and
   taken once, at construction (``_FAMILIES``: its two step functions and
   its pools); the scheduler, the allocator and recovery's replay see no
   family.
@@ -128,7 +140,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe, transformer
+from dist_keras_tpu.models import (
+    lfm2_moe,
+    mla_moe,
+    olmo_hybrid,
+    transformer,
+)
 from dist_keras_tpu.observability import events, metrics, perf, spans
 from dist_keras_tpu.observability import slo as _slo
 from dist_keras_tpu.resilience.faults import fault_point
@@ -235,7 +252,8 @@ class Generation:
 # handed the state rows, last) and ``observe_step(counts, at,
 # lengths=None, page_size=None)`` for those counts (None when the family
 # sends none).
-_FAMILIES = {m.FAMILY: m for m in (transformer, mla_moe, lfm2_moe)}
+_FAMILIES = {m.FAMILY: m
+             for m in (transformer, mla_moe, lfm2_moe, olmo_hybrid)}
 
 
 def _step_views(packed, pmax, state=False):
@@ -362,8 +380,9 @@ class DecodeEngine:
 
     Args:
       keras_model: a ``models.transformer.Transformer``, a
-        ``models.mla_moe.LatentMoEDecoder`` or a
-        ``models.lfm2_moe.Lfm2MoeDecoder`` (or anything the
+        ``models.mla_moe.LatentMoEDecoder``, a
+        ``models.lfm2_moe.Lfm2MoeDecoder`` or a
+        ``models.olmo_hybrid.OlmoHybridDecoder`` (or anything the
         serialization layer round-trips to one); its ``cfg`` names the
         block family.  A ``Transformer`` decodes with token in == logit
         out, so its config must have ``input_dim == n_classes`` (the
@@ -382,7 +401,8 @@ class DecodeEngine:
       state_rows: per-sequence state rows per replica, for a family that
         keeps such state (ignored otherwise).  Default: one for every
         sequence the door can admit (``min(max_queue, num_pages)``), so
-        that pages and the queue bound refuse before rows do.
+        that pages and the queue bound refuse before rows do; a family
+        whose row is large is given fewer, and rows refuse first.
       max_new_default: ``max_new_tokens`` when a request omits it.
       eos_id: default stop token (None = length-only stopping).
       devices: explicit device list (default ``jax.devices()``).
@@ -409,8 +429,9 @@ class DecodeEngine:
             raise ValueError(
                 "DecodeEngine needs a decoder's model contract (a cfg "
                 "dict: models.transformer.Transformer, "
-                "models.mla_moe.LatentMoEDecoder or "
-                f"models.lfm2_moe.Lfm2MoeDecoder); got "
+                "models.mla_moe.LatentMoEDecoder, "
+                "models.lfm2_moe.Lfm2MoeDecoder or "
+                f"models.olmo_hybrid.OlmoHybridDecoder); got "
                 f"{type(model).__name__}")
         self.cfg = cfg
         # the model's block family, looked up once: everything below

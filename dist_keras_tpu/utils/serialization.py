@@ -62,6 +62,12 @@ def deserialize_model(d):
         model = Lfm2MoeDecoder(cfg=arch["config"])
         model.set_weights(d["weights"])
         return model
+    if arch.get("class_name") == "OlmoHybridDecoder":
+        from dist_keras_tpu.models.olmo_hybrid import OlmoHybridDecoder
+
+        model = OlmoHybridDecoder(cfg=arch["config"])
+        model.set_weights(d["weights"])
+        return model
     if arch.get("class_name") == "Sequential" and "layers" in arch and all(
             "class_name" in spec for spec in arch["layers"]):
         try:

@@ -70,7 +70,7 @@ def chip_kernel_cases():
 
 CASES = ["flash_fwd/bf16_train", "flash_bwd/bf16_train",
          "flash_fwd/f32_prefill", "flash_bwd/f32_prefill",
-         "paged_decode/f32", "latent_decode/f32"]
+         "paged_decode/f32", "latent_decode/f32", "gdn_state_step/f32"]
 
 
 @pytest.fixture(scope="module")

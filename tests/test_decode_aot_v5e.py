@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe, transformer
+from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid, transformer
 from dist_keras_tpu.models.transformer import (
     init_transformer_params,
     transformer_config,
@@ -389,6 +389,129 @@ def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
                 if a.dtype == jnp.int32]
         assert [a.shape for a in ints] == [
             (rung + 34,), (rung * (pages_per_seq + 6),)]
+        assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
+
+
+# -- the gated-delta-rule, full-attention family (models/olmo_hybrid.py) ---
+# olmo-hybrid-7b as the benchmark cuts it: every width as published, layers
+# 0-7 (6 linear layers, 2 full ones), 32 slots of 1536 positions in pages
+# of 16, 40 state rows
+H_SLOTS, H_POSITIONS, H_PAGE, H_ROWS = 32, 1536, 16, 40
+
+
+def _hybrid_cfg():
+    return olmo_hybrid.olmo_hybrid_config(
+        vocab_size=100352, seq_len=H_POSITIONS, d_model=3840, n_heads=30,
+        d_ff=11008,
+        layer_types=(["linear_attention"] * 3 + ["full_attention"]) * 2,
+        linear_heads=30, linear_key_dim=96, linear_value_dim=192)
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("packed_decode", H_SLOTS, 0.1), ("decode", 8, 0.1),
+    ("packed_prefill", 1024, 0.4), ("prefill", 384, 0.2)])
+def test_delta_rule_step_leaves_its_three_pools_in_place(topo, as_tpu, phase,
+                                                         rung, temp_gb):
+    """The fourth family's steps hold no copy of the ``v | k`` pool (rows
+    of 7,680 lanes over the two attention layers only), of the recurrent
+    matrices' pool (2.2 MB a sequence and layer over the six linear layers
+    only) nor of a layer of either, no vocabulary-sized temporary, and
+    their temporaries stay under ``temp_gb``.  Decoding updates the
+    matrices with one ``gdn_state_step`` Mosaic kernel a linear layer whose
+    output IS its operand (the donated pool: nothing of its size is
+    gathered, scattered or copied) and reads the K/V with one
+    ``latent_decode`` kernel an attention layer over the flat float32 pool
+    itself; the prefill attends with one ``flash_fwd`` each at 30 heads of
+    128 and writes a sequence's matrices with one dynamic-update-slice a
+    linear layer.  **Peak memory:** arguments (weights 9.74 GB, pools 3.79
+    GB as the chip lays them out) and temporaries are held under 14.5 GB
+    together, the issue's line for falling back to fewer positions."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = _hybrid_cfg()
+    pages_per_seq = H_POSITIONS // H_PAGE
+    engine = _bare_engine(cfg, olmo_hybrid, H_PAGE, H_SLOTS * pages_per_seq,
+                          state_rows=H_ROWS)
+    kv_shape, taps_shape, state_shape = engine.pool_shapes
+    assert kv_shape == (2, engine.num_pages + 1, H_PAGE, 7680)
+    assert taps_shape == (6, H_ROWS + 1, 3, 11520)
+    assert state_shape == (6, H_ROWS + 1, 30, 96, 192)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(olmo_hybrid.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+        params, S(kv_shape, jnp.float32), S(taps_shape, jnp.float32),
+        S(state_shape, jnp.float32), *args).compile()
+    text = compiled.as_text()
+
+    kv_elems, state_elems = math.prod(kv_shape), math.prod(state_shape)
+    big = {kv_elems: "K/V pool", kv_elems // kv_shape[0]: "K/V layer",
+           state_elems: "state pool",
+           state_elems // state_shape[0]: "state layer",
+           cfg["vocab_size"] * cfg["d_model"]: "vocabulary"}
+    roots = _roots(text)
+    writes, offenders = {"K/V pool": 0, "state pool": 0}, []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in big or opcode in FREE:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = roots.get(called.group(1)) if called else None
+        if opcode == "fusion" and called.group(1).startswith("bitcast"):
+            continue                                  # a view: moves nothing
+        if big[elems] == "vocabulary" and "fus" in comp:
+            continue        # a row times the head: nothing this size written
+        if big[elems] in writes and (opcode in WRITES or (
+                opcode == "fusion" and root in WRITES)):
+            writes[big[elems]] += opcode in WRITES
+            continue
+        offenders.append(f"{comp}: %{name} = {opcode} of {big[elems]} size")
+    assert not offenders, offenders
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    steps = [k for k in kernels if "gdn_state_step" in k]
+    # a decode step's matrices are written by the kernel, whose second
+    # result IS its fourth operand (the tuple counts by its first above)
+    assert all("output_to_operand_aliasing={{1}: (3, {})}" in k
+               for k in steps), steps[:1]
+    writes["state pool"] += len(steps)
+    # each layer writes its own pool in place (at 8 slots the compiler
+    # splits one K/V scatter in two)
+    assert writes["K/V pool"] >= 2 and writes["state pool"] == 6, writes
+    m = compiled.memory_analysis()
+    # all three donated, as the chip lays them out: a 192-wide minor
+    # dimension takes 256 lanes, three rows of taps a few more
+    pools = 4 * (kv_elems + math.prod(taps_shape)
+                 + state_elems // 192 * 256)
+    assert pools <= m.alias_size_in_bytes < 1.01 * pools
+    assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
+    peak = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert 13.0 * GB < peak < 14.5 * GB, peak
+    if "prefill" in phase:
+        assert len(kernels) == 2 and all("flash_fwd" in k for k in kernels)
+        assert all(f"f32[30,{rung},128]" in k for k in kernels), kernels[0]
+        return
+    reads = [k for k in kernels if "latent_decode" in k]
+    assert len(reads) == 2 and len(steps) == 6 and len(kernels) == 8
+    flat = f"f32[{math.prod(kv_shape[:2])},{H_PAGE},7680]"
+    assert all(flat in k for k in reads), reads[0]
+    rows = f"f32[{math.prod(state_shape[:2])},30,96,192]"
+    assert all(rows in k for k in steps), steps[0]
+    if phase == "packed_decode":
+        # one array crosses: the six of every family and this one's rows;
+        # nothing rides behind the tokens
+        ints = [a for a in jax.tree.leaves(compiled.args_info)
+                if a.dtype == jnp.int32]
+        assert [a.shape for a in ints] == [
+            (rung,), (rung * (pages_per_seq + 6),)]
         assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
 
 

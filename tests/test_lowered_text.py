@@ -1,4 +1,4 @@
-"""The two block families that were served before PR 32 compile to the
+"""The three block families that were served before PR 36 compile to the
 programs recorded here.
 
 PR 32 widened the family seam (a pool states the layers it spans and
@@ -21,6 +21,14 @@ four prefill entries are still those recorded from commit 9317672 (PR 31),
 in a checkout of that commit: a prefill is synchronous and its program
 did not move.
 
+**PR 36 added a fourth family and the third's four entries.**  It shares
+``lfm2_moe``'s depthwise taps (one function for both families'
+convolutions) and gave ``latent_attention_auto`` a ``block_pages`` argument
+whose default is the kernel's own, and was held to leaving the three older
+families' programs as they were: the ``lfm2_moe`` hashes were recorded
+from commit 0436209 (PR 35), in a checkout of that commit, and the eight
+older entries pass as recorded.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.
@@ -32,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import mla_moe
+from dist_keras_tpu.models import lfm2_moe, mla_moe
 from dist_keras_tpu.models.transformer import Transformer, transformer_config
 from dist_keras_tpu.serving import DecodeEngine
 
@@ -55,7 +63,15 @@ def _mla_moe():
         routed_scaling_factor=2.446, rope_theta=800000.0), seed=1)
 
 
-MODELS = {"transformer": _transformer, "mla_moe": _mla_moe}
+def _lfm2_moe():
+    return lfm2_moe.Lfm2MoeDecoder(cfg=lfm2_moe.lfm2_moe_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=8, n_kv_heads=2,
+        d_ff=96, moe_d_ff=48, n_routed_experts=8, top_k=2,
+        layer_types=["conv", "conv", "full_attention", "conv"] * 2), seed=1)
+
+
+MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
+          "lfm2_moe": _lfm2_moe}
 
 # sha256 of the lowered text, for the CPU (the ``jnp`` references serve)
 # and for a TPU (the Pallas kernels do: ``use_pallas()`` asks
@@ -78,6 +94,15 @@ RECORDED = {
         "d0ee9c0422cd0fd5708a6c3a56a0795d0b657947d10ffd2e0b6c075839fe6e5c",
     ("mla_moe", "decode", "tpu"):
         "e2591b9341c56b9da03fda179174e2a5be7d531be252bcd8a9163c4090676c8c",
+    # recorded on commit 0436209 (PR 35)
+    ("lfm2_moe", "prefill", "cpu"):
+        "040e017d7c9a04402c5e44389f9a3ed663c03c060a33c944adebe8cd8568bcb8",
+    ("lfm2_moe", "decode", "cpu"):
+        "b5f6484c9d681f54d31d2490b1a5e4e01f2c737a95589c6aa5f65af2b81f02e8",
+    ("lfm2_moe", "prefill", "tpu"):
+        "cf4e1013665548eda09803ada4679cadf4f08caea1090ba280a72fab46a61e9a",
+    ("lfm2_moe", "decode", "tpu"):
+        "518c1f84f3d1775d6c360c57a48d99d3d3c60b57283b8971e8348596a23d50d2",
 }
 
 
@@ -93,12 +118,14 @@ def lowered_hash(family, phase, platform):
     with DecodeEngine(MODELS[family](), **LADDERS) as eng:
         rep = eng._replicas[0]
         pmax = eng.max_pages_per_seq
+        # a family with per-sequence state has one more packed column
+        rows = int(eng._state)
         if phase == "decode":
             # behind the pools the output of the step before it
-            step, n, carried = eng._decode_jit, 4 * (pmax + 5), \
+            step, n, carried = eng._decode_jit, 4 * (pmax + 5 + rows), \
                 (rep.no_tokens,)
         else:
-            step, n, carried = eng._prefill_jit, 3 * 16 + 1, ()
+            step, n, carried = eng._prefill_jit, 3 * 16 + 1 + rows, ()
         jax.default_backend = lambda: platform
         try:
             lowered = step.trace(
