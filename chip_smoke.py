@@ -55,6 +55,7 @@ from dist_keras_tpu.models import mnist_cnn
 from dist_keras_tpu.models.transformer import (
     Transformer,
     apply_block,
+    kv_block_pages,
     layer_norm,
     transformer_config,
 )
@@ -321,7 +322,7 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
                    f"{rep.device.id}")
 
         # what the jitted steps trace: flash in the prefill; the decode
-        # step reads its K/V pool through the jnp reference, no kernel
+        # step reads its ``v | k`` pool through the latent_decode kernel
         rep0 = eng._replicas[0]
         vec = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)  # noqa: E731
         top, slots = prefill_ladder[-1], decode_ladder[-1]
@@ -335,7 +336,7 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
             vec(slots * (eng.max_pages_per_seq + 5))).as_text()
         kernels = _kernel_counts(prefill_text, ("flash_fwd",))
         _check_kernels_traced("C", kernels)
-        kernels.update(_kernel_counts(decode_text, ("paged_decode",)))
+        kernels.update(_kernel_counts(decode_text, ("latent_decode",)))
 
         # traffic: per replica one short and one long prompt (the long
         # ones reach the top prefill rung), sent concurrently
@@ -425,9 +426,9 @@ def _decode_server(vocab, seq, d_model, n_heads, n_layers, replicas,
                 "retrace_bound": stats["retrace_bound"],
                 "replica_devices": [r.device.id for r in eng._replicas],
                 "replica_peak_pages": served, "kernels": kernels,
-                "decode_attention": ("paged_decode kernel"
-                                     if kernels["paged_decode"]
-                                     else "paged_attention_reference"),
+                "decode_attention": ("latent_decode kernel"
+                                     if kernels["latent_decode"]
+                                     else "latent_attention_reference"),
                 "matmul_precision":
                     jax.config.jax_default_matmul_precision}
     finally:
@@ -541,6 +542,31 @@ def _latent_case(slots, heads, page_size, n_pages, width=640, used=576):
     return (q, pool, table, _case_lengths(slots, page_size, n_pages))
 
 
+# the transformer family's read at the galactica row: ``v | k`` of 32
+# heads of 128, 8,192 lanes
+_KV_ROWS = {"rank": 32 * 128, "scale": 128 ** -0.5}
+
+
+def _kv_rows_case(slots, page_size, n_pages, heads=32, d=128):
+    """float32 rows ``v | k`` of ``2 x heads x d`` lanes, two layers flat
+    with the page ids offset to the second, and the queries as
+    ``attend_rows`` lays them out (head h's in the lanes of its own keys,
+    zeros elsewhere); lengths as in :func:`_paged_case`."""
+    rng = np.random.default_rng(3)
+    per_layer = slots * n_pages + 1
+    width = heads * d
+    q = np.zeros((slots, heads, 2 * width), np.float32)
+    for h in range(heads):
+        q[:, h, width + h * d:width + (h + 1) * d] = rng.standard_normal(
+            (slots, d), np.float32)
+    pool = rng.standard_normal((2 * per_layer, page_size, 2 * width),
+                               np.float32)
+    table = jnp.asarray(per_layer + rng.permutation(per_layer)[
+        :slots * n_pages].reshape(slots, n_pages), jnp.int32)
+    return (jnp.asarray(q), jnp.asarray(pool), table,
+            _case_lengths(slots, page_size, n_pages))
+
+
 def _state_step_case(slots, heads, dk=96, dv=192):
     """A flat pool of two layers' per-sequence matrices (``slots + 1`` rows
     a layer, the published key and value widths), the slots' rows of the
@@ -582,6 +608,11 @@ def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
         functools.partial(decode_attention.latent_attention_kernel,
                           **_LATENT),
         _latent_case(slots, n_heads, page_size, -(-seq // page_size)))
+    # 256 positions a slot: four of the read's blocks of 64
+    cases["latent_decode/kv_rows_f32"] = (
+        functools.partial(decode_attention.latent_attention_kernel,
+                          block_pages=kv_block_pages(page_size), **_KV_ROWS),
+        _kv_rows_case(slots, page_size, 256 // page_size))
     cases["gdn_state_step/f32"] = (
         pallas_gated_delta.state_step_kernel,
         _state_step_case(slots, n_heads))
@@ -611,6 +642,8 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
             q, kp, vp, table, lengths)
         latent_ref = decode_attention.latent_attention_reference(
             *cases["latent_decode/f32"][1], **_LATENT)
+        rows_ref = decode_attention.latent_attention_reference(
+            *cases["latent_decode/kv_rows_f32"][1], **_KV_ROWS)
         states, rows, *position = cases["gdn_state_step/f32"][1]
         step_o, step_rows = gated_delta.gated_delta_step(states[rows],
                                                          *position)
@@ -628,6 +661,8 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
         _rel_err(run("paged_decode/f32"), paged_ref), TOLERANCE)
     report["latent_decode/f32"] = (
         _rel_err(run("latent_decode/f32"), latent_ref), TOLERANCE)
+    report["latent_decode/kv_rows_f32"] = (
+        _rel_err(run("latent_decode/kv_rows_f32"), rows_ref), TOLERANCE)
     # the output and the WHOLE pool: the rows no slot names are untouched
     report["gdn_state_step/f32"] = (
         max(_rel_err(g, r)

@@ -23,13 +23,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from dist_keras_tpu.models.layers import glorot_uniform
+# models/olmo_hybrid.py attends through it too: the function lives with
+# the first family that had ``v | k`` rows (ROADMAP D1)
+from dist_keras_tpu.models.lfm2_moe import attend_rows
 from dist_keras_tpu.ops.attention import attention  # noqa: F401 (oracle)
-from dist_keras_tpu.ops.pallas.decode_attention import (
-    paged_attention_reference,
-)
+from dist_keras_tpu.ops.pallas.decode_attention import latent_walked_positions
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
 FAMILY = "transformer"
+# cached positions a grid step of the decode read fetches, whatever the
+# page size: 8 pages of 8 positions of 8,192 lanes are 2 MB a buffer, two
+# of them in VMEM.  At 8 slots of 1,024-2,048 positions, 32 / 64 / 128
+# read 3.15 / 2.98 / 3.07 ms six layers and made a whole decode step of
+# 12.35 / 12.22 / 12.32 ms on a v5e; 256 does not fit VMEM (PERF.md, PR 37)
+KV_BLOCK_POSITIONS = 64
 
 
 def transformer_config(input_dim, seq_len, d_model=64, n_heads=4,
@@ -199,7 +206,16 @@ def transformer_apply(params, x, cfg, *, causal=False, attn_fn=None,
 
 
 # -- what ``serving.decode.DecodeEngine`` takes from a block family -----
-# (``models/mla_moe.py`` and ``models/lfm2_moe.py`` have the same names)
+# (``models/mla_moe.py``, ``models/lfm2_moe.py`` and
+# ``models/olmo_hybrid.py`` have the same names).  The cache is ONE paged
+# pool over all layers whose entry is the row ``v | k`` of a cached
+# position: every head's values, then every head's keys.  A prefill
+# attends over its own q, k, v (the flash forward) and writes a row a
+# position; a decode step writes its row and reads the slots' LIVE pages
+# where they lie, through the read the other three families share
+# (``lfm2_moe.attend_rows``: on a TPU ``latent_attention_kernel``, blocks
+# of pages double-buffered in VMEM and everything past a slot's length
+# skipped; elsewhere ``latent_attention_reference``).
 def vocab(cfg):
     """The vocabulary a decoder of ``cfg`` reads and writes; a config
     this family cannot decode is refused here."""
@@ -219,30 +235,52 @@ def vocab(cfg):
     return int(cfg["n_classes"])
 
 
-def cache_entry_shapes(cfg):
-    """The trailing shape of each pool a replica holds: a K and a V pool
-    of ``heads x head_dim`` entries."""
-    heads = cfg["n_heads"]
-    return ((heads, cfg["d_model"] // heads),) * 2
-
-
 def cache_pools(cfg):
-    """What the engine allocates: the K and the V pool, paged, an entry
-    a cached position in every layer."""
-    return tuple((cfg["n_layers"], "page", entry)
-                 for entry in cache_entry_shapes(cfg))
+    """What the engine allocates, ``(layers spanned, "page" or
+    "sequence", entry)`` a pool: the one ``v | k`` pool, paged, a row of
+    ``2 x d_model`` values a cached position in every layer."""
+    return ((cfg["n_layers"], "page", (2 * cfg["d_model"],)),)
 
 
-def prefill_step(cfg, params, kp, vp, tokens, length, page_idx, page_off):
-    """One padded prompt -> (first generated token, updated pools).
+def kv_block_pages(page_size):
+    """Pages of ``page_size`` positions a grid step of the read fetches."""
+    return max(1, KV_BLOCK_POSITIONS // int(page_size))
+
+
+def _kv_row(k, v):
+    """``k, v (T, H, dh)`` -> the cache entries ``v | k`` ``(T, 2 H dh)``."""
+    t = k.shape[0]
+    return jnp.concatenate([v.reshape(t, -1), k.reshape(t, -1)], -1)
+
+
+def _write_prompt_rows(pool, li, page_idx, page_off, rows):
+    """A prompt's rows ``(T, 2 d_model)`` into layer ``li`` of the pool, in
+    place on the donated pool (the scattered dimensions are its major
+    ones).  A prefill's positions are 0 .. T - 1 of one sequence: position
+    t lies at offset ``t % page_size`` of the sequence's page ``t //
+    page_size``, so a rung of whole pages is written a PAGE an update, 64
+    contiguous (8, 128) tiles of 8,192 lanes, where a row an update is
+    one sublane of each (2,048 rows: 12 ms more a six-layer prefill on a
+    v5e, PERF.md, PR 37).  A page's id is its first position's; the
+    padding that shares the prompt's last page lands behind its length
+    there, where no read looks and the next decode steps write, the rest
+    on the scratch page as ``page_idx`` says."""
+    t, ps = rows.shape[0], pool.shape[2]
+    if t % ps:
+        return pool.at[li, page_idx, page_off].set(rows)
+    return pool.at[li, page_idx[::ps]].set(rows.reshape(t // ps, ps, -1))
+
+
+def prefill_step(cfg, params, pool, tokens, length, page_idx, page_off):
+    """One padded prompt -> (first generated token, updated pool).
 
     ``tokens (T,) int32`` padded to a prefill rung; positions past
-    ``length`` write their K/V to the scratch page (``page_idx``
+    ``length`` write their row to the scratch page (``page_idx``
     routes them there) and never influence position ``length - 1``
     under the causal mask."""
     t = tokens.shape[0]
     with jax.named_scope("embed"):
-        x = jax.nn.one_hot(tokens, cfg["n_classes"], dtype=kp.dtype)
+        x = jax.nn.one_hot(tokens, cfg["n_classes"], dtype=pool.dtype)
         hs = (x @ params["proj"] + params["pos"][:t])[None]
     for li, blk in enumerate(params["blocks"]):
         with jax.named_scope("qkv"):
@@ -251,10 +289,8 @@ def prefill_step(cfg, params, kp, vp, tokens, length, page_idx, page_off):
             k = jnp.einsum("btd,dhk->bthk", y, blk["wk"])
             v = jnp.einsum("btd,dhk->bthk", y, blk["wv"])
         with jax.named_scope("kv_write"):
-            # the scattered dimensions are the pool's major ones:
-            # in place on the donated pools, update (T, H, dh)
-            kp = kp.at[li, page_idx, page_off].set(k[0])
-            vp = vp.at[li, page_idx, page_off].set(v[0])
+            pool = _write_prompt_rows(pool, li, page_idx, page_off,
+                                      _kv_row(k[0], v[0]))
         with jax.named_scope("attend"):
             a = attention_auto(q, k, v, causal=True)
         with jax.named_scope("attn_out"):
@@ -268,17 +304,18 @@ def prefill_step(cfg, params, kp, vp, tokens, length, page_idx, page_off):
         logits = (hf @ params["head"]["kernel"]
                   + params["head"]["bias"])
         first = jnp.argmax(logits).astype(jnp.int32)
-    return first, kp, vp
+    return first, pool
 
 
-def decode_step(cfg, params, kp, vp, tokens, positions, page_tables,
+def decode_step(cfg, params, pool, tokens, positions, page_tables,
                 write_page, write_off, lengths):
-    """One token step for a padded slot set -> (next tokens,
-    updated pools).  Padding slots carry ``length == 0`` and write
-    to the scratch page; the paged attention's dead-row guard
-    makes their output exact zeros (then discarded)."""
+    """One token step for a padded slot set -> (next tokens, updated
+    pool).  Padding slots carry ``length == 0`` and write to the scratch
+    page; the read's dead-row guard makes their output exact zeros (then
+    discarded)."""
+    block_pages = kv_block_pages(pool.shape[2])
     with jax.named_scope("embed"):
-        hs = (jax.nn.one_hot(tokens, cfg["n_classes"], dtype=kp.dtype)
+        hs = (jax.nn.one_hot(tokens, cfg["n_classes"], dtype=pool.dtype)
               @ params["proj"] + params["pos"][positions])
     for li, blk in enumerate(params["blocks"]):
         with jax.named_scope("qkv"):
@@ -287,16 +324,13 @@ def decode_step(cfg, params, kp, vp, tokens, positions, page_tables,
             k = jnp.einsum("sd,dhk->shk", y, blk["wk"])
             v = jnp.einsum("sd,dhk->shk", y, blk["wv"])
         with jax.named_scope("kv_write"):
-            kp = kp.at[li, write_page, write_off].set(k)
-            vp = vp.at[li, write_page, write_off].set(v)
+            pool = pool.at[li, write_page, write_off].set(_kv_row(k, v))
         with jax.named_scope("attend"):
-            # the whole pool viewed flat over (layer, page), the
-            # page ids offset to this layer's: ``kp[li]`` would
-            # materialise the layer's pages before the read
-            a = paged_attention_reference(
-                q, kp.reshape(-1, *kp.shape[2:]),
-                vp.reshape(-1, *vp.shape[2:]),
-                page_tables + li * kp.shape[1], lengths)
+            # the whole pool viewed flat over (layer, page), the page
+            # ids offset to this layer's: ``pool[li]`` would copy it
+            a = attend_rows(q, pool.reshape(-1, *pool.shape[2:]),
+                            page_tables + li * pool.shape[1], lengths,
+                            cfg["n_heads"], block_pages)
         with jax.named_scope("attn_out"):
             hs = hs + jnp.einsum("shk,hkd->sd", a, blk["wo"])
         with jax.named_scope("mlp"):
@@ -308,11 +342,25 @@ def decode_step(cfg, params, kp, vp, tokens, positions, page_tables,
         logits = (hf @ params["head"]["kernel"]
                   + params["head"]["bias"])
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return nxt, kp, vp
+    return nxt, pool
 
 
-# this family's steps send no counts behind their tokens
-observe_step = None
+def observe_step(counts, at, lengths=None, page_size=None):
+    """This family's steps send no counts behind their tokens.  A decode
+    step passes its slots' ``lengths`` (host values, zeros for padding)
+    and the ``page_size`` and adds one sample to each per-step histogram,
+    stamped ``at`` like ``decode.step_s``: the live cached positions its
+    read covers in each layer, and the positions the read's blocks of
+    pages fetch for them."""
+    if lengths is None:
+        return
+    from dist_keras_tpu.observability import metrics
+
+    metrics.histogram("decode.kv.live_positions").observe(
+        int(lengths.sum()), at=at)
+    metrics.histogram("decode.kv.walked_positions").observe(
+        latent_walked_positions(lengths, page_size,
+                                kv_block_pages(page_size)), at=at)
 
 
 class Transformer:

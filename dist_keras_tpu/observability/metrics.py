@@ -368,6 +368,9 @@ KNOWN_METRICS = {
     "decode.latent.live_positions": "histogram",
     "decode.latent.walked_positions": "histogram",
     "decode.kv.live_positions": "histogram",
+    # the transformer family's read (models/transformer.py): what its
+    # blocks of pages fetch for a step's lengths
+    "decode.kv.walked_positions": "histogram",
     "decode.state_rows_used": "gauge",
     # the gated-delta-rule family's steps (models/olmo_hybrid.py): the
     # rows a decode step read and wrote, and what a prefill's scan

@@ -55,7 +55,7 @@ def test_stage_decode_server_toy():
     assert out["completed"] == 4 * out["rounds"] + 1
     assert out["replica_devices"] == [0, 1]
     assert all(out["replica_peak_pages"])
-    assert out["decode_attention"] == "paged_attention_reference"
+    assert out["decode_attention"] == "latent_attention_reference"
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +70,8 @@ def chip_kernel_cases():
 
 CASES = ["flash_fwd/bf16_train", "flash_bwd/bf16_train",
          "flash_fwd/f32_prefill", "flash_bwd/f32_prefill",
-         "paged_decode/f32", "latent_decode/f32", "gdn_state_step/f32"]
+         "paged_decode/f32", "latent_decode/f32",
+         "latent_decode/kv_rows_f32", "gdn_state_step/f32"]
 
 
 @pytest.fixture(scope="module")

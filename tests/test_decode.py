@@ -155,10 +155,11 @@ def test_kv_scratch_page_outside_pool():
 
 def test_pool_shape_is_page_major_and_what_replicas_hold(engine_and_model):
     eng, m = engine_and_model
-    heads = m.cfg["n_heads"]
-    shape = (m.cfg["n_layers"], eng.num_pages + 1, eng.page_size, heads,
-             m.cfg["d_model"] // heads)
-    assert eng.pool_shapes == (shape, shape)    # K and V
+    # ONE pool, a row ``v | k`` a position: every head's values, then
+    # every head's keys
+    shape = (m.cfg["n_layers"], eng.num_pages + 1, eng.page_size,
+             2 * m.cfg["d_model"])
+    assert eng.pool_shapes == (shape,)
     for rep in eng._replicas:
         assert rep.cache.scratch_page == shape[1] - 1
         assert tuple(pool.shape for pool in rep.pools) == eng.pool_shapes
@@ -1268,6 +1269,181 @@ _PAGED_LENGTH_CASES = {
 def test_paged_kernel_equals_the_reference(name):
     _assert_kernel_equals_reference(
         _paged_case(**_PAGED_LENGTH_CASES[name]))
+
+
+# -- the transformer step's read: ``v | k`` rows through ``attend_rows`` --
+def _rows_case(lengths, n_pages, **kw):
+    """``_paged_case`` at 4 heads of 128 and the same pool as rows ``v |
+    k`` of 1,024 lanes, as ``transformer.decode_step`` lays it out."""
+    q, kp, vp, table, lengths = _paged_case(
+        lengths, heads=4, head_dim=128, n_pages=n_pages, **kw)
+    rows = jnp.concatenate([vp.reshape(*vp.shape[:2], -1),
+                            kp.reshape(*kp.shape[:2], -1)], -1)
+    return (q, kp, vp, table, lengths), rows
+
+
+def _read_through_the_interpreted_kernel(monkeypatch):
+    """What ``attend_rows`` dispatches to on a TPU, interpreted here."""
+    monkeypatch.setattr(
+        lfm2_moe, "latent_attention_auto", functools.partial(
+            decode_attention.latent_attention_kernel, interpret=True))
+
+
+# pages of 8 positions, blocks of 2 pages: a block is 16 positions
+_ROWS_CASES = {
+    "padding_slot_then_one_position": dict(lengths=[0, 1], n_pages=4),
+    "lengths_end_inside_a_block": dict(lengths=[5, 17, 30], n_pages=4),
+    "block_boundary": dict(lengths=[16, 32, 0, 15], n_pages=4),
+    "table_wider_than_whole_blocks": dict(lengths=[40, 33, 0, 16, 7],
+                                          n_pages=5),
+    "second_layer_of_a_flat_pool": dict(lengths=[9, 24, 32], n_pages=4,
+                                        layer=1, layers=2),
+    "rung_8_with_padding_slots": dict(
+        lengths=[21, 0, 8, 0, 0, 30, 0, 1], n_pages=4),
+}
+
+
+@pytest.mark.parametrize("path", ["kernel", "reference"])
+@pytest.mark.parametrize("name", sorted(_ROWS_CASES))
+def test_read_through_rows_equals_the_kv_reference(name, path, monkeypatch):
+    """What ``transformer.decode_step`` reads its pool with (head h's
+    query laid into the lanes of its own keys, the row's leading half the
+    values, head h keeping its own lanes of the sum) against the plain
+    read of a K and a V pool, both float32 under "highest": on a TPU's
+    path the kernel (interpreted here), elsewhere the ``jnp`` reference.
+    The other heads' lanes meet exact zeros, so the two differ by the
+    order of float32 sums alone (limit 1e-5 of the largest output); a
+    ``length == 0`` slot yields exact zeros."""
+    (q, kp, vp, table, lengths), rows = _rows_case(**_ROWS_CASES[name])
+    if path == "kernel":
+        _read_through_the_interpreted_kernel(monkeypatch)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(decode_attention.paged_attention_reference(
+            q, kp, vp, table, lengths))
+        got = np.asarray(lfm2_moe.attend_rows(q, rows, table, lengths,
+                                              q.shape[1], 2))
+    assert got.shape == want.shape
+    assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))
+    for i, n in enumerate(np.asarray(lengths)):
+        if n == 0:
+            assert not got[i].any(), i
+
+
+def test_decode_step_writes_its_row_and_reads_through_the_kernel(
+        monkeypatch):
+    """The family's own ``decode_step`` on a filled pool, its read the
+    interpreted kernel, against the step whose read is the ``jnp``
+    reference: the same tokens, and bit for bit the same pool (each live
+    slot's new row ``v | k`` at its write page and offset, the padding
+    slots' on the scratch page)."""
+    from dist_keras_tpu.models import transformer
+
+    with _engine(decode_ladder=(1, 4), prefill_ladder=(8, 16)) as eng:
+        rep, ps, pmax = eng._replicas[0], eng.page_size, \
+            eng.max_pages_per_seq
+        rng = np.random.default_rng(3)
+        pages = rng.permutation(eng.num_pages).astype(np.int32)
+        tables = np.zeros((4, pmax), np.int32)
+        toks, positions, wpage, woff, lengths = (
+            np.zeros((4,), np.int32) for _ in range(5))
+        wpage[:] = rep.cache.scratch_page
+        for i, at in enumerate([1, 9, 22]):         # slot 3 is padding
+            tables[i] = pages[i * pmax:(i + 1) * pmax]
+            toks[i], positions[i] = rng.integers(0, VOCAB), at
+            wpage[i], woff[i] = tables[i, at // ps], at % ps
+            lengths[i] = at + 1
+        args = tuple(map(jnp.asarray, (toks, positions, tables, wpage,
+                                       woff, lengths)))
+        (pool,) = _filled_pools(eng, 7)
+        step = functools.partial(transformer.decode_step, eng.cfg)
+        with jax.default_matmul_precision("highest"):
+            want_toks, want_pool = jax.jit(step)(rep.params, pool, *args)
+            _read_through_the_interpreted_kernel(monkeypatch)
+            got_toks, got_pool = jax.jit(step)(rep.params, pool, *args)
+        np.testing.assert_array_equal(np.asarray(got_toks)[:3],
+                                      np.asarray(want_toks)[:3])
+        np.testing.assert_array_equal(np.asarray(got_pool),
+                                      np.asarray(want_pool))
+        changed = np.argwhere((np.asarray(got_pool) != np.asarray(pool))
+                              .any(-1))
+        assert sorted(map(tuple, changed)) == sorted(
+            (li, int(wpage[i]), int(woff[i]))
+            for li in range(eng.cfg["n_layers"]) for i in range(4))
+
+
+@pytest.mark.parametrize("rung,n,ps", [(16, 13, 4), (16, 16, 4), (8, 1, 8),
+                                       (14, 9, 4)])
+def test_prompt_rows_are_written_a_page_an_update(rung, n, ps):
+    """``transformer._write_prompt_rows`` against the scatter of a row a
+    position, on the arrays the worker builds (``page_idx`` the
+    sequence's pages repeated, the scratch page from the prompt's length
+    on; ``page_off`` the position modulo the page size): every position
+    of the prompt holds its row, every other page is untouched, and what
+    differs lies behind the prompt's length in its last page or on the
+    scratch page.  A rung that is no whole number of pages takes the
+    scatter of rows."""
+    from dist_keras_tpu.models import transformer
+
+    rng = np.random.default_rng(rung + n)
+    n_pages, width, li = 9, 8, 1
+    pool = jnp.asarray(rng.normal(size=(2, n_pages + 1, ps, width)),
+                       jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(rung, width)), jnp.float32)
+    pages = rng.permutation(n_pages)[:-(-n // ps)]
+    page_idx = np.full((rung,), n_pages, np.int32)          # scratch
+    page_idx[:n] = np.repeat(pages, ps)[:n]
+    page_off = (np.arange(rung) % ps).astype(np.int32)
+    got = np.asarray(transformer._write_prompt_rows(
+        pool, li, jnp.asarray(page_idx), jnp.asarray(page_off), rows))
+    per_row = np.asarray(pool.at[li, page_idx, page_off].set(rows))
+    for t in range(n):
+        np.testing.assert_array_equal(got[li, page_idx[t], page_off[t]],
+                                      np.asarray(rows[t]))
+    differs = {tuple(ix[:3]) for ix in np.argwhere(got != per_row)}
+    behind = {(li, int(pages[-1]), off) for off in range(n % ps or ps, ps)}
+    scratch = {(li, n_pages, off) for off in range(ps)}
+    assert differs <= behind | scratch
+    if rung % ps:
+        assert not differs
+    np.testing.assert_array_equal(got[1 - li], np.asarray(pool[1 - li]))
+
+
+def test_decode_steps_stamp_live_and_walked_positions():
+    """``transformer.observe_step``: a decode step stamps the sum of its
+    slots' lengths on ``decode.kv.live_positions`` and what the read's
+    blocks of ``kv_block_pages(page_size)`` pages fetch for them on
+    ``decode.kv.walked_positions`` (host arithmetic on the lengths the
+    worker already has); a prefill stamps nothing."""
+    from dist_keras_tpu.models import transformer
+
+    assert _metrics.KNOWN_METRICS["decode.kv.walked_positions"] == \
+        "histogram"
+    live_h = _metrics.histogram("decode.kv.live_positions")
+    walked_h = _metrics.histogram("decode.kv.walked_positions")
+    assert [transformer.kv_block_pages(ps) for ps in (4, 8, 16, 64, 128)] \
+        == [16, 8, 4, 1, 1]
+    lengths = np.asarray([0, 1, 64, 65, 200], np.int32)
+    at = time.perf_counter()
+    transformer.observe_step((), at, lengths=lengths, page_size=8)
+    assert live_h.samples_between(at, at + 1e-6)[0] == [(at, 330)]
+    assert walked_h.samples_between(at, at + 1e-6)[0] == [
+        (at, (0 + 1 + 1 + 2 + 4) * 64)]
+    transformer.observe_step((), at + 1e-3)
+    assert walked_h.samples_between(at + 1e-3, at + 2e-3)[0] == []
+
+    # through the engine: a sample a decode step, with the step's stamp
+    lo = time.perf_counter()
+    with _engine(decode_ladder=(1, 4), prefill_ladder=(8, 16),
+                 page_size=4) as eng:
+        eng.generate(list(range(1, 7)), max_new_tokens=5, timeout_s=300)
+        steps = eng._replicas[0].steps
+    hi = time.perf_counter()
+    live = [v for _, v in live_h.samples_between(lo, hi)[0]]
+    walked = [v for _, v in walked_h.samples_between(lo, hi)[0]]
+    assert len(live) == len(walked) == steps >= 4
+    # a prompt of 6: the steps read 7, 8, ... positions, one block of 64
+    assert live == list(range(7, 7 + steps))
+    assert walked == [64] * steps
 
 
 # -- drain / stats contract --------------------------------------------

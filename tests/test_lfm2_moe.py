@@ -595,10 +595,10 @@ def test_pools_span_their_own_layers_and_the_old_families_are_unchanged():
         assert eng.kv_stats()["state_rows"] == 7
     old = transformer_config(input_dim=16, seq_len=32, d_model=16, n_heads=2,
                              n_layers=2, n_classes=16)
-    assert transformer.cache_pools(old) == ((2, "page", (2, 8)),) * 2
+    assert transformer.cache_pools(old) == ((2, "page", (32,)),)
     with DecodeEngine(Transformer(old), replicas=1, prefill_ladder=(4,),
                       decode_ladder=(1,), page_size=4) as eng:
-        assert eng.pool_shapes == ((2, eng.num_pages + 1, 4, 2, 8),) * 2
+        assert eng.pool_shapes == ((2, eng.num_pages + 1, 4, 32),)
         assert eng.state_rows == 0 and not eng._state
     latent = mla_moe.mla_moe_config(
         vocab_size=32, seq_len=16, d_model=16, n_heads=2,

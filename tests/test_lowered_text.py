@@ -29,6 +29,16 @@ families' programs as they were: the ``lfm2_moe`` hashes were recorded
 from commit 0436209 (PR 35), in a checkout of that commit, and the eight
 older entries pass as recorded.
 
+**PR 37 meant to change the transformer family's four entries, and only
+those.**  Its cache became ONE pool of ``v | k`` rows (a prefill writes a
+row a position where it wrote a K and a V entry; a decode step reads the
+slots' live pages through ``lfm2_moe.attend_rows``, on a TPU the
+``latent_decode`` kernel, where it gathered every slot's whole table), so
+its CPU and TPU decode programs now differ.  The four hashes were recorded
+anew on that PR's tree; the kernel, its dispatch and ``attend_rows`` were
+not edited, and the eight entries of ``mla_moe`` and ``lfm2_moe`` pass as
+recorded.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.
@@ -76,20 +86,21 @@ MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
 # sha256 of the lowered text, for the CPU (the ``jnp`` references serve)
 # and for a TPU (the Pallas kernels do: ``use_pallas()`` asks
 # ``jax.default_backend()``, patched here): the prefills' recorded on
-# commit 9317672 (PR 31), the decode steps' on PR 35's tree
+# commit 9317672 (PR 31), the decode steps' on PR 35's tree, the
+# transformer's four on PR 37's
 RECORDED = {
     ("transformer", "prefill", "cpu"):
-        "fe59c3565961787bc3f1f6bbe9e7c7f6ffdaeeb0d16971e055c1c7bbeee7549a",
+        "aab1a7b471491bf415faab99972747eb39240ad186535dcaf3e65f73278eb653",
     ("transformer", "decode", "cpu"):
-        "5e5df36b3903704085261d2964f3cd8d0de7519fadd38ec8e3b19d90f327b5ec",
+        "2623f805ead120d59dbbbfab8d67ea9e56c75cbd8d9319b6d948407bc3f52881",
     ("mla_moe", "prefill", "cpu"):
         "e3ee6957027c2cdc8d3239a7007edb69d5e90c757b04248f8f17e061f3ea60af",
     ("mla_moe", "decode", "cpu"):
         "747fae6db7e70fb990ebd203437755c284129cbb3ef915740aed3ed6924d8e8f",
     ("transformer", "prefill", "tpu"):
-        "cf0c89111d800675d3dd1168210c8a2edf951a349621233e69f2e127dede0ed3",
+        "086a51e210eb6d720a97f4bb5f6382afbbd79cfb74231f1f1cd65d4e4b2ad622",
     ("transformer", "decode", "tpu"):
-        "5e5df36b3903704085261d2964f3cd8d0de7519fadd38ec8e3b19d90f327b5ec",
+        "97c7d5c85ae32d8661db1274bb6247a7d0bdfd55570b986a7a583a820c469612",
     ("mla_moe", "prefill", "tpu"):
         "d0ee9c0422cd0fd5708a6c3a56a0795d0b657947d10ffd2e0b6c075839fe6e5c",
     ("mla_moe", "decode", "tpu"):

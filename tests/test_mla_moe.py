@@ -329,10 +329,10 @@ def test_pool_shape_is_one_latent_pool_and_the_old_family_is_unchanged():
         assert float(jnp.abs(pool[..., width:]).max()) == 0.0
     old = transformer_config(input_dim=16, seq_len=32, d_model=16, n_heads=2,
                              n_layers=2, n_classes=16)
-    assert transformer.cache_entry_shapes(old) == ((2, 8), (2, 8))
+    assert transformer.cache_pools(old) == ((2, "page", (32,)),)
     with DecodeEngine(Transformer(old), replicas=1, prefill_ladder=(4,),
                       decode_ladder=(1,), page_size=4) as eng:
-        assert eng.pool_shapes == ((2, eng.num_pages + 1, 4, 2, 8),) * 2
+        assert eng.pool_shapes == ((2, eng.num_pages + 1, 4, 32),)
 
 
 @pytest.mark.parametrize("cfg_kw,match", [
