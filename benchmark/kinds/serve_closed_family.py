@@ -1,10 +1,7 @@
 """A closed loop over the engine of the configuration's own block family:
 ``clients`` callers, each sending its next request when the last one
-returned.  The callers may start ``lead_in_s`` seconds before the window
-opens, so that the window samples a server that is running and not one
-prefilling every caller's first request at once; tokens that came out
-before the window count nowhere and those seconds fall to set-up.  When
-the window closes, what is still in the engine is cut there.
+returned (``serving.closed_loop``).  When the window closes, what is still
+in the engine is cut there.
 
 The family (``benchmark/families``) builds the engine from the
 configuration and compares with its own plain reference; the loop, the
@@ -15,45 +12,18 @@ from __future__ import annotations
 
 import gc
 import os
-import queue
 import threading
 import time
 
 from benchmark import checks, families, meter, serving, trafficgen
-from benchmark.reference import serve_check
 
-PER_REQUEST = ("ttft_ms", "generator_late_ms", "queue_wait_ms",
-               "prefill_ms")
 PAIRS = ("decode.moe.pairs_held", "decode.moe.pairs_total")
-# the worker's own regions, whose window means go to the log: they tell
-# one process's steps from another's in an untraced run
-REGIONS = ("decode.sched", "decode.step.build", "decode.step.dispatch",
-           "decode.step.wait", "decode.step.emit", "decode.prefill.build",
-           "decode.prefill.dispatch", "decode.prefill.wait")
-
-
-def reduce_window(records, t0, seconds, sub_windows):
-    """``serving.reduce_records`` over a window that opened on a running
-    loop: a token from before ``t0`` counts nowhere, and a request sent
-    before ``t0`` has no time to its first token."""
-    for r in records:
-        r.times = [t for t in r.times if t >= t0]
-    series, counters = serving.reduce_records(records, t0, seconds,
-                                              sub_windows)
-    close = t0 + seconds
-    seen = [r for r in records
-            if r.doc is not None and any(t <= close for t in r.times)]
-    for name in PER_REQUEST:
-        series[name] = [v for v, r in zip(series[name], seen)
-                        if r.sent >= t0]
-    return series, counters
 
 
 def run(ctx):
     from dist_keras_tpu.observability import metrics
 
     family = families.of(ctx.config)
-    lead_in = float(ctx.traffic.get("lead_in_s", 0.0))
     spans = meter.Spans()
     compiles = meter.CompileCounter()
     ctx.mark("imports done")
@@ -86,12 +56,12 @@ def run(ctx):
 
     def tick():
         """Closes the traced segment once it has run its length; a helper
-        thread stops the profiler while the load goes on."""
+        thread stops the profiler while the load goes on, and the trace is
+        reduced once the window has closed."""
         if profiler is not None and "stopper" not in state \
                 and time.perf_counter() >= state["trace_until"]:
             profiler.close_window()
-            state["stopper"] = threading.Thread(
-                target=lambda: state.update(trace=profiler.finish()))
+            state["stopper"] = threading.Thread(target=profiler.stop)
             state["stopper"].start()
 
     try:
@@ -101,48 +71,18 @@ def run(ctx):
               flush=True)
         serving.warm(engine, pool, vocab)
         ctx.mark("warm")
-        replies = queue.Queue()
-        records = []
-
-        def send():
-            req = pool[len(records) % len(pool)]
-            rec = serving.Record(req, time.perf_counter())
-            with spans("bench.submit"):
-                serving.submit(engine, rec, on_done=replies.put)
-            records.append(rec)
-
-        started = time.perf_counter()
-        for _ in range(clients):
-            send()
-        t0 = None
-        while t0 is None or time.perf_counter() - t0 < ctx.seconds:
-            if t0 is None and time.perf_counter() - started >= lead_in:
-                begin()
-                t0 = time.perf_counter()
-            with spans("bench.wait_reply"):
-                try:
-                    replies.get(timeout=0.05)
-                    replied = True
-                except queue.Empty:
-                    replied = False
-            if t0 is not None:
-                tick()
-            # every reply taken is answered by the caller's next request,
-            # on either side of the window's opening (a caller dropped
-            # there would leave its slot empty for the whole window);
-            # after the window's close nothing is sent
-            if replied and (t0 is None
-                            or time.perf_counter() - t0 < ctx.seconds):
-                send()
-        serving.close_window(engine, records)
+        records, t0 = serving.closed_loop(ctx, engine, pool, clients, spans,
+                                          begin, tick)
         if profiler is not None:
             state["trace_until"] = 0.0
             tick()
             state["stopper"].join()
+            state["trace"] = profiler.reduced()
         in_window = compiles.count
         after = engine.stats()
         held, total = (b - a for a, b in zip(state["pairs"], pairs()))
-        steps_ms = [1e3 * s for s in step_hist.samples]
+        steps_ms, steps, steps_from_s = serving.window_steps(
+            step_hist, t0, ctx.seconds)
         peak = meter.memory_peak_bytes(ctx.devices)
         counters = family.counters(engine, cfg)
     finally:
@@ -152,23 +92,17 @@ def run(ctx):
     gc.collect()
     ctx.mark("window closed, engine freed; the reference follows")
 
-    series, reduced = reduce_window(
+    series, reduced = serving.reduce_records(
         records, t0, ctx.seconds, int(ctx.traffic.get("sub_windows", 0)))
     counters.update(reduced)
     series["decode_step_ms"] = steps_ms
     prefills = [v for at, v in metrics.histogram(
         "decode.prefill_s").samples_between(t0, t0 + ctx.seconds)[0]]
-    print(f"serving: {len(steps_ms)} decode steps of mean "
+    print(f"serving: {steps} decode steps of mean "
           f"{sum(steps_ms) / max(1, len(steps_ms)):.3f} ms, "
           f"{len(prefills)} prefills of {sum(prefills):.3f} s together, in "
           f"the window", flush=True)
-    for region in REGIONS:
-        inside, cut = metrics.histogram(
-            "perf.phase." + region).samples_between(t0, t0 + ctx.seconds)
-        inside = [v for at, v in inside]
-        if inside and not cut:
-            print(f"serving: {region} {len(inside)} times, mean "
-                  f"{1e3 * sum(inside) / len(inside):.3f} ms", flush=True)
+    serving.log_regions(t0, ctx.seconds)
     print(f"serving: {counters['requests_finished']} of {len(records)} "
           f"requests finished, {counters['requests_cut_at_close']} cut at "
           f"the window's close", flush=True)
@@ -180,14 +114,10 @@ def run(ctx):
     counters.update({
         "memory_peak_bytes": peak,
         "window_compiles": in_window,
-        "slots_mean": stepped / len(steps_ms) if steps_ms else None,
+        "slots_mean": stepped / steps if steps else None,
         "held_pairs_pct": 100.0 * held / total if total else None,
     })
-    served = [{"tokens": r.doc["tokens"], "prompt_len": r.doc["prompt_len"]}
-              for r in records if serving.finished(r)]
-    samples = serve_check.pick(served, int(ctx.traffic["check_requests"]),
-                               ctx.seed)
-    compared = family.compare(ctx, cfg, samples)
+    compared = family.compare(ctx, cfg, serving.check_samples(ctx, records))
     ctx.mark("compared")
     failed = sum(1 for r in records if r.doc is None)
     compared.append(checks.limit("failed_requests", failed, 0))
@@ -196,7 +126,7 @@ def run(ctx):
         "failed": failed,
         "checks": compared,
         "trace": state.get("trace"),
-        "shapes": {},
         "series": series,
+        "series_from_s": steps_from_s,
         "counters": counters,
     }

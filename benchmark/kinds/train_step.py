@@ -198,7 +198,6 @@ def run(ctx):
         "failed": int(np.sum(~np.isfinite(window_losses))),
         "checks": compared,
         "trace": reduced,
-        "shapes": {},
         "series": {
             "subwindow_samples_per_s": rates,
         },

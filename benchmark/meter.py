@@ -56,8 +56,9 @@ class Spans:
 class Profiler:
     """One traced segment: ``start`` opens the profiler (Python tracer
     off: a call per Python function would swamp the scheduler threads)
-    and the span ``bench.window``; ``close_window`` ends the span and
-    ``finish`` stops the profiler and reduces the trace."""
+    and the span ``bench.window``; ``close_window`` ends the span,
+    ``stop`` stops the profiler and ``reduced`` reduces the trace
+    (``finish``: both)."""
 
     WINDOW = "bench.window"
 
@@ -83,16 +84,26 @@ class Profiler:
         self._window.__exit__(None, None, None)
         self.spans.active = False
 
-    def finish(self):
-        """Stop the profiler and reduce its trace (any thread; seconds of
-        work) -> the reduced trace."""
+    def stop(self):
+        """Stop the profiler, which writes the trace out (any thread;
+        seconds of work, most of it outside the interpreter)."""
         import jax
 
+        jax.profiler.stop_trace()
+
+    def reduced(self):
+        """-> the stopped profiler's trace, reduced: seconds of work in
+        the interpreter, so a serving kind does it once its window has
+        closed (inside the window it took the worker's thread a fifth of
+        its steps at 100 steps a second, and made the generator late)."""
         from benchmark.trace import reduce
 
-        jax.profiler.stop_trace()
         trace = reduce.Trace.from_file(reduce.find_xplane(self.logdir))
         return trace.reduce(window_span=self.WINDOW)
+
+    def finish(self):
+        self.stop()
+        return self.reduced()
 
 
 def memory_peak_bytes(devices):

@@ -1,6 +1,8 @@
 """A statistic of one of the run's series (a list of host-clock or
 program-histogram readings): ``median``, or ``p<q>`` with at least
-``min_beyond`` samples beyond it."""
+``min_beyond`` samples beyond it.  A series that holds only the window's
+tail (``series_from_s``: the program's histograms keep their most recent
+samples) is marked as ``program_window`` marks one."""
 
 import statistics
 
@@ -11,6 +13,8 @@ def read(outcome, ctx, series, stat, min_beyond=0):
     values = outcome["series"].get(series)
     if not values:
         return None
+    if series in outcome.get("series_from_s", {}):
+        ctx.tails.append(outcome["series_from_s"][series])
     if stat == "median":
         return statistics.median(values)
     if not stat.startswith("p"):

@@ -9,8 +9,8 @@ from benchmark.readers import program_window
 
 
 def read(outcome, ctx, over, under):
-    top = program_window.window_samples(ctx, over)
-    bottom = program_window.window_samples(ctx, under)
+    top = program_window.window_samples(ctx, over, whole=True)
+    bottom = program_window.window_samples(ctx, under, whole=True)
     if not top or not bottom or sum(bottom) <= 0:
         return None
     return 100.0 * (sum(top) / sum(bottom) - 1.0)

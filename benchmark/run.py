@@ -50,6 +50,9 @@ class Context:
         self.scratch = scratch
         self.process_start = process_start
         self.setup_s = None
+        # left by a reader whose value is of the window's tail alone: the
+        # seconds into the window from which it runs
+        self.tails = []
 
     def mark(self, what):
         """One line of the run's log: seconds since the process began."""
@@ -84,10 +87,14 @@ def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
     outcome["counters"]["chip_startup_s"] = chip_startup_s
     metrics = {}
     for entry, spec, reader in wanted:
+        ctx.tails = []
         value = reader.read(outcome, ctx, **spec.get("args", {}))
         if value is not None:
             metrics[entry["name"]] = {"value": float(value),
                                       "unit": entry["unit"]}
+            if ctx.tails:
+                # not the whole window's: said beside the value
+                metrics[entry["name"]]["window_from_s"] = max(ctx.tails)
     for check in outcome["checks"]:
         print("check", json.dumps(check), flush=True)
     result = {

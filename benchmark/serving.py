@@ -9,12 +9,13 @@ import bisect
 import gc
 import json
 import os
+import queue
 import threading
 import time
 
 import numpy as np
 
-from benchmark import checks, meter, weights
+from benchmark import checks, meter, trafficgen, weights
 from benchmark.reference import serve_check
 
 
@@ -160,6 +161,69 @@ def finished(rec):
     return rec.doc is not None and rec.doc["finish"] != "cancelled"
 
 
+def closed_loop(ctx, engine, pool, clients, spans, begin, tick):
+    """``clients`` callers, each sending its next request of ``pool`` (in
+    ``trafficgen.closed_order``) when the last one returned, until the
+    window closes -> (records, t0).  The callers start ``lead_in_s``
+    seconds before the window opens (``begin()``, the end of set-up), so
+    that the window samples a server that is running and not one that
+    prefills every caller's first request back to back and then steps
+    them in lockstep; tokens that came out before the window count
+    nowhere (``reduce_records``) and those seconds fall to set-up.  What
+    is still in the engine at the window's close is cut there."""
+    lead_in = float(ctx.traffic.get("lead_in_s", 0.0))
+    order = trafficgen.closed_order(ctx.traffic, pool, clients)
+    replies = queue.Queue()
+    records = []
+
+    def send():
+        rec = Record(next(order), time.perf_counter())
+        with spans("bench.submit"):
+            submit(engine, rec, on_done=replies.put)
+        records.append(rec)
+
+    t0 = None
+    started = time.perf_counter()
+    if lead_in <= 0.0:
+        begin()
+        t0 = time.perf_counter()
+    for _ in range(clients):
+        send()
+    while t0 is None or time.perf_counter() - t0 < ctx.seconds:
+        if t0 is None and time.perf_counter() - started >= lead_in:
+            begin()
+            t0 = time.perf_counter()
+        with spans("bench.wait_reply"):
+            try:
+                replies.get(timeout=0.05)
+                replied = True
+            except queue.Empty:
+                replied = False
+        if t0 is not None:
+            tick()
+        # every reply taken is answered by the caller's next request, on
+        # either side of the window's opening (a caller dropped there
+        # would leave its slot empty for the whole window); after the
+        # window's close nothing is sent
+        if replied and (t0 is None
+                        or time.perf_counter() - t0 < ctx.seconds):
+            send()
+    close_window(engine, records)
+    return records, t0
+
+
+def check_samples(ctx, records):
+    """The finished requests ``correct`` compares with the reference: a
+    sample of ``check_requests`` drawn from the seed, the longest among
+    them, of those the mix drew whole (a first reply cut by
+    ``stagger_start`` is not one of the mix's)."""
+    served = [{"tokens": r.doc["tokens"], "prompt_len": r.doc["prompt_len"]}
+              for r in records
+              if finished(r) and not r.req.get("staggered")]
+    return serve_check.pick(served, int(ctx.traffic["check_requests"]),
+                            ctx.seed)
+
+
 BURST_GAP_S = 0.002
 
 
@@ -185,21 +249,25 @@ def burst_rates(events, lo, hi, parts):
 
 def reduce_records(records, t0, seconds, sub_windows=0):
     """Per-request records -> (series, counters), times in ms.  Only what
-    happened inside the window counts: a token that came out after its
-    close belongs to no series."""
+    happened inside the window counts: a token that came out before it
+    opened (a closed loop's lead-in) or after its close belongs to no
+    series, and a request sent before it opened has no time to its first
+    token, no queue and no prefill."""
     close = t0 + seconds
     seen = []
     for r in records:
-        times = [t for t in r.times if t <= close]
+        times = [t for t in r.times if t0 <= t <= close]
         if r.doc is not None and times:
             seen.append((r, times))
     events = sorted(t for _, times in seen for t in times)
-    ttft, gaps, late, queue, prefill = [], [], [], [], []
+    ttft, gaps, late, waited, prefill = [], [], [], [], []
     for r, times in seen:
+        gaps.extend(1e3 * (b - a) for a, b in zip(times, times[1:]))
+        if r.sent < t0:
+            continue
         first = times[0]
         ttft.append(1e3 * (first - r.due))
         late.append(1e3 * (r.sent - r.due))
-        gaps.extend(1e3 * (b - a) for a, b in zip(times, times[1:]))
         # the engine's worker is one loop: this prefill began when the
         # loop's previous event (a step's or a prefill's token) was out,
         # or when the request arrived, whichever is later
@@ -207,9 +275,9 @@ def reduce_records(records, t0, seconds, sub_windows=0):
         before = events[i - 1] if i > 0 else r.sent
         began = max(before, r.sent)
         prefill.append(1e3 * (first - began))
-        queue.append(1e3 * (began - r.due))
+        waited.append(1e3 * (began - r.due))
     series = {"ttft_ms": ttft, "gap_ms": gaps, "generator_late_ms": late,
-              "queue_wait_ms": queue, "prefill_ms": prefill}
+              "queue_wait_ms": waited, "prefill_ms": prefill}
     if sub_windows:
         series["subwindow_tokens_per_s"] = burst_rates(
             events, t0, close, sub_windows)
@@ -221,6 +289,35 @@ def reduce_records(records, t0, seconds, sub_windows=0):
             1 for r in records if r.doc is not None and not finished(r)),
     }
     return series, counters
+
+
+def window_steps(step_hist, t0, seconds):
+    """The window's decode steps -> (each one's ms, their exact count,
+    ``series_from_s``).  The program's histogram keeps its most recent
+    4,096 samples and counts them all: where the window held more, the
+    list is its tail, and ``series_from_s`` says from which second."""
+    pairs, cut = step_hist.samples_between(t0, t0 + seconds)
+    tail = {"decode_step_ms": pairs[0][0] - t0} if cut and pairs else {}
+    return ([1e3 * v for _, v in pairs], step_hist.totals()["count"], tail)
+
+
+# the worker's own regions, whose window means go to the log: they tell
+# one process's steps from another's in an untraced run
+REGIONS = ("decode.sched", "decode.step.build", "decode.step.dispatch",
+           "decode.step.wait", "decode.step.emit", "decode.prefill.build",
+           "decode.prefill.dispatch", "decode.prefill.wait")
+
+
+def log_regions(t0, seconds):
+    from dist_keras_tpu.observability import metrics
+
+    for region in REGIONS:
+        inside, cut = metrics.histogram(
+            "perf.phase." + region).samples_between(t0, t0 + seconds)
+        if inside and not cut:
+            mean = sum(v for _, v in inside) / len(inside)
+            print(f"serving: {region} {len(inside)} times, mean "
+                  f"{1e3 * mean:.3f} ms", flush=True)
 
 
 def measure(ctx, drive):
@@ -252,17 +349,15 @@ def measure(ctx, drive):
                 ctx.traffic["trace_seconds"])
         ctx.setup_done()
 
-    def finish_trace():
-        state["trace"] = profiler.finish()
-
     def tick():
         """Called by the loop between its own actions: closes the traced
         segment once it has run its length.  Stopping the profiler takes
-        seconds, so a helper thread does it while the load goes on."""
+        seconds, so a helper thread does it while the load goes on; the
+        trace is reduced once the window has closed."""
         if profiler is not None and "stopper" not in state \
                 and time.perf_counter() >= state["trace_until"]:
             profiler.close_window()
-            state["stopper"] = threading.Thread(target=finish_trace)
+            state["stopper"] = threading.Thread(target=profiler.stop)
             state["stopper"].start()
 
     try:
@@ -271,15 +366,12 @@ def measure(ctx, drive):
             state["trace_until"] = 0.0
             tick()
             state["stopper"].join()
+            state["trace"] = profiler.reduced()
         in_window = compiles.count
         after = engine.stats()
-        steps_ms = [1e3 * s for s in step_hist.samples]
+        steps_ms, steps, steps_from_s = window_steps(step_hist, t0,
+                                                     ctx.seconds)
         peak = meter.memory_peak_bytes(ctx.devices)
-        # the pool's shape as kv_cache.py lays it out: one scratch page
-        # past the allocator's
-        pool = (cfg["n_layers"], cfg["n_heads"],
-                engine.kv_stats()["num_pages"] + 1, engine.page_size,
-                cfg["d_model"] // cfg["n_heads"])
     finally:
         compiles.close()
         engine.close(drain=False)
@@ -290,12 +382,16 @@ def measure(ctx, drive):
     series, counters = reduce_records(
         records, t0, ctx.seconds, int(ctx.traffic.get("sub_windows", 0)))
     series["decode_step_ms"] = steps_ms
+    log_regions(t0, ctx.seconds)
+    worst = max(records, key=lambda r: r.sent - r.due)
+    print(f"serving: the generator sent {len(records)} requests, the one "
+          f"due at {worst.due - t0:.3f} s the latest, by "
+          f"{1e3 * (worst.sent - worst.due):.3f} ms", flush=True)
     print(f"serving: {counters['requests_finished']} of {len(records)} "
           f"requests finished in the window, "
           f"{counters['requests_cut_at_close']} cut at its close",
           flush=True)
     before = state["before"]
-    steps = len(steps_ms)
     # every admitted request's first token comes from its prefill, the
     # rest from decode steps: tokens a step is the mean of slots in use
     stepped = (after["tokens"] - before["tokens"]) \
@@ -305,11 +401,7 @@ def measure(ctx, drive):
         "window_compiles": in_window,
         "slots_mean": stepped / steps if steps else None,
     })
-    served = [{"tokens": r.doc["tokens"], "prompt_len": r.doc["prompt_len"]}
-              for r in records if finished(r)]
-    samples = serve_check.pick(served, int(ctx.traffic["check_requests"]),
-                               ctx.seed)
-    compared = serve_check.compare(ctx, cfg, samples)
+    compared = serve_check.compare(ctx, cfg, check_samples(ctx, records))
     ctx.mark("compared")
     failed = sum(1 for r in records if r.doc is None)
     compared.append(checks.limit("failed_requests", failed, 0))
@@ -318,7 +410,7 @@ def measure(ctx, drive):
         "failed": failed,
         "checks": compared,
         "trace": state.get("trace"),
-        "shapes": {"kv_pool": pool},
         "series": series,
+        "series_from_s": steps_from_s,
         "counters": counters,
     }

@@ -112,14 +112,20 @@ def test_serving_steps_fit(topo, galactica, as_tpu, phase):
     from jax.sharding import SingleDeviceSharding
 
     from benchmark import serving
+    from dist_keras_tpu.models import transformer
     from dist_keras_tpu.serving.decode import DecodeEngine
 
     one = SingleDeviceSharding(topo.devices[0])
     cfg = serving.model_config(galactica)
     serve = galactica["serve"]
-    engine = DecodeEngine.__new__(DecodeEngine)   # the two step bodies only
-    engine.vocab = cfg["n_classes"]
-    engine.page_size = serve["page_size"]
+    slots = serve["decode_ladder"][-1]
+    pages = -(-cfg["seq_len"] // serve["page_size"])
+    # the two step bodies and the pools' shapes only: no weights are made
+    engine = DecodeEngine.__new__(DecodeEngine)
+    engine.cfg, engine._family = cfg, transformer
+    engine.page_size, engine.num_pages = serve["page_size"], slots * pages
+    engine._pools = tuple(transformer.cache_pools(cfg))
+    engine._state, engine.state_rows = False, 0
 
     def S(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -128,11 +134,7 @@ def test_serving_steps_fit(topo, galactica, as_tpu, phase):
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(lambda k: weights.transformer(k, cfg),
                        jax.random.PRNGKey(0)))
-    slots = serve["decode_ladder"][-1]
-    pages = -(-cfg["seq_len"] // serve["page_size"])
-    pool = S((cfg["n_layers"], cfg["n_heads"], slots * pages + 1,
-              serve["page_size"], cfg["d_model"] // cfg["n_heads"]),
-             jnp.float32)
+    pools = [S(shape, jnp.float32) for shape in engine.pool_shapes]
     if phase == "decode":
         fn, args = engine._decode_fn, (
             S((slots,)), S((slots,)), S((slots, pages)), S((slots,)),
@@ -140,6 +142,7 @@ def test_serving_steps_fit(topo, galactica, as_tpu, phase):
     else:
         t = serve["prefill_ladder"][-1]
         fn, args = engine._prefill_fn, (S((t,)), S(()), S((t,)), S((t,)))
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool, pool, *args).compile()
+    compiled = jax.jit(
+        fn, donate_argnums=tuple(range(1, 1 + len(pools)))).lower(
+        params, *pools, *args).compile()
     assert footprint(compiled) < 16.0

@@ -83,8 +83,8 @@ def test_token_altered_where_it_is_produced(monkeypatch, tmp_path, checks,
         decode = engine._decode_jit
 
         def off_by_one(*args):
-            tokens, kp, vp = decode(*args)
-            return (tokens + 1) % cfg["n_classes"], kp, vp
+            tokens, *pools = decode(*args)
+            return ((tokens + 1) % cfg["n_classes"], *pools)
 
         engine._decode_jit = off_by_one
         return engine, cfg

@@ -177,7 +177,7 @@ def test_cell_rehearsed_with_its_own_family(monkeypatch, tmp_path, trace):
     assert got["moe_load_max_over_mean.longgen"]["value"] >= 1.0
     assert got["latent_live_positions_mean.longgen"]["value"] > 1.0
     assert 0.0 < got["prefill_share_pct.longgen"]["value"] < 100.0
-    assert got["window_compiles_serve.longgen"]["value"] == 0.0
+    assert got["window_compiles_serve.batch"]["value"] == 0.0
     # device-trace readers find no TPU plane on the CPU and report nothing
     assert "decode_hbm_roofline.longgen" not in got
 

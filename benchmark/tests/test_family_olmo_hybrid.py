@@ -70,7 +70,7 @@ def test_manifest_resolves_the_cell_to_this_family():
         serve["prefill_ladder"][-1] == 1024
     names = {m["name"] for m in man["per_layer"]
              if CELL in m.get("workloads", [])}
-    assert {"decode_hbm_roofline.threads", "flash_share_pct_serve.threads",
+    assert {"decode_hbm_roofline.threads", "flash_share_pct_serve.batch",
             "state_live_rows_mean.threads", "kv_live_positions_mean.threads",
             "scan_padding_share_pct.threads",
             "state_step_roofline.threads"} <= names
@@ -199,7 +199,7 @@ def test_cell_rehearsed_with_its_own_family(monkeypatch, tmp_path, trace):
     # prompts of 3-28 tokens in rungs of 16 and 32: most of a rung is padding
     assert got["scan_padding_share_pct.threads"]["value"] > 10.0
     assert 0.0 < got["prefill_share_pct.threads"]["value"] < 100.0
-    assert got["window_compiles_serve.threads"]["value"] == 0.0
+    assert got["window_compiles_serve.batch"]["value"] == 0.0
     # device-trace readers find no TPU plane on the CPU and report nothing
     assert "decode_hbm_roofline.threads" not in got
     assert "state_step_roofline.threads" not in got

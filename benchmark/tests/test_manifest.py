@@ -60,6 +60,26 @@ def test_each_layer_metric_moves_a_metric_its_cells_report():
             assert cell in target.get("workloads", cells), (m["name"], cell)
 
 
+def test_no_per_layer_entry_is_a_copy_of_another():
+    """Every entry's file exists, and no two entries that move the same
+    end-to-end metric have identical files: such a pair is ONE entry
+    whose ``workloads`` lists both cells (the list holds at most 128)."""
+    man = manifest.load()
+    assert len(man["per_layer"]) < 80
+    seen = {}
+    for entry in man["per_layer"]:
+        path = os.path.join(HERE, "metrics", entry["name"] + ".json")
+        assert os.path.isfile(path), entry["name"]
+        with open(path) as f:
+            spec = json.load(f)
+        key = (entry["moves"], json.dumps(spec, sort_keys=True))
+        assert key not in seen, (entry["name"], seen[key])
+        seen[key] = entry["name"]
+    listed = {e["name"] + ".json" for g in ("end_to_end", "per_layer")
+              for e in man[g]}
+    assert set(os.listdir(os.path.join(HERE, "metrics"))) == listed
+
+
 @pytest.mark.parametrize("bad", ["", "a b", "a,b", "a/b", "-a", ".a",
                                  "x" * 65, "µs"])
 def test_forbidden_names(bad):

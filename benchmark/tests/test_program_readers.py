@@ -79,7 +79,7 @@ def traced(tmp_path):
     path = reduce.find_xplane(str(tmp_path / "trace"))
     reduced = reduce.Trace.from_file(path).reduce()
     ctx = types.SimpleNamespace(scratch=str(tmp_path))
-    return {"trace": reduced, "shapes": {}}, ctx
+    return {"trace": reduced}, ctx
 
 
 def test_the_trace_by_hand_reads_as_meant(traced):
@@ -87,8 +87,12 @@ def test_the_trace_by_hand_reads_as_meant(traced):
     reduced = outcome["trace"]
     assert reduced["window_s"] == pytest.approx(950e-6)
     assert reduced["busy_s"] == pytest.approx(550e-6)
-    # the reducer's own table knows the load generator's spans only
-    assert set(reduced["idle_gaps"]) == {"bench.wait_due"}
+    # a gap is named by the worker's region where one is open, by the
+    # load generator's span elsewhere
+    idle = reduced["idle_gaps"]
+    assert idle["bench.wait_due"] == pytest.approx(50e-6)
+    assert {n for n in idle if not n.startswith("perf.decode.")} == {
+        "bench.wait_due"}
 
 
 def test_idle_goes_to_the_workers_innermost_region(traced, capsys):
@@ -103,18 +107,15 @@ def test_idle_goes_to_the_workers_innermost_region(traced, capsys):
     # a region that saw no idle time reads 0, not nothing
     assert trace_span_idle.read(outcome, ctx, span="step.emit") == 0.0
     # 50-60 lies in decode.sched, 60-62 in decode.step before its first
-    # child; 950-1000 under no region of the program's: the table in the
-    # log says so
-    idle, window_s = trace_span_idle.table(outcome["trace"], reduce.find_xplane(
-        os.path.join(ctx.scratch, "trace")))
-    assert window_s == pytest.approx(window)
+    # child; 950-1000 under no region of the program's, so under the load
+    # generator's span: the table in the log says so
+    idle = outcome["trace"]["idle_gaps"]
+    assert outcome["trace"]["window_s"] == pytest.approx(window)
     assert idle["perf.decode.sched"] == pytest.approx(10e-6)
     assert idle["perf.decode.step"] == pytest.approx(2e-6)
-    assert idle["_no_span_"] == pytest.approx(50e-6)
     assert sum(idle.values()) == pytest.approx(400e-6)
-    assert not any(name.startswith("bench.") for name in idle)
     out = capsys.readouterr().out
-    assert "perf.decode.step.wait" in out and "_no_span_" in out
+    assert "perf.decode.step.wait" in out and "bench.wait_due" in out
     assert out.count("perf.decode.step.wait") == 1      # printed once
 
 
@@ -143,7 +144,7 @@ def registry():
 
 def _ctx(process_start=1000.0, setup_s=30.0, seconds=51.0):
     return types.SimpleNamespace(process_start=process_start,
-                                 setup_s=setup_s, seconds=seconds)
+                                 setup_s=setup_s, seconds=seconds, tails=[])
 
 
 def test_histogram_statistic_over_the_window_alone(registry):
@@ -176,19 +177,67 @@ def test_several_histograms_give_the_sum_of_their_statistics(registry):
                              stat="p50") is None
 
 
-def test_a_window_the_histogram_no_longer_holds_reads_nothing(
-        registry, monkeypatch, capsys):
+def test_a_window_the_histogram_holds_a_part_of(registry, monkeypatch,
+                                               capsys):
+    """The program keeps a histogram's most recent samples only: a
+    percentile or a mean is then of the window's last ones and is never
+    handed back as if it were the whole window's (the reader leaves the
+    second it runs from in ``ctx.tails``, for the result line), a sum
+    reads nothing, and so does a window of which nothing is left."""
+    from benchmark.readers import program_window
+
     monkeypatch.setattr(registry.Histogram, "WINDOW", 4)
     h = registry.histogram("decode.step_s")
     for i in range(10):
-        h.observe(0.05, at=1030.0 + i)
-    assert program_hist.read({}, _ctx(), histograms=["decode.step_s"],
-                             stat="p50") is None
-    assert "no longer holds the whole window" in capsys.readouterr().out
-    # a later window that it still holds in full is read
-    assert program_hist.read({}, _ctx(setup_s=36.0),
+        h.observe(0.01 * i, at=1030.0 + i)       # kept: 0.06 .. 0.09
+    ctx = _ctx()
+    assert program_hist.read({}, ctx, histograms=["decode.step_s"],
+                             stat="p50") == pytest.approx(75.0)
+    assert ctx.tails == [pytest.approx(6.0)]
+    ctx = _ctx()
+    assert program_window.read({}, ctx, "decode.step_s", "mean") \
+        == pytest.approx(0.075)
+    assert ctx.tails == [pytest.approx(6.0)]
+    assert "the window's last 4 samples, from 6.0 s" in \
+        capsys.readouterr().out
+    ctx = _ctx()
+    assert program_window.read({}, ctx, "decode.step_s", "sum_pct") is None
+    assert ctx.tails == []
+    assert program_hist.read({}, _ctx(setup_s=25.0, seconds=5.0),
                              histograms=["decode.step_s"], stat="p50") \
-        == pytest.approx(50.0)
+        is None
+    assert "no longer holds the whole window" in capsys.readouterr().out
+    # a later window that it still holds in full is read as it is
+    ctx = _ctx(setup_s=36.0)
+    assert program_hist.read({}, ctx, histograms=["decode.step_s"],
+                             stat="p50") == pytest.approx(75.0)
+    assert ctx.tails == [] and capsys.readouterr().out == ""
+
+
+def test_a_tail_is_marked_in_the_result_line(monkeypatch, tmp_path):
+    """A traced run whose window outgrew the program's histograms: every
+    per-step statistic in the result line says from which second of the
+    window it runs (``window_from_s``); what is counted whole, or read from
+    the benchmark's own records, carries no such key."""
+    from dist_keras_tpu.observability import metrics
+
+    monkeypatch.setattr(metrics.Histogram, "WINDOW", 8)
+    man = manifest.load()
+    cell = next(c["name"] for c in man["workloads"]
+                if manifest.traffic_of(c)["kind"] == "serve_open")
+    got = rehearsal.rehearse(monkeypatch, tmp_path, cell, seed=12,
+                             trace=True)["metrics"]
+    per_step = [n for n in got if n.startswith(
+        ("decode_step_p50_ms", "step_wait_p50_ms", "step_dispatch_p50_ms",
+         "kv_live_positions_mean", "steps_overlapped_share"))]
+    assert len(per_step) == 5
+    for name in per_step:
+        assert 0.0 < got[name]["window_from_s"] < 1.5, name
+    whole = [n for n in got if n.startswith(
+        ("sched_slots_mean", "ttft_p50_ms", "gap_p50_ms", "hbm_peak_gb"))]
+    assert len(whole) == 4
+    for name in whole:
+        assert set(got[name]) == {"value", "unit"}, name
 
 
 def test_a_program_without_stamps_reads_nothing(registry, monkeypatch):

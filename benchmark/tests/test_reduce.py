@@ -60,3 +60,38 @@ def test_attribute_innermost_and_no_span():
     got = reduce._attribute(1.0, 5.0, spans)
     assert got == pytest.approx({"outer": 2.0, "inner": 2.0})
     assert reduce._attribute(11.0, 12.0, spans) == {"_no_span_": 1.0}
+
+
+def test_a_gap_is_named_by_the_workers_region_under_the_benchmarks_span():
+    """The load generator's ``bench.wait_reply`` slices and the decode
+    worker's ``perf.decode.*`` regions lie in one trace: the worker's
+    names the gap, also where the generator's slice began later."""
+    spans = [(0.0, 4.0, "perf.decode.step"),
+             (1.0, 3.0, "perf.decode.step.dispatch"),
+             (2.0, 6.0, "bench.wait_reply"),
+             (4.5, 5.0, "perf.decode.sched")]
+    got = reduce._attribute(0.5, 6.0, spans, reduce.SPAN_PREFIXES)
+    assert got == pytest.approx({
+        "perf.decode.step": 1.5, "perf.decode.step.dispatch": 2.0,
+        "bench.wait_reply": 1.5, "perf.decode.sched": 0.5})
+
+
+def test_both_layers_regions_are_kept_from_a_file(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    with jax.profiler.TraceAnnotation("bench.wait_reply"):
+        with jax.profiler.TraceAnnotation("perf.decode.step.dispatch"):
+            jnp.ones((8, 8)).sum().block_until_ready()
+        with jax.profiler.TraceAnnotation("perf.phase.other"):
+            pass
+    jax.profiler.stop_trace()
+    trace = reduce.Trace.from_file(reduce.find_xplane(str(tmp_path)))
+    assert {n for _, _, n in trace.host_spans} == {
+        "bench.wait_reply", "perf.decode.step.dispatch"}
+    only = reduce.Trace.from_file(reduce.find_xplane(str(tmp_path)),
+                                  span_prefixes=("bench.",))
+    assert {n for _, _, n in only.host_spans} == {"bench.wait_reply"}

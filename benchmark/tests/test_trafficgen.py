@@ -101,3 +101,80 @@ def test_bursts_and_classes_need_only_data():
 def test_unknown_distribution_is_an_error():
     with pytest.raises(ValueError):
         trafficgen.quantile({"dist": "zipf"}, 0.5)
+
+
+def lengths_hash(reqs):
+    import hashlib
+
+    return hashlib.sha256(json.dumps(
+        [[len(r["prompt"]), r["max_new"]] for r in reqs]).encode()
+    ).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", ["docbatch", "longgen"])
+def test_stagger_start_cuts_each_callers_first_reply_only(name):
+    tr = load(name)
+    assert tr["stagger_start"] is True and tr["stagger_why"]
+    n = tr["clients"]
+    pool = trafficgen.requests(tr, tr["requests"], 50000, 2 ** 31 + 11)
+    order = trafficgen.closed_order(tr, pool, n)
+    sent = [next(order) for _ in range(n + 2 * len(pool))]
+    for i, (req, whole) in enumerate(zip(sent[:n], pool)):
+        assert req["staggered"] is True
+        assert req["max_new"] == -(-whole["max_new"] * (i + 1) // n) >= 1
+        assert req["prompt"] is whole["prompt"]
+    assert sent[n - 1]["max_new"] == pool[n - 1]["max_new"]
+    # from the n + 1-th on the pool as drawn, cycled; the pool itself
+    # is never written to
+    whole = (pool * 3)[n:n + 2 * len(pool)]
+    assert all(a is b for a, b in zip(sent[n:], whole))
+    assert not any("staggered" in r for r in pool)
+    # the callers' first replies end at even distances over a cycle
+    cut = [r["max_new"] for r in sent[:n]]
+    mean = sum(r["max_new"] for r in pool) / len(pool)
+    assert max(cut) - min(cut) > 0.7 * mean
+
+
+@pytest.mark.parametrize("name,vocab,pinned", [
+    ("turns", 65536, {7: "508428868d5d1bed",
+                      2 ** 31 + 5: "2c68ed942a07ee29"}),
+    ("threads", 100352, {7: "e5a5e7ae56b6a8a9",
+                         2 ** 31 + 5: "47c674ae96340f45"})])
+def test_without_the_key_the_pool_is_what_it_was(name, vocab, pinned):
+    """The control cells' traffic: no ``stagger_start``, so what the
+    callers send is the pool in order, seed for seed what the parent of
+    PR 38 drew (the hashes are of its lengths)."""
+    tr = load(name)
+    assert "stagger_start" not in tr
+    for seed, digest in pinned.items():
+        pool = trafficgen.requests(tr, tr["requests"], vocab, seed)
+        assert lengths_hash(pool) == digest
+        order = trafficgen.closed_order(tr, pool, tr["clients"])
+        assert all(next(order) is r for r in pool + pool)
+
+
+def test_lead_in_requests_fall_outside_the_windows_count():
+    """A closed loop's callers start ``lead_in_s`` before the window:
+    their tokens from before it opens count nowhere, and a request sent
+    before it has no time to its first token."""
+    from benchmark import serving
+
+    tr = load("docbatch")
+    assert tr["lead_in_s"] == 2.0 and tr["lead_in_why"]
+    t0, seconds = 100.0, 10.0
+    done = {"finish": "length"}
+
+    def record(sent, times):
+        rec = serving.Record({"max_new": len(times)}, sent)
+        rec.sent, rec.times, rec.doc = sent, times, done
+        return rec
+
+    early = record(t0 - 2.0, [t0 - 1.5, t0 - 1.0])          # all before
+    across = record(t0 - 1.0, [t0 - 0.5, t0 + 0.5, t0 + 1.0])
+    inside = record(t0 + 2.0, [t0 + 2.25, t0 + 2.5, t0 + 11.0])
+    series, counters = serving.reduce_records(
+        [early, across, inside], t0, seconds)
+    assert counters["window_tokens_per_s"] == pytest.approx(4 / seconds)
+    assert series["ttft_ms"] == pytest.approx([250.0])
+    assert len(series["queue_wait_ms"]) == len(series["prefill_ms"]) == 1
+    assert sorted(series["gap_ms"]) == pytest.approx([250.0, 500.0])

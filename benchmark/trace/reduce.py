@@ -8,7 +8,10 @@ operation, named by the operation's HLO text (``%fusion.26 = f32[4096,
 16384]{...} fusion(...)``); the plane ``/host:CPU`` holds one line per host
 thread, and ``jax.profiler.TraceAnnotation`` regions appear there under
 their own names.  Device and host events share one clock (ns since the
-trace began).
+trace began).  Host regions kept: the benchmark's ``bench.*`` spans around
+its calls into the program and, below them, the decode worker's own
+``perf.decode.*`` regions (same trace, same clock), so that an idle gap
+is named by what the worker was doing wherever it says.
 """
 
 from __future__ import annotations
@@ -19,6 +22,10 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
+# outermost layer first: where regions of two layers are open at once, the
+# deeper layer's names the gap (a span of the load generator's thread may
+# well have begun after the worker's region it waits on)
+SPAN_PREFIXES = ("bench.", "perf.decode.")
 OPS_LINE = "XLA Ops"
 _RESULT = re.compile(r"^%?(?P<op>[^\s=]+) = \(?(?P<dtype>[a-z]+[0-9]*)"
                      r"\[(?P<dims>[0-9,]*)\]")
@@ -90,13 +97,14 @@ def _gaps(intervals, lo, hi):
 class Trace:
     """Device operations and host annotations of one trace, in seconds."""
 
-    def __init__(self, device_ops, host_spans):
+    def __init__(self, device_ops, host_spans, span_prefixes=SPAN_PREFIXES):
         # {chip: [(start, end, hlo_text)]}, [(start, end, name)]
         self.device_ops = device_ops
         self.host_spans = host_spans
+        self.span_prefixes = tuple(span_prefixes)
 
     @classmethod
-    def from_file(cls, path, span_prefix="bench."):
+    def from_file(cls, path, span_prefixes=SPAN_PREFIXES):
         from jax.profiler import ProfileData
 
         data = ProfileData.from_file(path)
@@ -115,11 +123,11 @@ class Trace:
             elif plane.name == HOST_PLANE:
                 for line in plane.lines:
                     for ev in line.events:
-                        if ev.name.startswith(span_prefix):
+                        if ev.name.startswith(tuple(span_prefixes)):
                             t0 = ev.start_ns * 1e-9
                             host_spans.append(
                                 (t0, t0 + ev.duration_ns * 1e-9, ev.name))
-        return cls(device_ops, host_spans)
+        return cls(device_ops, host_spans, span_prefixes)
 
     def window(self, name):
         """[start, end] of the one host span called ``name`` (the traced
@@ -143,8 +151,9 @@ class Trace:
     def reduce(self, window_span="bench.window"):
         """-> dict with ``window_s``, ``busy_s`` (union of device operation
         intervals, averaged over chips), ``ops`` {label: seconds, averaged
-        over chips}, ``events`` [(seconds, hlo_text)] of chip 0 and
-        ``idle_gaps`` {host span open at the time: seconds}, of chip 0."""
+        over chips}, ``events`` [(seconds, hlo_text)] of chip 0,
+        ``idle_gaps`` {host span open at the time: seconds}, of chip 0, and
+        ``host_spans``, the names of the host spans kept."""
         lo, hi = self.window(window_span)
         ops = self.clipped(lo, hi)
         chips = sorted(ops) or [0]
@@ -160,7 +169,8 @@ class Trace:
                  if n != window_span]
         idle = {}
         for a, b in _gaps([(x, y) for x, y, _ in first], lo, hi):
-            for name, seconds in _attribute(a, b, spans).items():
+            for name, seconds in _attribute(
+                    a, b, spans, self.span_prefixes).items():
                 idle[name] = idle.get(name, 0.0) + seconds
         return {
             "window_s": hi - lo,
@@ -168,13 +178,19 @@ class Trace:
             "ops": per_op,
             "events": [(b - a, text) for a, b, text in first],
             "idle_gaps": idle,
+            "host_spans": sorted({n for _, _, n in spans}),
         }
 
 
-def _attribute(a, b, spans):
+def _attribute(a, b, spans, prefixes=("",)):
     """Split the gap [a, b] among the host spans open during it: the
-    innermost (latest started) span wins each instant; time under no span
-    goes to ``_no_span_``."""
+    innermost span wins each instant, which is the one of the deepest
+    layer (the last of ``prefixes`` its name starts with) and there the
+    latest started; time under no span goes to ``_no_span_``."""
+    def depth(name):
+        return max((i for i, p in enumerate(prefixes)
+                    if name.startswith(p)), default=-1)
+
     cuts = {a, b}
     live = [(s, e, n) for s, e, n in spans if e > a and s < b]
     for s, e, _ in live:
@@ -183,8 +199,8 @@ def _attribute(a, b, spans):
     out = {}
     for x, y in zip(edges, edges[1:]):
         mid = (x + y) / 2
-        open_now = [(s, n) for s, e, n in live if s <= mid < e]
-        name = max(open_now)[1] if open_now else "_no_span_"
+        open_now = [(depth(n), s, n) for s, e, n in live if s <= mid < e]
+        name = max(open_now)[2] if open_now else "_no_span_"
         out[name] = out.get(name, 0.0) + (y - x)
     return out
 

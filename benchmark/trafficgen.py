@@ -14,11 +14,14 @@ further requests are due at the window's first instant, so that the
 window samples a server that has been running and not one that fills up
 from empty.  A closed loop has
 ``clients`` and no arrival process; its ``block`` deals the requests so
-that every stretch of the list holds the same mix.
+that every stretch of the list holds the same mix.  With ``stagger_start``
+each caller's FIRST request has its reply cut so that the callers' phases
+are spread evenly over a cycle from the start (``closed_order``).
 """
 
 from __future__ import annotations
 
+import itertools
 import math
 from statistics import NormalDist
 
@@ -98,6 +101,24 @@ def _blocked(reqs, block, rng):
         out.extend(members[i] for i in rng.permutation(block))
     return out + [reqs[i] for i in rng.permutation(
         range(per * block, len(reqs)))]
+
+
+def closed_order(traffic, pool, clients):
+    """The requests of a closed loop in the order they are sent, without
+    end: the pool, cycled.  With ``stagger_start`` caller ``i`` of
+    ``clients`` has the reply of its first request cut to ``ceil(length *
+    (i + 1) / clients)`` (marked ``staggered``: a cut reply is not one the
+    mix draws): the callers then end their first replies one after another
+    at even distances, as those of a server that has run for an hour do,
+    and not all at once as callers that began together.  Nothing after a
+    caller's first request changes."""
+    stagger = clients if traffic.get("stagger_start") else 0
+    for i in range(stagger):
+        whole = pool[i % len(pool)]
+        yield dict(whole, staggered=True,
+                   max_new=-(-whole["max_new"] * (i + 1) // clients))
+    for at in itertools.count(stagger):
+        yield pool[at % len(pool)]
 
 
 def open_schedule(traffic, seconds, vocab, seed):
