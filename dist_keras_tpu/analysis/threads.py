@@ -69,6 +69,8 @@ KNOWN_THREAD_ROOTS = {
     # telemetry plane (round 11)
     "obs.sampler": "observability/timeseries.py:MetricsSampler._loop",
     "obs.exporter": "~observability/prometheus.py:_Handler.*",
+    # the host-stall witness: one a process while a DecodeEngine lives
+    "obs.stall_witness": "observability/perf.py:_witness_loop",
     # parameter-server training mode (round 17)
     "ps.http": "ps/server.py:PSServer.serve_forever",
     "ps.http_handler": "~ps/server.py:_Handler.*",

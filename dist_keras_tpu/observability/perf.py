@@ -36,6 +36,16 @@ stay on in production (<5% of train wall, gated):
   host region on the device trace's clock in ANY session that is —
   ``utils.profiling.trace``, a benchmark's own ``start_trace``, a
   remote capture through ``jax.profiler.start_server``.
+- **Host stalls** — :func:`watch_stalls` keeps ONE daemon thread a
+  process awake a hundred times a second while a ``DecodeEngine``
+  lives, and every wake-up that comes more than 30 ms late is a sample
+  of ``perf.host_stall_s`` (the seconds it was late, stamped with the
+  moment it was due) with the process's CPU seconds over the same
+  interval beside it (``perf.host_stall_cpu_s``): "the machine stood
+  still" against "the interpreter was busy", which a region's wall
+  (``decode.step.wait`` grows by 0.1 s either way, and equally when
+  the device is slow) cannot tell apart.  It opens no trace region
+  and writes nothing.
 
 Everything lands in the process metrics registry, so it rides the
 epoch-boundary snapshots, the ``MetricsSampler`` time series, the
@@ -84,6 +94,20 @@ PHASES = ("data", "step", "comm", "comm_overlap", "comm_blocked", "ckpt",
           "decode.step.wait", "decode.step.emit")
 
 _annotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+# The stall witness's two constants, from the scratch witnesses of PR 38
+# (PERF.md section 6, "After the refusal"; section 7).  The wait: the
+# machine's freezes last 0.09-0.11 s, so a wait of 10 ms places one to a
+# tenth of its length, and a hundred wake-ups a second of microseconds
+# each take the interpreter's lock only while the worker waits on the
+# device.  The threshold: a wake-up of a busy serving process comes up to
+# a few milliseconds late (another thread's switch interval of 5 ms, a
+# burst of callbacks), and every freeze seen was at least 88 ms: 30 ms
+# lies three times clear of both, as PR 38's child process had it.
+STALL_WAIT_S = 0.010
+STALL_LATE_S = 0.030
+
+_witness = {"users": 0, "thread": None, "stop": None}
 
 
 def _on_duration(name, duration_secs, **kw):
@@ -179,6 +203,55 @@ class phase:
             time.perf_counter() - t0, at=t0)
         self._ann.__exit__(*exc)
         return False
+
+
+def _witness_loop(wait, clock=time.perf_counter, cpu=time.process_time):
+    """The witness thread's body: ``wait(STALL_WAIT_S)`` (the stop
+    event's, True once it is set) until stopped; a wake-up more than
+    ``STALL_LATE_S`` after it was due is ONE sample of the lateness and
+    one of the process's CPU seconds since the wake-up before, both
+    stamped with the moment it was due, so that
+    ``samples_between`` puts the stall in the window it fell in.  The
+    histograms are looked up at the stall, not held: a registry reset
+    under a running witness (tests) leaves it observing the live ones."""
+    woke, used = clock(), cpu()
+    while not wait(STALL_WAIT_S):
+        due = woke + STALL_WAIT_S
+        woke, before, used = clock(), used, cpu()
+        if woke - due > STALL_LATE_S:
+            metrics.histogram("perf.host_stall_s").observe(
+                woke - due, at=due)
+            metrics.histogram("perf.host_stall_cpu_s").observe(
+                used - before, at=due)
+
+
+def watch_stalls():
+    """One more user of the process's stall witness (a ``DecodeEngine``
+    being built): the first starts the thread.  The two histograms
+    exist from here on, empty until the first stall, so a reader can
+    tell "no stall" from "no witness"."""
+    with _lock:
+        _witness["users"] += 1
+        metrics.histogram("perf.host_stall_s")
+        metrics.histogram("perf.host_stall_cpu_s")
+        if _witness["thread"] is None:
+            stop = _witness["stop"] = threading.Event()
+            _witness["thread"] = threading.Thread(
+                target=_witness_loop, args=(stop.wait,), daemon=True,
+                name="dk-perf-stall-witness")
+            _witness["thread"].start()
+
+
+def unwatch_stalls():
+    """A user of the witness is gone (its engine closed): the last one
+    stops the thread and waits for it, a wait's length at most."""
+    with _lock:
+        _witness["users"] = max(0, _witness["users"] - 1)
+        if _witness["users"] or _witness["thread"] is None:
+            return
+        thread, _witness["thread"] = _witness["thread"], None
+        _witness["stop"].set()
+    thread.join(timeout=5.0)
 
 
 def snapshot(snap=None):

@@ -25,8 +25,9 @@ Design points (each mirrors an existing engine contract):
 - **Paged KV, and per-sequence state beside it.**  Each replica owns
   the pools of its model's block family.  The family states each pool
   (its ``cache_pools``) as ``(layers it spans, "page" or "sequence", the
-  entry's shape)``: one K and one V pool of ``heads x head_dim`` entries
-  over every layer for ``models/transformer.py``, ONE pool of ``latent +
+  entry's shape)``: ONE pool of ``v | k`` rows of ``2 x d_model`` values
+  (every head's values, then every head's keys) over every layer for
+  ``models/transformer.py``, ONE pool of ``latent +
   rope`` wide entries over every layer for ``models/mla_moe.py``, for
   ``models/lfm2_moe.py`` one paged pool of ``v | k`` rows over its
   attention layers only and one pool of convolution state, a row a
@@ -301,6 +302,13 @@ def _prefill_views(packed, state=False):
     return toks, packed[-1], page_idx, page_off
 
 
+def _still_running(out):
+    """Has the device NOT yet finished the step that puts ``out`` out?
+    (0.2 us a call; a seam of its own so that a test can hold a step
+    "running".)"""
+    return not out.is_ready()
+
+
 def _to_device(packed):
     """A dispatch's ONE transfer to the device, counted: ``perf.h2d_bytes``
     grows by the array's size and ``perf.h2d_s`` gains the enqueue's wall,
@@ -317,16 +325,23 @@ class _Flight:
     needs besides the host's canonical state, which it has not touched."""
 
     __slots__ = ("group", "slot", "rung", "out", "lengths", "t0",
-                 "overlapped", "attempt")
+                 "overlapped", "fed", "attempt")
 
-    def __init__(self, group, rung, out, lengths, t0, overlapped, attempt):
+    def __init__(self, group, rung, out, lengths, t0, overlapped, fed,
+                 attempt):
         self.group = group            # the sequences, slot by slot
         self.slot = {seq: i for i, seq in enumerate(group)}
         self.rung = rung
         self.out = out                # device: tokens to the top rung, counts
         self.lengths = lengths        # host view, for the family's counts
         self.t0 = t0                  # perf_counter at the launch
-        self.overlapped = overlapped  # launched under its predecessor
+        # launched before its predecessor was fetched (NOT "while the
+        # device was busy": that is ``fed``)
+        self.overlapped = overlapped
+        # the predecessor was still running right after the launch: the
+        # chip never ran dry (False: it had drained; None: no sample,
+        # there was no predecessor or the pass ran a prefill)
+        self.fed = fed
         self.attempt = attempt        # failures this step had before
 
 
@@ -352,6 +367,7 @@ class _DecodeReplica:
         self.no_tokens = no_tokens  # what a step carries with none in flight
         self.landed_at = 0.0      # perf_counter: the last tokens on the host
         self.attempt = 0          # failures of the step to launch next
+        self.prefilling = False   # the worker's pass ran a prefill
         self._pinned = {}         # id(params_host) -> device params
 
     def put_params(self, params):
@@ -554,6 +570,7 @@ class DecodeEngine:
         self._reg_ttft = metrics.histogram("decode.ttft_s")
         self._reg_step = metrics.histogram("decode.step_s")
         self._reg_overlapped = metrics.histogram("decode.step_overlapped")
+        self._reg_fed = metrics.histogram("decode.launch_fed")
         self._reg_discarded = metrics.counter("decode.tokens_discarded")
         self._reg_prefill = metrics.histogram("decode.prefill_s")
         self._reg_queue_wait = metrics.histogram("decode.queue_wait_s")
@@ -561,6 +578,7 @@ class DecodeEngine:
         self._reg_kv = metrics.gauge("decode.kv_used_pages")
         self._reg_rows = metrics.gauge("decode.state_rows_used")
         perf.install()  # retrace listener: the ladder bound, verified
+        perf.watch_stalls()  # until _shutdown_threads
 
         self._workers = [threading.Thread(
             target=self._worker_main, args=(rep,), daemon=True,
@@ -1195,8 +1213,12 @@ class DecodeEngine:
                 group[0].params, *rep.pools,
                 rep.no_tokens if prev is None else prev.out,
                 _to_device(packed))
+        # fed or drained, asked once the successor is queued: a
+        # predecessor still running then never left the chip without work
+        fed = (None if prev is None or rep.prefilling
+               else _still_running(prev.out))
         flight = _Flight(group, rung, out, lengths, t0, prev is not None,
-                         rep.attempt)
+                         fed, rep.attempt)
         rep.attempt = 0
         return flight
 
@@ -1207,7 +1229,10 @@ class DecodeEngine:
         predecessor's tokens on the host to its own (dispatch + wait with
         nothing in flight; under overlap the period between two steps'
         tokens), one sample a step, stamped with that start, as are
-        ``decode.step_overlapped`` and the family's counts."""
+        ``decode.step_overlapped`` (launched before its predecessor was
+        fetched), ``decode.launch_fed`` where the launch took a sample
+        (that predecessor was still running on the device) and the
+        family's counts."""
         now = time.perf_counter()
         t0 = max(flight.t0, rep.landed_at)
         dt = now - t0
@@ -1216,6 +1241,8 @@ class DecodeEngine:
         self._m_step.observe(dt, at=t0)
         self._reg_step.observe(dt, at=t0)
         self._reg_overlapped.observe(float(flight.overlapped), at=t0)
+        if flight.fed is not None:
+            self._reg_fed.observe(float(flight.fed), at=t0)
         if self._family.observe_step is not None:
             # the counts came off the device behind the tokens, in the
             # one array the wait already fetched
@@ -1377,6 +1404,11 @@ class DecodeEngine:
                                 phase="expiry",
                                 generated=len(seq.generated()))
                 self._resolve(seq, fin)
+            # a pass that runs a prefill samples no ``decode.launch_fed``:
+            # a step launched behind the leading prefill is fed by it
+            # whatever the host did, and one launched after a prefill's
+            # wait finds the chip drained by design
+            rep.prefilling = bool(prefills)
             for i, seq in enumerate(prefills):
                 self._prefill(rep, seq, self._rung_for(
                     seq.prompt_len, self.prefill_ladder), leads=i == 0)
@@ -1704,6 +1736,7 @@ class DecodeEngine:
         for t in self._workers:
             if t is not threading.current_thread():
                 t.join(timeout=10)
+        perf.unwatch_stalls()
         self._drained.set()
 
     def close(self, drain=True, timeout_s=None):

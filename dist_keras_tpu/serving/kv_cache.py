@@ -2,10 +2,11 @@
 
 The decode engine (``serving/decode.py``) keeps each replica's attention
 keys/values in page pools of the shapes ``DecodeEngine.pool_shapes``: for
-a ``Transformer`` a K and a V device array per replica of ``(layers,
-num_pages + 1, page_size, heads, head_dim)`` — page-major: a page is one
-contiguous ``(page_size, heads, head_dim)`` block of one layer.  This module owns the HOST-side
-accounting for that pool — which pages are free, which sequence holds
+a ``Transformer`` ONE device array per replica of ``v | k`` rows,
+``(layers, num_pages + 1, page_size, 2 * d_model)`` (every head's values,
+then every head's keys) — page-major: a page is one contiguous
+``(page_size, 2 * d_model)`` block of one layer.  This module owns the
+HOST-side accounting for that pool — which pages are free, which sequence holds
 which pages — so the device arrays never need compaction and a
 sequence's KV never moves once written (vLLM's PagedAttention layout,
 PAPERS.md).
