@@ -216,6 +216,19 @@ def transformer_apply(params, x, cfg, *, causal=False, attn_fn=None,
 # (``lfm2_moe.attend_rows``: on a TPU ``latent_attention_kernel``, blocks
 # of pages double-buffered in VMEM and everything past a slot's length
 # skipped; elsewhere ``latent_attention_reference``).
+#
+# Both steps embed a token by reading its row of ``proj``, as the other
+# three families read theirs: the float32 row as stored (a ``one_hot``
+# product read the whole table a step and handed on the row rounded to
+# the product's precision).  An id outside the table reads a row all the
+# same: an indexed read clamps past the end and counts a negative id from
+# the end, where the product gave zeros.  No such id reaches a live
+# entry: the engine's door refuses a prompt token outside ``[0,
+# vocab(cfg))`` (``DecodeEngine.submit_generate``: ``ValueError``, the
+# front end's 400), a fed-back token is an ``argmax`` over ``n_classes``
+# logits, and the worker pads with token 0.  A padded entry, whatever it
+# reads, moves no live one (``tests/test_decode_embedding.py``, bit for
+# bit): see each step's docstring.
 def vocab(cfg):
     """The vocabulary a decoder of ``cfg`` reads and writes; a config
     this family cannot decode is refused here."""
@@ -228,10 +241,11 @@ def vocab(cfg):
     if cfg["input_dim"] != cfg["n_classes"]:
         raise ValueError(
             "a Transformer decodes with token-in == logit-out (its "
-            "embedding is one_hot(tokens) @ proj): "
+            "embedding is the token's row of proj, and a decoded token "
+            "is fed back, so proj holds a row for every logit): "
             f"input_dim={cfg['input_dim']} != "
             f"n_classes={cfg['n_classes']}.  The models.mla_moe "
-            "family embeds by row gather and needs no such match")
+            "family has a table of its own and needs no such match")
     return int(cfg["n_classes"])
 
 
@@ -277,11 +291,10 @@ def prefill_step(cfg, params, pool, tokens, length, page_idx, page_off):
     ``tokens (T,) int32`` padded to a prefill rung; positions past
     ``length`` write their row to the scratch page (``page_idx``
     routes them there) and never influence position ``length - 1``
-    under the causal mask."""
+    under the causal mask, whatever token id they carry."""
     t = tokens.shape[0]
     with jax.named_scope("embed"):
-        x = jax.nn.one_hot(tokens, cfg["n_classes"], dtype=pool.dtype)
-        hs = (x @ params["proj"] + params["pos"][:t])[None]
+        hs = (params["proj"][tokens] + params["pos"][:t])[None]
     for li, blk in enumerate(params["blocks"]):
         with jax.named_scope("qkv"):
             y = layer_norm(blk["ln1"], hs)
@@ -312,11 +325,11 @@ def decode_step(cfg, params, pool, tokens, positions, page_tables,
     """One token step for a padded slot set -> (next tokens, updated
     pool).  Padding slots carry ``length == 0`` and write to the scratch
     page; the read's dead-row guard makes their output exact zeros (then
-    discarded)."""
+    discarded), and no operation of the step mixes slots: whatever token
+    id a padding slot carries moves no live one."""
     block_pages = kv_block_pages(pool.shape[2])
     with jax.named_scope("embed"):
-        hs = (jax.nn.one_hot(tokens, cfg["n_classes"], dtype=pool.dtype)
-              @ params["proj"] + params["pos"][positions])
+        hs = params["proj"][tokens] + params["pos"][positions]
     for li, blk in enumerate(params["blocks"]):
         with jax.named_scope("qkv"):
             y = layer_norm(blk["ln1"], hs)

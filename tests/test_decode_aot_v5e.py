@@ -125,17 +125,9 @@ def _step_and_args(engine, phase, rung, pages_per_seq, S, counts=0):
         S((rung,)), S(()), S((rung,)), S((rung,))) + (S(()),) * rows
 
 
-@pytest.mark.parametrize("phase,rung,temp_gb", [
-    ("decode", SLOTS, 0.1), ("prefill", 128, 1.0), ("prefill", 2048, 1.0),
-    ("packed_decode", SLOTS, 0.1), ("packed_prefill", 2048, 1.0)])
-def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
-                                               temp_gb):
-    """The ONE pool of ``v | k`` rows, donated, is written in place once a
-    layer and never copied or sliced.  The 8-slot decode step reads it
-    through one ``latent_decode`` kernel a layer that walks the live
-    pages where they lie: no gathered copy of every slot's whole table
-    (two of 268 MB a layer until PR 37, the temporaries then bounded
-    under 1 GB), the temporaries under 0.1 GB."""
+def _compiled_transformer_step(topo, phase, rung):
+    """``(compiled, pool_shape)`` of one of the transformer family's steps
+    at the widths above, its one pool donated."""
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -153,8 +145,25 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
                        jax.random.PRNGKey(0)))
     pool = S(pool_shape, jnp.float32)
     fn, args = _step_and_args(engine, phase, rung, PAGES_PER_SEQ, S)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, pool, *args).compile()
+    return jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile(), pool_shape
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("decode", SLOTS, 0.1), ("prefill", 128, 1.0), ("prefill", 2048, 0.5),
+    ("packed_decode", SLOTS, 0.1), ("packed_prefill", 2048, 0.5)])
+def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
+                                               temp_gb):
+    """The ONE pool of ``v | k`` rows, donated, is written in place once a
+    layer and never copied or sliced.  The 8-slot decode step reads it
+    through one ``latent_decode`` kernel a layer that walks the live
+    pages where they lie: no gathered copy of every slot's whole table
+    (two of 268 MB a layer until PR 37, the temporaries then bounded
+    under 1 GB), the temporaries under 0.1 GB.  A 2,048-rung prefill's
+    are 0.374 GB, held under 0.5 (the same before PR 40: the compiler had
+    folded the ``one_hot`` operand into the product it fed and never
+    stored its 410 MB)."""
+    compiled, pool_shape = _compiled_transformer_step(topo, phase, rung)
     text = compiled.as_text()
 
     pool_elems = math.prod(pool_shape)
@@ -186,6 +195,47 @@ def test_serving_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
         assert len(calls) == CFG["n_layers"], len(calls)
     else:
         assert "flash_fwd" in text and "latent_decode" not in text
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("phase,rung", [
+    ("packed_decode", SLOTS), ("packed_prefill", 2048)])
+def test_serving_step_multiplies_by_the_vocabulary_once(topo, as_tpu, phase,
+                                                        rung):
+    """A token is embedded by reading its row of ``proj`` (PR 40): the
+    program the worker dispatches holds exactly ONE product with the
+    vocabulary's dimension, the head's (a ``one_hot(tokens) @ proj`` read
+    the whole 0.82 GB table a step to select a rung of 16 KB rows, and
+    led both galactica serving traces), nothing of the vocabulary's size
+    under the ``embed`` scope, and no ``(rung, n_classes)`` mask of an
+    ``iota`` compared with the tokens."""
+    compiled, _ = _compiled_transformer_step(topo, phase, rung)
+    text = compiled.as_text()
+    dims = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+            for m in map(_INSTRUCTION.match, text.splitlines()) if m}
+    products, gathers = set(), []
+    for _, name, _, opcode, line in _instructions(text):
+        scope = _OP_NAME.search(line)
+        scope = scope.group(1) if scope else ""
+        operands = re.findall(r"%([\w.\-]+)", line.split("(", 1)[1])
+        if "/embed/" in scope:
+            assert VOCAB not in dims[name], line
+            if opcode == "gather":
+                gathers.append(name)
+        # a product keeps its primitive's name however the compiler
+        # realises it: a convolution, or (a prefill's one row times the
+        # head) a multiply feeding its own reduce
+        if scope.endswith("dot_general") and any(
+                VOCAB in dims.get(n, ()) for n in [name, *operands]):
+            products.add(scope.split("/", 1)[1])
+        assert not (opcode == "compare"
+                    and dims[name] == [rung, VOCAB]), line
+    assert products == {"head/dot_general"}, products
+    # the parse saw the scope: the read is a gather of (rung, d_model)
+    assert gathers and all(
+        dims[g] == [rung, CFG["d_model"]] for g in gathers), gathers
 
 
 # -- the latent-attention, sparse-expert family (models/mla_moe.py) ------

@@ -39,6 +39,15 @@ anew on that PR's tree; the kernel, its dispatch and ``attend_rows`` were
 not edited, and the eight entries of ``mla_moe`` and ``lfm2_moe`` pass as
 recorded.
 
+**PR 40 meant to change the transformer family's four entries, and only
+those.**  Its steps embed a token by reading its row of ``proj``
+(``params["proj"][tokens]``, a gather, as the other families read their
+tables) where they multiplied ``one_hot(tokens)`` by the whole table, so
+the ``embed`` scope of both programs changed and nothing else of them.
+The four hashes were recorded anew on that PR's tree; the eight entries of
+``mla_moe`` and ``lfm2_moe`` pass as recorded, and ``olmo_hybrid``'s four
+programs (not pinned here) hash alike on that tree and its parent.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.
@@ -87,20 +96,20 @@ MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
 # and for a TPU (the Pallas kernels do: ``use_pallas()`` asks
 # ``jax.default_backend()``, patched here): the prefills' recorded on
 # commit 9317672 (PR 31), the decode steps' on PR 35's tree, the
-# transformer's four on PR 37's
+# transformer's four on PR 40's
 RECORDED = {
     ("transformer", "prefill", "cpu"):
-        "aab1a7b471491bf415faab99972747eb39240ad186535dcaf3e65f73278eb653",
+        "7b03fbe7591b94974e34c7e6270bdf8741c4f8d1bf002384047d9c5e585b17ce",
     ("transformer", "decode", "cpu"):
-        "2623f805ead120d59dbbbfab8d67ea9e56c75cbd8d9319b6d948407bc3f52881",
+        "6878a25ead1b6dbfd1c8a5677854118847f3b18431cf0fb6fddd563b8a881788",
     ("mla_moe", "prefill", "cpu"):
         "e3ee6957027c2cdc8d3239a7007edb69d5e90c757b04248f8f17e061f3ea60af",
     ("mla_moe", "decode", "cpu"):
         "747fae6db7e70fb990ebd203437755c284129cbb3ef915740aed3ed6924d8e8f",
     ("transformer", "prefill", "tpu"):
-        "086a51e210eb6d720a97f4bb5f6382afbbd79cfb74231f1f1cd65d4e4b2ad622",
+        "7d037a6f7e92b9de29ab965bf57ab1954331a061fbe1c5dade8dec9015341905",
     ("transformer", "decode", "tpu"):
-        "97c7d5c85ae32d8661db1274bb6247a7d0bdfd55570b986a7a583a820c469612",
+        "5b34faccbeb7297919bb6afacb1fb8d59d8abea483e42b1ebeb9481d664f6d16",
     ("mla_moe", "prefill", "tpu"):
         "d0ee9c0422cd0fd5708a6c3a56a0795d0b657947d10ffd2e0b6c075839fe6e5c",
     ("mla_moe", "decode", "tpu"):
