@@ -3,6 +3,10 @@
 A cell names a configuration and a traffic mix; the mix's ``kind`` names
 the module that drives it; each metric names its reader.  Nothing here
 knows any particular cell, configuration or metric.
+
+How a cell joins (``PERF.md`` section 3): it appends its name to the
+``workloads`` list of each shared entry whose reader's inputs its kind
+produces, and brings entries and files of its own only for what is its own.
 """
 
 from __future__ import annotations
@@ -16,6 +20,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+# the sources whose readers take the program's own counters, spans and
+# stamped histograms and so need no device trace
+PROGRAM_SOURCES = ("program_counter", "program_span")
 
 
 class BadManifest(ValueError):
@@ -97,12 +104,15 @@ def kind_of(traffic):
     return _module("kinds", traffic["kind"], "traffic kind")
 
 
-def metrics_for(man, cell_name, group):
+def metrics_for(man, cell_name, group, sources=None):
     """The metrics of ``group`` this cell reports -> [(entry, spec,
-    reader module)], ``spec`` being the metric's own file."""
+    reader module)], ``spec`` being the metric's own file; with
+    ``sources``, those alone whose ``source`` is one of them."""
     out = []
     for entry in man[group]:
         if "workloads" in entry and cell_name not in entry["workloads"]:
+            continue
+        if sources is not None and entry["source"] not in sources:
             continue
         spec = _json(os.path.join(HERE, "metrics", entry["name"] + ".json"),
                      f"metric {entry['name']}")

@@ -4,13 +4,13 @@ milliseconds of seconds): ``mean``, ``p<q>``, or ``sum_pct`` — the
 samples' sum as a share of the window's seconds, in percent (regions that
 observe their seconds).  Samples are those stamped inside the window; a
 program without the histogram or without stamps reads nothing.  The
-program's histograms keep their most recent 4,096 samples: where a window
-holds more (steps of 10 ms over 51 s), a mean or a percentile is of the
-window's LAST samples, as many as are kept, and is never reported as if
-it were the whole window's: the reader leaves the second it runs from in
-``ctx.tails``, and the result line carries it beside the value
-(``window_from_s``, ``run.run_cell``); a sum needs the whole window and
-reads nothing then."""
+program's histograms keep their most recent samples (16,384 since PR 39,
+a whole 51 s window of 3.1 ms steps): where a window holds more, a mean or
+a percentile is of the window's LAST samples, as many as are kept, and is
+never reported as if it were the whole window's: the reader leaves the
+second it runs from in ``ctx.tails``, and the result line carries it
+beside the value (``window_from_s``, ``run._read``); a sum needs the whole
+window and reads nothing then."""
 
 from benchmark import stats
 

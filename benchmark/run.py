@@ -6,14 +6,19 @@
 A new process each time: enable the compile cache, build the cell's state
 on the device from the seed, warm the cell's shapes (set-up), measure for
 ``--seconds``, decide ``correct`` against the plain reference, print one
-JSON object as the last line of standard output.  Set-up runs from the
-process's start to the first timed step or request.  The seconds inside
-``jax.devices()``, the machine bringing its chip up, are reported beside
-it (the counter ``chip_startup_s``) and stay inside it: what that call
-does not do then is done at the first executions (measured, PR 23), so
-taking it out makes set-up less steady, not more.  Without the accelerator
-the cell asks for, or on a ``device_kind`` the table of peaks does not
-list, the run exits non-zero and prints no result.
+JSON object as the last line of standard output.  Its ``metrics`` are the
+cell's end-to-end metrics, or with ``--trace 1`` its per-layer metrics;
+an untraced line carries beside them, under ``per_layer``, the per-layer
+metrics that need no trace (the program's own counters and spans), and
+every line ends with the numbers ``correct`` compared, each beside its
+limit (``compared``, also the last lines of standard error).  Set-up runs
+from the process's start to the first timed step or request.  The seconds
+inside ``jax.devices()``, the machine bringing its chip up, are reported
+beside it (the counter ``chip_startup_s``) and stay inside it: what that
+call does not do then is done at the first executions (measured, PR 23),
+so taking it out makes set-up less steady, not more.  Without the
+accelerator the cell asks for, or on a ``device_kind`` the table of peaks
+does not list, the run exits non-zero and prints no result.
 """
 
 import time
@@ -22,6 +27,7 @@ _PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -66,6 +72,22 @@ class Context:
         self.mark("set-up done, window begins")
 
 
+def _read(wanted, outcome, ctx):
+    """Each metric's reader over the closed window -> {name: value and
+    unit}; a reader that finds nothing to read leaves its metric out."""
+    metrics = {}
+    for entry, spec, reader in wanted:
+        ctx.tails = []
+        value = reader.read(outcome, ctx, **spec.get("args", {}))
+        if value is not None:
+            metrics[entry["name"]] = {"value": float(value),
+                                      "unit": entry["unit"]}
+            if ctx.tails:
+                # not the whole window's: said beside the value
+                metrics[entry["name"]]["window_from_s"] = max(ctx.tails)
+    return metrics
+
+
 def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
              scratch, process_start=None, chip_startup_s=0.0):
     """Drive one cell on ``devices`` -> the result object.  The look for a
@@ -78,6 +100,9 @@ def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
     kind = manifest.kind_of(traffic)
     group = "per_layer" if trace else "end_to_end"
     wanted = manifest.metrics_for(man, cell_name, group)
+    # what needs no trace is read in every run, once the window has closed
+    beside = [] if trace else manifest.metrics_for(
+        man, cell_name, "per_layer", manifest.PROGRAM_SOURCES)
     ctx = Context(cell, config, traffic, seed, seconds, trace, devices,
                   peaks, scratch,
                   _PROCESS_START if process_start is None
@@ -85,16 +110,7 @@ def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
     outcome = kind.run(ctx)
     outcome["counters"]["setup_s"] = ctx.setup_s
     outcome["counters"]["chip_startup_s"] = chip_startup_s
-    metrics = {}
-    for entry, spec, reader in wanted:
-        ctx.tails = []
-        value = reader.read(outcome, ctx, **spec.get("args", {}))
-        if value is not None:
-            metrics[entry["name"]] = {"value": float(value),
-                                      "unit": entry["unit"]}
-            if ctx.tails:
-                # not the whole window's: said beside the value
-                metrics[entry["name"]]["window_from_s"] = max(ctx.tails)
+    metrics = _read(wanted, outcome, ctx)
     for check in outcome["checks"]:
         print("check", json.dumps(check), flush=True)
     result = {
@@ -106,6 +122,8 @@ def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
         "device": device.describe(
             devices, outcome["counters"]["memory_peak_bytes"]),
     }
+    if not trace:
+        result["per_layer"] = _read(beside, outcome, ctx)
     reduced = outcome.get("trace")
     if trace and reduced is not None:
         from benchmark.trace import reduce
@@ -116,6 +134,12 @@ def run_cell(man, cell_name, seed, seconds, trace, devices, peaks,
             "device_ops": reduce.top(reduced["ops"]),
             "idle_gaps": reduce.top(reduced["idle_gaps"]),
         }
+    # last in the line: each number compared, beside its limit (one that
+    # is not finite as text: the line stays plain JSON)
+    result["compared"] = {
+        c["name"]: {"value": c["value"] if math.isfinite(c["value"])
+                    else repr(c["value"]), "limit": c["limit"]}
+        for c in outcome["checks"]}
     return result
 
 
@@ -155,6 +179,9 @@ def main(argv=None):
                       bool(args.trace), devices, peaks, scratch,
                       chip_startup_s=chip_startup_s)
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     return 0
 
 

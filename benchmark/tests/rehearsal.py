@@ -32,13 +32,14 @@ def toy_files():
     return out
 
 
-def shrink(traffic):
+def shrink(traffic, outputs=(2, 6)):
     t = copy.deepcopy(traffic)
     if "seq_len" in t:
         t.update(seq_len=16, batches=4)
     for cls in t.get("classes", []):
         cls["prompt_len"] = {"dist": "uniform", "min": 3, "max": 28}
-        cls["output_len"] = {"dist": "uniform", "min": 2, "max": 6}
+        cls["output_len"] = {"dist": "uniform", "min": outputs[0],
+                             "max": outputs[1]}
     if "arrival" in t:
         t["arrival"]["rate_per_s"] = 20.0
     if "requests" in t:
@@ -52,7 +53,7 @@ def shrink(traffic):
 
 
 def rehearse(monkeypatch, tmp_path, cell_name, seed=7, seconds=1.5,
-             trace=False):
+             trace=False, outputs=(2, 6)):
     import jax
 
     from benchmark import run
@@ -65,7 +66,7 @@ def rehearse(monkeypatch, tmp_path, cell_name, seed=7, seconds=1.5,
             c["file"] = toys[json.load(f).get("family")]
     real = manifest.traffic_of
     monkeypatch.setattr(manifest, "traffic_of",
-                        lambda cell: shrink(real(cell)))
+                        lambda cell: shrink(real(cell), outputs))
     chips = manifest.cell(man, cell_name)["chips"]
     return run.run_cell(man, cell_name, seed, seconds, trace,
                         jax.devices()[:chips], PEAKS, str(tmp_path),

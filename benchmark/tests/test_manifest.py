@@ -64,11 +64,15 @@ def test_no_per_layer_entry_is_a_copy_of_another():
     """Every entry's file exists, and no two entries that move the same
     end-to-end metric have identical files: such a pair is ONE entry
     whose ``workloads`` lists both cells (the list holds at most 128)."""
-    man = manifest.load()
-    assert len(man["per_layer"]) < 80
+    _no_copies(manifest.load())
+
+
+def _no_copies(man, root=ROOT):
+    assert len(man["per_layer"]) <= 128, len(man["per_layer"])
+    metrics = os.path.join(root, "benchmark", "metrics")
     seen = {}
     for entry in man["per_layer"]:
-        path = os.path.join(HERE, "metrics", entry["name"] + ".json")
+        path = os.path.join(metrics, entry["name"] + ".json")
         assert os.path.isfile(path), entry["name"]
         with open(path) as f:
             spec = json.load(f)
@@ -77,7 +81,78 @@ def test_no_per_layer_entry_is_a_copy_of_another():
         seen[key] = entry["name"]
     listed = {e["name"] + ".json" for g in ("end_to_end", "per_layer")
               for e in man[g]}
-    assert set(os.listdir(os.path.join(HERE, "metrics"))) == listed
+    assert set(os.listdir(metrics)) == listed
+
+
+def test_a_fifth_serving_cell_joins_by_data_alone(tmp_path, monkeypatch):
+    """On a copy of the benchmark: a cell appended to ``workloads``, to
+    its end-to-end metric's list and to every shared per-layer list, with
+    one entry and file of its own, resolves like the cells that are there;
+    one entry past the driver's 128 does not."""
+    import shutil
+
+    copy = tmp_path / "benchmark"
+    shutil.copytree(HERE, copy, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    man = manifest.load()
+    # the end-to-end metric several cells share, and the first of them
+    rate = next(m for m in man["end_to_end"]
+                if len(m.get("workloads", [])) > 1)
+    closed = list(rate["workloads"])
+    donor = manifest.cell(man, closed[0])
+    shared = [m["name"] for m in man["per_layer"]
+              if m.get("workloads") == closed]
+    assert len(shared) > 10
+    new = dict(donor, name="fifth_serve_cell", traffic="fifth")
+    shutil.copy(copy / "traffic" / (donor["traffic"] + ".json"),
+                copy / "traffic" / "fifth.json")
+    shutil.copy(copy / "limits" / (donor["name"] + ".json"),
+                copy / "limits" / "fifth_serve_cell.json")
+
+    def own_entry(name):
+        entry = {"name": name, "unit": "count", "better": "lower",
+                 "source": "program_counter", "layer": "device",
+                 "moves": rate["name"], "workloads": [new["name"]]}
+        spec = {k: entry[k] for k in ("unit", "layer", "moves", "source")}
+        spec.update(reader="counter", args={"counter": name})
+        (copy / "metrics" / (name + ".json")).write_text(json.dumps(spec))
+        return entry
+
+    own = own_entry("passes_a_block.fifth")
+    grown = json.loads(json.dumps(man))
+    grown["workloads"].append(new)
+    for m in grown["end_to_end"] + grown["per_layer"]:
+        if m.get("workloads") == closed:
+            m["workloads"].append(new["name"])
+    grown["per_layer"].append(own)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown))
+
+    monkeypatch.setattr(manifest, "HERE", str(copy))
+    got = manifest.load(str(tmp_path))
+    cell = manifest.cell(got, new["name"])
+    assert manifest.config_of(got, cell, str(tmp_path))
+    assert manifest.kind_of(manifest.traffic_of(cell)).run
+
+    def names(cell_name, group="per_layer"):
+        return [e["name"] for e, _, _ in
+                manifest.metrics_for(got, cell_name, group)]
+
+    assert names(new["name"], "end_to_end") == names(donor["name"],
+                                                     "end_to_end")
+    theirs = set(names(donor["name"]))
+    mine = set(names(new["name"]))
+    assert set(shared) <= mine and set(shared) <= theirs
+    assert mine - theirs == {own["name"]}
+    _no_copies(got, str(tmp_path))
+
+    # the driver's cap: 128 entries pass, one more does not
+    while len(got["per_layer"]) < 129:
+        got["per_layer"].append(
+            own_entry(f"filler_{len(got['per_layer'])}.fifth"))
+    with pytest.raises(AssertionError, match="129"):
+        _no_copies(got, str(tmp_path))
+    os.remove(copy / "metrics" / (got["per_layer"].pop()["name"] + ".json"))
+    _no_copies(got, str(tmp_path))
 
 
 @pytest.mark.parametrize("bad", ["", "a b", "a,b", "a/b", "-a", ".a",

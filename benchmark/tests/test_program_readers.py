@@ -229,7 +229,7 @@ def test_a_tail_is_marked_in_the_result_line(monkeypatch, tmp_path):
                              trace=True)["metrics"]
     per_step = [n for n in got if n.startswith(
         ("decode_step_p50_ms", "step_wait_p50_ms", "step_dispatch_p50_ms",
-         "kv_live_positions_mean", "steps_overlapped_share"))]
+         "kv_live_positions_mean", "steps_fed_share"))]
     assert len(per_step) == 5
     for name in per_step:
         assert 0.0 < got[name]["window_from_s"] < 1.5, name
@@ -260,7 +260,7 @@ def test_a_traced_serving_run_reports_the_engines_own_numbers(
     from_hist = [e["name"] for e, spec, _ in
                  manifest.metrics_for(man, cell, "per_layer")
                  if spec["reader"] == "program_hist"]
-    assert len(from_hist) == 5
+    assert len(from_hist) == 4
     for name in from_hist:
         assert got[name]["value"] > 0 and got[name]["unit"] == "ms", name
     # the step's two halves lie inside the step the engine times itself

@@ -164,30 +164,19 @@ def _host_metrics(cell):
 
 
 SERVING = [c["name"] for c in MAN["workloads"] if _host_metrics(c["name"])]
-SHRINK = rehearsal.shrink
-
-
-def _longer_replies(traffic):
-    """The toy mix's replies of 2-6 tokens end a request nearly every step,
-    so nearly every scheduling pass runs a prefill, and a launch is
-    sampled as fed or drained only in a pass that runs none."""
-    t = SHRINK(traffic)
-    for cls in t.get("classes", []):
-        cls["output_len"] = {"dist": "uniform", "min": 16, "max": 30}
-    return t
-
-
 @pytest.mark.parametrize("cell", SERVING)
 def test_a_rehearsed_serving_cell_reports_the_hosts_numbers(
         monkeypatch, tmp_path, cell):
-    """They come out of a traced rehearsal with a value each (traced: the
-    harness reads per-layer metrics in traced runs alone), and none is a
-    tail's."""
+    """They come out of an UNTRACED rehearsal with a value each (they need
+    no trace, so every run reads them, beside its end-to-end metrics), and
+    none is a tail's.  The toy mix's replies of 2-6 tokens end a request
+    nearly every step, so nearly every scheduling pass runs a prefill, and
+    a launch is sampled as fed or drained only in a pass that runs none:
+    longer replies."""
     wanted = _host_metrics(cell)
-    assert len(wanted) == 3, wanted
-    monkeypatch.setattr(rehearsal, "shrink", _longer_replies)
+    assert len(wanted) == 4, wanted
     got = rehearsal.rehearse(monkeypatch, tmp_path, cell, seed=39,
-                             trace=True)["metrics"]
+                             outputs=(16, 30))["per_layer"]
     for name in wanted:
         assert name in got, (name, sorted(got))
         assert got[name]["value"] >= 0.0
