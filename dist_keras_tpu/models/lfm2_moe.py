@@ -139,6 +139,11 @@ def vocab(cfg):
     return int(cfg["vocab_size"])
 
 
+def step_width(cfg):
+    """Positions a slot a step: one token."""
+    return 1
+
+
 def cache_pools(cfg):
     """What the engine allocates, ``(layers spanned, "page" or
     "sequence", entry)`` a pool: the ``v | k`` pool, paged, over the

@@ -131,6 +131,11 @@ def cache_entry_shapes(cfg):
     return ((-(-width // LANES) * LANES,),)
 
 
+def step_width(cfg):
+    """Positions a slot a step: one token."""
+    return 1
+
+
 def cache_pools(cfg):
     """What the engine allocates: the one latent pool, paged, a row a
     cached position in every layer."""
@@ -266,11 +271,13 @@ def held_experts(experts, x, idx, w, first_held, valid):
             jnp.sum(chosen, (0, 1), dtype=jnp.int32))
 
 
-def moe_layer(moe, x, cfg, valid):
+def moe_layer(moe, x, cfg, valid, router=route):
     """-> (the layer's output for ``x (N, d)``, routing counts).  A
-    layer whose ``moe`` holds no ``"shared"`` has no shared expert."""
+    layer whose ``moe`` holds no ``"shared"`` has no shared expert;
+    ``router`` is the family's (``models/sdar_moe.py`` routes by a
+    softmax)."""
     with jax.named_scope("moe_route"):
-        idx, w = route(moe, x, cfg)
+        idx, w = router(moe, x, cfg)
     with jax.named_scope("moe_experts"):
         y, sizes = held_experts(moe["experts"], x, idx, w,
                                 cfg["held_experts"][0], valid)

@@ -148,6 +148,11 @@ def _widths(cfg):
     return h, dk, dv, h * (2 * dk + dv)
 
 
+def step_width(cfg):
+    """Positions a slot a step: one token."""
+    return 1
+
+
 def cache_pools(cfg):
     """What the engine allocates, ``(layers spanned, "page" or
     "sequence", entry)`` a pool: the ``v | k`` pool, paged, over the

@@ -249,6 +249,11 @@ def vocab(cfg):
     return int(cfg["n_classes"])
 
 
+def step_width(cfg):
+    """Positions a slot a step: one token."""
+    return 1
+
+
 def cache_pools(cfg):
     """What the engine allocates, ``(layers spanned, "page" or
     "sequence", entry)`` a pool: the one ``v | k`` pool, paged, a row of

@@ -400,6 +400,18 @@ KNOWN_METRICS = {
     "decode.state.live_rows": "histogram",
     "prefill.scan_positions": "histogram",
     "prefill.scan_padded_positions": "histogram",
+    # generation in blocks (models/sdar_moe.py; the engine's passes over
+    # blocks): a stamped sample a PASS of the slots in it, the masked
+    # positions it fixed over all of them and the share of its slots whose
+    # pass committed their block (percent); a sample a pass that committed
+    # blocks of the passes they took, their commits among them (the mean
+    # over those blocks); and the tokens a request's last block computed
+    # beyond its max_new_tokens
+    "decode.block.slots": "histogram",
+    "decode.block.tokens_fixed": "histogram",
+    "decode.block.commit_share": "histogram",
+    "decode.block.passes": "histogram",
+    "decode.block.tokens_trimmed": "counter",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

@@ -39,26 +39,34 @@ from dist_keras_tpu.parallel.mesh import SEQ_AXIS
 _NEG_INF = -1e30
 
 
-def attention(q, k, v, causal=False, scale=None):
-    """Reference attention. q,k,v: (B, T, H, D) -> (B, T, H, D)."""
+def attention(q, k, v, causal=False, scale=None, mask_block=1):
+    """Reference attention. q,k,v: (B, T, H, D) -> (B, T, H, D).  With
+    ``mask_block`` over 1 the causal mask is BLOCK-causal: position ``t``
+    sees ``s`` iff ``s // mask_block <= t // mask_block`` (generation by
+    diffusion over blocks: a block's positions all see one another)."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     logits = jnp.einsum("bthd,bshd->bhts", q, k) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if mask_block == 1:
+            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        else:
+            mask = ((tk - tq + jnp.arange(tq))[:, None] // mask_block
+                    >= jnp.arange(tk)[None, :] // mask_block)
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
 def attention_with_lse(q, k, v, causal=False, scale=None, q_offset=0,
-                       kv_offset=0):
+                       kv_offset=0, mask_block=1):
     """Attention returning (out (B,T,H,D), lse (B,H,T) float32).
 
     ``q_offset``/``kv_offset`` shift the global positions used by the
-    causal mask (sequence-parallel blocks).  Fully-masked rows produce a
-    zero output row and lse = -1e30 (finite, so downstream logaddexp
+    causal mask (sequence-parallel blocks), which is block-causal with
+    ``mask_block`` over 1 (:func:`attention`).  Fully-masked rows produce
+    a zero output row and lse = -1e30 (finite, so downstream logaddexp
     merges stay NaN-free).
     """
     d = q.shape[-1]
@@ -69,6 +77,8 @@ def attention_with_lse(q, k, v, causal=False, scale=None, q_offset=0,
         tq, tk = q.shape[1], k.shape[1]
         qpos = q_offset + jnp.arange(tq)
         kpos = kv_offset + jnp.arange(tk)
+        if mask_block != 1:
+            qpos, kpos = qpos // mask_block, kpos // mask_block
         mask = qpos[:, None] >= kpos[None, :]
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)

@@ -97,19 +97,29 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _causal_mask(logits, qi, ki, block_q, block_k, q_offset, kv_offset):
+def _causal_mask(logits, qi, ki, block_q, block_k, q_offset, kv_offset,
+                 mask_block=1):
+    """``mask_block`` over 1: the BLOCK-causal mask, a query sees the keys
+    of its own block of ``mask_block`` positions and of every earlier
+    one.  Tiles and offsets that are multiples of ``mask_block`` keep the
+    callers' tile skipping valid as it is: a tile's first key then opens
+    a block, so it is visible to a query iff it is not after it."""
     qpos = (q_offset + qi * block_q
             + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0))
     kpos = (kv_offset + ki * block_k
             + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1))
-    return jnp.where(qpos >= kpos, logits, _NEG_INF)
+    if mask_block == 1:
+        return jnp.where(qpos >= kpos, logits, _NEG_INF)
+    return jnp.where(qpos // mask_block >= kpos // mask_block, logits,
+                     _NEG_INF)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, q_offset, kv_offset):
+                *, scale, causal, block_q, block_k, q_offset, kv_offset,
+                mask_block=1):
     qi, ki = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -137,7 +147,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             preferred_element_type=jnp.float32) * scale    # (BQ, BK) f32
         if causal:
             logits = _causal_mask(logits, qi, ki, block_q, block_k,
-                                  q_offset, kv_offset)
+                                  q_offset, kv_offset, mask_block)
         m_prev = m_scr[...]                          # (BQ, 1)
         m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
         # fully-masked rows inside a visible tile: m_new == -1e30, and
@@ -188,19 +198,22 @@ def _kv_index_map(causal, block_q, block_k, q_offset, kv_offset, group=1):
 
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, q_offset,
-              kv_offset, interpret):
+              kv_offset, interpret, mask_block=1):
     """q: (BH, Tq, D), k: (BHkv, Tk, D), v: (BHkv, Tk, Dv) -> (out (BH,
     Tq, Dv), lse (BH, Tq, 1)).  The values may be narrower or wider than
     the queries and keys (latent attention: 192 and 128), and there may
     be fewer K/V heads than query heads (grouped-query attention: ``BH /
     BHkv`` consecutive query heads read one K/V head, fetched through the
     index map, never repeated in memory); the backward is written for
-    ``Dv == D`` and ``BHkv == BH`` only."""
+    ``Dv == D`` and ``BHkv == BH`` only, and for ``mask_block == 1``
+    (the plain causal mask; over 1 it is block-causal, tiles and offsets
+    multiples of it: :func:`_causal_mask`)."""
     bh, tq, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
+        block_k=block_k, q_offset=q_offset, kv_offset=kv_offset,
+        **({} if mask_block == 1 else {"mask_block": mask_block}))
     kv_map = _kv_index_map(causal, block_q, block_k, q_offset, kv_offset,
                            group=bh // k.shape[0])
     return pl.pallas_call(
@@ -452,23 +465,36 @@ def _from_bh(x, b, h):
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              block_q=1024, block_k=1024, q_offset=0,
-                             kv_offset=0, interpret=False):
+                             kv_offset=0, interpret=False, mask_block=1):
     """q,k,v: (B, T, H, D) -> (out (B,T,H,D), lse (B,H,T) float32); k
     and v may have fewer heads, a divisor of H (grouped-query attention).
 
     Falls back to the jnp reference when T doesn't tile evenly (rare;
     tests and ragged tails).  Offsets shift the *global* positions of the
     local q / kv blocks for causal masking under sequence parallelism.
+    ``mask_block`` over 1 makes the causal mask block-causal
+    (:func:`_causal_mask`; forward only, and the reference where a tile
+    or an offset is no multiple of it).
     """
     b, tq, h, d = q.shape
     tk = k.shape[1]
     scale = (d ** -0.5) if scale is None else scale
     bq = _fit_block(tq, block_q)
     bk = _fit_block(tk, block_k)
+    if mask_block != 1 and bq is not None and bk is not None and any(
+            n % mask_block for n in (bq, bk, q_offset, kv_offset)):
+        bq = None
     if bq is None or bk is None:
         return _ref_with_lse(q, *repeat_kv_heads(h, k, v), causal=causal,
                              scale=scale, q_offset=q_offset,
-                             kv_offset=kv_offset)
+                             kv_offset=kv_offset,
+                             **({} if mask_block == 1
+                                else {"mask_block": mask_block}))
+    if mask_block != 1:
+        out, lse = _fwd_call(_to_bh(q), _to_bh(k), _to_bh(v), causal,
+                             scale, bq, bk, int(q_offset), int(kv_offset),
+                             interpret, mask_block)
+        return _from_bh(out, b, h), lse.reshape(b, h, tq)
     if v.shape[-1] != d or k.shape[2] != h:
         # values of another width than queries and keys, or fewer K/V
         # heads than query heads: forward only (the custom backward
@@ -494,23 +520,26 @@ def repeat_kv_heads(h, k, v):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=1024,
-                    block_k=1024, interpret=False):
+                    block_k=1024, interpret=False, mask_block=1):
     """Pallas attention. q,k,v: (B, T, H, D) -> (B, T, H, D)."""
     out, _ = flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
                                       block_q=block_q, block_k=block_k,
-                                      interpret=interpret)
+                                      interpret=interpret,
+                                      mask_block=mask_block)
     return out
 
 
 def attention_auto(q, k, v, causal=False, scale=None, block_q=1024,
-                   block_k=1024):
+                   block_k=1024, mask_block=1):
     """Backend-dispatching attention: Pallas kernel on TPU, jnp reference
     elsewhere.  Decided at trace time via ``jax.default_backend()`` so it
-    works under jit/shard_map (tracers carry no device info)."""
+    works under jit/shard_map (tracers carry no device info).
+    ``mask_block`` is the causal mask's block length (1: plain causal)."""
     if use_pallas():
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               mask_block=mask_block)
     from dist_keras_tpu.ops.attention import attention
 
     return attention(q, *repeat_kv_heads(q.shape[2], k, v), causal=causal,
-                     scale=scale)
+                     scale=scale, mask_block=mask_block)

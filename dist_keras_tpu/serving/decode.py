@@ -145,6 +145,7 @@ from dist_keras_tpu.models import (
     lfm2_moe,
     mla_moe,
     olmo_hybrid,
+    sdar_moe,
     transformer,
 )
 from dist_keras_tpu.observability import events, metrics, perf, spans
@@ -175,7 +176,8 @@ class _Sequence:
     __slots__ = ("sid", "tokens", "prompt_len", "max_new", "eos_id",
                  "future", "on_token", "t", "tw", "ctx", "params",
                  "params_host", "pages", "row", "kv_len", "steps",
-                 "cancelled",
+                 "cancelled", "prefilled", "block", "block_steps",
+                 "fixed_at", "passes",
                  "ttft_s", "t_first", "deadline", "priority",
                  "recoveries", "finished")
 
@@ -196,8 +198,17 @@ class _Sequence:
         self.params_host = params_host  # host ref: re-pin on recovery
         self.pages = pages
         self.row = row            # its per-sequence state row, or None
-        self.kv_len = 0           # KV positions written so far
+        self.kv_len = 0           # KV positions written so far (committed)
+        self.prefilled = False    # its prefill has run (reset by recovery)
         self.steps = 0            # decode iterations consumed
+        # a family that generates in blocks: the open block's tokens as of
+        # the last LANDED pass (the mask id where nothing is fixed yet),
+        # the passes it has taken, the pass that fixed each position, and
+        # for every generated token the pass of its block that fixed it
+        self.block = None
+        self.block_steps = 0
+        self.fixed_at = None
+        self.passes = None
         self.cancelled = False
         self.ttft_s = None
         self.t_first = None
@@ -210,7 +221,7 @@ class _Sequence:
         return self.tokens[self.prompt_len:]
 
     def result_doc(self, finish):
-        return {
+        doc = {
             "tokens": list(self.tokens),
             "generated": self.generated(),
             "prompt_len": self.prompt_len,
@@ -219,6 +230,11 @@ class _Sequence:
             "finish": finish,
             "recoveries": self.recoveries,
         }
+        if self.passes is not None:
+            # generation in blocks: for each generated token the pass of
+            # its block that fixed it, counted from 0 (the trajectory)
+            doc["passes"] = list(self.passes)
+        return doc
 
 
 class Generation:
@@ -247,17 +263,22 @@ class Generation:
 # cannot decode), ``cache_pools(cfg)`` (for each pool ``(layers, rows,
 # entry)``: how many layers it spans, whether its rows are ``"page"``s of
 # cached positions or one ``"sequence"`` each, and the trailing shape of
-# one entry), ``prefill_step`` / ``decode_step`` (``(cfg, params, *pools,
-# ...) -> (int32 array, *pools)``: the tokens first, then whatever counts
-# the family sends along; a family with a per-sequence pool is also
-# handed the state rows, last) and ``observe_step(counts, at,
+# one entry), ``step_width(cfg)`` (positions a slot a step: 1 where a
+# step yields one token a sequence; a family that generates in BLOCKS
+# states the block's length, and with ``step_fixes(cfg)`` the id that
+# stands at a block position nothing is fixed at yet and how many such
+# positions a pass fixes), ``prefill_step`` / ``decode_step`` (``(cfg,
+# params, *pools, ...) -> (int32 array, *pools)``: the tokens first, then
+# whatever counts the family sends along; a family with a per-sequence
+# pool is also handed the state rows, last) and ``observe_step(counts, at,
 # lengths=None, page_size=None)`` for those counts (None when the family
 # sends none).
 _FAMILIES = {m.FAMILY: m
-             for m in (transformer, mla_moe, lfm2_moe, olmo_hybrid)}
+             for m in (transformer, mla_moe, lfm2_moe, olmo_hybrid,
+                       sdar_moe)}
 
 
-def _step_views(packed, pmax, state=False):
+def _step_views(packed, pmax, state=False, width=1):
     """The six arrays of a decode step inside its ONE packed int32 array,
     in ``decode_step``'s order.  ``packed`` holds ``rung * (pmax + 5)``
     values: the page tables row by row (``pmax`` entries a slot), then
@@ -279,7 +300,27 @@ def _step_views(packed, pmax, state=False):
     step in flight's output", which is still on the device.  The
     engine's compiled wrapper (``_packed_decode_fn``) resolves it before
     the family's step sees the tokens; no other view depends on a
-    token."""
+    token.
+
+    A family whose step computes ``width`` positions a slot (a BLOCK) is
+    handed seven arrays (eight with ``state``): ``rung * (pmax + width +
+    5)`` values, the tables, then the slots' open blocks (``width``
+    entries a slot, the mask id where nothing is fixed yet), then
+    positions (where each block starts), write pages, write offsets,
+    lengths (``start + width``) and how many masked positions the pass
+    fixes (0: it commits the block).  A block's entries name their
+    source like a token's: ``-(j + 1)`` at block position ``b`` means
+    "block position ``b`` of slot ``j`` of the pass in flight's output".
+    With ``width`` 1 nothing of the above changes."""
+    if width > 1:
+        arrays = 6 + state
+        rung = packed.shape[0] // (pmax + width + arrays - 1)
+        tables = packed[:rung * pmax].reshape(rung, pmax)
+        toks = packed[rung * pmax:rung * (pmax + width)].reshape(rung,
+                                                                 width)
+        positions, wpage, woff, lengths, fix, *rows = \
+            packed[rung * (pmax + width):].reshape(arrays - 1, rung)
+        return (toks, positions, tables, wpage, woff, lengths, fix, *rows)
     arrays = 6 if state else 5
     rung = packed.shape[0] // (pmax + arrays)
     tables = packed[:rung * pmax].reshape(rung, pmax)
@@ -325,10 +366,10 @@ class _Flight:
     needs besides the host's canonical state, which it has not touched."""
 
     __slots__ = ("group", "slot", "rung", "out", "lengths", "t0",
-                 "overlapped", "fed", "attempt")
+                 "overlapped", "fed", "attempt", "fix")
 
     def __init__(self, group, rung, out, lengths, t0, overlapped, fed,
-                 attempt):
+                 attempt, fix=None):
         self.group = group            # the sequences, slot by slot
         self.slot = {seq: i for i, seq in enumerate(group)}
         self.rung = rung
@@ -343,6 +384,9 @@ class _Flight:
         # there was no predecessor or the pass ran a prefill)
         self.fed = fed
         self.attempt = attempt        # failures this step had before
+        # a pass over blocks: the masked positions each slot's pass fixes
+        # (0: it commits the slot's block); None where a step is a token
+        self.fix = fix
 
 
 class _DecodeReplica:
@@ -454,6 +498,12 @@ class DecodeEngine:
         # this line sees pools and steps, no family
         self._family = _FAMILIES[cfg.get("family", transformer.FAMILY)]
         self.vocab = self._family.vocab(cfg)
+        # positions a slot a step: 1, or the length of the blocks the
+        # family generates in (then: the id of a position nothing is
+        # fixed at yet, and the masked positions a pass fixes)
+        self._width = int(self._family.step_width(cfg))
+        self._mask_id, self._fix_a_pass = (
+            self._family.step_fixes(cfg) if self._width > 1 else (None, 0))
         self.seq_len = int(cfg["seq_len"])
         self._host_params = model.params
 
@@ -474,6 +524,11 @@ class DecodeEngine:
         self.max_new_default = int(max_new_default)
         self.eos_id = eos_id if eos_id is None else int(eos_id)
         self.page_size = int(page_size)
+        if self.page_size % self._width or self.seq_len % self._width:
+            raise ValueError(
+                f"page_size={page_size} and the model's seq_len "
+                f"({self.seq_len}) must be whole blocks of "
+                f"{self._width} positions: a block never straddles a page")
         self.max_pages_per_seq = -(-self.seq_len // self.page_size)
         if num_pages is None:
             num_pages = self.max_slots * self.max_pages_per_seq
@@ -547,6 +602,7 @@ class DecodeEngine:
         self._n_errors = 0
         self._n_cancelled = 0
         self._n_tokens = 0
+        self._n_steps = 0
         self._n_quarantines = 0
         self._n_recovered = 0
         self._n_shed = 0
@@ -572,6 +628,8 @@ class DecodeEngine:
         self._reg_overlapped = metrics.histogram("decode.step_overlapped")
         self._reg_fed = metrics.histogram("decode.launch_fed")
         self._reg_discarded = metrics.counter("decode.tokens_discarded")
+        self._reg_block_passes = metrics.histogram("decode.block.passes")
+        self._reg_trimmed = metrics.counter("decode.block.tokens_trimmed")
         self._reg_prefill = metrics.histogram("decode.prefill_s")
         self._reg_queue_wait = metrics.histogram("decode.queue_wait_s")
         self._reg_active = metrics.gauge("decode.active")
@@ -626,6 +684,8 @@ class DecodeEngine:
         tokens padded to the top rung, then the family's counts), so a
         step carries any rung's output into the one program of its own."""
         *pools, carried, packed = args
+        if self._width > 1:
+            return self._packed_pass_fn(params, pools, carried, packed)
         toks, *rest = _step_views(packed, self.max_pages_per_seq,
                                   self._state)
         top, rung = self.max_slots, toks.shape[0]
@@ -637,6 +697,28 @@ class DecodeEngine:
             out = jnp.concatenate(
                 [out[:rung], jnp.zeros((top - rung,), out.dtype),
                  out[rung:]])
+        return (out, *pools)
+
+    def _packed_pass_fn(self, params, pools, carried, packed):
+        """:meth:`_packed_decode_fn` where a step is a pass over blocks of
+        ``width`` positions: the same resolution a block position at a
+        time (entry ``-(j + 1)`` at position ``b`` takes position ``b``
+        of slot ``j``'s block in ``carried``), and an output that holds
+        the blocks' tokens padded to the top rung's, then the counts."""
+        width = self._width
+        toks, *rest = _step_views(packed, self.max_pages_per_seq,
+                                  self._state, width)
+        top, rung = self.max_slots, toks.shape[0]
+        with jax.named_scope("carried_tokens"):
+            source = (jnp.clip(-toks - 1, 0, top - 1) * width
+                      + jnp.arange(width, dtype=jnp.int32))
+            toks = jnp.where(toks < 0, carried[source], toks)
+        out, *pools = self._decode_fn(params, *pools, toks, *rest)
+        if rung < top:
+            out = jnp.concatenate(
+                [out[:rung * width],
+                 jnp.zeros(((top - rung) * width,), out.dtype),
+                 out[rung * width:]])
         return (out, *pools)
 
     def _decode_out_width(self, params):
@@ -652,8 +734,9 @@ class DecodeEngine:
             self._decode_fn, params,
             *(jax.ShapeDtypeStruct(shape, jnp.float32)
               for shape in self.pool_shapes),
-            ints(top), ints(top), ints(top, self.max_pages_per_seq),
-            *(ints(top),) * (3 + self._state))
+            ints(top, self._width) if self._width > 1 else ints(top),
+            ints(top), ints(top, self.max_pages_per_seq),
+            *(ints(top),) * (3 + (self._width > 1) + self._state))
         return out.shape[0]
 
     @property
@@ -766,7 +849,7 @@ class DecodeEngine:
             raise ValueError(
                 f"prompt length {len(toks)} exceeds the prefill "
                 f"ladder (max {self.prefill_ladder[-1]})")
-        total = len(toks) + max_new
+        total = self._positions_for(len(toks), max_new)
         if total > self.seq_len:
             raise ValueError(
                 f"prompt + max_new_tokens = {total} exceeds the "
@@ -809,7 +892,8 @@ class DecodeEngine:
             if deadline_s is not None \
                     and self._ewma_prefill is not None \
                     and self._ewma_step is not None:
-                est = self._ewma_prefill + max_new * self._ewma_step
+                est = self._ewma_prefill + self._ewma_step \
+                    * self._steps_for(len(toks), max_new)
                 if est > deadline_s:
                     self._n_rejected += 1
                     self._reg_rejected.inc()
@@ -849,6 +933,27 @@ class DecodeEngine:
                     max_new=max_new, replica=rep.index,
                     pages=len(pages))
         return Generation(self, seq)
+
+    def _positions_for(self, prompt_len, max_new):
+        """The positions a request may come to hold, its reservation: a
+        family that generates in blocks computes (and reserves) whole
+        blocks, whatever of the last one is returned."""
+        total = prompt_len + max_new
+        return -(-total // self._width) * self._width
+
+    def _steps_for(self, prompt_len, max_new):
+        """The decode steps a request takes after its prefill: one a token
+        or, in blocks, the PASSES of its ``ceil((tail + max_new) /
+        width)`` blocks: as many as fix the block's masks (fewer in the
+        first block, which opens holding the prompt's tail) and one that
+        commits it."""
+        width, fix = self._width, self._fix_a_pass
+        if width == 1:
+            return max_new
+        tail = prompt_len % width
+        blocks = -(-(tail + max_new) // width)
+        return (-(-(width - tail) // fix) + (blocks - 1) * (width // fix)
+                + blocks)
 
     def _row_of(self, rep, sid):
         """The state row ``alloc`` reserved with a sequence's pages."""
@@ -960,6 +1065,34 @@ class DecodeEngine:
             return "length"
         return None
 
+    def _first_token(self, seq):
+        """``seq``'s first generated token is going out: its TTFT."""
+        seq.ttft_s = time.monotonic() - seq.t
+        seq.t_first = time.time()
+        ex = ((seq.ctx.trace_id, seq.ctx.span_id)
+              if seq.ctx is not None else None)
+        self._m_ttft.observe(seq.ttft_s, exemplar=ex)
+        self._reg_ttft.observe(seq.ttft_s, exemplar=ex)
+
+    def _prefill_len(self, seq):
+        """The tokens a sequence's prefill is shown: its prompt.  In
+        blocks a RECOVERED sequence is shown its committed tokens too (one
+        block-causal prefill rebuilds their K/V; committed blocks are
+        whole, so only a prompt's tail is ever left to the open block),
+        unless they pass the prefill ladder: then the prompt, and commit
+        passes over the blocks the host knows catch up."""
+        if self._width > 1 and self._rung_for(
+                len(seq.tokens), self.prefill_ladder) is not None:
+            return len(seq.tokens)
+        return seq.prompt_len
+
+    def _open_block(self, seq, start):
+        """The block that opens at position ``start`` as the host knows
+        it: the tokens it has there (the prompt's tail; a recovered
+        sequence's committed tokens) and the mask id behind them."""
+        known = seq.tokens[start:start + self._width]
+        return known + [self._mask_id] * (self._width - len(known))
+
     def _prefill(self, rep, seq, rung, leads):
         """Run one admitted prompt through its prefill ``rung``; emits
         the first generated token (TTFT) or fails the sequence typed.
@@ -992,16 +1125,27 @@ class DecodeEngine:
         A RECOVERED sequence (``seq.tokens`` longer than the prompt)
         replays the same prefill over the prompt only — its prediction
         is a token the stream already delivered, so it is discarded
-        and the teacher-forced decode steps replay the rest."""
+        and the teacher-forced decode steps replay the rest.
+
+        **In blocks** a prefill commits the K/V of the prompt's WHOLE
+        blocks (``kv_len`` may stay 0: ``seq.prefilled`` says it ran),
+        yields no token (the TTFT is taken when the first block commits,
+        :meth:`_land`) and leaves the open block as the host knows it:
+        the prompt's tail and mask ids (:meth:`_open_block`;
+        :meth:`_prefill_len` for what a recovered sequence is shown)."""
         with contextlib.ExitStack() as region:
             region.enter_context(
                 perf.phase("decode.prefill", sid=seq.sid, rung=rung))
             with perf.phase("decode.prefill.build"):
-                n, ps = seq.prompt_len, self.page_size
+                ps = self.page_size
+                shown = self._prefill_len(seq)
+                # what the prefill commits: the whole prompt, or (in
+                # blocks) its whole blocks; the tail is the open block's
+                n = shown - shown % self._width
                 packed = np.zeros((3 * rung + 1 + self._state,), np.int32)
                 toks, _, page_idx, page_off, *_ = _prefill_views(
                     packed, self._state)
-                toks[:n] = seq.tokens[:n]
+                toks[:shown] = seq.tokens[:shown]
                 # position t goes to page t // ps; the padding to the
                 # scratch
                 page_idx[:n] = np.repeat(seq.pages, ps)[:n]
@@ -1025,16 +1169,18 @@ class DecodeEngine:
                     first, *rep.pools = self._prefill_jit(
                         seq.params, *rep.pools, _to_device(packed))
                 if rep.flight is not None or (leads and any(
-                        other.kv_len for other in rep.active)):
+                        other.prefilled for other in rep.active)):
                     region.close()
                     self._advance(rep, launch=leads)
                     t0 = max(t0, rep.landed_at)
                     region.enter_context(perf.phase(
                         "decode.prefill", sid=seq.sid, rung=rung))
                 with perf.phase("decode.prefill.wait"):
-                    # the token, then whatever counts the family sends
-                    first, *counts = np.asarray(first).reshape(-1)
-                    first = int(first)
+                    # the token (a prefill in blocks yields none), then
+                    # whatever counts the family sends
+                    counts = np.asarray(first).reshape(-1)
+                    if self._width == 1:
+                        first, counts = int(counts[0]), counts[1:]
             except _ReplicaDead:
                 raise
             # dklint: ignore[broad-except] a failed prefill lands TYPED on its own future with pages reclaimed
@@ -1055,25 +1201,26 @@ class DecodeEngine:
             self._ewma_prefill = (
                 dt if self._ewma_prefill is None
                 else 0.8 * self._ewma_prefill + 0.2 * dt)
-        seq.kv_len = seq.prompt_len
-        if not replay:
-            seq.ttft_s = time.monotonic() - seq.t
-            seq.t_first = time.time()
-            ex = ((seq.ctx.trace_id, seq.ctx.span_id)
-                  if seq.ctx is not None else None)
-            self._m_ttft.observe(seq.ttft_s, exemplar=ex)
-            self._reg_ttft.observe(seq.ttft_s, exemplar=ex)
+        seq.kv_len, seq.prefilled = n, True
+        if self._width > 1:
+            seq.block = self._open_block(seq, n)
+            seq.block_steps, seq.fixed_at = 0, [None] * self._width
+            if seq.passes is None:
+                seq.passes = []
+        elif not replay:
+            self._first_token(seq)
         if events.enabled():
             spans.span_at("serve.prefill", seq.ctx, tw0, time.time(),
                           rung=rung, replica=rep.index)
         events.emit("decode_prefill", sid=seq.sid, rung=rung,
                     replica=rep.index, duration_s=dt,
                     ttft_s=seq.ttft_s, replay=replay)
-        if replay:
+        if replay or self._width > 1:
             # the first generated token was emitted before the crash;
             # the replayed prediction is that same token (greedy,
             # pinned params) — discard it, the canonical seq.tokens
-            # drive the teacher-forced catch-up steps
+            # drive the teacher-forced catch-up steps.  A prefill in
+            # blocks has no token to emit: its passes follow
             return
         self._emit_token(seq, first)
         finish = self._sequence_done(seq, first)
@@ -1163,13 +1310,18 @@ class DecodeEngine:
         if err is not None:
             self._step_failed(rep, blamed, err)
 
-    @staticmethod
-    def _ends_with(flight, seq):
+    def _ends_with(self, flight, seq):
         """Will ``seq`` reach its ``max_new`` when ``flight`` lands?  Known
         from its count alone (the step's prediction is a NEW token unless
-        a recovered sequence is still catching up)."""
-        return (seq in flight.slot
-                and seq.kv_len + 1 >= len(seq.tokens)
+        a recovered sequence is still catching up).  In blocks: when the
+        pass in flight COMMITS the block that holds its last token."""
+        if seq not in flight.slot:
+            return False
+        if self._width > 1:
+            return (flight.fix[flight.slot[seq]] == 0
+                    and seq.kv_len + self._width - seq.prompt_len
+                    >= seq.max_new)
+        return (seq.kv_len + 1 >= len(seq.tokens)
                 and len(seq.tokens) + 1 - seq.prompt_len >= seq.max_new)
 
     def _launch(self, rep, group, rung, prev):
@@ -1185,10 +1337,13 @@ class DecodeEngine:
         with perf.phase("decode.step.build"):
             ps = self.page_size
             pmax = self.max_pages_per_seq
-            packed = np.zeros((rung * (pmax + 5 + self._state),),
-                              np.int32)
+            width = self._width
+            wide = width > 1          # a pass over blocks, not a token
+            packed = np.zeros(
+                (rung * (pmax + 5 + wide * width + self._state),), np.int32)
             toks, positions, tables, wpage, woff, lengths, *rows = \
-                _step_views(packed, pmax, self._state)
+                _step_views(packed, pmax, self._state, width)
+            fix = rows.pop(0) if wide else None
             wpage[:] = rep.cache.scratch_page
             if self._state:
                 # a padding slot's state goes to the scratch row
@@ -1197,14 +1352,19 @@ class DecodeEngine:
             ahead = prev.slot if prev is not None else {}
             for i, seq in enumerate(group):
                 j = ahead.get(seq)
-                at = seq.kv_len + (j is not None)
-                toks[i] = (seq.tokens[at] if at < len(seq.tokens)
-                           else -(j + 1))
+                if wide:
+                    at, block, fix[i] = self._next_pass(
+                        seq, None if j is None else prev.fix[j])
+                    toks[i] = -(j + 1) if block is None else block
+                else:
+                    at = seq.kv_len + (j is not None)
+                    toks[i] = (seq.tokens[at] if at < len(seq.tokens)
+                               else -(j + 1))
                 positions[i] = at
                 tables[i, :len(seq.pages)] = seq.pages
                 wpage[i] = seq.pages[at // ps]
                 woff[i] = at % ps
-                lengths[i] = at + 1
+                lengths[i] = at + width
         t0 = time.perf_counter()
         fault_point("decode.step")
         perf.count_dispatch()
@@ -1218,9 +1378,33 @@ class DecodeEngine:
         fed = (None if prev is None or rep.prefilling
                else _still_running(prev.out))
         flight = _Flight(group, rung, out, lengths, t0, prev is not None,
-                         fed, rep.attempt)
+                         fed, rep.attempt, fix)
         rep.attempt = 0
         return flight
+
+    def _next_pass(self, seq, flying):
+        """What ``seq``'s next pass computes, as the host knows without
+        the tokens of the pass in flight (``flying``: how many positions
+        that pass fixes for it, 0 a commit, None when it has none in
+        flight) -> (where the open block starts, its tokens or None when
+        they are the output of the pass in flight, how many masked
+        positions to fix: 0 commits).  The schedule is static
+        (``low_confidence_static``): a pass fixes a known NUMBER of
+        positions, so the count of masks left is arithmetic; which
+        positions, and with what, only the device knows until the pass
+        lands."""
+        if flying is None:
+            start, block = seq.kv_len, seq.block
+            masked = block.count(self._mask_id)
+        elif flying == 0:
+            # the block in flight commits: the next one opens
+            start = seq.kv_len + self._width
+            block = self._open_block(seq, start)
+            masked = block.count(self._mask_id)
+        else:
+            start, block = seq.kv_len, None
+            masked = seq.block.count(self._mask_id) - flying
+        return start, block, min(self._fix_a_pass, masked)
 
     def _land(self, rep, flight, out):
         """``flight``'s output is on the host: account the step, emit its
@@ -1238,6 +1422,7 @@ class DecodeEngine:
         dt = now - t0
         rep.landed_at = now
         rep.steps += 1
+        self._n_steps += 1
         self._m_step.observe(dt, at=t0)
         self._reg_step.observe(dt, at=t0)
         self._reg_overlapped.observe(float(flight.overlapped), at=t0)
@@ -1246,25 +1431,35 @@ class DecodeEngine:
         if self._family.observe_step is not None:
             # the counts came off the device behind the tokens, in the
             # one array the wait already fetched
-            self._family.observe_step(out[self.max_slots:], t0,
-                                      lengths=flight.lengths,
-                                      page_size=self.page_size)
+            self._family.observe_step(
+                out[self.max_slots * self._width:], t0,
+                lengths=flight.lengths, page_size=self.page_size,
+                **({} if flight.fix is None else {"fix": flight.fix}))
         with perf.phase("decode.step.emit"):
             with self._cond:
                 self._shapes.add(("decode", flight.rung))
                 self._ewma_step = (dt if self._ewma_step is None
                                    else 0.8 * self._ewma_step + 0.2 * dt)
             events.emit("decode_step", replica=rep.index, rung=flight.rung,
-                        n=len(flight.group), duration_s=dt)
-            finished, discarded = [], 0
+                        n=len(flight.group), duration_s=dt,
+                        **({} if flight.fix is None
+                           else {"fixed": int(flight.fix.sum())}))
+            finished, discarded, took = [], 0, []
             for i, seq in enumerate(flight.group):
                 if seq.finished:
                     # ended under this step (an eos seen a step late, a
                     # cancel, a deadline): computed and thrown away
                     discarded += 1
                     continue
-                seq.kv_len += 1
                 seq.steps += 1
+                if flight.fix is not None:
+                    finish = self._land_pass(
+                        seq, flight.fix[i],
+                        out[i * self._width:(i + 1) * self._width], took)
+                    if finish is not None:
+                        finished.append((seq, finish))
+                    continue
+                seq.kv_len += 1
                 if seq.kv_len < len(seq.tokens):
                     # replay catch-up: this prediction is a token the
                     # stream already delivered before the crash — discard
@@ -1276,6 +1471,11 @@ class DecodeEngine:
                     finished.append((seq, finish))
             if discarded:
                 self._reg_discarded.inc(discarded)
+            if took:
+                # one sample a pass that committed blocks, their mean: a
+                # sample a block would outrun a histogram's memory (19,600
+                # blocks a 51 s window of the benchmark's cell)
+                self._reg_block_passes.observe(sum(took) / len(took), at=t0)
             if finished:
                 with self._cond:
                     for seq, finish in finished:
@@ -1287,6 +1487,53 @@ class DecodeEngine:
                                 generated=len(seq.generated()),
                                 steps=seq.steps)
                     self._resolve(seq, finish)
+
+    def _land_pass(self, seq, fixes, after, took):
+        """One slot of a landed pass over blocks: ``after`` is ``seq``'s
+        open block as the pass left it -> the sequence's finish, or None
+        (``took`` gains the passes a block took, where this one committed
+        it).
+
+        A denoising pass (``fixes`` > 0) only moves the host's copy of
+        the block on: the positions it fixed are those that held the mask
+        id and hold a token now, and each remembers the pass that fixed
+        it.  NOTHING is emitted: a block's tokens become final out of
+        order, and a cancel or a deadline under an open block returns
+        none of it.  The COMMIT (``fixes`` == 0; its rows in the pool are
+        the block's final ones) advances ``kv_len`` by the block, emits
+        the block's tokens in position order (those the host did not have
+        already: the prompt's tail and a recovered sequence's committed
+        tokens are not emitted again), each with the pass that fixed it,
+        ends the sequence at ``max_new`` or on ``eos_id`` (what the block
+        holds beyond is counted on ``decode.block.tokens_trimmed`` and
+        not emitted), and opens the next block."""
+        width = self._width
+        if fixes:
+            for b in range(width):
+                if seq.block[b] == self._mask_id \
+                        and int(after[b]) != self._mask_id:
+                    seq.block[b] = int(after[b])
+                    seq.fixed_at[b] = seq.block_steps
+            seq.block_steps += 1
+            return None
+        start = seq.kv_len
+        seq.kv_len += width
+        took.append(seq.block_steps + 1)
+        finish, trimmed = None, 0
+        for b in range(len(seq.tokens) - start, width):
+            if finish is not None:
+                trimmed += 1
+                continue
+            if seq.ttft_s is None:
+                self._first_token(seq)
+            seq.passes.append(seq.fixed_at[b])
+            self._emit_token(seq, seq.block[b])
+            finish = self._sequence_done(seq, seq.block[b])
+        if trimmed:
+            self._reg_trimmed.inc(trimmed)
+        seq.block = self._open_block(seq, seq.kv_len)
+        seq.block_steps, seq.fixed_at = 0, [None] * width
+        return finish
 
     def _step_failed(self, rep, blamed, err):
         """A launch or a fetch failed and nothing is in flight any more:
@@ -1387,7 +1634,7 @@ class DecodeEngine:
                     # order: a recovered sequence re-enters here with
                     # kv_len == 0 and replays exactly like a fresh
                     # admission
-                    prefills = [s for s in rep.active if s.kv_len == 0]
+                    prefills = [s for s in rep.active if not s.prefilled]
             for seq, target in o_migrated:
                 self._reg_recovered.inc()
                 events.emit("decode_recover", sid=seq.sid, src=None,
@@ -1411,7 +1658,8 @@ class DecodeEngine:
             rep.prefilling = bool(prefills)
             for i, seq in enumerate(prefills):
                 self._prefill(rep, seq, self._rung_for(
-                    seq.prompt_len, self.prefill_ladder), leads=i == 0)
+                    self._prefill_len(seq), self.prefill_ladder),
+                    leads=i == 0)
                 if rep.killed:
                     raise _ReplicaDead(Overloaded("replica_lost"))
             self._advance(rep)
@@ -1432,7 +1680,7 @@ class DecodeEngine:
         if launch:
             with self._cond:
                 for seq in rep.active:
-                    if seq.kv_len == 0:
+                    if not seq.prefilled:
                         continue  # not prefilled yet: it joins the next pass
                     groups.setdefault(id(seq.params), []).append(seq)
         for group in list(groups.values()) or [[]]:
@@ -1469,7 +1717,7 @@ class DecodeEngine:
         surviving replica with the most free pages that can hold the
         sequence's WORST-CASE reservation — the same door contract as
         submit_generate.  -> the replica, or None when nowhere fits."""
-        total = seq.prompt_len + seq.max_new
+        total = self._positions_for(seq.prompt_len, seq.max_new)
         live = [r for r in self._live_replicas_locked()]
         live.sort(key=lambda r: -r.cache.stats()["free_pages"])
         for rep in live:
@@ -1485,7 +1733,7 @@ class DecodeEngine:
         """Could ANY live replica's whole pool hold this sequence's
         worst-case reservation?  If yes, a full-but-alive pool is a
         capacity wait, not a loss."""
-        total = seq.prompt_len + seq.max_new
+        total = self._positions_for(seq.prompt_len, seq.max_new)
         return any(r.cache.pages_for(total) <= r.cache.num_pages
                    for r in self._live_replicas_locked())
 
@@ -1512,7 +1760,7 @@ class DecodeEngine:
             if target is None:
                 still.append(seq)
                 continue
-            seq.kv_len = 0          # replay regenerates the KV
+            seq.kv_len, seq.prefilled = 0, False  # replay regenerates the KV
             seq.recoveries += 1
             seq.params = target.pin(seq.params_host)
             target.queue.append(seq)
@@ -1582,7 +1830,7 @@ class DecodeEngine:
                     self._account_exit_locked(seq, "error")
                     resolved.append((seq, None, err))
                     continue
-                seq.kv_len = 0          # replay regenerates the KV
+                seq.kv_len, seq.prefilled = 0, False  # replay regenerates the KV
                 seq.recoveries += 1
                 seq.params = target.pin(seq.params_host)
                 target.queue.append(seq)
@@ -1842,6 +2090,9 @@ class DecodeEngine:
             "errors": self._n_errors,
             "cancelled": self._n_cancelled,
             "tokens": self._n_tokens,
+            # decode steps landed: a token a slot each, or (generation in
+            # blocks) PASSES, which fix 0 to a block of tokens a slot
+            "steps": self._n_steps,
             "quarantines": self._n_quarantines,
             "recovered": self._n_recovered,
             "shed": self._n_shed,

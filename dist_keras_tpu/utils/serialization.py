@@ -32,6 +32,14 @@ def serialize_model(model):
     }
 
 
+# the decoders ``DecodeEngine`` serves, by class name: the module of
+# ``models/`` each lives in (a ``cfg`` in, ``set_weights`` after)
+_DECODERS = {"Transformer": "transformer", "LatentMoEDecoder": "mla_moe",
+             "Lfm2MoeDecoder": "lfm2_moe",
+             "OlmoHybridDecoder": "olmo_hybrid",
+             "SdarMoeDecoder": "sdar_moe"}
+
+
 def deserialize_model(d):
     """dict -> Model, same contract as utils.py:~55.
 
@@ -44,28 +52,12 @@ def deserialize_model(d):
     from dist_keras_tpu.models.model import model_from_json
 
     arch = json.loads(d["model"])
-    if arch.get("class_name") == "Transformer":
-        from dist_keras_tpu.models.transformer import Transformer
+    if arch.get("class_name") in _DECODERS:
+        import importlib
 
-        model = Transformer(cfg=arch["config"])
-        model.set_weights(d["weights"])
-        return model
-    if arch.get("class_name") == "LatentMoEDecoder":
-        from dist_keras_tpu.models.mla_moe import LatentMoEDecoder
-
-        model = LatentMoEDecoder(cfg=arch["config"])
-        model.set_weights(d["weights"])
-        return model
-    if arch.get("class_name") == "Lfm2MoeDecoder":
-        from dist_keras_tpu.models.lfm2_moe import Lfm2MoeDecoder
-
-        model = Lfm2MoeDecoder(cfg=arch["config"])
-        model.set_weights(d["weights"])
-        return model
-    if arch.get("class_name") == "OlmoHybridDecoder":
-        from dist_keras_tpu.models.olmo_hybrid import OlmoHybridDecoder
-
-        model = OlmoHybridDecoder(cfg=arch["config"])
+        module = importlib.import_module(
+            "dist_keras_tpu.models." + _DECODERS[arch["class_name"]])
+        model = getattr(module, arch["class_name"])(cfg=arch["config"])
         model.set_weights(d["weights"])
         return model
     if arch.get("class_name") == "Sequential" and "layers" in arch and all(

@@ -19,7 +19,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid, transformer
+from dist_keras_tpu.models import (
+    lfm2_moe,
+    mla_moe,
+    olmo_hybrid,
+    sdar_moe,
+    transformer,
+)
 from dist_keras_tpu.models.transformer import (
     init_transformer_params,
     transformer_config,
@@ -97,6 +103,7 @@ def _bare_engine(cfg, family, page_size, num_pages, state_rows=0):
     engine._pools = tuple(family.cache_pools(cfg))
     engine._state = bool(state_rows)
     engine.state_rows = state_rows
+    engine._width = family.step_width(cfg)
     return engine
 
 
@@ -112,6 +119,13 @@ def _step_and_args(engine, phase, rung, pages_per_seq, S, counts=0):
     engine.max_pages_per_seq = pages_per_seq
     engine.max_slots = rung
     rows = int(engine._state)
+    width = engine._width
+    if width > 1 and phase == "packed_decode":
+        # a pass over blocks: the blocks' tokens in the carried output and
+        # in the packed array, and the column of what each slot fixes
+        return engine._packed_decode_fn, (
+            S((rung * width + counts,)),
+            S((rung * (pages_per_seq + width + 5 + rows),)))
     if phase == "decode":
         return engine._decode_fn, (
             S((rung,)), S((rung,)), S((rung, pages_per_seq)), S((rung,)),
@@ -457,6 +471,119 @@ def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
         assert [a.shape for a in ints] == [
             (rung + 34,), (rung * (pages_per_seq + 6),)]
         assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
+
+
+# -- the block-diffusion, sparse-expert family (models/sdar_moe.py) --------
+# sdar-30b-a3b-chat as the benchmark cuts it: every width as published,
+# layers 0-15, experts 0-15 of 128 held, 32 slots of 1536 positions in
+# pages of 16, blocks of 4 positions
+B_SLOTS, B_POSITIONS, B_PAGE, B_BLOCK = 32, 1536, 16, 4
+
+
+def _blocks_cfg():
+    return sdar_moe.sdar_moe_config(
+        vocab_size=151936, seq_len=B_POSITIONS, d_model=2048, n_heads=32,
+        n_kv_heads=4, head_dim=128, moe_d_ff=768, n_routed_experts=128,
+        top_k=8, n_layers=16, held_experts=list(range(16)),
+        block_length=B_BLOCK, denoising_steps=4, mask_token_id=151669)
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("packed_decode", B_SLOTS, 0.2), ("packed_prefill", 1024, 0.3)])
+def test_block_diffusion_step_leaves_the_pool_in_place(topo, as_tpu, phase,
+                                                       rung, temp_gb):
+    """The fifth family's pass (32 slots x 4 positions: 128 rows) and its
+    1,024 prefill hold no copy of the ``v | k`` pool (rows of 1,024 lanes
+    over all 16 layers) nor of a layer of it, no expert-sized temporary
+    and (the pass) one vocabulary-sized product, the head's; each layer
+    writes its rows once, in place, and reads them back with one
+    ``latent_decode`` kernel over the flat float32 pool at ``4 x 32 = 128``
+    query rows a slot (the prefill: one ``flash_fwd``, 32 query heads over
+    4 K/V heads of 128).  The configuration file's ``reduced_why`` quotes
+    these programs' ``memory_analysis``."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = _blocks_cfg()
+    pages_per_seq = B_POSITIONS // B_PAGE
+    engine = _bare_engine(cfg, sdar_moe, B_PAGE, B_SLOTS * pages_per_seq)
+    (kv_shape,) = engine.pool_shapes
+    assert kv_shape == (16, engine.num_pages + 1, B_PAGE, 1024)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(sdar_moe.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    counts = len(cfg["held_experts"]) + 2
+    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S,
+                              counts=counts)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, S(kv_shape, jnp.float32), *args).compile()
+    text = compiled.as_text()
+
+    kv_elems = math.prod(kv_shape)
+    big = {kv_elems: "K/V pool", kv_elems // kv_shape[0]: "K/V layer",
+           16 * 2048 * 768: "expert matrix"}
+    roots = _roots(text)
+    scatters, offenders = 0, []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in big or opcode in FREE:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = roots.get(called.group(1)) if called else None
+        if opcode == "fusion" and called.group(1).startswith("bitcast"):
+            continue                                  # a view: moves nothing
+        if big[elems] == "K/V pool" and (opcode in WRITES or (
+                opcode == "fusion" and root in WRITES)):
+            scatters += opcode in WRITES
+            continue
+        if big[elems] == "expert matrix" and re.search(
+                r"= f32\[16,\d+,\d+\]\{[^}]*S\(1\)\}", line):
+            # the compiler's own prefetch of an ARGUMENT (a held layer's
+            # gate, up or down matrices, 0.1 GB) into its fast memory
+            # space ahead of the product: counted in the temporaries
+            # below, nothing the program computes
+            continue
+        offenders.append(f"{comp}: %{name} = {opcode} of {big[elems]} size")
+    assert not offenders, offenders
+    assert scatters == 16, scatters
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * kv_elems            # donated
+    assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
+    # weights 8.55 GB and the pool 3.22 GB: 74% of the chip's 16 GB with
+    # the pass's temporaries.  The prefill takes neither the head (it
+    # yields no token) nor the last layer's experts (nothing reads what
+    # they would add): 7.00 GB
+    weights = m.argument_size_in_bytes - 4 * kv_elems
+    assert (6.9 if "prefill" in phase else 8.5) * GB < weights < (
+        7.1 if "prefill" in phase else 8.6) * GB
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 16
+    if "prefill" in phase:
+        assert all("flash_fwd" in k for k in kernels)
+        assert all(f"f32[32,{rung},128]" in k and f"f32[4,{rung},128]" in k
+                   for k in kernels), kernels[0]
+        assert str(cfg["vocab_size"]) not in "".join(
+            line for line in text.splitlines() if " dot(" in line
+            or "convolution(" in line)
+        return
+    assert all("latent_decode" in k for k in kernels)
+    flat = f"f32[{math.prod(kv_shape[:2])},{B_PAGE},1024]"
+    # a slot's 4 x 32 query rows, each laid over the row's 1,024 lanes
+    assert all(flat in k and f"f32[{rung},128,1024]" in k
+               for k in kernels), kernels[0]
+    ints = [a for a in jax.tree.leaves(compiled.args_info)
+            if a.dtype == jnp.int32]
+    assert [a.shape for a in ints] == [
+        (rung * B_BLOCK + counts,),
+        (rung * (pages_per_seq + B_BLOCK + 5),)]
+    assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
 
 
 # -- the gated-delta-rule, full-attention family (models/olmo_hybrid.py) ---

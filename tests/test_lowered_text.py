@@ -1,4 +1,4 @@
-"""The three block families that were served before PR 36 compile to the
+"""The four block families whose step is one token a slot compile to the
 programs recorded here.
 
 PR 32 widened the family seam (a pool states the layers it spans and
@@ -48,6 +48,15 @@ The four hashes were recorded anew on that PR's tree; the eight entries of
 ``mla_moe`` and ``lfm2_moe`` pass as recorded, and ``olmo_hybrid``'s four
 programs (not pinned here) hash alike on that tree and its parent.
 
+**PR 42 widened the seam for a fifth family and pinned the fourth's four
+entries.**  ``models/sdar_moe.py`` generates in blocks: a family states
+how many positions a slot a step computes (``step_width``), the packed
+array holds a block's tokens and one more column where that is over 1, and
+the flash forward takes the causal mask's block length.  It was held to
+leaving every family of width 1 its programs: the twelve entries pass as
+recorded, and ``olmo_hybrid``'s four were recorded from commit 42c93af (PR
+41), in a checkout of that commit, and hash alike on PR 42's tree.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.
@@ -59,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe
+from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid
 from dist_keras_tpu.models.transformer import Transformer, transformer_config
 from dist_keras_tpu.serving import DecodeEngine
 
@@ -89,8 +98,18 @@ def _lfm2_moe():
         layer_types=["conv", "conv", "full_attention", "conv"] * 2), seed=1)
 
 
+def _olmo_hybrid():
+    return olmo_hybrid.OlmoHybridDecoder(cfg=olmo_hybrid.olmo_hybrid_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=4, d_ff=96,
+        layer_types=(["linear_attention"] * 3 + ["full_attention"]) * 2,
+        linear_heads=4, linear_key_dim=8, linear_value_dim=16), seed=1)
+
+
 MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
-          "lfm2_moe": _lfm2_moe}
+          "lfm2_moe": _lfm2_moe, "olmo_hybrid": _olmo_hybrid}
+# what a family's engine is given beside the ladders (a state row of this
+# family is large: the engine is told how many it holds)
+ENGINE = {"olmo_hybrid": dict(state_rows=3)}
 
 # sha256 of the lowered text, for the CPU (the ``jnp`` references serve)
 # and for a TPU (the Pallas kernels do: ``use_pallas()`` asks
@@ -123,6 +142,15 @@ RECORDED = {
         "cf4e1013665548eda09803ada4679cadf4f08caea1090ba280a72fab46a61e9a",
     ("lfm2_moe", "decode", "tpu"):
         "518c1f84f3d1775d6c360c57a48d99d3d3c60b57283b8971e8348596a23d50d2",
+    # recorded on commit 42c93af (PR 41)
+    ("olmo_hybrid", "prefill", "cpu"):
+        "df63cb3f0ac5da35623f7628a07b8f4ede7c23df98a325ba23f699e1bc2b20c1",
+    ("olmo_hybrid", "decode", "cpu"):
+        "5dd6babf81056ac3024025256c341fb81f85fabe44b1f06ac461e238d492e6b9",
+    ("olmo_hybrid", "prefill", "tpu"):
+        "b1e87a964519051d5be74035447e0dce5a97ef638047a9f91585c62e70575a95",
+    ("olmo_hybrid", "decode", "tpu"):
+        "dcc76d0cb380b219c8853e188447db52cd8c80dde39477185813cb0efe4fb8ce",
 }
 
 
@@ -135,7 +163,8 @@ def lowered_hash(family, phase, platform):
     # trace; with no frames kept, the text does not depend on who calls
     frames = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 0)
-    with DecodeEngine(MODELS[family](), **LADDERS) as eng:
+    with DecodeEngine(MODELS[family](), **LADDERS,
+                      **ENGINE.get(family, {})) as eng:
         rep = eng._replicas[0]
         pmax = eng.max_pages_per_seq
         # a family with per-sequence state has one more packed column
