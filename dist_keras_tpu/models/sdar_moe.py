@@ -34,7 +34,8 @@ committed history; they become history at the block's COMMIT, the pass
 over its final tokens.  ``B`` divides the page size, so a block never
 straddles a page.
 
-*A pass* (:func:`decode_step`).  For each slot: the open block's ``B``
+*A pass* (:func:`decode_step`).  For each ENTRY (a block of a sequence;
+"slot" below): the open block's ``B``
 tokens (fixed ones, and ``mask_token_id`` where none is fixed yet), where
 the block starts, and how many masked positions this pass FIXES (0: the
 pass commits).  It writes the ``B`` provisional rows, reads the slot's
@@ -52,8 +53,16 @@ Generation (the engine's part, ``serving/decode.py``): a prefill commits
 the prompt's whole blocks and yields no token; the open block holds the
 prompt's tail and masks; ``cfg["denoising_steps"]`` passes of ``B /
 steps`` positions each fix a block of ``B`` masks (fewer where the block
-opened with fewer), one more commits it, and the next block opens.
-Greedy throughout.
+opened with fewer).  The block's COMMIT, a pass over its final tokens
+that fixes nothing, needs no pass of its own: it rides as one entry of
+the pass whose next entry is the sequence's NEXT block's first denoising
+pass (same pages, ``start + B``, ``lengths = start + 2 B``).  Every
+layer writes all entries' rows before its read, each entry reads its own
+``lengths`` and the mask lets a block see every earlier one, so the new
+block reads exactly the committed rows it would a pass later and the
+commit never sees the new block's.  Only a sequence's last block, and a
+commit whose pass has no entry to spare, take a pass alone.  Greedy
+throughout.
 """
 
 from __future__ import annotations
@@ -305,9 +314,15 @@ def decode_step(cfg, params, kv, tokens, positions, page_tables,
     starts (its row ``write_off`` of page ``write_page``), ``lengths (S,)``
     the positions a slot reads, ``start + B``, and ``fix (S,)`` how many
     masked positions the pass fixes (0: it commits; the rows it writes
-    are then the block's final ones).  Padding slots carry ``length ==
-    0``, write to the scratch page, reach no expert, and the read's
-    dead-row guard zeroes their attention."""
+    are then the block's final ones).  The rows of ``tokens`` are ENTRIES:
+    two of them may be one sequence's (same ``page_tables`` row), a
+    committing block at ``start`` and the next block at ``start + B``
+    with ``lengths = start + 2 B``: each layer's write below puts both
+    entries' rows into the pool before its read, so the second reads the
+    first's final rows and the first, whose length stops before them,
+    none of the second's.  Padding slots carry ``length == 0``, write to
+    the scratch page, reach no expert, and the read's dead-row guard
+    zeroes their attention."""
     s, b = tokens.shape
     h, hk, eps = cfg["n_heads"], cfg["n_kv_heads"], cfg["rms_norm_eps"]
     within = jnp.arange(b, dtype=jnp.int32)
@@ -342,13 +357,18 @@ def decode_step(cfg, params, kv, tokens, positions, page_tables,
     return jnp.concatenate([after.reshape(-1), counts]), kv
 
 
-def observe_step(counts, at, lengths=None, page_size=None, fix=None):
+def observe_step(counts, at, lengths=None, page_size=None, fix=None,
+                 folded=0):
     """The counts behind a step's tokens -> the registry
-    (``mla_moe.observe_routing``).  A pass hands in its slots' ``lengths``
-    (host values, zeros for padding: the rows its read covers in each
-    layer, the open blocks' among them) and what each slot was to ``fix``,
-    and stamps ``decode.kv.live_positions`` and the pass's three
-    ``decode.block.*`` samples."""
+    (``mla_moe.observe_routing``).  A pass hands in its entries'
+    ``lengths`` (host values, zeros for padding: the rows its read covers
+    in each layer, the open blocks' among them), what each entry was to
+    ``fix`` and how many of its commits were ``folded`` (their sequence's
+    next block an entry of the same pass), and stamps
+    ``decode.kv.live_positions`` and the pass's ``decode.block.*``
+    samples: the SEQUENCES in it (a folded commit's two entries are one),
+    the positions fixed, the share of its live entries that committed
+    and, where any did, the share of those commits that were folded."""
     from dist_keras_tpu.observability import metrics
 
     observe_routing(counts, at, decode=lengths is not None)
@@ -356,14 +376,19 @@ def observe_step(counts, at, lengths=None, page_size=None, fix=None):
         return
     live = np.asarray(lengths) > 0
     fix = np.asarray(fix)[live]
+    commits = int(np.sum(fix == 0))
     metrics.histogram("decode.kv.live_positions").observe(
         int(np.asarray(lengths).sum()), at=at)
-    metrics.histogram("decode.block.slots").observe(int(live.sum()), at=at)
+    metrics.histogram("decode.block.slots").observe(
+        int(live.sum()) - folded, at=at)
     metrics.histogram("decode.block.tokens_fixed").observe(
         int(fix.sum()), at=at)
     if fix.size:
         metrics.histogram("decode.block.commit_share").observe(
-            100.0 * float(np.mean(fix == 0)), at=at)
+            100.0 * commits / fix.size, at=at)
+    if commits:
+        metrics.histogram("decode.block.commit_folded").observe(
+            100.0 * folded / commits, at=at)
 
 
 class SdarMoeDecoder:

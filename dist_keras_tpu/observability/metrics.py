@@ -401,16 +401,20 @@ KNOWN_METRICS = {
     "prefill.scan_positions": "histogram",
     "prefill.scan_padded_positions": "histogram",
     # generation in blocks (models/sdar_moe.py; the engine's passes over
-    # blocks): a stamped sample a PASS of the slots in it, the masked
-    # positions it fixed over all of them and the share of its slots whose
-    # pass committed their block (percent); a sample a pass that committed
-    # blocks of the passes they took, their commits among them (the mean
-    # over those blocks); and the tokens a request's last block computed
-    # beyond its max_new_tokens
+    # blocks): a stamped sample a PASS of the sequences in it, the masked
+    # positions it fixed over all of them and the share of its live
+    # entries that committed their block (percent; a sequence whose commit
+    # rides with its next block holds two entries of the pass); a sample a
+    # pass that committed blocks of the passes they took, a commit that
+    # rode alone among them (the mean over those blocks), and of the share
+    # of its commits that rode with their sequence's next block
+    # (percent); and the tokens a request's last block computed beyond
+    # its max_new_tokens
     "decode.block.slots": "histogram",
     "decode.block.tokens_fixed": "histogram",
     "decode.block.commit_share": "histogram",
     "decode.block.passes": "histogram",
+    "decode.block.commit_folded": "histogram",
     "decode.block.tokens_trimmed": "counter",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding

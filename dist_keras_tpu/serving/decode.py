@@ -267,7 +267,9 @@ class Generation:
 # step yields one token a sequence; a family that generates in BLOCKS
 # states the block's length, and with ``step_fixes(cfg)`` the id that
 # stands at a block position nothing is fixed at yet and how many such
-# positions a pass fixes), ``prefill_step`` / ``decode_step`` (``(cfg,
+# positions a pass fixes; its ``decode_step`` is over ENTRIES, of which a
+# sequence may hold two, :func:`_step_views`), ``prefill_step`` /
+# ``decode_step`` (``(cfg,
 # params, *pools, ...) -> (int32 array, *pools)``: the tokens first, then
 # whatever counts the family sends along; a family with a per-sequence
 # pool is also handed the state rows, last) and ``observe_step(counts, at,
@@ -303,14 +305,20 @@ def _step_views(packed, pmax, state=False, width=1):
     token.
 
     A family whose step computes ``width`` positions a slot (a BLOCK) is
-    handed seven arrays (eight with ``state``): ``rung * (pmax + width +
-    5)`` values, the tables, then the slots' open blocks (``width``
-    entries a slot, the mask id where nothing is fixed yet), then
+    handed seven arrays (eight with ``state``): ``entries * (pmax + width
+    + 5)`` values, the tables, then the entries' blocks (``width``
+    values an entry, the mask id where nothing is fixed yet), then
     positions (where each block starts), write pages, write offsets,
     lengths (``start + width``) and how many masked positions the pass
-    fixes (0: it commits the block).  A block's entries name their
+    fixes (0: it commits the block).  An ENTRY is a block of a sequence,
+    and a rung's pass holds more entries than sequences
+    (``DecodeEngine._entries``): a sequence whose block commits may hold
+    two, the commit and RIGHT BEHIND it the block that opens next (the
+    same page table, ``start + width``, ``start + 2 * width`` rows read:
+    layer by layer the first entry writes the committed rows the second
+    reads, and never sees the second's).  A block's values name their
     source like a token's: ``-(j + 1)`` at block position ``b`` means
-    "block position ``b`` of slot ``j`` of the pass in flight's output".
+    "block position ``b`` of entry ``j`` of the pass in flight's output".
     With ``width`` 1 nothing of the above changes."""
     if width > 1:
         arrays = 6 + state
@@ -343,6 +351,15 @@ def _prefill_views(packed, state=False):
     return toks, packed[-1], page_idx, page_off
 
 
+def _spare_entries(rung, passes):
+    """Entries a pass of ``rung`` sequences holds beyond one a sequence,
+    for the blocks that open behind a commit: a block takes ``passes``
+    denoising passes, so in steady state ``rung / passes`` sequences
+    commit a pass (rounded up; at least one, so that a sequence alone
+    never waits a pass for its next block)."""
+    return -(-rung // passes)
+
+
 def _still_running(out):
     """Has the device NOT yet finished the step that puts ``out`` out?
     (0.2 us a call; a seam of its own so that a test can hold a step
@@ -365,13 +382,20 @@ class _Flight:
     """One decode step launched and not yet landed: what its landing
     needs besides the host's canonical state, which it has not touched."""
 
-    __slots__ = ("group", "slot", "rung", "out", "lengths", "t0",
-                 "overlapped", "fed", "attempt", "fix")
+    __slots__ = ("group", "entries", "slot", "rung", "out", "lengths",
+                 "t0", "overlapped", "fed", "attempt", "fix")
 
-    def __init__(self, group, rung, out, lengths, t0, overlapped, fed,
-                 attempt, fix=None):
+    def __init__(self, group, entries, rung, out, lengths, t0, overlapped,
+                 fed, attempt, fix=None):
         self.group = group            # the sequences, slot by slot
-        self.slot = {seq: i for i, seq in enumerate(group)}
+        # the sequence of each live ENTRY of the step, in the packed
+        # array's order: the group's where a step is a token; in a pass
+        # over blocks a sequence whose block commits may hold two, the
+        # commit and right behind it the block that opens next
+        self.entries = entries
+        # where the next step finds a sequence's tokens in ``out``: its
+        # entry, the LAST one where it holds two (its open block's)
+        self.slot = {seq: i for i, seq in enumerate(self.entries)}
         self.rung = rung
         self.out = out                # device: tokens to the top rung, counts
         self.lengths = lengths        # host view, for the family's counts
@@ -384,9 +408,15 @@ class _Flight:
         # there was no predecessor or the pass ran a prefill)
         self.fed = fed
         self.attempt = attempt        # failures this step had before
-        # a pass over blocks: the masked positions each slot's pass fixes
-        # (0: it commits the slot's block); None where a step is a token
+        # a pass over blocks: the masked positions each entry's pass fixes
+        # (0: it commits the entry's block); None where a step is a token
         self.fix = fix
+
+    def folds(self, i):
+        """Is entry ``i`` a commit whose sequence's NEXT block rides in
+        the same pass (the entry right behind it)?"""
+        return (0 <= i < len(self.entries) - 1
+                and self.entries[i + 1] is self.entries[i])
 
 
 class _DecodeReplica:
@@ -453,7 +483,10 @@ class DecodeEngine:
         padded to the smallest rung that fits (``ValueError`` past the
         largest — the front end's 400).
       decode_ladder: ascending fixed SLOT counts for decode steps; the
-        largest rung is the per-replica concurrency cap.
+        largest rung is the per-replica concurrency cap.  A rung counts
+        SEQUENCES: the one program of a rung of a family that generates
+        in blocks also holds the few spare entries in which a committing
+        block's successor rides (:meth:`_entries`; derived, not set).
       page_size: KV positions per page.
       num_pages: pool pages per replica.  Default sizes the pool so a
         full slot set of maximum-length sequences fits.
@@ -703,33 +736,46 @@ class DecodeEngine:
         """:meth:`_packed_decode_fn` where a step is a pass over blocks of
         ``width`` positions: the same resolution a block position at a
         time (entry ``-(j + 1)`` at position ``b`` takes position ``b``
-        of slot ``j``'s block in ``carried``), and an output that holds
-        the blocks' tokens padded to the top rung's, then the counts."""
+        of ENTRY ``j``'s block in ``carried``), and an output that holds
+        the entries' blocks padded to the top rung's entries
+        (:meth:`_entries`), then the counts."""
         width = self._width
         toks, *rest = _step_views(packed, self.max_pages_per_seq,
                                   self._state, width)
-        top, rung = self.max_slots, toks.shape[0]
+        top, entries = self._entries(self.max_slots), toks.shape[0]
         with jax.named_scope("carried_tokens"):
             source = (jnp.clip(-toks - 1, 0, top - 1) * width
                       + jnp.arange(width, dtype=jnp.int32))
             toks = jnp.where(toks < 0, carried[source], toks)
         out, *pools = self._decode_fn(params, *pools, toks, *rest)
-        if rung < top:
+        if entries < top:
             out = jnp.concatenate(
-                [out[:rung * width],
-                 jnp.zeros(((top - rung) * width,), out.dtype),
-                 out[rung * width:]])
+                [out[:entries * width],
+                 jnp.zeros(((top - entries) * width,), out.dtype),
+                 out[entries * width:]])
         return (out, *pools)
+
+    def _entries(self, rung):
+        """The entries the ONE program of slot rung ``rung`` computes.  A
+        token step: the rung.  A pass over blocks: the rung and the spare
+        entries in which a committing sequence's NEXT block rides
+        (:func:`_spare_entries`, from what the family states); an unused
+        one is padding like any other (``length == 0``, the scratch page,
+        no expert, no K/V read), so the fused and the plain pass are the
+        same compiled program."""
+        if self._width == 1:
+            return rung
+        return rung + _spare_entries(rung, self._width // self._fix_a_pass)
 
     def _decode_out_width(self, params):
         """How many int32 values a decode step of this family puts out,
-        the tokens padded to the top rung: known from the step's shapes
-        alone (nothing runs), and what a step carries when none is in
-        flight has to have it."""
+        the tokens padded to the top rung's entries: known from the
+        step's shapes alone (nothing runs), and what a step carries when
+        none is in flight has to have it."""
         def ints(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-        top = self.max_slots
+        top = self._entries(self.max_slots)
         out, *_ = jax.eval_shape(
             self._decode_fn, params,
             *(jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -945,15 +991,17 @@ class DecodeEngine:
         """The decode steps a request takes after its prefill: one a token
         or, in blocks, the PASSES of its ``ceil((tail + max_new) /
         width)`` blocks: as many as fix the block's masks (fewer in the
-        first block, which opens holding the prompt's tail) and one that
-        commits it."""
+        first block, which opens holding the prompt's tail), and one that
+        commits the LAST block: every other block's commit rides in the
+        pass that opens the next one (a pass with no spare entry makes
+        such a commit ride alone, a pass more: the door does not know)."""
         width, fix = self._width, self._fix_a_pass
         if width == 1:
             return max_new
         tail = prompt_len % width
         blocks = -(-(tail + max_new) // width)
         return (-(-(width - tail) // fix) + (blocks - 1) * (width // fix)
-                + blocks)
+                + 1)
 
     def _row_of(self, rep, sid):
         """The state row ``alloc`` reserved with a sequence's pages."""
@@ -1314,15 +1362,20 @@ class DecodeEngine:
         """Will ``seq`` reach its ``max_new`` when ``flight`` lands?  Known
         from its count alone (the step's prediction is a NEW token unless
         a recovered sequence is still catching up).  In blocks: when the
-        pass in flight COMMITS the block that holds its last token."""
+        pass in flight COMMITS the block that holds its last token (such
+        a commit rides alone: nothing opens behind a last block, so the
+        sequence's entry of the pass is the commit)."""
         if seq not in flight.slot:
             return False
         if self._width > 1:
             return (flight.fix[flight.slot[seq]] == 0
-                    and seq.kv_len + self._width - seq.prompt_len
-                    >= seq.max_new)
+                    and self._last_block(seq, seq.kv_len))
         return (seq.kv_len + 1 >= len(seq.tokens)
                 and len(seq.tokens) + 1 - seq.prompt_len >= seq.max_new)
+
+    def _last_block(self, seq, start):
+        """Does the block at ``start`` hold ``seq``'s last token?"""
+        return start + self._width - seq.prompt_len >= seq.max_new
 
     def _launch(self, rep, group, rung, prev):
         """Build and dispatch one step for ``group`` on ``prev``'s output
@@ -1333,14 +1386,28 @@ class DecodeEngine:
         lengths) are views of ONE packed int32 host array
         (:func:`_step_views`; 54 KB at 32 slots of 416 pages) that goes
         to the device in one transfer and is cut apart inside the
-        compiled step; what the step carries is there already."""
+        compiled step; what the step carries is there already.
+
+        A pass over blocks gives a sequence one ENTRY, its open block
+        (:meth:`_next_pass`), and a sequence whose entry COMMITS a second
+        one right behind it: the block that opens next, as the host
+        knows it (:meth:`_open_block`: masks), with what its first
+        denoising pass fixes.  The commit's products are all there, in
+        the pass that would have run anyway.  No second entry where the
+        commit ends the sequence, where the pass has no entry to spare
+        (:meth:`_entries`; the commit then rides alone and the block
+        opens a pass later, which also moves the sequence's phase off
+        the crowded pass), or where the host already knows the next
+        block whole (a recovered sequence catching up: that block's own
+        commit follows)."""
         with perf.phase("decode.step.build"):
             ps = self.page_size
             pmax = self.max_pages_per_seq
             width = self._width
             wide = width > 1          # a pass over blocks, not a token
+            room = self._entries(rung)
             packed = np.zeros(
-                (rung * (pmax + 5 + wide * width + self._state),), np.int32)
+                (room * (pmax + 5 + wide * width + self._state),), np.int32)
             toks, positions, tables, wpage, woff, lengths, *rows = \
                 _step_views(packed, pmax, self._state, width)
             fix = rows.pop(0) if wide else None
@@ -1348,23 +1415,41 @@ class DecodeEngine:
             if self._state:
                 # a padding slot's state goes to the scratch row
                 rows[0][:] = rep.cache.scratch_row
-                rows[0][:len(group)] = [seq.row for seq in group]
             ahead = prev.slot if prev is not None else {}
-            for i, seq in enumerate(group):
-                j = ahead.get(seq)
-                if wide:
-                    at, block, fix[i] = self._next_pass(
-                        seq, None if j is None else prev.fix[j])
-                    toks[i] = -(j + 1) if block is None else block
-                else:
-                    at = seq.kv_len + (j is not None)
-                    toks[i] = (seq.tokens[at] if at < len(seq.tokens)
-                               else -(j + 1))
+            entries = []
+
+            def enter(seq, at, source, fixes=None):
+                i = len(entries)
+                entries.append(seq)
+                toks[i] = source
                 positions[i] = at
                 tables[i, :len(seq.pages)] = seq.pages
                 wpage[i] = seq.pages[at // ps]
                 woff[i] = at % ps
                 lengths[i] = at + width
+                if wide:
+                    fix[i] = fixes
+                if self._state:
+                    rows[0][i] = seq.row
+
+            for n, seq in enumerate(group):
+                j = ahead.get(seq)
+                if not wide:
+                    at = seq.kv_len + (j is not None)
+                    enter(seq, at, seq.tokens[at] if at < len(seq.tokens)
+                          else -(j + 1))
+                    continue
+                at, block, masks = self._next_pass(seq, prev)
+                enter(seq, at, -(j + 1) if block is None else block,
+                      min(self._fix_a_pass, masks))
+                if masks or self._last_block(seq, at) \
+                        or len(entries) + len(group) - n > room:
+                    continue    # no commit, the last one, or no entry spare
+                block = self._open_block(seq, at + width)
+                masks = block.count(self._mask_id)
+                if masks:   # else the host knows it whole: its own commit
+                    enter(seq, at + width, block,
+                          min(self._fix_a_pass, masks))
         t0 = time.perf_counter()
         fault_point("decode.step")
         perf.count_dispatch()
@@ -1377,34 +1462,40 @@ class DecodeEngine:
         # predecessor still running then never left the chip without work
         fed = (None if prev is None or rep.prefilling
                else _still_running(prev.out))
-        flight = _Flight(group, rung, out, lengths, t0, prev is not None,
-                         fed, rep.attempt, fix)
+        flight = _Flight(group, entries, rung, out, lengths, t0,
+                         prev is not None, fed, rep.attempt, fix)
         rep.attempt = 0
         return flight
 
-    def _next_pass(self, seq, flying):
+    def _next_pass(self, seq, prev):
         """What ``seq``'s next pass computes, as the host knows without
-        the tokens of the pass in flight (``flying``: how many positions
-        that pass fixes for it, 0 a commit, None when it has none in
-        flight) -> (where the open block starts, its tokens or None when
-        they are the output of the pass in flight, how many masked
-        positions to fix: 0 commits).  The schedule is static
+        the tokens of the pass in flight (``prev``; None when there is
+        none) -> (where its open block starts, the block's tokens or None
+        when they are the output of its entry of the pass in flight, how
+        many of its positions are still masked: none left means the pass
+        COMMITS the block).  The schedule is static
         (``low_confidence_static``): a pass fixes a known NUMBER of
         positions, so the count of masks left is arithmetic; which
         positions, and with what, only the device knows until the pass
-        lands."""
-        if flying is None:
-            start, block = seq.kv_len, seq.block
-            masked = block.count(self._mask_id)
-        elif flying == 0:
-            # the block in flight commits: the next one opens
-            start = seq.kv_len + self._width
-            block = self._open_block(seq, start)
-            masked = block.count(self._mask_id)
-        else:
-            start, block = seq.kv_len, None
-            masked = seq.block.count(self._mask_id) - flying
-        return start, block, min(self._fix_a_pass, masked)
+        lands.  The reckoning is from the host's state as it WILL be when
+        the pass in flight has landed: behind a commit in flight the
+        sequence stands a block further, its open block the one that
+        commit opens, less what that block's own entry of the same pass
+        fixes where the commit carried it along."""
+        j = None if prev is None else prev.slot.get(seq)
+        if j is None:
+            return seq.kv_len, seq.block, seq.block.count(self._mask_id)
+        flying = prev.fix[j]
+        if flying and not prev.folds(j - 1):
+            # a denoising pass of the block the host has
+            return (seq.kv_len, None,
+                    seq.block.count(self._mask_id) - flying)
+        # the block in flight commits: the next one opens, on the host
+        # (the commit rides alone) or in the same pass
+        start = seq.kv_len + self._width
+        block = self._open_block(seq, start)
+        masks = block.count(self._mask_id) - flying
+        return start, (None if flying else block), masks
 
     def _land(self, rep, flight, out):
         """``flight``'s output is on the host: account the step, emit its
@@ -1432,9 +1523,11 @@ class DecodeEngine:
             # the counts came off the device behind the tokens, in the
             # one array the wait already fetched
             self._family.observe_step(
-                out[self.max_slots * self._width:], t0,
+                out[self._entries(self.max_slots) * self._width:], t0,
                 lengths=flight.lengths, page_size=self.page_size,
-                **({} if flight.fix is None else {"fix": flight.fix}))
+                **({} if flight.fix is None else {
+                    "fix": flight.fix,
+                    "folded": len(flight.entries) - len(flight.group)}))
         with perf.phase("decode.step.emit"):
             with self._cond:
                 self._shapes.add(("decode", flight.rung))
@@ -1445,20 +1538,25 @@ class DecodeEngine:
                         **({} if flight.fix is None
                            else {"fixed": int(flight.fix.sum())}))
             finished, discarded, took = [], 0, []
-            for i, seq in enumerate(flight.group):
-                if seq.finished:
+            for i, seq in enumerate(flight.entries):
+                if seq.finished or (finished and finished[-1][0] is seq):
                     # ended under this step (an eos seen a step late, a
-                    # cancel, a deadline): computed and thrown away
+                    # cancel, a deadline; the block behind a commit that
+                    # met its eos): computed and thrown away
                     discarded += 1
                     continue
-                seq.steps += 1
                 if flight.fix is not None:
+                    # a pass, not an entry: a sequence that holds two
+                    # entries of it spent one
+                    seq.steps += not flight.folds(i - 1)
                     finish = self._land_pass(
                         seq, flight.fix[i],
-                        out[i * self._width:(i + 1) * self._width], took)
+                        out[i * self._width:(i + 1) * self._width],
+                        took, not flight.folds(i))
                     if finish is not None:
                         finished.append((seq, finish))
                     continue
+                seq.steps += 1
                 seq.kv_len += 1
                 if seq.kv_len < len(seq.tokens):
                     # replay catch-up: this prediction is a token the
@@ -1488,11 +1586,9 @@ class DecodeEngine:
                                 steps=seq.steps)
                     self._resolve(seq, finish)
 
-    def _land_pass(self, seq, fixes, after, took):
-        """One slot of a landed pass over blocks: ``after`` is ``seq``'s
-        open block as the pass left it -> the sequence's finish, or None
-        (``took`` gains the passes a block took, where this one committed
-        it).
+    def _land_pass(self, seq, fixes, after, took, alone):
+        """One entry of a landed pass over blocks: ``after`` is ``seq``'s
+        open block as the pass left it -> the sequence's finish, or None.
 
         A denoising pass (``fixes`` > 0) only moves the host's copy of
         the block on: the positions it fixed are those that held the mask
@@ -1506,7 +1602,12 @@ class DecodeEngine:
         tokens are not emitted again), each with the pass that fixed it,
         ends the sequence at ``max_new`` or on ``eos_id`` (what the block
         holds beyond is counted on ``decode.block.tokens_trimmed`` and
-        not emitted), and opens the next block."""
+        not emitted), and opens the next block on the host.  Where that
+        block rode in the same pass its entry lands NEXT, as the first
+        denoising pass of the block just opened.  ``took`` gains the
+        passes the committed block cost: its denoising passes, and one
+        more where its commit rode ``alone`` (with the sequence's next
+        block in the same pass, that block pays for the pass)."""
         width = self._width
         if fixes:
             for b in range(width):
@@ -1518,7 +1619,7 @@ class DecodeEngine:
             return None
         start = seq.kv_len
         seq.kv_len += width
-        took.append(seq.block_steps + 1)
+        took.append(seq.block_steps + alone)
         finish, trimmed = None, 0
         for b in range(len(seq.tokens) - start, width):
             if finish is not None:

@@ -104,6 +104,8 @@ def _bare_engine(cfg, family, page_size, num_pages, state_rows=0):
     engine._state = bool(state_rows)
     engine.state_rows = state_rows
     engine._width = family.step_width(cfg)
+    if engine._width > 1:
+        engine._mask_id, engine._fix_a_pass = family.step_fixes(cfg)
     return engine
 
 
@@ -122,10 +124,13 @@ def _step_and_args(engine, phase, rung, pages_per_seq, S, counts=0):
     width = engine._width
     if width > 1 and phase == "packed_decode":
         # a pass over blocks: the blocks' tokens in the carried output and
-        # in the packed array, and the column of what each slot fixes
+        # in the packed array, and the column of what each entry fixes;
+        # the rung's sequences and its spare entries (a commit's next
+        # block rides in one)
+        entries = engine._entries(rung)
         return engine._packed_decode_fn, (
-            S((rung * width + counts,)),
-            S((rung * (pages_per_seq + width + 5 + rows),)))
+            S((entries * width + counts,)),
+            S((entries * (pages_per_seq + width + 5 + rows),)))
     if phase == "decode":
         return engine._decode_fn, (
             S((rung,)), S((rung,)), S((rung, pages_per_seq)), S((rung,)),
@@ -492,13 +497,14 @@ def _blocks_cfg():
     ("packed_decode", B_SLOTS, 0.2), ("packed_prefill", 1024, 0.3)])
 def test_block_diffusion_step_leaves_the_pool_in_place(topo, as_tpu, phase,
                                                        rung, temp_gb):
-    """The fifth family's pass (32 slots x 4 positions: 128 rows) and its
+    """The fifth family's pass (32 slots and their 8 spare entries x 4
+    positions: 160 rows) and its
     1,024 prefill hold no copy of the ``v | k`` pool (rows of 1,024 lanes
     over all 16 layers) nor of a layer of it, no expert-sized temporary
     and (the pass) one vocabulary-sized product, the head's; each layer
     writes its rows once, in place, and reads them back with one
     ``latent_decode`` kernel over the flat float32 pool at ``4 x 32 = 128``
-    query rows a slot (the prefill: one ``flash_fwd``, 32 query heads over
+    query rows an entry (the prefill: one ``flash_fwd``, 32 query heads over
     4 K/V heads of 128).  The configuration file's ``reduced_why`` quotes
     these programs' ``memory_analysis``."""
     import functools
@@ -575,14 +581,17 @@ def test_block_diffusion_step_leaves_the_pool_in_place(topo, as_tpu, phase,
         return
     assert all("latent_decode" in k for k in kernels)
     flat = f"f32[{math.prod(kv_shape[:2])},{B_PAGE},1024]"
-    # a slot's 4 x 32 query rows, each laid over the row's 1,024 lanes
-    assert all(flat in k and f"f32[{rung},128,1024]" in k
+    # 32 sequences and the 8 entries their blocks of 4 passes ask for; an
+    # entry's 4 x 32 query rows, each laid over the row's 1,024 lanes
+    entries = engine._entries(rung)
+    assert entries == 40
+    assert all(flat in k and f"f32[{entries},128,1024]" in k
                for k in kernels), kernels[0]
     ints = [a for a in jax.tree.leaves(compiled.args_info)
             if a.dtype == jnp.int32]
     assert [a.shape for a in ints] == [
-        (rung * B_BLOCK + counts,),
-        (rung * (pages_per_seq + B_BLOCK + 5),)]
+        (entries * B_BLOCK + counts,),
+        (entries * (pages_per_seq + B_BLOCK + 5),)]
     assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
 
 

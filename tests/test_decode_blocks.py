@@ -2,8 +2,13 @@
 BLOCKS (``models/sdar_moe.py``; PR 42): the packed array of a pass, what
 the worker puts into it with a pass in flight, and a block's life under
 every exit: commit, cancel, deadline, a finish inside the block, a killed
-replica.  The family's own mathematics is held to the plain reference in
-``tests/test_sdar_moe.py``; here the undisturbed engine is the oracle.
+replica.  Since PR 43 a block's commit rides in the pass that opens its
+sequence's next block (two ENTRIES of one pass; a rung's program holds a
+few spare entries for them), so a block of ``T`` denoising passes costs
+``T`` passes and only a sequence's last block, or a commit that found no
+entry to spare, pays one more.  The family's own mathematics is held to
+the plain reference in ``tests/test_sdar_moe.py``; here the undisturbed
+engine is the oracle.
 """
 
 import threading
@@ -80,9 +85,11 @@ def test_packed_pass_is_the_familys_own(rung):
     """The dispatched program, handed the worker's ONE packed array and
     the output of the pass before it, gives bit for bit the blocks and
     the pool of the family's ``decode_step`` on its seven arrays apart.
-    Every other live slot names its block's SOURCE, a slot of the carried
-    output, in place of the tokens; the output is as wide at every rung
-    (blocks to the top rung, then the family's counts)."""
+    A rung's program holds the rung's sequences and its spare entries
+    (2 and 5 here).  Every other live entry names its block's SOURCE, an
+    entry of the carried output, in place of the tokens; the output is as
+    wide at every rung (blocks to the top rung's entries, then the
+    family's counts)."""
     import functools
 
     import jax.numpy as jnp
@@ -91,10 +98,12 @@ def test_packed_pass_is_the_familys_own(rung):
         rep, ps, pmax = eng._replicas[0], eng.page_size, \
             eng.max_pages_per_seq
         rng = np.random.default_rng(rung)
-        live = max(1, rung - 1)
+        room, top = eng._entries(rung), eng._entries(4)
+        assert (room, top) == (rung + 1, 5)
+        live = max(1, room - 1)
         carried = rng.integers(0, MASK, eng._out_width).astype(np.int32)
-        assert rep.no_tokens.shape == carried.shape == (4 * 4 + 4 + 2,)
-        packed = np.zeros((rung * (pmax + 4 + 5),), np.int32)
+        assert rep.no_tokens.shape == carried.shape == (5 * 4 + 4 + 2,)
+        packed = np.zeros((room * (pmax + 4 + 5),), np.int32)
         toks, positions, tables, wpage, woff, lengths, fix = _step_views(
             packed, pmax, False, 4)
         wpage[:] = rep.cache.scratch_page
@@ -113,58 +122,70 @@ def test_packed_pass_is_the_familys_own(rung):
                 rep.params, pool, *map(jnp.asarray, (
                     toks, positions, tables, wpage, woff, lengths, fix))))
         for i in range(0, live, 2):
-            j = int(rng.integers(0, 4))
+            j = int(rng.integers(0, top))
             carried[4 * j:4 * j + 4] = toks[i]
             toks[i] = -(j + 1)
         out, got_pool = eng._decode_jit(
             rep.params, pool + 0.0, jnp.asarray(carried),
             jnp.asarray(packed))
         assert out.shape == (eng._out_width,)
-        np.testing.assert_array_equal(out[:4 * rung], want[0][:4 * rung])
-        assert not np.asarray(out[4 * rung:16]).any()
-        np.testing.assert_array_equal(out[16:], want[0][4 * rung:])
+        np.testing.assert_array_equal(out[:4 * room], want[0][:4 * room])
+        assert not np.asarray(out[4 * room:4 * top]).any()
+        np.testing.assert_array_equal(out[4 * top:], want[0][4 * room:])
         np.testing.assert_array_equal(got_pool, want[1])
-        # a slot that was to fix one position fixed one, a commit none
+        # an entry that was to fix one position fixed one, a commit none
         after = np.asarray(out[:4 * live]).reshape(live, 4)
         for i in range(live):
             masks = int((after[i] == MASK).sum())
             assert masks == 1 - int(fix[i])
 
 
+def record_passes(eng, monkeypatch):
+    """Every pass the worker hands the device, in order: for each live
+    entry ``(its block's tokens or their source, where the block starts,
+    what the pass fixes there, the entry's first page)``."""
+    seen = []
+    real = eng._decode_jit
+
+    def recording(*args):
+        toks, positions, tables, wpage, woff, lengths, fix = _step_views(
+            np.array(args[-1]), eng.max_pages_per_seq, False, 4)
+        live = int((lengths > 0).sum())
+        assert not lengths[live:].any()     # padding behind, all of it
+        for i in range(live):
+            assert wpage[i] == tables[i][positions[i] // 4] \
+                and woff[i] == 0 and lengths[i] == positions[i] + 4
+        seen.append([(toks[i].tolist(), int(positions[i]), int(fix[i]),
+                      int(tables[i][0])) for i in range(live)])
+        return real(*args)
+    monkeypatch.setattr(eng, "_decode_jit", recording)
+    return seen
+
+
 def test_worker_packs_a_block_its_start_and_what_to_fix(monkeypatch):
     """One request of 7 prompt tokens and 6 new ones, alone: the passes'
     arrays, one by one.  The first block opens holding the prompt's tail
-    (one mask: one pass, then its commit), the others as four masks; a
-    block comes from the host when the host knows it (after a prefill,
-    after a commit) and from the pass in flight otherwise."""
-    seen = []
+    (one mask: one pass); its commit and the block that opens behind it
+    are two entries of ONE pass, on the same pages, the commit first; the
+    pass after takes the open block from the SECOND entry of the pass in
+    flight; the last block's commit rides alone.  A block comes from the
+    host when the host knows it (after a prefill, behind a commit) and
+    from the pass in flight otherwise."""
     with engine_for() as eng:
-        real = eng._decode_jit
-
-        def recording(*args):
-            seen.append(np.array(args[-1]))
-            return real(*args)
-        monkeypatch.setattr(eng, "_decode_jit", recording)
+        seen = record_passes(eng, monkeypatch)
         prompt = prompt_of(7)
         doc = eng.generate(prompt, max_new_tokens=6, timeout_s=600)
-        pages = None
-    assert doc["steps"] == len(seen) == 2 + 5 + 5
-    rows = []
-    for packed in seen:
-        toks, positions, tables, wpage, woff, lengths, fix = _step_views(
-            packed, eng.max_pages_per_seq, False, 4)
-        pages = tables[0] if pages is None else pages
-        assert tables[0].tolist() == pages.tolist()
-        assert wpage[0] == pages[positions[0] // 4] and woff[0] == 0
-        assert lengths[0] == positions[0] + 4
-        rows.append((toks[0].tolist(), int(positions[0]), int(fix[0])))
-    carried, masks = [-1] * 4, [MASK] * 4
+    assert doc["steps"] == len(seen) == 1 + 4 + 4 + 1
+    assert len({page for entries in seen for *_, page in entries}) == 1
+    rows = [[entry[:3] for entry in entries] for entries in seen]
+    first, second, masks = [-1] * 4, [-2] * 4, [MASK] * 4
     assert rows == [
-        (prompt[4:] + [MASK], 4, 1), (carried, 4, 0),
-        (masks, 8, 1), (carried, 8, 1), (carried, 8, 1), (carried, 8, 1),
-        (carried, 8, 0),
-        (masks, 12, 1), (carried, 12, 1), (carried, 12, 1),
-        (carried, 12, 1), (carried, 12, 0)]
+        [(prompt[4:] + [MASK], 4, 1)],
+        [(first, 4, 0), (masks, 8, 1)],
+        [(second, 8, 1)], [(first, 8, 1)], [(first, 8, 1)],
+        [(first, 8, 0), (masks, 12, 1)],
+        [(second, 12, 1)], [(first, 12, 1)], [(first, 12, 1)],
+        [(first, 12, 0)]]
     assert len(doc["generated"]) == 6 and len(doc["passes"]) == 6
     assert doc["passes"][0] == 0 and sorted(doc["passes"][1:5]) == [
         0, 1, 2, 3]
@@ -184,11 +205,13 @@ def test_tokens_come_out_a_block_at_its_commit_in_position_order(highest):
         doc = eng.submit_generate(prompt_of(6), max_new_tokens=10,
                                   on_token=on_token).result(timeout=600)
     assert stream == doc["generated"] and len(stream) == 10
-    # 2 tokens of the first block (its commit is the 3rd pass), then two
-    # whole blocks, each at its 5th pass
+    # 2 tokens of the first block (its commit is the 3rd pass, which is
+    # also the next block's first), then two whole blocks, each four
+    # passes on; only the last commit is a pass of its own
     commits = sorted(set(stamps))
     assert [stamps.count(c) for c in commits] == [2, 4, 4]
-    assert [b - a for a, b in zip(commits, commits[1:])] == [5, 5]
+    assert [b - a for a, b in zip(commits, commits[1:])] == [4, 4]
+    assert doc["steps"] == 2 + 4 + 4 + 1
     assert any(doc["passes"][i] > doc["passes"][i + 1] for i in range(9))
 
 
@@ -248,22 +271,22 @@ def test_a_finish_inside_a_block_trims_what_lies_behind(highest):
     """``max_new_tokens`` inside a block, and ``eos_id`` inside a block:
     the block is computed and committed whole, the tokens behind the last
     one are counted and not emitted, and the pass launched behind an
-    ``eos`` the host had not seen is discarded."""
+    ``eos`` the host had not seen is discarded, and so is the next
+    block's entry that rode with the commit that met the ``eos``."""
     trimmed = metrics.counter("decode.block.tokens_trimmed")
     discarded = metrics.counter("decode.tokens_discarded")
     with engine_for(seed=4) as eng:     # weights whose reply varies
         was = trimmed.value
         doc = eng.generate(prompt_of(8), max_new_tokens=6, timeout_s=600)
         assert doc["finish"] == "length" and len(doc["generated"]) == 6
-        assert doc["steps"] == 10 and trimmed.value - was == 2
+        assert doc["steps"] == 9 and trimmed.value - was == 2
         whole = eng.generate(prompt_of(8), max_new_tokens=12,
                              timeout_s=600)
         assert whole["generated"][:6] == doc["generated"]
         # end on a token inside a later block that no earlier position
         # holds (seeded weights repeat themselves)
         tokens = whole["generated"]
-        at = next(i for i in range(4, 11) if i % 4 != 3
-                  and tokens[i] not in tokens[:i])
+        at = next(i for i in range(4, 7) if tokens[i] not in tokens[:i])
         was, thrown = trimmed.value, discarded.value
         doc = eng.generate(prompt_of(8), max_new_tokens=12,
                            eos_id=tokens[at], timeout_s=600)
@@ -271,9 +294,11 @@ def test_a_finish_inside_a_block_trims_what_lies_behind(highest):
         assert doc["generated"] == tokens[:at + 1]
         assert trimmed.value - was == 3 - at % 4
         deadline = time.monotonic() + 60
-        while discarded.value == thrown and time.monotonic() < deadline:
+        while discarded.value < thrown + 2 and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert discarded.value - thrown == 1
+        # the third block's entry of the commit's own pass, and its entry
+        # of the pass launched behind
+        assert discarded.value - thrown == 2
         eng.assert_no_leaks()
 
 
@@ -322,13 +347,163 @@ def test_killed_replica_mid_block_replays_to_the_undisturbed_doc(
         eng.close(drain=True)
 
 
+# -- a commit rides with its sequence's next block (PR 43) -------------------
+@pytest.mark.parametrize("steps", [4, 2])
+def test_a_block_takes_its_denoising_passes_when_its_commit_folds(
+        steps, monkeypatch):
+    """One request of four blocks, alone (a rung of one holds a spare
+    entry): every block but the last costs its ``T`` denoising passes,
+    its commit an entry of the pass that opens the next block; the last
+    block's commit has nothing to ride with and is a pass of its own.
+    The program's counters say so: ``decode.block.passes`` reads ``T``
+    and then ``T + 1``, ``decode.block.commit_folded`` 100 and then 0,
+    and a pass with a folded commit still counts ONE sequence."""
+    lo = time.perf_counter()
+    with engine_for(denoising_steps=steps) as eng:
+        seen = record_passes(eng, monkeypatch)
+        doc = eng.generate(prompt_of(8), max_new_tokens=15, timeout_s=600)
+    hi = time.perf_counter()
+
+    def window(name):
+        return [v for _, v in
+                metrics.histogram(name).samples_between(lo, hi)[0]]
+
+    assert doc["steps"] == len(seen) == 4 * steps + 1
+    assert [len(entries) for entries in seen] == (
+        [1] * steps + ([2] + [1] * (steps - 1)) * 3 + [1])
+    fix = 4 // steps
+    for entries in seen:
+        if len(entries) == 2:
+            (_, at, commits, page), (block, then, fixes, same) = entries
+            assert (commits, fixes, then, same) == (0, fix, at + 4, page)
+            assert block == [MASK] * 4
+    assert seen[-1][0][2] == 0                  # the last commit, alone
+    assert window("decode.block.passes") == [steps] * 3 + [steps + 1]
+    assert window("decode.block.commit_folded") == [100.0] * 3 + [0.0]
+    assert window("decode.block.slots") == [1] * len(seen)
+    assert window("decode.block.commit_share") == [
+        100.0 * sum(1 for e in entries if e[2] == 0) / len(entries)
+        for entries in seen]
+    assert sum(window("decode.block.tokens_fixed")) == 16
+    assert max(doc["passes"]) == steps - 1 and len(doc["passes"]) == 15
+
+
+def test_more_commits_than_spare_entries_fall_back_and_lose_nothing(
+        highest, monkeypatch):
+    """Four prompts of whole blocks in the rung of four, whose program has
+    ONE spare entry: all four commit in one pass, one of them carries its
+    next block along, three commit alone and open theirs a pass later
+    (which moves their phase off the crowded pass), and every reply is
+    what the request gets alone.  A rung goes by its SEQUENCES."""
+    sizes = [(8, 12), (4, 12), (12, 12), (8, 12)]
+    prompts = [prompt_of(p, 7 + i) for i, (p, _) in enumerate(sizes)]
+    with engine_for() as alone:
+        wants = [alone.generate(p, max_new_tokens=n, timeout_s=600)
+                 for p, (_, n) in zip(prompts, sizes)]
+        assert [w["steps"] for w in wants] == [13] * 4
+    with engine_for(decode_ladder=(4,)) as eng:
+        assert eng._entries(4) == 5
+        seen = record_passes(eng, monkeypatch)
+        gens = [eng.submit_generate(p, max_new_tokens=n)
+                for p, (_, n) in zip(prompts, sizes)]
+        docs = [g.result(timeout=600) for g in gens]
+        st = eng.stats()
+        assert {tuple(s) for s in st["shapes_dispatched"]} == {
+            ("decode", 4), ("prefill", 8), ("prefill", 16)}
+        assert st["retrace_count"] <= st["retrace_bound"]
+        eng.assert_no_leaks()
+    for doc, want in zip(docs, wants):
+        assert doc["generated"] == want["generated"]
+        assert doc["passes"] == want["passes"]
+    assert max(len(entries) for entries in seen) == 5
+    crowded = [entries for entries in seen
+               if sum(1 for e in entries if e[2] == 0) == 4]
+    assert crowded and all(len(entries) == 5 for entries in crowded)
+    # one sequence kept its phase (13 passes); a commit that rode alone
+    # cost its sequence a pass
+    assert min(d["steps"] for d in docs) == 13
+    assert max(d["steps"] for d in docs) > 13
+
+
+@pytest.mark.parametrize("how", ["kill", "cancel", "fail"])
+def test_a_fused_pass_in_flight_is_survived(highest, monkeypatch, how):
+    """With a commit and its sequence's next block in ONE pass that is
+    launched and not landed: a killed replica's survivor replays to the
+    undisturbed doc (``kv_len`` advances at a commit's LANDING alone), a
+    cancel returns the landed blocks and nothing of either entry, and a
+    launch that fails is retried in place from the host's state."""
+    prompt = prompt_of(7, 5)
+    with engine_for() as alone:
+        want = alone.generate(prompt, max_new_tokens=17, timeout_s=600)
+    eng = engine_for(replicas=2 if how == "kill" else 1)
+    try:
+        stream, acted, gen = [], [], []
+        real_launch, real_jit = eng._launch, eng._decode_jit
+
+        def fused(packed):
+            _, _, tables, _, _, lengths, fix = _step_views(
+                np.array(packed), eng.max_pages_per_seq, False, 4)
+            return bool(lengths[1] and fix[0] == 0
+                        and (tables[0] == tables[1]).all())
+
+        def jit(*args):
+            if how == "fail" and not acted and len(stream) >= 5 \
+                    and fused(args[-1]):
+                acted.append("fail")
+                raise RuntimeError("a launch that fails")
+            return real_jit(*args)
+
+        def launch(rep, group, rung, prev):
+            flight = real_launch(rep, group, rung, prev)
+            if how != "fail" and not acted and len(stream) >= 5 \
+                    and len(flight.entries) > len(flight.group):
+                acted.append(how)
+                if how == "kill":
+                    eng.kill_replica(rep.index)
+                else:
+                    gen[0].cancel()
+            return flight
+
+        monkeypatch.setattr(eng, "_decode_jit", jit)
+        monkeypatch.setattr(eng, "_launch", launch)
+        gen.append(eng.submit_generate(prompt, max_new_tokens=17,
+                                       on_token=stream.append))
+        doc = gen[0].result(timeout=600)
+        assert acted == [how]
+        assert doc["generated"] == stream
+        if how == "cancel":
+            # the blocks that had landed: the tail's one token, then
+            # fours; the fused pass's commit never landed for the caller
+            assert doc["finish"] == "cancelled"
+            assert len(stream) in (5, 9) and stream == \
+                want["generated"][:len(stream)]
+            assert doc["passes"] == want["passes"][:len(stream)]
+        else:
+            assert doc["finish"] == "length"
+            assert doc["generated"] == want["generated"]
+            assert doc["passes"] == want["passes"]
+            assert doc["recoveries"] == (how == "kill")
+            # a failed launch costs no pass; a replay spends the open
+            # block's passes again
+            assert (doc["steps"] > want["steps"]) == (how == "kill")
+        st = eng.stats()
+        assert st["errors"] == 0
+        deadline = time.monotonic() + 60
+        while eng.kv_stats()["used_pages"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+        eng.assert_no_leaks()
+        assert eng.self_check() == 0
+    finally:
+        eng.close(drain=how != "cancel")
+
+
 # -- the door and the counters ----------------------------------------------
 @pytest.mark.parametrize("steps,prompt,new,passes", [
-    (4, 8, 8, 10),      # two blocks of four masks: 2 x (4 + 1)
-    (4, 7, 6, 2 + 5 + 5),   # the tail's block opens with one mask
+    (4, 8, 8, 9),       # two blocks of four masks, the last commit: 2 x 4 + 1
+    (4, 7, 6, 1 + 4 + 4 + 1),   # the tail's block opens with one mask
     (4, 5, 3, 4),       # one block: 3 masks, 3 passes and the commit
-    (2, 8, 8, 6),       # two tokens a pass: 2 x (2 + 1)
-    (2, 7, 6, 2 + 3 + 3),
+    (2, 8, 8, 5),       # two tokens a pass: 2 x 2 + 1
+    (2, 7, 6, 1 + 2 + 2 + 1),
 ])
 def test_the_door_reckons_passes(steps, prompt, new, passes):
     with engine_for(denoising_steps=steps) as eng:
@@ -370,19 +545,20 @@ def test_stats_and_events_say_passes_and_tokens_apart(tmp_path,
                                timeout_s=600)
             after = eng.stats()
         assert after["tokens"] - before["tokens"] == 7
-        assert after["steps"] - before["steps"] == 10 == doc["steps"]
-        assert after["step_s"]["count"] == 10
+        assert after["steps"] - before["steps"] == 9 == doc["steps"]
+        assert after["step_s"]["count"] == 9
     finally:
         events.reset()
     from dist_keras_tpu.observability import report
 
     records = report.read_events(str(tmp_path))
     steps = [r for r in records if r["kind"] == "decode_step"]
-    assert len(steps) == 10
-    # four denoising passes of one position and a commit, twice
-    assert [r["fixed"] for r in steps] == [1, 1, 1, 1, 0] * 2
+    assert len(steps) == 9
+    # four denoising passes of one position, twice (the fifth pass also
+    # commits the first block), and the last block's commit
+    assert [r["fixed"] for r in steps] == [1] * 8 + [0]
     done = [r for r in records if r["kind"] == "decode_complete"]
-    assert [(r["generated"], r["steps"]) for r in done] == [(7, 10)]
+    assert [(r["generated"], r["steps"]) for r in done] == [(7, 9)]
     prefill = [r for r in records if r["kind"] == "decode_prefill"]
     assert prefill[0]["ttft_s"] is None     # a prefill yields no token
     assert doc["ttft_s"] > 0

@@ -229,7 +229,10 @@ def test_one_pool_of_rows_and_the_other_families_are_a_token_wide():
         assert module.step_width({}) == 1
     with engine_for(cfg, weights_for(cfg), num_pages=20) as eng:
         assert eng.pool_shapes == ((3, 21, 4, 64),)
-        assert eng._out_width == 4 * 4 + len(HELD) + 2
+        # the top rung's four sequences and the one spare entry a block
+        # of four passes asks for: five blocks, then the counts
+        assert eng._entries(4) == 5 and eng._entries(1) == 2
+        assert eng._out_width == 5 * 4 + len(HELD) + 2
 
 
 # -- (3) generation through the engine -----------------------------------
@@ -288,21 +291,30 @@ def _record_sources(eng, monkeypatch):
 
 
 @pytest.mark.parametrize("steps", [4, 2])
-def test_slots_generate_together_as_each_does_alone(highest, steps):
-    """The top rung: four requests of different prompt tails and lengths
-    in one pass, some committing while others denoise, each the
-    reference's own."""
+@pytest.mark.parametrize("phase", ["apart", "together"])
+def test_slots_generate_together_as_each_does_alone(highest, steps, phase):
+    """The top rung: four requests in one pass, each the reference's own.
+    ``apart``: different prompt tails and lengths, some committing while
+    others denoise, a commit's next block in the pass's one spare entry.
+    ``together``: four prompts of whole blocks, so all four commit in one
+    pass and three of them find no entry to spare: those commit alone,
+    open their next block a pass later, and lose nothing."""
     cfg = config(denoising_steps=steps)
     params = weights_for(cfg)
+    sizes = CASES[:4] if phase == "apart" else [(8, 9), (4, 6), (12, 7),
+                                                (8, 5)]
     with engine_for(cfg, params, decode_ladder=(1, 4)) as eng:
-        gens = [(prompt_of(length, 1), n) for length, n in CASES[:4]]
+        gens = [(prompt_of(length, 1), n) for length, n in sizes]
         gens = [(p, n, eng.submit_generate(p, max_new_tokens=n))
                 for p, n in gens]
         for prompt, n, gen in gens:
             doc = gen.result(timeout=600)
             tokens, passes = reference_generate(params, prompt, n, cfg)
             assert (doc["generated"], doc["passes"]) == (tokens, passes)
+        # a rung is named by its SEQUENCES, whatever entries its pass holds
         assert ("decode", 4) in eng.stats()["shapes_dispatched"]
+        assert {rung for phase_, rung in eng.stats()["shapes_dispatched"]
+                if phase_ == "decode"} <= {1, 4}
         eng.assert_no_leaks()
 
 
@@ -458,9 +470,29 @@ STAMPED = ("decode.block.slots", "decode.block.tokens_fixed",
            "decode.moe.experts_hit")
 
 
-def test_counters_exist_and_are_stamped():
+def _record_passes(eng, monkeypatch):
+    """Every pass the worker hands the device, in order: (its live
+    entries, those that commit, the commits whose sequence's next block
+    is the entry right behind, recognised by the page table they share)."""
+    seen = []
+    real = eng._decode_jit
+
+    def recording(*args):
+        _, _, tables, _, _, lengths, fix = _step_views(
+            np.array(args[-1]), eng.max_pages_per_seq, False, eng._width)
+        live = int((lengths > 0).sum())
+        folded = sum(1 for i in range(live - 1) if fix[i] == 0
+                     and (tables[i] == tables[i + 1]).all())
+        seen.append((live, int((fix[:live] == 0).sum()), folded))
+        return real(*args)
+    monkeypatch.setattr(eng, "_decode_jit", recording)
+    return seen
+
+
+def test_counters_exist_and_are_stamped(monkeypatch):
     cfg = config()
     for name in PAIRS + STAMPED + ("decode.block.passes",
+                                   "decode.block.commit_folded",
                                    "decode.block.tokens_trimmed",
                                    "decode.moe.load_max_over_mean"):
         assert name in metrics.KNOWN_METRICS, name
@@ -469,12 +501,14 @@ def test_counters_exist_and_are_stamped():
     was = trimmed.value
     lo = time.perf_counter()
     with engine_for(cfg, weights_for(cfg), decode_ladder=(4,)) as eng:
+        seen = _record_passes(eng, monkeypatch)
         sizes = [(8, 9), (5, 6), (7, 10), (6, 4)]
         gens = [eng.submit_generate(prompt_of(p), max_new_tokens=n)
                 for p, n in sizes]
         docs = [g.result(timeout=600) for g in gens]
         passes = eng.stats()["steps"]
     hi = time.perf_counter()
+    assert len(seen) == passes
     steps = metrics.histogram("decode.step_s").samples_between(lo, hi)[0]
     assert len(steps) == passes
     for name in STAMPED:
@@ -493,34 +527,41 @@ def test_counters_exist_and_are_stamped():
     assert trimmed.value - was == computed - sum(n for _, n in sizes)
     assert sum(len(d["generated"]) for d in docs) \
         == sum(n for _, n in sizes)
-    # a block of four masks takes 4 + 1 passes; the one that holds a
-    # prompt's tail takes fewer.  One sample a pass that committed
-    # blocks, their mean: weighted by the blocks a pass committed (its
-    # slots x its commit share) the samples give back every block
-    took = metrics.histogram("decode.block.passes").samples_between(
-        lo, hi)[0]
-    by_pass = {at: s * c / 100.0 for (at, s), (_, c) in zip(
-        metrics.histogram("decode.block.slots").samples_between(lo, hi)[0],
-        metrics.histogram("decode.block.commit_share").samples_between(
-            lo, hi)[0])}
-    assert all(round(by_pass[at]) >= 1 for at, _ in took)
-    assert len(took) == sum(1 for n in by_pass.values() if round(n))
-    assert round(sum(v * by_pass[at] for at, v in took)) == \
-        5 * (sum(blocks) - 4) + sum(5 - p % 4 for p, _ in sizes)
-    assert all(2.0 <= v <= 5.0 for _, v in took)
+    # the SEQUENCES of a pass (a commit and the block that opens behind
+    # it are two entries of one), the share of its entries that commit
+    # and, where any does, the share of those whose next block rides along
+    entries, commits, folded = (list(col) for col in zip(*seen))
     slots = window("decode.block.slots")
-    assert max(slots) == 4 and min(slots) >= 1
+    assert slots == [e - f for e, f in zip(entries, folded)]
+    assert max(slots) == 4 and min(slots) >= 1 and max(entries) <= 5
     share = window("decode.block.commit_share")
+    assert share == pytest.approx(
+        [100.0 * c / e for c, e in zip(commits, entries)])
     assert 0.0 in share and max(share) <= 100.0
-    commits = sum(s * c / 100.0 for s, c in zip(slots, share))
-    assert round(commits) == sum(blocks)
+    assert sum(commits) == sum(blocks)
+    assert window("decode.block.commit_folded") == pytest.approx(
+        [100.0 * f / c for c, f in zip(commits, folded) if c])
+    # every block but a request's last has the next one open behind its
+    # commit, unless its pass had no entry to spare
+    assert 1 <= sum(folded) <= sum(blocks) - len(sizes)
+    # a block of four masks takes 4 passes, the one that holds a prompt's
+    # tail fewer, and a commit that rode alone is one more.  One sample a
+    # pass that committed blocks, their mean: weighted by the blocks a
+    # pass committed the samples give back every block
+    took = window("decode.block.passes")
+    assert len(took) == sum(1 for c in commits if c)
+    assert round(sum(v * c for v, c in zip(
+        took, (c for c in commits if c)))) == \
+        4 * (sum(blocks) - 4) + sum(4 - p % 4 for p, _ in sizes) \
+        + sum(commits) - sum(folded)
+    assert all(1.0 <= v <= 5.0 for v in took)
     live = window("decode.kv.live_positions")
-    assert min(live) >= 8 and max(live) <= 4 * 20
+    assert min(live) >= 8 and max(live) <= 5 * 20
     total, held = (metrics.counter(n).value - b
                    for n, b in zip(PAIRS, before))
-    # the rows of every pass (a block a slot) and the committed positions
-    # of every prefill, in all three layers
-    rows = 4 * sum(slots) + sum(p - p % 4 for p, _ in sizes)
+    # the rows of every pass (a block an entry) and the committed
+    # positions of every prefill, in all three layers
+    rows = 4 * sum(entries) + sum(p - p % 4 for p, _ in sizes)
     assert total == rows * cfg["top_k"] * 3
     assert 0 < held < total
 
