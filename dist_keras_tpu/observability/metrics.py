@@ -416,6 +416,15 @@ KNOWN_METRICS = {
     "decode.block.passes": "histogram",
     "decode.block.commit_folded": "histogram",
     "decode.block.tokens_trimmed": "counter",
+    # a looped model's steps (models/ouro.py: one stack of layers run
+    # several times a token): a stamped sample a decode step of the
+    # passes it ran and of the mean over its live slots of the pass whose
+    # state the head read (counted from 1; the counts come off the device
+    # behind the step's tokens), and the layer applications of every step
+    # and prefill (passes x layers of weights)
+    "decode.loop.passes": "histogram",
+    "decode.loop.exit_pass": "histogram",
+    "decode.loop.layer_passes": "counter",
     # decode survivability plane (serving/decode.py): quarantine +
     # sequence recovery, deadline admission/expiry, brownout shedding
     # (shed is deliberately NOT folded into decode.rejected — the

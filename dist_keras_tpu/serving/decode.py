@@ -34,7 +34,13 @@ Design points (each mirrors an existing engine contract):
   SEQUENCE, over its convolution layers only, and for
   ``models/olmo_hybrid.py`` one paged pool of ``v | k`` rows over its
   attention layers and TWO per-sequence pools over its linear-attention
-  layers (the convolutions' last inputs; the recurrent matrices).  A
+  layers (the convolutions' last inputs; the recurrent matrices).  **The
+  "layers it spans" are the family's count of ENTRIES,** not of its
+  layers of weights: ``models/ouro.py``, whose one stack of ``n_layers``
+  layers runs ``ut_steps`` times a token on shared weights, states one
+  paged pool over ``ut_steps x n_layers`` of them (a row a position for
+  every (pass, layer), written and read through a traced index inside
+  the loop its steps hold), and nothing here asks what an entry is.  A
   family may hold any number of pools of either kind: they are all
   addressed by the ONE page table and the ONE row a sequence has, and the
   steps take and return them in ``cache_pools``' order.  A paged pool is
@@ -145,6 +151,7 @@ from dist_keras_tpu.models import (
     lfm2_moe,
     mla_moe,
     olmo_hybrid,
+    ouro,
     sdar_moe,
     transformer,
 )
@@ -261,7 +268,8 @@ class Generation:
 # model's ``cfg["family"]`` gives; a cfg that names none is a
 # ``Transformer``'s), ``vocab(cfg)`` (which also refuses what the family
 # cannot decode), ``cache_pools(cfg)`` (for each pool ``(layers, rows,
-# entry)``: how many layers it spans, whether its rows are ``"page"``s of
+# entry)``: how many layers it spans (ENTRIES: a looped family states
+# passes times layers), whether its rows are ``"page"``s of
 # cached positions or one ``"sequence"`` each, and the trailing shape of
 # one entry), ``step_width(cfg)`` (positions a slot a step: 1 where a
 # step yields one token a sequence; a family that generates in BLOCKS
@@ -277,7 +285,7 @@ class Generation:
 # sends none).
 _FAMILIES = {m.FAMILY: m
              for m in (transformer, mla_moe, lfm2_moe, olmo_hybrid,
-                       sdar_moe)}
+                       sdar_moe, ouro)}
 
 
 def _step_views(packed, pmax, state=False, width=1):

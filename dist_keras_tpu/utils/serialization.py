@@ -37,7 +37,7 @@ def serialize_model(model):
 _DECODERS = {"Transformer": "transformer", "LatentMoEDecoder": "mla_moe",
              "Lfm2MoeDecoder": "lfm2_moe",
              "OlmoHybridDecoder": "olmo_hybrid",
-             "SdarMoeDecoder": "sdar_moe"}
+             "SdarMoeDecoder": "sdar_moe", "OuroDecoder": "ouro"}
 
 
 def deserialize_model(d):

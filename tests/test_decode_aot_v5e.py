@@ -23,6 +23,7 @@ from dist_keras_tpu.models import (
     lfm2_moe,
     mla_moe,
     olmo_hybrid,
+    ouro,
     sdar_moe,
     transformer,
 )
@@ -716,6 +717,124 @@ def test_delta_rule_step_leaves_its_three_pools_in_place(topo, as_tpu, phase,
         assert [a.shape for a in ints] == [
             (rung,), (rung * (pages_per_seq + 6),)]
         assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
+
+
+# -- the looped family (models/ouro.py) ------------------------------------
+# ouro-2.6b as the benchmark cuts it: every width as published, layers 0-11
+# of 48, all four passes, 16 slots of 768 positions in pages of 16
+O_SLOTS, O_POSITIONS, O_PAGE, O_LAYERS, O_PASSES = 16, 768, 16, 12, 4
+
+
+def _looped_cfg():
+    return ouro.ouro_config(
+        vocab_size=49152, seq_len=O_POSITIONS, d_model=2048, n_heads=16,
+        n_kv_heads=16, head_dim=128, d_ff=5632, n_layers=O_LAYERS,
+        ut_steps=O_PASSES)
+
+
+@pytest.mark.parametrize("phase,rung,temp_gb", [
+    ("packed_decode", O_SLOTS, 0.05), ("packed_prefill", 288, 0.05)])
+def test_looped_step_holds_the_stack_once_and_the_pool_in_place(
+        topo, as_tpu, phase, rung, temp_gb):
+    """The sixth family's decode step and its longest prefill, compiled
+    for a v5e at the cell's sizes: ONE loop over the four passes whose body
+    holds the twelve layers once (twelve ``latent_decode`` reads of the
+    flat float32 pool at 16 query rows a slot, or twelve ``flash_fwd`` of
+    16 heads of 128: not forty-eight), the ``v | k`` pool of ``48 x 769
+    x 16 x 4,096`` values (9.68 GB) donated and aliased THROUGH the loop,
+    every result of its size one of the twelve in-place scatters of the
+    body (the entry's index a traced value), and no copy of the pool, of
+    a pass's twelve entries or of one entry.  Weights 3.27 GB.  **The
+    temporaries (0.012 and 0.022 GB: what the configuration's
+    ``reduced_why`` quotes) say that the passes read the float32 leaves
+    themselves:** without ``ouro._float32_reader`` and the barrier beside
+    it the compiler holds a bfloat16 copy of the twelve layers, 1.27 GB,
+    for as long as either program runs (the last lines)."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = _looped_cfg()
+    pages_per_seq = O_POSITIONS // O_PAGE
+    engine = _bare_engine(cfg, ouro, O_PAGE, O_SLOTS * pages_per_seq)
+    (kv_shape,) = engine.pool_shapes
+    assert kv_shape == (O_PASSES * O_LAYERS, engine.num_pages + 1, O_PAGE,
+                        4096)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(ouro.init_params, cfg=cfg),
+                       jax.random.PRNGKey(0)))
+    fn, args = _step_and_args(engine, phase, rung, pages_per_seq, S,
+                              counts=ouro.N_COUNTS)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, S(kv_shape, jnp.float32), *args).compile()
+    text = compiled.as_text()
+
+    kv_elems = math.prod(kv_shape)
+    big = {kv_elems: "pool", kv_elems // O_PASSES: "a pass's entries",
+           kv_elems // kv_shape[0]: "entry"}
+    roots = _roots(text)
+    scatters, offenders = set(), []
+    for comp, name, elems, opcode, line in _instructions(text):
+        if elems not in big or opcode in FREE | {"while"}:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        root = roots.get(called.group(1)) if called else None
+        if opcode == "fusion" and called.group(1).startswith("bitcast"):
+            continue                                  # a view: moves nothing
+        if big[elems] == "pool" and opcode in WRITES:
+            scatters.add(name)
+            continue
+        if big[elems] == "pool" and opcode == "fusion" and root in WRITES:
+            continue                       # the fusion around a scatter
+        offenders.append(f"{comp}: %{name} = {opcode} of {big[elems]} size")
+    assert not offenders, offenders
+    # a layer writes its rows once, in place (the decode step's compiler
+    # clones one scatter's fusion: the same row written where it lies)
+    assert O_LAYERS <= len(scatters) <= O_LAYERS + 1, scatters
+    # the passes are a loop of the program: one while, its state the pool
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 1
+    assert f"f32[{','.join(map(str, kv_shape))}]" in loops[0]
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * kv_elems            # donated
+    assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
+    weights = m.argument_size_in_bytes - 4 * kv_elems
+    assert 3.26 * GB < weights < 3.28 * GB, weights
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == O_LAYERS, len(kernels)
+    if "prefill" in phase:
+        assert all("flash_fwd" in k for k in kernels)
+        assert all(f"f32[16,{rung},128]" in k for k in kernels), kernels[0]
+        return
+    assert all("latent_decode" in k for k in kernels)
+    flat = f"f32[{math.prod(kv_shape[:2])},{O_PAGE},4096]"
+    assert all(flat in k and f"f32[{rung},16,4096]" in k
+               for k in kernels), kernels[0]
+    # the head's is the one product with the vocabulary's dimension
+    assert sum(str(cfg["vocab_size"]) in line for line in text.splitlines()
+               if " convolution(" in line or " dot(" in line) == 1
+    ints = [a for a in jax.tree.leaves(compiled.args_info)
+            if a.dtype == jnp.int32]
+    assert [a.shape for a in ints] == [
+        (rung + ouro.N_COUNTS,), (rung * (pages_per_seq + 5),)]
+    assert re.search(r"^HloModule jit__packed_decode_fn", text, re.M)
+    # what the reader is for: without it the layers are held twice
+    real = ouro._float32_reader
+    ouro._float32_reader = lambda blk: 0.0
+    try:
+        # a function of its own: ``fn`` itself is traced already
+        copied = jax.jit(lambda *a: fn(*a), donate_argnums=(1,)).lower(
+            params, S(kv_shape, jnp.float32), *args).compile()
+    finally:
+        ouro._float32_reader = real
+    assert 1.2 * GB < copied.memory_analysis().temp_size_in_bytes < 1.3 * GB
 
 
 # -- the train step (parallel/transformer_tp.py) --------------------------

@@ -1,4 +1,4 @@
-"""The four block families whose step is one token a slot compile to the
+"""The five block families whose step is one token a slot compile to the
 programs recorded here.
 
 PR 32 widened the family seam (a pool states the layers it spans and
@@ -57,6 +57,14 @@ leaving every family of width 1 its programs: the twelve entries pass as
 recorded, and ``olmo_hybrid``'s four were recorded from commit 42c93af (PR
 41), in a checkout of that commit, and hash alike on PR 42's tree.
 
+**PR 44 added a sixth family behind the seam as it stood and its own four
+entries.**  ``models/ouro.py`` (one stack of layers run several times a
+token as a loop of both programs, a cache entry a (pass, layer)) needed
+no line of the engine but the registry's entry: the sixteen older entries
+pass as recorded (``sdar_moe``'s programs, whose step is a pass over
+blocks, are not pinned here), and this family's four were recorded on
+that PR's tree.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.
@@ -68,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid
+from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid, ouro
 from dist_keras_tpu.models.transformer import Transformer, transformer_config
 from dist_keras_tpu.serving import DecodeEngine
 
@@ -105,8 +113,15 @@ def _olmo_hybrid():
         linear_heads=4, linear_key_dim=8, linear_value_dim=16), seed=1)
 
 
+def _ouro():
+    return ouro.OuroDecoder(cfg=ouro.ouro_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=4, n_kv_heads=4,
+        head_dim=16, d_ff=96, n_layers=3), seed=1)
+
+
 MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
-          "lfm2_moe": _lfm2_moe, "olmo_hybrid": _olmo_hybrid}
+          "lfm2_moe": _lfm2_moe, "olmo_hybrid": _olmo_hybrid,
+          "ouro": _ouro}
 # what a family's engine is given beside the ladders (a state row of this
 # family is large: the engine is told how many it holds)
 ENGINE = {"olmo_hybrid": dict(state_rows=3)}
@@ -151,6 +166,15 @@ RECORDED = {
         "b1e87a964519051d5be74035447e0dce5a97ef638047a9f91585c62e70575a95",
     ("olmo_hybrid", "decode", "tpu"):
         "dcc76d0cb380b219c8853e188447db52cd8c80dde39477185813cb0efe4fb8ce",
+    # recorded on PR 44's tree: the family is new there
+    ("ouro", "prefill", "cpu"):
+        "a38205adb71a39f35021726ee839654c4757762be53fab625312b479a06662a4",
+    ("ouro", "decode", "cpu"):
+        "27412625876cb9e2a960a00e2f04a89951b5849523756e5e8e560c2646da49da",
+    ("ouro", "prefill", "tpu"):
+        "f18f0e83b96469e9336f0281f852d4b5c51f34d918dde9f5819a6f721d674517",
+    ("ouro", "decode", "tpu"):
+        "44b36cf01f363809ef2b6425764d4d2ba15ce62fc9cf90827c4d55d87a36b142",
 }
 
 

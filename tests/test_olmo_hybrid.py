@@ -232,7 +232,7 @@ def test_serialization_round_trip_holds_no_second_set_of_weights():
     for a, b in zip(jax.tree.leaves(model.params),
                     jax.tree.leaves(back.params)):
         np.testing.assert_array_equal(a, b)
-    assert _FAMILIES["olmo_hybrid"] is olmo_hybrid and len(_FAMILIES) == 5
+    assert _FAMILIES["olmo_hybrid"] is olmo_hybrid and len(_FAMILIES) == 6
 
 
 @pytest.mark.parametrize("bad,match", [
