@@ -344,7 +344,7 @@ def _sequence_layers(params, tokens, length, cfg, write_kv, write_state):
     valid = positions < length
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg)
+    counts = _zero_counts(cfg, tokens.shape[0])
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, cfg["rms_norm_eps"])
         if "conv" in blk:

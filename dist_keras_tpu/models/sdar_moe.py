@@ -79,6 +79,7 @@ from dist_keras_tpu.models.lfm2_moe import _qkv, attend_rows
 from dist_keras_tpu.models.mla_moe import (
     _swiglu_params,
     _zero_counts,
+    add_counts,
     moe_layer,
     observe_routing,
     rms_norm,
@@ -221,7 +222,7 @@ def _ffn(blk, x, cfg, valid, counts):
     out, c = moe_layer(blk["moe"],
                        rms_norm(blk["ffn_norm"], x, cfg["rms_norm_eps"]),
                        cfg, valid, router=route)
-    return x + out, counts + c
+    return x + out, add_counts(counts, c)
 
 
 def _logits(params, hs, cfg):
@@ -261,7 +262,7 @@ def _sequence_layers(params, tokens, valid, cfg, write):
     positions = jnp.arange(t, dtype=jnp.int32)
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg)
+    counts = _zero_counts(cfg, t)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, cfg["rms_norm_eps"])
         q, entry = _qkv(blk["attn"], y, positions, cfg)

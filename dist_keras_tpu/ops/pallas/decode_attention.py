@@ -270,7 +270,7 @@ def _page_copies(pages_ref, page_ids, buf, sem):
             for i, page in enumerate(page_ids)]
 
 
-def _operand_dtype():
+def operand_dtype():
     """What a product rounds float32 operands to under the ambient
     ``jax.default_matmul_precision``: bfloat16 (one MXU pass, what the
     reference's ``einsum``s do on a TPU) unless a caller asked for more,
@@ -388,7 +388,7 @@ def latent_attention_kernel(q, latent_pages, page_table, lengths, *, rank,
     ``"parallel"``: the carried prefetch orders it).  A block is fetched
     once and meets every head's absorbed query as two MXU products,
     ``(H, R) x (R, T)`` and ``(H, T) x (T, rank)``, operands rounded as
-    the ambient matmul precision says (:func:`_operand_dtype`), sums,
+    the ambient matmul precision says (:func:`operand_dtype`), sums,
     maximum and exponentials in float32.  Blocks at or past a slot's
     length are skipped; a padding slot (``length == 0``) yields exact
     zeros.  Under its own ``jax.jit`` so that a step traces and lowers
@@ -403,7 +403,7 @@ def latent_attention_kernel(q, latent_pages, page_table, lengths, *, rank,
     # slice of the block is free, any other would be a copy of it
     vcols = rank if rank % 128 == 0 else r
     kernel = functools.partial(_latent_kernel, scale=scale, n_pages=n_pages,
-                               operand=_operand_dtype())
+                               operand=operand_dtype())
     q_map = lambda si, j, pt, ln: (si, 0, 0)                  # noqa: E731
     extra = ({} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"))})

@@ -73,6 +73,15 @@ def _roots(text):
     return roots
 
 
+def _expert_loops(text):
+    """The loops of the held experts' grouped form in a compiled program
+    (one over the experts, inside it one over an expert's sorted pairs):
+    what ``mla_moe.held_experts`` runs in a call of more than 1,024 tokens
+    (PR 47), and in no shorter one."""
+    return [line for line in text.splitlines()
+            if " while(" in line and "moe_experts/while" in line]
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -278,17 +287,20 @@ def _latent_cfg():
 
 
 @pytest.mark.parametrize("phase,rung,temp_gb", [
-    ("decode", L_SLOTS, 0.1), ("decode", 8, 0.1), ("prefill", 2560, 0.8),
-    ("prefill", 6144, 1.5), ("packed_decode", L_SLOTS, 0.1)])
+    ("decode", L_SLOTS, 0.1), ("decode", 8, 0.1), ("prefill", 2560, 0.4),
+    ("prefill", 6144, 0.8), ("packed_decode", L_SLOTS, 0.1)])
 def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
                                               temp_gb):
     """The new family's steps hold no copy of the latent pool (rows of
     whole lanes: at the entry's own 576 values the v5e's default layout
     puts the pages minor and every step converts the pool twice, 4.4 GB
     each way) and no vocabulary-sized temporary (the head's norm weight
-    folded into the head); their temporaries stay under ``temp_gb``: the
-    held experts' hidden rows of one layer for a prefill, next to nothing
-    for a decode step, whose read of the pool is one Mosaic kernel a
+    folded into the head); their temporaries stay under ``temp_gb``: for
+    a prefill the flash forward's and one pass of 256 sorted pairs of the
+    held experts (the masked dense pass held every expert's hidden rows
+    of a layer, and its bounds were 0.8 and 1.5 GB until PR 47; the
+    grouped form's experts are converted behind a barrier, or every
+    layer's are at once), next to nothing for a decode step, whose read of the pool is one Mosaic kernel a
     layer that walks the live pages where they lie (no gathered rows:
     0.27 GB of them in bfloat16 until PR 28, under 0.5 GB then)."""
     import functools
@@ -344,7 +356,15 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
     assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
     if phase == "prefill":
         assert "flash_fwd" in text
+        # both rungs are over ``mla_moe.GROUPED_OVER``: each of the eight
+        # expert layers runs its held experts over the sorted pairs, a
+        # loop over the experts around a loop over one expert's pairs, and
+        # the flash forward stays the program's only kernel
+        assert len(_expert_loops(text)) == 2 * (cfg["n_layers"] - 1)
+        assert all("flash_fwd" in line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line)
         return
+    assert not _expert_loops(text)            # a step is the dense pass
     if phase == "packed_decode":
         # the worker's one array crosses into the step whole: nothing
         # else of its type comes in, and the program is still found by
@@ -461,6 +481,8 @@ def test_conv_expert_step_leaves_both_pools_in_place(topo, as_tpu, phase,
     assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
+    # rungs of 1,024 tokens or fewer: the held experts stay the dense pass
+    assert not _expert_loops(text)
     if "prefill" in phase:
         assert len(kernels) == 2 and all("flash_fwd" in k for k in kernels)
         # q of 32 heads, k and v of 8: fetched through the index map
@@ -572,6 +594,8 @@ def test_block_diffusion_step_leaves_the_pool_in_place(topo, as_tpu, phase,
     kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == 16
+    # the top prefill rung is 1,024 tokens, a pass 160 rows: the dense pass
+    assert not _expert_loops(text)
     if "prefill" in phase:
         assert all("flash_fwd" in k for k in kernels)
         assert all(f"f32[32,{rung},128]" in k and f"f32[4,{rung},128]" in k

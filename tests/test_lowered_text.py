@@ -65,6 +65,19 @@ pass as recorded (``sdar_moe``'s programs, whose step is a pass over
 blocks, are not pinned here), and this family's four were recorded on
 that PR's tree.
 
+**PR 47 gave ``mla_moe.held_experts`` a second form and was held to
+leaving every program here as it was.**  A call of more than 1,024 tokens
+(``mla_moe.GROUPED_OVER``) runs the held experts over the chosen pairs
+sorted by expert, an expert at a time through plain products; a call of
+1,024 or fewer runs the masked dense pass, the same operations in the
+same order (the sum, then the counts).  The choice is by the call's
+static token count, every rung here is a toy one far under it, and a
+prefill of the grouped form alone sends one count more: the twenty
+entries pass as recorded (``mla_moe``'s and ``lfm2_moe``'s, which call
+the function, among them), and so do the decode programs and the prefill
+rungs of 256-1,024 tokens that ``lfm2_serve_turns`` and
+``sdar_serve_blocks`` run.
+
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
 benchmark cell's program.

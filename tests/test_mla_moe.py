@@ -2,8 +2,10 @@
 at a small size on the CPU, against the plain reference the benchmark
 keeps (``benchmark/reference/mla_moe_ref.py``): whole-sequence forward,
 prefill then decoding through ``DecodeEngine``'s paged latent pool, the
-routing case table, the share test, the pool's layout and the routing
-counters.  Logits are compared, not tokens.
+routing case table, the two forms of the held experts' sum (a masked
+dense pass, and over 1,024 tokens the pairs sorted by expert), the share
+test, the pool's layout and the routing counters.  Logits are compared,
+not tokens.
 
 Tolerances.  Everything here is float32 on the CPU, the program under
 ``jax.default_matmul_precision("highest")`` where it is compared (the
@@ -12,6 +14,8 @@ the order of float32 sums only: logits of magnitude up to 3 agree to
 about 3e-6, and the limit is 1e-4 (30 times the reading, five hundred
 times under the 0.05 by which a bfloat16 product moves such a logit).
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -255,6 +259,198 @@ def test_every_token_to_one_expert_drops_none(highest):
     valid = jnp.arange(24) < 10
     _, counts = mla_moe.moe_layer(moe, x, cfg, valid)
     assert counts.tolist() == [10, 10, 2, 30]
+    # nor does the grouped form know a capacity: the same router over more
+    # tokens than the crossover, every pair on two experts, five passes each
+    n = N_OVER[0]
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, cfg["d_model"]))
+    got, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((n,), bool))
+    assert counts.tolist() == [n, n, 2, 3 * n]
+    want = ref.expert_layer(moe, x, conf, (2, 3))
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+# -- (4b) the two forms of the held experts' sum ---------------------------
+# a call of more than mla_moe.GROUPED_OVER tokens runs the pairs sorted by
+# expert, an expert at a time; the dense form of the same call is what the
+# same function gives with the crossover out of reach
+N_OVER = (1280, 2560)
+# of the largest output: the order of a token's three float32 additions;
+# a bfloat16 rounding of the rows and the experts (the default precision
+# on the CPU leaves the dense side float32)
+EXACT, ROUNDED = 1e-6, 2e-2
+
+
+def _dense_form(monkeypatch, fn, *args, **kw):
+    with monkeypatch.context() as m:
+        m.setattr(mla_moe, "GROUPED_OVER", 10 ** 9)
+        return fn(*args, **kw)
+
+
+def _some_of(rng, experts, k, n):
+    """``k`` distinct experts of ``experts`` a token, in a random order."""
+    return rng.permuted(np.tile(np.asarray(experts), (n, 1)), axis=1)[:, :k]
+
+
+# name -> (the experts held, the experts a token's three are drawn from)
+GROUPED = {
+    "every_expert_held": (range(8), range(8)),
+    "pairs_on_experts_not_held": ((2, 3, 4), range(8)),
+    # and expert 3 put in every token's second place
+    "all_to_one_held_expert": ((2, 3, 4), (0, 1, 5, 6, 7)),
+    "a_held_expert_without_a_pair": ((2, 3, 4), (0, 1, 2, 4, 5, 6, 7)),
+    "no_pair_held": ((2, 3, 4), (0, 1, 5, 6, 7)),
+    "padding_in_the_middle_and_at_the_end": ((2, 3, 4), range(8)),
+}
+
+
+def _grouped_case(case, n):
+    held, drawn = GROUPED[case]
+    rng = np.random.default_rng(sorted(GROUPED).index(case))
+    idx, at = _some_of(rng, drawn, 3, n), np.arange(n)
+    if case == "all_to_one_held_expert":
+        idx[:, 1] = 3
+    valid = (((at < 300) | (at >= 420)) & (at < n - 130)
+             if case.startswith("padding") else np.ones(n, bool))
+    experts = mla_moe._swiglu_params(jax.random.PRNGKey(3), 64, 48,
+                                     (len(held),))
+    x = jax.random.normal(jax.random.PRNGKey(4), (n, 64))
+    w = jnp.asarray(rng.uniform(0.1, 1.0, idx.shape), jnp.float32)
+    return (experts, x, jnp.asarray(idx, jnp.int32), w, held[0],
+            jnp.asarray(valid))
+
+
+@pytest.mark.parametrize("precision,bound", [("highest", EXACT),
+                                             (None, ROUNDED)])
+@pytest.mark.parametrize("n", N_OVER)
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_grouped_form_equals_the_dense_form(monkeypatch, case, n, precision,
+                                            bound):
+    """Both forms on the same pairs: under "highest" they differ by the
+    order of a token's at most ``top_k`` float32 additions; under the
+    default the grouped form rounds the rows and the experts to bfloat16
+    (the one MXU pass of the dense products on a TPU; on the CPU the
+    dense side stays float32), a rounding's size of the largest output.
+    The pairs on each held expert are the same numbers; an expert with
+    pairs takes several passes of ``GROUP_TILE_ROWS``, the last not full,
+    and one without takes none."""
+    args = _grouped_case(case, n)
+    with jax.default_matmul_precision(precision or "default"):
+        want, want_sizes = _dense_form(monkeypatch, mla_moe.held_experts,
+                                       *args)
+        got, sizes = mla_moe.held_experts(*args)
+    sizes = np.asarray(sizes)
+    assert sizes.tolist() == want_sizes.tolist()
+    tile = mla_moe.GROUP_TILE_ROWS
+    assert all(size == 0 or size > tile for size in sizes)
+    # (all to one: 1,280 and 2,560 pairs, whole passes and none behind)
+    assert any(sizes % tile) or case in ("no_pair_held",
+                                         "all_to_one_held_expert")
+    if case == "no_pair_held":
+        assert sizes.sum() == 0 and not got.any()
+    if case == "a_held_expert_without_a_pair":
+        assert sizes[1] == 0 and sizes[0] > tile < sizes[2]
+    top = max(float(jnp.max(jnp.abs(want))), 1.0)
+    np.testing.assert_allclose(got, want, atol=bound * top, rtol=0)
+
+
+def test_rows_behind_an_experts_pairs_are_selected_away(monkeypatch,
+                                                        highest):
+    """A pass's rows behind its expert's last pair are another expert's
+    pairs or a padding token's, whose content may be anything: with NaN
+    in every padding token, every other token's sum is the dense form's
+    still (a zero routing weight would not do: 0 x NaN)."""
+    experts, x, idx, w, first, valid = _grouped_case(
+        "padding_in_the_middle_and_at_the_end", N_OVER[0])
+    x = jnp.where(valid[:, None], x, jnp.nan)
+    args = (experts, x, idx, w, first, valid)
+    want, _ = _dense_form(monkeypatch, mla_moe.held_experts, *args)
+    got, sizes = mla_moe.held_experts(*args)
+    # some expert's last pass is not full: rows behind its pairs there are
+    assert any(np.asarray(sizes) % mla_moe.GROUP_TILE_ROWS)
+    real = np.asarray(valid)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.isfinite(np.asarray(want)[real]).all()
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               atol=EXACT * 10, rtol=0)
+    assert not np.asarray(got)[~real].any()       # no pair, nothing added
+
+
+def _sdar_layer():
+    from dist_keras_tpu.models import sdar_moe
+
+    cfg = sdar_moe.sdar_moe_config(
+        vocab_size=VOCAB, seq_len=48, d_model=64, n_heads=4, n_kv_heads=2,
+        head_dim=16, moe_d_ff=48, n_routed_experts=8, top_k=3, n_layers=1,
+        held_experts=[1, 2, 3], block_length=4, denoising_steps=4,
+        mask_token_id=VOCAB - 1)
+    moe = sdar_moe.init_params(jax.random.PRNGKey(5), cfg)["blocks"][0]["moe"]
+    return cfg, moe, {"router": sdar_moe.route}
+
+
+def _lfm2_layer():
+    from dist_keras_tpu.models import lfm2_moe
+
+    cfg = lfm2_moe.lfm2_moe_config(
+        vocab_size=VOCAB, seq_len=48, d_model=64, n_heads=4, n_kv_heads=2,
+        d_ff=96, moe_d_ff=48, n_routed_experts=8, top_k=3,
+        layer_types=["conv", "full_attention", "conv"])
+    blocks = lfm2_moe.init_params(jax.random.PRNGKey(6), cfg)["blocks"]
+    return cfg, next(b["moe"] for b in blocks if "moe" in b), {}
+
+
+def _mla_layer():
+    cfg = config()
+    return cfg, weights_for(cfg)["blocks"][1]["moe"], {}
+
+
+@pytest.mark.parametrize("layer", [_mla_layer, _sdar_layer, _lfm2_layer],
+                         ids=["mla_moe", "sdar_moe", "lfm2_moe"])
+def test_each_familys_layer_takes_the_grouped_form_over_the_crossover(
+        monkeypatch, highest, layer):
+    """``moe_layer`` as the three families call it (``sdar_moe`` with its
+    softmax router and no shared expert, ``lfm2_moe`` with every expert
+    held): over the crossover the layer's output and its counts are the
+    dense form's."""
+    cfg, moe, kw = layer()
+    n = N_OVER[0]
+    x = jax.random.normal(jax.random.PRNGKey(7), (n, cfg["d_model"]))
+    valid = jnp.arange(n) < n - 9
+    want, want_counts = _dense_form(monkeypatch, mla_moe.moe_layer, moe, x,
+                                    cfg, valid, **kw)
+    got, counts = mla_moe.moe_layer(moe, x, cfg, valid, **kw)
+    assert counts.tolist() == want_counts.tolist()
+    assert counts[-1] == (n - 9) * cfg["top_k"]
+    top = max(float(jnp.max(jnp.abs(want))), 1.0)
+    np.testing.assert_allclose(got, want, atol=10 * EXACT * top, rtol=0)
+
+
+def test_forward_over_the_crossover_equals_the_reference(highest):
+    """A whole sequence of more than 1,024 tokens: every expert layer
+    takes the grouped form, and the logits are the plain reference's."""
+    n = N_OVER[0]
+    cfg = config(seq_len=n + 2, n_layers=2)
+    params = weights_for(cfg)
+    tokens = np.random.default_rng(11).integers(0, VOCAB, n)
+    got = mla_moe.forward(params, jnp.asarray(tokens), cfg)
+    want = reference_logits(params, tokens, cfg)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize("tokens,functions", [(N_OVER[0], 2), (64, 0)],
+                         ids=["over", "under"])
+def test_a_long_sequences_layers_of_one_structure_lower_once(tokens,
+                                                             functions):
+    """Over the crossover the program holds one function for the dense
+    layer and ONE for the three expert layers, called a layer each (a
+    long prefill's set-up traces and lowers a structure once, PR 47); a
+    shorter sequence's text has no such function, as before."""
+    cfg = config(seq_len=N_OVER[0] + 2, n_layers=4)
+    params = jax.eval_shape(lambda: weights_for(cfg))
+    text = jax.jit(lambda p, t: mla_moe.forward(p, t, cfg)).lower(
+        params, jax.ShapeDtypeStruct((tokens,), jnp.int32)).as_text()
+    assert len(re.findall(r"func\.func private @layer", text)) == functions
+    assert len(re.findall(r"call @layer", text)) == (
+        cfg["n_layers"] if functions else 0)
 
 
 def test_switch_router_is_the_one_selection_at_k_1():
@@ -388,6 +584,125 @@ def test_routing_counters_exist_and_are_stamped():
     hit = [v for _, v in metrics.histogram(
         "decode.moe.experts_hit").samples_between(lo, hi)[0]]
     assert all(0 <= v <= 2 * 2 for v in hit)
+
+
+def test_a_prefill_stamps_its_form_and_its_tile_fill(monkeypatch, highest):
+    """``decode.moe.prefill_grouped``: a sample a prefill, stamped like
+    ``decode.prefill_s``: 100 for a rung over the crossover, 0 under it;
+    ``decode.moe.tile_fill_pct``: a sample a prefill of the grouped form,
+    the held pairs over the rows its passes covered, which the program
+    reckons layer by layer and sends behind its other counts, below zero
+    (``-1 - rows``): the counts themselves say the form.  The first token
+    and the pairs of such a prefill are the dense form's and the plain
+    reference's."""
+    import functools
+    import time
+
+    for name in ("decode.moe.prefill_grouped", "decode.moe.tile_fill_pct"):
+        assert metrics.KNOWN_METRICS[name] == "histogram"
+    rung, tile = N_OVER[0] + 8, mla_moe.GROUP_TILE_ROWS
+    # ONE expert layer, so that the counts are that layer's own
+    cfg = config(seq_len=rung + 8, n_layers=2)
+    params = weights_for(cfg)
+    rng = np.random.default_rng(5)
+    long = rng.integers(0, VOCAB, rung - 30).tolist()
+    lo = time.perf_counter()
+    with engine_for(cfg, params, prefill_ladder=(16, rung),
+                    decode_ladder=(2,)) as eng:
+        docs = [eng.submit_generate(prompt, max_new_tokens=2).result(
+            timeout=600) for prompt in (long, long[:10])]
+        (pool_shape,) = eng.pool_shapes
+    hi = time.perf_counter()
+
+    def between(name):
+        pairs, truncated = metrics.histogram(name).samples_between(lo, hi)
+        assert not truncated
+        return pairs
+
+    grouped, fill = (between("decode.moe.prefill_grouped"),
+                     between("decode.moe.tile_fill_pct"))
+    assert [v for _, v in grouped] == [100.0, 0.0]
+    assert [at for at, _ in grouped] == [
+        at for at, _ in between("decode.prefill_s")]
+    assert [at for at, _ in fill] == [grouped[0][0]]
+    # the arithmetic, on the counts of the same prefill run apart: one
+    # count more than a dense program's rides behind them
+    tokens = np.zeros((rung,), np.int32)
+    tokens[:len(long)] = long
+    step = jax.jit(functools.partial(mla_moe.prefill_step, cfg))
+    args = (params, jnp.zeros(pool_shape), jnp.asarray(tokens), len(long),
+            jnp.full((rung,), pool_shape[1] - 1),
+            jnp.zeros((rung,), jnp.int32))
+    out = np.asarray(step(*args)[0])
+    first, counts = out[0], out[1:]
+    assert counts.shape == (3 + mla_moe.N_COUNTS + 1,)
+    sizes, covered = counts[:3].astype(np.int64), -1 - int(counts[-1])
+    assert covered == tile * (-(-sizes // tile)).sum() > sizes.sum() > 0
+    assert fill[0][1] == 100.0 * sizes.sum() / covered
+    # the dense form of the same prefill: the same token and pairs, and
+    # the parent's width; the token is the engine's and the reference's
+    dense = np.asarray(_dense_form(
+        monkeypatch, jax.jit(functools.partial(mla_moe.prefill_step, cfg)),
+        *args)[0])
+    assert dense.tolist() == out[:-1].tolist()
+    want = reference_logits(params, long, cfg)
+    assert first == docs[0]["generated"][0] == int(jnp.argmax(want[-1]))
+    # the same counts by hand: with the last slot and without it
+    at = time.perf_counter()
+    mla_moe.observe_step(counts, at)
+    mla_moe.observe_step(counts[:-1], at + 1e-3)
+    assert metrics.histogram("decode.moe.prefill_grouped").samples_between(
+        at, at + 1)[0] == [(at, 100.0), (at + 1e-3, 0.0)]
+    assert metrics.histogram("decode.moe.tile_fill_pct").samples_between(
+        at, at + 1)[0] == [(at, fill[0][1])]
+
+
+@pytest.mark.parametrize("module", ["mla_moe", "lfm2_moe", "sdar_moe"])
+def test_only_a_long_prefills_counts_grow(module):
+    """The engine carries ONE output width from step to step, whatever the
+    rung: a decode step over more rows than the crossover runs the grouped
+    form and still sends the counts a dense one sends, as a prefill of
+    1,024 tokens does; a prefill over the crossover sends one more."""
+    import functools
+    import importlib
+
+    mod = importlib.import_module(f"dist_keras_tpu.models.{module}")
+    rows = N_OVER[0]
+    if module == "mla_moe":
+        cfg, width = config(seq_len=rows + 2, n_layers=2), 1
+        params = jax.eval_shape(lambda: weights_for(cfg))
+    else:
+        cfg = {"lfm2_moe": _lfm2_layer, "sdar_moe": _sdar_layer}[module]()[0]
+        cfg = {**cfg, "seq_len": rows + 2}
+        width = mod.step_width(cfg)
+        params = jax.eval_shape(functools.partial(mod.init_params, cfg=cfg),
+                                jax.random.PRNGKey(0))
+    held = len(cfg["held_experts"])
+    pools = [jax.ShapeDtypeStruct(
+        (layers, 9 if kind == "page" else rows + 1)
+        + ((4,) if kind == "page" else ()) + tuple(entry), jnp.float32)
+        for layers, kind, entry in mod.cache_pools(cfg)]
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def prefill_width(n):
+        args = (ints(n), ints(), ints(n), ints(n))
+        if len(pools) > 1:
+            args += (ints(),)
+        out = jax.eval_shape(functools.partial(mod.prefill_step, cfg),
+                             params, *pools, *args)[0]
+        return out.shape[0] - (1 if width == 1 else 0)
+
+    assert prefill_width(mla_moe.GROUPED_OVER) == held + mla_moe.N_COUNTS
+    assert prefill_width(rows) == held + mla_moe.N_COUNTS + 1
+    if module != "mla_moe":
+        return
+    step, _ = jax.eval_shape(
+        functools.partial(mla_moe.decode_step, cfg), params, *pools,
+        ints(rows), ints(rows), ints(rows, 2), ints(rows), ints(rows),
+        ints(rows))
+    assert step.shape == (rows + held + mla_moe.N_COUNTS,)
 
 
 def test_walked_positions_are_stamped_once_a_step_with_the_block_arithmetic():
