@@ -503,21 +503,10 @@ def _flash_case(bh, t, d, block, dtype):
 
 
 def _case_lengths(slots, page_size, n_pages):
-    """A padding slot, one position, a page boundary, the full extent."""
+    """A padding slot, one position (a partial page), a page boundary, the
+    full extent."""
     return jnp.asarray([(0, 1, page_size, n_pages * page_size)[i % 4]
                         for i in range(slots)], jnp.int32)
-
-
-def _paged_case(slots, heads, d, page_size, n_pages):
-    """float32, like the engine's pools; lengths cover a padding slot, a
-    partial page, a page boundary and the full extent."""
-    rng = np.random.default_rng(0)
-    pool = slots * n_pages + 1
-    q = jnp.asarray(rng.normal(size=(slots, heads, d)), jnp.float32)
-    kp, vp = (jnp.asarray(rng.normal(size=(pool, page_size, heads, d)),
-                          jnp.float32) for _ in range(2))
-    table = jnp.asarray(rng.integers(0, pool, (slots, n_pages)), jnp.int32)
-    return (q, kp, vp, table, _case_lengths(slots, page_size, n_pages))
 
 
 # the latent pool's read at the published widths (rank 512 + rope 64 in a
@@ -527,8 +516,8 @@ _LATENT = {"rank": 512, "scale": 192 ** -0.5}
 
 def _latent_case(slots, heads, page_size, n_pages, width=640, used=576):
     """float32 rows of whole lanes, like the engine's latent pool, two
-    layers of it flat with the page ids offset to the second; lengths as
-    in :func:`_paged_case`, the extent past the kernel's first block."""
+    layers of it flat with the page ids offset to the second; lengths of
+    :func:`_case_lengths`, the extent past the kernel's first block."""
     rng = np.random.default_rng(1)
     per_layer = slots * n_pages + 1
     live = np.arange(width) < used
@@ -551,7 +540,7 @@ def _kv_rows_case(slots, page_size, n_pages, heads=32, d=128):
     """float32 rows ``v | k`` of ``2 x heads x d`` lanes, two layers flat
     with the page ids offset to the second, and the queries as
     ``attend_rows`` lays them out (head h's in the lanes of its own keys,
-    zeros elsewhere); lengths as in :func:`_paged_case`."""
+    zeros elsewhere); lengths of :func:`_case_lengths`."""
     rng = np.random.default_rng(3)
     per_layer = slots * n_pages + 1
     width = heads * d
@@ -600,10 +589,6 @@ def kernel_cases(batch, seq, n_heads, head_dim, prefill, slots, page_size):
         q, k, v, do = c["args"]
         cases[f"flash_fwd/{tag}"] = (c["fwd"], (q, k, v))
         cases[f"flash_bwd/{tag}"] = (c["bwd"], (q, k, v, do))
-    cases["paged_decode/f32"] = (
-        decode_attention.paged_attention_kernel,
-        _paged_case(slots, n_heads, head_dim, page_size,
-                    -(-seq // page_size)))
     cases["latent_decode/f32"] = (
         functools.partial(decode_attention.latent_attention_kernel,
                           **_LATENT),
@@ -637,9 +622,6 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
 
     with jax.default_matmul_precision("highest"):
         refs = {tag: c["ref_both"](*c["args"]) for tag, c in flash.items()}
-        q, kp, vp, table, lengths = cases["paged_decode/f32"][1]
-        paged_ref = decode_attention.paged_attention_reference(
-            q, kp, vp, table, lengths)
         latent_ref = decode_attention.latent_attention_reference(
             *cases["latent_decode/f32"][1], **_LATENT)
         rows_ref = decode_attention.latent_attention_reference(
@@ -657,8 +639,6 @@ def stage_kernels(batch, seq, n_heads, head_dim, prefill, slots,
         report[f"flash_bwd/{tag}"] = (
             max(_rel_err(g, r) for g, r in zip(grads, ref_grads)),
             TOLERANCE)
-    report["paged_decode/f32"] = (
-        _rel_err(run("paged_decode/f32"), paged_ref), TOLERANCE)
     report["latent_decode/f32"] = (
         _rel_err(run("latent_decode/f32"), latent_ref), TOLERANCE)
     report["latent_decode/kv_rows_f32"] = (

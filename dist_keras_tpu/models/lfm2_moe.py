@@ -28,7 +28,7 @@ owner is overwritten whole before anything reads it.
 *Grouped-query attention* (``"full_attention"``).  ``q`` is ``heads x
 head_dim``, ``k`` and ``v`` ``kv_heads x head_dim``; RMSNorm over each
 head's width on ``q`` and ``k``, then rotary positions rotated by halves
-(no de-interleaving, unlike ``mla_moe.rope``); K/V head ``i`` serves
+(no de-interleaving: ``blocks.rope_halves``); K/V head ``i`` serves
 query heads ``g i .. g i + g - 1``.  **The cache entry is ``v | k``**,
 every K/V head's values then every K/V head's keys, ``2 x kv_heads x
 head_dim`` values a position in ONE pool that spans the attention layers
@@ -46,40 +46,41 @@ that chose it over a gather of whole page tables and over the
 page-a-grid-step kernel).
 
 *Feed-forward.*  The first ``num_dense_layers`` layers are one SwiGLU;
-the others are ``mla_moe.moe_layer`` as it is: sigmoid scores in float32
+the others are ``blocks.moe_layer`` as it is: sigmoid scores in float32
 at "highest" precision, the top ``k`` of ``s + b`` chosen, weights the
 chosen ``s`` over their sum plus ``route_norm_eps`` (1e-6 here), times
 ``routed_scaling_factor``; every routed expert is held (``held_experts``
 is all of them) and computed as that module's masked dense pass; there is
 no shared expert.  No capacity, no dropped token.
 
-Both steps return, behind their tokens, ``mla_moe``'s routing counts;
+Both steps return, behind their tokens, the expert layer's routing counts;
 :func:`observe_step` turns them into the ``decode.moe.*`` instruments and
 stamps ``decode.kv.live_positions`` a decode step.
 """
 
 from __future__ import annotations
 
-import functools
-import json
+import sys
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from dist_keras_tpu.models.layers import glorot_uniform
-from dist_keras_tpu.models.mla_moe import (
-    _swiglu_params,
-    _zero_counts,
+from dist_keras_tpu.models.blocks import (
+    FamilyDecoder,
+    attend_entries,
+    attend_rows,
+    causal_taps,
+    causal_taps_token,
     ffn,
+    logits,
     observe_routing,
+    pool_layer,
+    qkv_normed_rotated,
     rms_norm,
+    swiglu_params,
+    zero_counts,
 )
-from dist_keras_tpu.ops.pallas.decode_attention import (
-    LATENT_BLOCK_PAGES,
-    latent_attention_auto,
-)
-from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
+from dist_keras_tpu.models.layers import glorot_uniform
 
 FAMILY = "lfm2_moe"
 CONV, ATTENTION = "conv", "full_attention"
@@ -125,7 +126,7 @@ def lfm2_moe_config(vocab_size, seq_len, d_model, n_heads, n_kv_heads,
         "conv_l_cache": int(conv_l_cache),
         "routed_scaling_factor": float(routed_scaling_factor),
         "rope_theta": float(rope_theta),
-        # what ``mla_moe.ffn`` / ``route`` / ``moe_layer`` read: every
+        # what ``blocks.ffn`` / ``route_sigmoid`` / ``moe_layer`` read: every
         # routed expert is held here, and the chosen scores are divided
         # by their sum plus 1e-6 (the published code's)
         "rms_norm_eps": float(norm_eps),
@@ -183,7 +184,7 @@ def init_layer_params(key, cfg, layer):
             "wo": glorot_uniform(ko, (h, hd, d)),
         }
     if layer < cfg["num_dense_layers"]:
-        blk["mlp"] = _swiglu_params(kf, d, cfg["d_ff"])
+        blk["mlp"] = swiglu_params(kf, d, cfg["d_ff"])
         return blk
     n = cfg["n_routed_experts"]
     blk["moe"] = {
@@ -192,7 +193,7 @@ def init_layer_params(key, cfg, layer):
         # differ
         "router_bias": jax.random.uniform(kb, (n,), jnp.float32,
                                           -0.02, 0.02),
-        "experts": _swiglu_params(ke, d, cfg["moe_d_ff"], (n,)),
+        "experts": swiglu_params(ke, d, cfg["moe_d_ff"], (n,)),
     }
     return blk
 
@@ -208,44 +209,6 @@ def init_params(key, cfg):
 
 
 # -- the pieces ---------------------------------------------------------
-def rope_halves(x, positions, theta):
-    """Rotary positions on ``x (T, heads, d)`` at ``positions (T,)``,
-    rotated by halves: element ``i`` pairs with element ``i + d / 2``."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def causal_taps(kernel, u, length):
-    """A depthwise causal convolution over one whole sequence, and what it
-    needs of the past afterwards: ``kernel (channels, L)``, ``u (T,
-    channels)`` -> (``v_t = sum_j kernel[:, j] u_{t - (L-1) + j}`` with
-    ``u`` zero before the sequence's start, ``u`` at positions ``length -
-    L + 1 .. length - 1``, zeros on the left of a sequence shorter than
-    that).  Shared by this family's gated short convolution and
-    ``models/olmo_hybrid.py``'s three."""
-    taps = kernel.shape[1]
-    t = u.shape[0]
-    padded = jnp.pad(u, ((taps - 1, 0), (0, 0)))
-    v = sum(kernel[:, j] * padded[j:j + t] for j in range(taps))
-    # position p is row p + taps - 1 of ``padded``
-    return v, jax.lax.dynamic_slice_in_dim(padded, length, taps - 1)
-
-
-def causal_taps_token(kernel, u, state):
-    """The same convolution one position on, a slot each: ``u (S,
-    channels)``, ``state (S, L - 1, channels)`` -> (``v (S, channels)``,
-    the window ``(S, L, channels)`` it was taken over: ``window[:, 1:]``
-    is the state one position on)."""
-    window = jnp.concatenate([state, u[:, None]], 1)         # (S, L, d)
-    v = sum(kernel[:, j] * window[:, j] for j in range(window.shape[1]))
-    return v, window
-
-
 def _conv_gates(conv, y):
     """-> (``u = B * X``, ``C``), each ``(T, d)``."""
     with jax.named_scope("conv_in"):
@@ -277,65 +240,6 @@ def _conv_token(conv, y, state):
     return _conv_out(conv, c, v), window[:, 1:]
 
 
-def _qkv(attn, y, positions, cfg):
-    """-> (q (T, H, hd), the cache entry ``v | k`` (T, 2 Hkv hd)), q and
-    k normalised a head and rotated."""
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    with jax.named_scope("qkv"):
-        q = jnp.einsum("td,dhk->thk", y, attn["wq"])
-        k = jnp.einsum("td,dhk->thk", y, attn["wk"])
-        v = jnp.einsum("td,dhk->thk", y, attn["wv"])
-    with jax.named_scope("qk_norm_rope"):
-        q = rope_halves(rms_norm(attn["q_norm"], q, eps), positions, theta)
-        k = rope_halves(rms_norm(attn["k_norm"], k, eps), positions, theta)
-    t = y.shape[0]
-    return q, jnp.concatenate([v.reshape(t, -1), k.reshape(t, -1)], -1)
-
-
-def attend_entries(q, entry, hk):
-    """Causal attention of one whole sequence over its own ``v | k``
-    entries, ``hk`` K/V heads serving the query heads -> (T, H, hd).
-    ``models/olmo_hybrid.py`` attends through it too (as many K/V heads as
-    query heads)."""
-    t = q.shape[0]
-    v, k = jnp.split(entry, 2, axis=-1)
-    return attention_auto(q[None], k.reshape(1, t, hk, -1),
-                          v.reshape(1, t, hk, -1), causal=True)[0]
-
-
-def attend_rows(q, pool_rows, page_tables, lengths, hk,
-                block_pages=LATENT_BLOCK_PAGES):
-    """One query a slot over the paged ``v | k`` rows of ``hk`` K/V heads
-    -> (S, H, hd); ``block_pages`` is the read kernel's (a family whose
-    rows are wider than this one's states fewer)."""
-    s, h, hd = q.shape
-    mine = (jnp.arange(h)[:, None] // (h // hk)
-            == jnp.arange(hk)[None]).astype(q.dtype)            # (H, Hkv)
-    # head h's query in the lanes of its own K/V head's keys
-    wide = (q[:, :, None, :] * mine[None, :, :, None]).reshape(s, h, -1)
-    wide = jnp.concatenate([jnp.zeros_like(wide), wide], -1)
-    o = latent_attention_auto(wide, pool_rows, page_tables, lengths,
-                              rank=hk * hd, scale=hd ** -0.5,
-                              block_pages=block_pages)
-    # and of the summed values' row its own K/V head's lanes
-    return jnp.einsum("shkd,hk->shd", o.reshape(s, h, hk, hd), mine)
-
-
-def _logits(params, hs, cfg):
-    with jax.named_scope("head"):
-        # behind a barrier, as in ``mla_moe._logits``: the compiler
-        # otherwise folds the norm's weight into the (tied) table
-        return jax.lax.optimization_barrier(rms_norm(
-            params["norm_f"], hs, cfg["rms_norm_eps"])) @ params["embed"].T
-
-
-def pool_layer(cfg, layer):
-    """Which layer of its pool layer ``layer`` writes: its ordinal among
-    the layers of its kind."""
-    kind = cfg["layer_types"][layer]
-    return cfg["layer_types"][:layer].count(kind)
-
-
 def _sequence_layers(params, tokens, length, cfg, write_kv, write_state):
     """The layers over one whole sequence -> (hidden (T, d), counts);
     ``write_kv(pool layer, entry)`` / ``write_state(pool layer, state)``
@@ -344,14 +248,14 @@ def _sequence_layers(params, tokens, length, cfg, write_kv, write_state):
     valid = positions < length
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg, tokens.shape[0])
+    counts = zero_counts(cfg, tokens.shape[0])
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, cfg["rms_norm_eps"])
         if "conv" in blk:
             o, state = _conv_sequence(blk["conv"], y, length)
             write_state(pool_layer(cfg, li), state)
         else:
-            q, entry = _qkv(blk["attn"], y, positions, cfg)
+            q, entry = qkv_normed_rotated(blk["attn"], y, positions, cfg)
             write_kv(pool_layer(cfg, li), entry)
             with jax.named_scope("attend"):
                 a = attend_entries(q, entry, cfg["n_kv_heads"])
@@ -367,7 +271,7 @@ def forward(params, tokens, cfg):
     hs, _ = _sequence_layers(params, tokens, tokens.shape[0], cfg,
                              lambda li, entry: None,
                              lambda li, state: None)
-    return _logits(params, hs, cfg)
+    return logits(params, hs, cfg)
 
 
 def prefill_step(cfg, params, kv, state, tokens, length, page_idx,
@@ -393,7 +297,7 @@ def prefill_step(cfg, params, kv, state, tokens, length, page_idx,
 
     hs, counts = _sequence_layers(params, tokens, length, cfg, write_kv,
                                   write_state)
-    first = jnp.argmax(_logits(params, hs[length - 1], cfg))
+    first = jnp.argmax(logits(params, hs[length - 1], cfg))
     return (jnp.concatenate([first.astype(jnp.int32)[None], counts]),
             *pools)
 
@@ -406,7 +310,7 @@ def _decode_layers(cfg, params, kv, state, tokens, positions, page_tables,
     valid = lengths > 0
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg)
+    counts = zero_counts(cfg)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, eps)
         at = pool_layer(cfg, li)
@@ -417,7 +321,7 @@ def _decode_layers(cfg, params, kv, state, tokens, positions, page_tables,
             with jax.named_scope("state_write"):
                 state = state.at[at, rows].set(new)
         else:
-            q, entry = _qkv(blk["attn"], y, positions, cfg)
+            q, entry = qkv_normed_rotated(blk["attn"], y, positions, cfg)
             with jax.named_scope("kv_write"):
                 kv = kv.at[at, write_page, write_off].set(entry)
             with jax.named_scope("attend_pool"):
@@ -441,13 +345,13 @@ def decode_step(cfg, params, kv, state, tokens, positions, page_tables,
     hs, counts, kv, state = _decode_layers(
         cfg, params, kv, state, tokens, positions, page_tables, write_page,
         write_off, lengths, rows)
-    nxt = jnp.argmax(_logits(params, hs, cfg), -1).astype(jnp.int32)
+    nxt = jnp.argmax(logits(params, hs, cfg), -1).astype(jnp.int32)
     return jnp.concatenate([nxt, counts]), kv, state
 
 
 def observe_step(counts, at, lengths=None, page_size=None):
     """The counts behind a step's tokens -> the registry
-    (``mla_moe.observe_routing``).  A decode step passes its slots'
+    (``blocks.observe_routing``).  A decode step passes its slots'
     ``lengths`` (host values, zeros for padding) and stamps their sum,
     the live positions its K/V read covers in each attention layer, on
     ``decode.kv.live_positions``."""
@@ -459,44 +363,7 @@ def observe_step(counts, at, lengths=None, page_size=None):
             int(lengths.sum()), at=at)
 
 
-class Lfm2MoeDecoder:
-    """Model-contract wrapper (cfg + params + weights round-trip) that the
-    serialization layer and ``DecodeEngine`` take.  Weights are made from
-    ``seed`` on first use, so a deserialized copy that is handed its
-    weights never holds a second, random set."""
-
-    def __init__(self, cfg=None, seed=0, **cfg_kw):
-        self.cfg = cfg or lfm2_moe_config(**cfg_kw)
-        self.name = "lfm2_moe_decoder"
-        self._seed = seed
-        self._params = None
-
-    @property
-    def params(self):
-        if self._params is None:
-            self._params = init_params(jax.random.PRNGKey(self._seed),
-                                       self.cfg)
-        return self._params
-
-    def apply(self, params, tokens, *, training=False, rng=None):
-        return forward(params, tokens, self.cfg)
-
-    def __call__(self, tokens, *, training=False, rng=None):
-        return self.apply(self.params, jnp.asarray(tokens))
-
-    def set_params(self, params):
-        self._params = jax.tree.map(jnp.asarray, params)
-
-    def get_weights(self):
-        return [np.asarray(leaf) for leaf in jax.tree.leaves(self.params)]
-
-    def set_weights(self, weights):
-        shapes = jax.eval_shape(
-            functools.partial(init_params, cfg=self.cfg),
-            jax.random.PRNGKey(0))
-        self._params = jax.tree.unflatten(
-            jax.tree.structure(shapes), [jnp.asarray(w) for w in weights])
-
-    def to_json(self):
-        return json.dumps({"class_name": "Lfm2MoeDecoder",
-                           "config": self.cfg})
+class Lfm2MoeDecoder(FamilyDecoder):
+    family = sys.modules[__name__]
+    config = staticmethod(lfm2_moe_config)
+    name = "lfm2_moe_decoder"

@@ -44,13 +44,14 @@ ids of the published count): it routes over all of them, normalises over
 all ``k`` chosen and adds only the held experts' terms plus the shared
 expert.  What absent experts would add is left out; nothing stands in
 for the other chips.  The held experts run as a masked dense pass over
-every token in a call of up to :data:`GROUPED_OVER` tokens, and over the
-chosen pairs sorted by expert in a longer one (:func:`held_experts` says
-why).
+every token in a call of up to ``GROUPED_OVER`` tokens, and over the
+chosen pairs sorted by expert in a longer one (the expert layer is
+``models/blocks.py``'s, which two more families run: ``held_experts``
+there says why).
 
 Both steps return, behind their tokens, a few routing counts in the same
-small int32 array (:data:`N_COUNTS` past the per-expert ones; a prefill
-of the grouped form one more, :func:`_zero_counts`), which
+small int32 array (``blocks.N_COUNTS`` past the per-expert ones; a prefill
+of the grouped form one more, ``blocks.zero_counts``), which
 :func:`observe_step` turns into the ``decode.moe.*`` / ``decode.latent.*``
 instruments.
 """
@@ -58,25 +59,31 @@ instruments.
 from __future__ import annotations
 
 import functools
-import json
+import sys
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from dist_keras_tpu.models.layers import glorot_uniform, select_top_k
+from dist_keras_tpu.models import blocks
+from dist_keras_tpu.models.blocks import (
+    FamilyDecoder,
+    ffn,
+    logits,
+    observe_routing,
+    rms_norm,
+    rope,
+    swiglu_params,
+    zero_counts,
+)
+from dist_keras_tpu.models.layers import glorot_uniform
 from dist_keras_tpu.ops.pallas.decode_attention import (
     latent_attention_auto,
     latent_walked_positions,
-    operand_dtype,
 )
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
 FAMILY = "mla_moe"
 LANES = 128
-# behind a step's tokens: pairs on each held expert, then expert layers'
-# (layer, held expert) cells that received a token, then all chosen pairs
-N_COUNTS = 2
 
 
 def mla_moe_config(vocab_size, seq_len, d_model, n_heads, qk_nope_head_dim,
@@ -127,14 +134,6 @@ def vocab(cfg):
     return int(cfg["vocab_size"])
 
 
-def cache_entry_shapes(cfg):
-    """The trailing shape of each pool a replica holds: one pool whose
-    entry is the normalised latent and the rotated shared key, in a row
-    of whole lanes."""
-    width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
-    return ((-(-width // LANES) * LANES,),)
-
-
 def step_width(cfg):
     """Positions a slot a step: one token."""
     return 1
@@ -142,22 +141,16 @@ def step_width(cfg):
 
 def cache_pools(cfg):
     """What the engine allocates: the one latent pool, paged, a row a
-    cached position in every layer."""
-    return tuple((cfg["n_layers"], "page", entry)
-                 for entry in cache_entry_shapes(cfg))
+    cached position in every layer: the normalised latent and the rotated
+    shared key, in a row of whole lanes."""
+    width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    return ((cfg["n_layers"], "page", (-(-width // LANES) * LANES,)),)
 
 
 def _pad_lanes(x, cfg):
     """``(..., rank + rope)`` -> the pool's row width, zeros behind."""
-    pad = cache_entry_shapes(cfg)[0][0] - x.shape[-1]
+    pad = cache_pools(cfg)[0][2][0] - x.shape[-1]
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-
-
-def _swiglu_params(key, d, f, lead=()):
-    kg, ku, kd = jax.random.split(key, 3)
-    return {"w_gate": glorot_uniform(kg, lead + (d, f)),
-            "w_up": glorot_uniform(ku, lead + (d, f)),
-            "w_down": glorot_uniform(kd, lead + (f, d))}
 
 
 def init_layer_params(key, cfg, layer):
@@ -178,7 +171,7 @@ def init_layer_params(key, cfg, layer):
         "ffn_norm": jnp.ones((d,)),
     }
     if layer < cfg["first_k_dense"]:
-        blk["mlp"] = _swiglu_params(kf, d, cfg["d_ff"])
+        blk["mlp"] = swiglu_params(kf, d, cfg["d_ff"])
         return blk
     n_all, n_held = cfg["n_routed_experts"], len(cfg["held_experts"])
     blk["moe"] = {
@@ -187,8 +180,8 @@ def init_layer_params(key, cfg, layer):
         # differ
         "router_bias": jax.random.uniform(kb, (n_all,), jnp.float32,
                                           -0.02, 0.02),
-        "experts": _swiglu_params(ke, d, cfg["moe_d_ff"], (n_held,)),
-        "shared": _swiglu_params(
+        "experts": swiglu_params(ke, d, cfg["moe_d_ff"], (n_held,)),
+        "shared": swiglu_params(
             ks, d, cfg["n_shared_experts"] * cfg["moe_d_ff"]),
     }
     return blk
@@ -211,231 +204,6 @@ def init_params(key, cfg):
 
 
 # -- the pieces ---------------------------------------------------------
-def rms_norm(w, x, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return y.astype(x.dtype) * w
-
-
-def rope(x, positions, theta):
-    """Rotary positions on ``x (T, heads, d)`` at ``positions (T,)``:
-    the slice de-interleaved (even elements, then odd), then rotated by
-    halves."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[:, None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def swiglu(p, x):
-    return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
-
-
-def route(moe, x, cfg):
-    """-> (expert ids (N, k), weights (N, k) float32) over ALL the routed
-    experts, held here or not.  The chosen scores are divided by their
-    sum plus ``cfg["route_norm_eps"]`` (this family's published code:
-    1e-20, the default; ``models/lfm2_moe.py`` states its own 1e-6)."""
-    s = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), moe["router"].astype(jnp.float32),
-        precision="highest"))
-    idx, w = select_top_k(s, moe["router_bias"], cfg["top_k"])
-    w = w / (jnp.sum(w, -1, keepdims=True)
-             + cfg.get("route_norm_eps", 1e-20))
-    return idx, w * cfg["routed_scaling_factor"]
-
-
-# A call of more than this many tokens runs its held experts over the
-# chosen pairs sorted by expert, a shorter one over every token.  One bare
-# layer on a v5e, dense | grouped, ms (builders' chip runs, PR 45 and 46,
-# the grouped side through ``megablox.gmm``, which the plain loops below
-# trail by a quarter of its gain):
-#   tokens   8 held of 64, top 6,   32 held of 32, top 4,   16 held of 128,
-#            d 2048 f 1408          d 2048 f 1792           top 8, f 768
-#   1,024    1.24 | 1.26            5.68 | 6.17             1.42 | 1.52
-#   2,560    2.89 | 1.50            14.84 | 8.27            3.77 | 1.68
-#   4,096    5.17 | 2.04
-# The dense pass leads or ties at 1,024 at all three shapes and trails at
-# 2,560, so one rule serves them: every decode step and every prefill rung
-# of 1,024 or fewer stays the dense program.  Not a knob: nothing the
-# benchmark runs lies between 1,024 and 2,560.
-GROUPED_OVER = 1024
-
-# Rows of one expert's sorted pairs a pass takes through its products.  A
-# whole nine-layer prefill of ``kimivl_serve_longgen`` at 256 rows a pass,
-# dense | grouped, ms (builder's chip run, PR 46): 44.2 | 41.65 at the
-# 2,560 rung, 81.6 | 65.42 at 4,096, 125.7 | 102.50 at 6,144.  A prefill
-# of 512 rows a pass never came back on the chip (the same session).
-GROUP_TILE_ROWS = 256
-
-
-def _held_dense(experts, x, w, chosen):
-    """Every held expert over every token, each token's result weighted
-    by its routing weight for that expert, zero where ``chosen (N, k,
-    held)`` says the expert was not."""
-    gate = jnp.sum(jnp.where(chosen, w[..., None], 0.0), 1)   # (N, held)
-    hidden = (jax.nn.silu(jnp.einsum("nd,edf->enf", x, experts["w_gate"]))
-              * jnp.einsum("nd,edf->enf", x, experts["w_up"]))
-    ys = jnp.einsum("enf,efd->end", hidden, experts["w_down"])
-    return jnp.einsum("end,ne->nd", ys, gate.astype(ys.dtype))
-
-
-def _held_grouped(experts, x, w, group, sizes):
-    """The pairs whose ``group (N, k)`` is a held expert (the others carry
-    ``len(sizes)``), sorted by it; then expert by expert, and within one
-    :data:`GROUP_TILE_ROWS` sorted pairs at a time: the tokens' rows
-    gathered, the expert's three products, each pair's result times its
-    routing weight added to its token's row.  The work is the
-    ``sum(sizes)`` counted pairs (in whole passes), whatever the static
-    ``N x k``; two loops of plain products and no kernel call, so that
-    the program stays the size of the dense one and loads from the
-    compile cache as fast (PERF.md, PR 45-47)."""
-    (n, k), rows_a_pass = group.shape, GROUP_TILE_ROWS
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    # a counting sort: a pair's place is its group's first row plus the
-    # pairs of its group ahead of it, so a group's tokens stay in order.
-    # The last group (experts not held, padding tokens) lies behind the
-    # held pairs and nothing visits it.  One pass of rows more than the
-    # pairs, so that no slice below is moved back from the end; a row past
-    # the pairs reads pair 0
-    mine = group.reshape(-1) == jnp.arange(sizes.shape[0] + 1)[:, None]
-    ahead = jnp.cumsum(mine, 1, dtype=jnp.int32) - 1
-    first = jnp.concatenate([starts, ends[-1:]])[:, None]
-    place = jnp.sum(jnp.where(mine, first + ahead, 0), 0)
-    order = jnp.zeros((n * k + rows_a_pass,), jnp.int32).at[place].set(
-        jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
-    token, weight = order // k, w.reshape(-1)[order]
-    # the rounding the dense product's one MXU pass gives the same values
-    # (float32 when a caller asked for "highest").  Behind a barrier with
-    # the layer's own input, or the compiler converts all the held experts
-    # in front of the loop
-    operand = operand_dtype()
-    experts, x = jax.lax.optimization_barrier((experts, x))
-    x = x.astype(operand)
-
-    def one_expert(e, out):
-        gate, up, down = (
-            jax.lax.dynamic_index_in_dim(experts[name], e, keepdims=False)
-            .astype(operand) for name in ("w_gate", "w_up", "w_down"))
-
-        def one_pass(carry):
-            i, out = carry
-            lo = starts[e] + i * rows_a_pass
-            rows = jax.lax.dynamic_slice_in_dim(token, lo, rows_a_pass)
-            xs = x[rows]
-            hidden = (jax.nn.silu(jnp.dot(
-                xs, gate, preferred_element_type=jnp.float32)) * jnp.dot(
-                    xs, up, preferred_element_type=jnp.float32))
-            ys = jnp.dot(hidden.astype(operand), down,
-                         preferred_element_type=jnp.float32)
-            ys = ys * jax.lax.dynamic_slice_in_dim(
-                weight, lo, rows_a_pass)[:, None]
-            # the rows behind the expert's last pair are another expert's
-            # or nobody's (a padding token's, whose content is anything):
-            # selected away, not multiplied by a zero weight (0 x NaN)
-            live = i * rows_a_pass + jnp.arange(rows_a_pass) < sizes[e]
-            return i + 1, out.at[rows].add(jnp.where(live[:, None], ys, 0.0))
-
-        return jax.lax.while_loop(
-            lambda carry: carry[0] * rows_a_pass < sizes[e], one_pass,
-            (jnp.int32(0), out))[1]
-
-    return jax.lax.fori_loop(
-        0, sizes.shape[0], one_expert,
-        jnp.zeros((n, experts["w_down"].shape[2]), jnp.float32))
-
-
-def held_experts(experts, x, idx, w, first_held, valid):
-    """The held experts' part of the routed sum for tokens ``x (N, d)``
-    -> (``(N, d)``, pairs on each held expert ``(n_held,)`` int32).
-
-    Every chosen pair whose expert is held is computed and none can be
-    dropped (no capacity; a padding token, ``valid`` false, has no pair),
-    in one of two forms of the same sum, picked by the call's static
-    ``N``:
-
-    *Up to* :data:`GROUPED_OVER` *tokens, a masked dense pass*
-    (:func:`_held_dense`): a decode step reads the held experts' weights
-    either way, a short prefill's products are too small for the sort and
-    the gathers to pay, and a step's time does not depend on where the
-    router sent its tokens.
-
-    *Over it, the pairs sorted by expert* (:func:`_held_grouped`): an
-    expert at a time, its pairs :data:`GROUP_TILE_ROWS` at a time through
-    plain products, the gathered rows and the expert in the type the dense
-    product's one MXU pass rounds them to, float32 accumulation, each
-    pair's result times its routing weight added to its token's row.  The
-    router sends a token to ``top_k`` of all the experts and few are held
-    (0.75 pairs a token where the dense pass computes 8,
-    ``kimivl_serve_longgen``): the work is the counted pairs, and no
-    buffer is sized by a capacity.  The result differs from the dense
-    form's by the order of a token's at most ``top_k`` additions (and by
-    the dense combine's own bfloat16 pass).  The tables beside
-    :data:`GROUPED_OVER` and :data:`GROUP_TILE_ROWS` are why the rule is
-    what it is.  jax's own grouped products are not on this path:
-    ``jax.lax.ragged_dot`` lost to the dense pass at every size (PERF.md,
-    PR 27), and ``megablox.gmm`` ran these pairs a quarter faster than the
-    loops do in a program half as large again, which took twice as long
-    to load from the compile cache (PERF.md, PR 45 and 46)."""
-    n_held = experts["w_gate"].shape[0]
-    local = idx - first_held
-    here = (local >= 0) & (local < n_held) & valid[:, None]
-    chosen = here[..., None] & (local[..., None] == jnp.arange(n_held))
-    if x.shape[0] > GROUPED_OVER:
-        sizes = jnp.sum(chosen, (0, 1), dtype=jnp.int32)
-        # a pair not computed here joins a last group that nothing visits
-        return _held_grouped(experts, x, w, jnp.where(here, local, n_held),
-                             sizes), sizes
-    # the sum, then the counts: the order the pinned programs were lowered
-    # in (tests/test_lowered_text.py)
-    return (_held_dense(experts, x, w, chosen),
-            jnp.sum(chosen, (0, 1), dtype=jnp.int32))
-
-
-def moe_layer(moe, x, cfg, valid, router=route):
-    """-> (the layer's output for ``x (N, d)``, routing counts).  A
-    layer whose ``moe`` holds no ``"shared"`` has no shared expert;
-    ``router`` is the family's (``models/sdar_moe.py`` routes by a
-    softmax)."""
-    with jax.named_scope("moe_route"):
-        idx, w = router(moe, x, cfg)
-    with jax.named_scope("moe_experts"):
-        y, sizes = held_experts(moe["experts"], x, idx, w,
-                                cfg["held_experts"][0], valid)
-    if "shared" in moe:
-        with jax.named_scope("moe_shared"):
-            y = y + swiglu(moe["shared"], x)
-    total = jnp.sum(valid, dtype=jnp.int32) * cfg["top_k"]
-    return y, jnp.concatenate([
-        sizes, jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32), total])])
-
-
-def add_counts(counts, c):
-    """A layer's routing counts ``c`` onto its pass's ``counts``.  A
-    prefill of the grouped form keeps one slot more
-    (:func:`_zero_counts`): the rows its layers' passes covered (whole
-    passes of :data:`GROUP_TILE_ROWS`, an expert's last one as full as
-    its pairs make it), reckoned here from the layer's pairs on each held
-    expert and taken off a slot that starts at -1."""
-    if counts.shape[0] > c.shape[0]:
-        passes = jnp.sum(-(-c[:-N_COUNTS] // GROUP_TILE_ROWS))
-        c = jnp.concatenate([c, -GROUP_TILE_ROWS * passes[None]])
-    return counts + c
-
-
-def ffn(blk, x, cfg, valid, counts):
-    y = rms_norm(blk["ffn_norm"], x, cfg["rms_norm_eps"])
-    if "mlp" in blk:
-        with jax.named_scope("mlp"):
-            return x + swiglu(blk["mlp"], y), counts
-    out, c = moe_layer(blk["moe"], y, cfg, valid)
-    return x + out, add_counts(counts, c)
-
-
 def _query_and_entry(blk, y, positions, cfg):
     """-> (q_nope (T, H, nope), q_pe rotated (T, H, rope), the cache
     entry ``c | k_pe`` (T, rank + rope))."""
@@ -465,21 +233,6 @@ def _attend_sequence(blk, q_nope, q_pe, entry, cfg):
                           scale=q.shape[-1] ** -0.5)[0]
 
 
-def _zero_counts(cfg, sequence=0):
-    """What a pass's routing counts start from.  A whole-sequence pass
-    (a prefill) says how many tokens its ``sequence`` holds: over
-    :data:`GROUPED_OVER` its expert layers take the grouped form and one
-    slot more rides behind the others, ``-1 - rows covered``
-    (:func:`add_counts`): below zero, as no count is, so that the host
-    tells the form from the counts alone (:func:`observe_routing`).  A
-    decode step's counts never grow, whatever its rung (the engine
-    carries one width)."""
-    zeros = jnp.zeros((len(cfg["held_experts"]) + N_COUNTS,), jnp.int32)
-    if sequence > GROUPED_OVER:
-        return jnp.concatenate([zeros, jnp.full((1,), -1, jnp.int32)])
-    return zeros
-
-
 def _sequence_layer(blk, hs, valid, counts, positions, cfg, write):
     """One layer over a whole sequence -> (hidden (T, d), counts);
     ``write(entry)`` takes the layer's cache entries."""
@@ -499,9 +252,11 @@ def _sequence_layers(params, tokens, valid, cfg, write):
     positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg, tokens.shape[0])
+    counts = zero_counts(cfg, tokens.shape[0])
 
-    if tokens.shape[0] <= GROUPED_OVER:
+    # read where it is stated, not copied: the expert layer and the counts
+    # go by the same value
+    if tokens.shape[0] <= blocks.GROUPED_OVER:
         for li, blk in enumerate(params["blocks"]):
             hs, counts = _sequence_layer(blk, hs, valid, counts, positions,
                                          cfg, functools.partial(write, li))
@@ -527,22 +282,13 @@ def _sequence_layers(params, tokens, valid, cfg, write):
     return hs, counts
 
 
-def _logits(params, hs, cfg):
-    with jax.named_scope("head"):
-        # behind a barrier: for a few rows the compiler otherwise folds
-        # the norm's weight into the head and scales all of the head's
-        # vocabulary x width every step (1.3 GB written and read again)
-        return jax.lax.optimization_barrier(rms_norm(
-            params["norm_f"], hs, cfg["rms_norm_eps"])) @ params["head"]
-
-
 # -- the three entry points ---------------------------------------------
 def forward(params, tokens, cfg):
     """One whole sequence ``tokens (T,)``, no cache -> logits (T, vocab)."""
     hs, _ = _sequence_layers(params, tokens,
                              jnp.ones(tokens.shape, bool), cfg,
                              lambda li, entry: None)
-    return _logits(params, hs, cfg)
+    return logits(params, hs, cfg)
 
 
 def prefill_step(cfg, params, pool, tokens, length, page_idx, page_off):
@@ -560,7 +306,7 @@ def prefill_step(cfg, params, pool, tokens, length, page_idx, page_off):
 
     valid = jnp.arange(tokens.shape[0]) < length
     hs, counts = _sequence_layers(params, tokens, valid, cfg, write)
-    first = jnp.argmax(_logits(params, hs[length - 1], cfg))
+    first = jnp.argmax(logits(params, hs[length - 1], cfg))
     return jnp.concatenate([first.astype(jnp.int32)[None], counts]), pools[0]
 
 
@@ -573,7 +319,7 @@ def _decode_layers(cfg, params, pool, tokens, positions, page_tables,
     valid = lengths > 0
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg)
+    counts = zero_counts(cfg)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["attn_norm"], hs, eps)
         q_nope, q_pe, entry = _query_and_entry(blk, y, positions, cfg)
@@ -609,39 +355,8 @@ def decode_step(cfg, params, pool, tokens, positions, page_tables,
     hs, counts, pool = _decode_layers(
         cfg, params, pool, tokens, positions, page_tables, write_page,
         write_off, lengths)
-    nxt = jnp.argmax(_logits(params, hs, cfg), -1).astype(jnp.int32)
+    nxt = jnp.argmax(logits(params, hs, cfg), -1).astype(jnp.int32)
     return jnp.concatenate([nxt, counts]), pool
-
-
-def observe_routing(counts, at, decode):
-    """The routing counts behind a step's tokens -> the ``decode.moe.*``
-    instruments: the pairs of every step and prefill; for a ``decode``
-    step one sample of each per-step histogram, stamped ``at`` like
-    ``decode.step_s``; for a prefill a sample of
-    ``decode.moe.prefill_grouped``, stamped like ``decode.prefill_s``,
-    and where its program is the grouped form (it says so itself: one
-    slot more behind the counts, below zero, :func:`_zero_counts`) one of
-    ``decode.moe.tile_fill_pct``."""
-    from dist_keras_tpu.observability import metrics
-
-    counts = np.asarray(counts)
-    grouped = not decode and counts[-1] < 0
-    if grouped:
-        counts, covered = counts[:-1], -1 - int(counts[-1])
-    held, hit, total = counts[:-N_COUNTS], counts[-2], counts[-1]
-    metrics.counter("decode.moe.pairs_total").inc(int(total))
-    metrics.counter("decode.moe.pairs_held").inc(int(held.sum()))
-    if not decode:
-        metrics.histogram("decode.moe.prefill_grouped").observe(
-            100.0 * grouped, at=at)
-        if grouped and covered:
-            metrics.histogram("decode.moe.tile_fill_pct").observe(
-                100.0 * int(held.sum()) / covered, at=at)
-        return
-    metrics.histogram("decode.moe.experts_hit").observe(int(hit), at=at)
-    if held.sum() > 0:
-        metrics.histogram("decode.moe.load_max_over_mean").observe(
-            held.max() / held.mean(), at=at)
 
 
 def observe_step(counts, at, lengths=None, page_size=None):
@@ -662,44 +377,7 @@ def observe_step(counts, at, lengths=None, page_size=None):
         latent_walked_positions(lengths, page_size), at=at)
 
 
-class LatentMoEDecoder:
-    """Model-contract wrapper (cfg + params + weights round-trip) that the
-    serialization layer and ``DecodeEngine`` take.  Weights are made from
-    ``seed`` on first use, so a deserialized copy that is handed its
-    weights never holds a second, random set."""
-
-    def __init__(self, cfg=None, seed=0, **cfg_kw):
-        self.cfg = cfg or mla_moe_config(**cfg_kw)
-        self.name = "latent_moe_decoder"
-        self._seed = seed
-        self._params = None
-
-    @property
-    def params(self):
-        if self._params is None:
-            self._params = init_params(jax.random.PRNGKey(self._seed),
-                                       self.cfg)
-        return self._params
-
-    def apply(self, params, tokens, *, training=False, rng=None):
-        return forward(params, tokens, self.cfg)
-
-    def __call__(self, tokens, *, training=False, rng=None):
-        return self.apply(self.params, jnp.asarray(tokens))
-
-    def set_params(self, params):
-        self._params = jax.tree.map(jnp.asarray, params)
-
-    def get_weights(self):
-        return [np.asarray(leaf) for leaf in jax.tree.leaves(self.params)]
-
-    def set_weights(self, weights):
-        shapes = jax.eval_shape(
-            functools.partial(init_params, cfg=self.cfg),
-            jax.random.PRNGKey(0))
-        self._params = jax.tree.unflatten(
-            jax.tree.structure(shapes), [jnp.asarray(w) for w in weights])
-
-    def to_json(self):
-        return json.dumps({"class_name": "LatentMoEDecoder",
-                           "config": self.cfg})
+class LatentMoEDecoder(FamilyDecoder):
+    family = sys.modules[__name__]
+    config = staticmethod(mla_moe_config)
+    name = "latent_moe_decoder"

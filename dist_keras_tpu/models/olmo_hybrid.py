@@ -42,15 +42,15 @@ into heads; no rotation (the published ``rope_theta`` is null and is taken
 at its word: the linear layers carry the order); no biases.  **The cache
 entry is ``v | k``**, every head's values then every head's keys, ``2 x
 d_model`` values a position in ONE paged pool over the attention layers
-only (7,680 lanes at the published widths).  Both reads are
-``lfm2_moe``'s own functions with as many K/V heads as query heads:
-prefill attends with the flash forward, decoding reads the pool with the
-latent family's read (``lfm2_moe.attend_rows``: on a TPU the kernel that
-walks a slot's live pages in place, here in blocks of ``KV_BLOCK_PAGES``
-pages).  One chip run chose it
-(32 slots, 28 k live positions, a layer's read: 1.39 ms against 4.18 for
-``paged_attention_kernel`` over a K and a V pool and 5.21 for the ``jnp``
-gather of whole tables; PERF.md, PR 36).
+only (7,680 lanes at the published widths).  Both reads are the ones the
+families share (``models/blocks.py``), with as many K/V heads as query
+heads: prefill attends with the flash forward, decoding reads the pool
+with the latent family's read (``blocks.attend_rows``: on a TPU the kernel
+that walks a slot's live pages in place, here in blocks of
+``KV_BLOCK_PAGES`` pages).  One chip run chose it
+(32 slots, 28 k live positions, a layer's read: 1.39 ms against 4.18 for a
+kernel of a page a grid step over a K and a V pool, since deleted, and
+5.21 for the ``jnp`` gather of whole tables; PERF.md, PR 36).
 
 The two tiny projections behind ``g`` and ``beta`` run in float32 at
 "highest" precision (``g`` is summed over a sequence inside an
@@ -66,27 +66,25 @@ arithmetic on the lengths).
 
 from __future__ import annotations
 
-import functools
-import json
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dist_keras_tpu.models.layers import glorot_uniform
-from dist_keras_tpu.models.lfm2_moe import (
+from dist_keras_tpu.models.blocks import (
+    FamilyDecoder,
     attend_entries,
     attend_rows,
     causal_taps,
     causal_taps_token,
+    logits,
     pool_layer,
-)
-from dist_keras_tpu.models.mla_moe import (
-    _logits,
-    _swiglu_params,
     rms_norm,
     swiglu,
+    swiglu_params,
 )
+from dist_keras_tpu.models.layers import glorot_uniform
 from dist_keras_tpu.ops.gated_delta import gated_delta_chunked
 from dist_keras_tpu.ops.pallas.gated_delta import state_step_auto
 
@@ -176,7 +174,7 @@ def init_layer_params(key, cfg, layer):
     kq, kk, kv, kg, ko, kab, kc, ka, kt, kf = jax.random.split(
         jax.random.fold_in(key, 1 + layer), 10)
     blk = {"mixer_norm": jnp.ones((d,)), "ffn_norm": jnp.ones((d,)),
-           "mlp": _swiglu_params(kf, d, cfg["d_ff"])}
+           "mlp": swiglu_params(kf, d, cfg["d_ff"])}
     if cfg["layer_types"][layer] == LINEAR:
         blk["linear"] = {
             "w_qkv": jnp.concatenate(
@@ -326,7 +324,7 @@ def forward(params, tokens, cfg):
     hs = _sequence_layers(params, tokens, tokens.shape[0], cfg,
                           lambda at, entry: None,
                           lambda at, taps, state: None)
-    return _logits(params, hs, cfg)
+    return logits(params, hs, cfg)
 
 
 def prefill_step(cfg, params, kv, taps, states, tokens, length, page_idx,
@@ -353,7 +351,7 @@ def prefill_step(cfg, params, kv, taps, states, tokens, length, page_idx,
 
     hs = _sequence_layers(params, tokens, length, cfg, write_kv,
                           write_state)
-    first = jnp.argmax(_logits(params, hs[length - 1], cfg))
+    first = jnp.argmax(logits(params, hs[length - 1], cfg))
     out = jnp.stack([first.astype(jnp.int32), length.astype(jnp.int32),
                      jnp.int32(tokens.shape[0])])
     return (out, *pools)
@@ -398,7 +396,7 @@ def decode_step(cfg, params, kv, taps, states, tokens, positions,
             with jax.named_scope("attn_out"):
                 mixed = jnp.einsum("shk,hkd->sd", a, blk["attn"]["wo"])
         hs = _ffn(blk, hs + rms_norm(blk["mixer_norm"], mixed, eps), cfg)
-    nxt = jnp.argmax(_logits(params, hs, cfg), -1).astype(jnp.int32)
+    nxt = jnp.argmax(logits(params, hs, cfg), -1).astype(jnp.int32)
     return nxt, kv, taps, states
 
 
@@ -427,44 +425,7 @@ def observe_step(counts, at, lengths=None, page_size=None):
         int(np.count_nonzero(lengths)), at=at)
 
 
-class OlmoHybridDecoder:
-    """Model-contract wrapper (cfg + params + weights round-trip) that the
-    serialization layer and ``DecodeEngine`` take.  Weights are made from
-    ``seed`` on first use, so a deserialized copy that is handed its
-    weights never holds a second, random set."""
-
-    def __init__(self, cfg=None, seed=0, **cfg_kw):
-        self.cfg = cfg or olmo_hybrid_config(**cfg_kw)
-        self.name = "olmo_hybrid_decoder"
-        self._seed = seed
-        self._params = None
-
-    @property
-    def params(self):
-        if self._params is None:
-            self._params = init_params(jax.random.PRNGKey(self._seed),
-                                       self.cfg)
-        return self._params
-
-    def apply(self, params, tokens, *, training=False, rng=None):
-        return forward(params, tokens, self.cfg)
-
-    def __call__(self, tokens, *, training=False, rng=None):
-        return self.apply(self.params, jnp.asarray(tokens))
-
-    def set_params(self, params):
-        self._params = jax.tree.map(jnp.asarray, params)
-
-    def get_weights(self):
-        return [np.asarray(leaf) for leaf in jax.tree.leaves(self.params)]
-
-    def set_weights(self, weights):
-        shapes = jax.eval_shape(
-            functools.partial(init_params, cfg=self.cfg),
-            jax.random.PRNGKey(0))
-        self._params = jax.tree.unflatten(
-            jax.tree.structure(shapes), [jnp.asarray(w) for w in weights])
-
-    def to_json(self):
-        return json.dumps({"class_name": "OlmoHybridDecoder",
-                           "config": self.cfg})
+class OlmoHybridDecoder(FamilyDecoder):
+    family = sys.modules[__name__]
+    config = staticmethod(olmo_hybrid_config)
+    name = "olmo_hybrid_decoder"

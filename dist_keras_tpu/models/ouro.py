@@ -49,7 +49,7 @@ a pass never reads another pass's keys.  Inside the loop the entry's index
 is a traced value: the write scatters into the carried, donated pool where
 it lies, and the read walks the slot's live pages of the pool viewed flat
 over (entry, page) with the page ids offset to the entry's
-(``lfm2_moe.attend_rows``: on a TPU the ``latent_decode`` kernel), so no
+(``blocks.attend_rows``: on a TPU the ``latent_decode`` kernel), so no
 pass copies the pool or an entry of it.
 
 Behind their tokens both steps send what :func:`observe_step` counts: the
@@ -60,19 +60,22 @@ slots), the passes the program ran and its layer applications.
 from __future__ import annotations
 
 import functools
-import json
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dist_keras_tpu.models.layers import glorot_uniform
-from dist_keras_tpu.models.lfm2_moe import (
+from dist_keras_tpu.models.blocks import (
+    FamilyDecoder,
     attend_entries,
     attend_rows,
+    rms_norm,
     rope_halves,
+    swiglu,
+    swiglu_params,
 )
-from dist_keras_tpu.models.mla_moe import _swiglu_params, rms_norm, swiglu
+from dist_keras_tpu.models.layers import glorot_uniform
 
 FAMILY = "ouro"
 # pages of ``v | k`` rows the read's kernel fetches a grid step: 4 pages of
@@ -152,7 +155,7 @@ def init_layer_params(key, cfg, layer):
         },
         "attn_out_norm": jnp.ones((d,)),
         "mlp_norm": jnp.ones((d,)),
-        "mlp": _swiglu_params(kf, d, cfg["d_ff"]),
+        "mlp": swiglu_params(kf, d, cfg["d_ff"]),
         "mlp_out_norm": jnp.ones((d,)),
     }
 
@@ -403,44 +406,7 @@ def observe_step(counts, at, lengths=None, page_size=None):
             exits / live, at=at)
 
 
-class OuroDecoder:
-    """Model-contract wrapper (cfg + params + weights round-trip) that the
-    serialization layer and ``DecodeEngine`` take.  Weights are made from
-    ``seed`` on first use, so a deserialized copy that is handed its
-    weights never holds a second, random set."""
-
-    def __init__(self, cfg=None, seed=0, **cfg_kw):
-        self.cfg = cfg or ouro_config(**cfg_kw)
-        self.name = "ouro_decoder"
-        self._seed = seed
-        self._params = None
-
-    @property
-    def params(self):
-        if self._params is None:
-            self._params = init_params(jax.random.PRNGKey(self._seed),
-                                       self.cfg)
-        return self._params
-
-    def apply(self, params, tokens, *, training=False, rng=None):
-        return forward(params, tokens, self.cfg)
-
-    def __call__(self, tokens, *, training=False, rng=None):
-        return self.apply(self.params, jnp.asarray(tokens))
-
-    def set_params(self, params):
-        self._params = jax.tree.map(jnp.asarray, params)
-
-    def get_weights(self):
-        return [np.asarray(leaf) for leaf in jax.tree.leaves(self.params)]
-
-    def set_weights(self, weights):
-        shapes = jax.eval_shape(
-            functools.partial(init_params, cfg=self.cfg),
-            jax.random.PRNGKey(0))
-        self._params = jax.tree.unflatten(
-            jax.tree.structure(shapes), [jnp.asarray(w) for w in weights])
-
-    def to_json(self):
-        return json.dumps({"class_name": "OuroDecoder",
-                           "config": self.cfg})
+class OuroDecoder(FamilyDecoder):
+    family = sys.modules[__name__]
+    config = staticmethod(ouro_config)
+    name = "ouro_decoder"

@@ -15,15 +15,16 @@ the final RMSNorm and an untied head.  Attention is grouped-query (``q`` is
 ``heads x head_dim``, ``k`` and ``v`` ``kv_heads x head_dim``, K/V head
 ``i`` serving query heads ``g i .. g i + g - 1``; ``heads x head_dim`` need
 not be ``d_model``), RMSNorm over each head's width on ``q`` and ``k``,
-then rotary positions by halves: ``lfm2_moe``'s ``_qkv`` as it is.  **The
+then rotary positions by halves (``blocks.qkv_normed_rotated``, which
+``lfm2_moe`` shares).  **The
 mask is BLOCK-causal:** position ``t`` sees ``s`` iff ``s // B <= t //
 B``, so a block's positions all see one another.  Every layer routes:
 ``p = softmax(m W_r)`` over ALL the routed experts in float32 at
 "highest" precision, the ``k`` largest taken and divided by their sum; no
 selection bias, no scaling factor, no shared expert.  The chip holds
 ``held_experts`` (consecutive ids) and adds their part of the routed sum
-alone, as ``mla_moe.held_experts`` computes it; nothing stands in for the
-absent ones (``mla_moe.moe_layer`` with this family's :func:`route`).
+alone, as ``blocks.held_experts`` computes it; nothing stands in for the
+absent ones (``blocks.moe_layer`` with this family's :func:`route`).
 
 *The cache.*  One paged pool of ``v | k`` rows (``2 x kv_heads x
 head_dim`` values a position: 1,024 lanes at the published widths) over
@@ -40,13 +41,13 @@ tokens (fixed ones, and ``mask_token_id`` where none is fixed yet), where
 the block starts, and how many masked positions this pass FIXES (0: the
 pass commits).  It writes the ``B`` provisional rows, reads the slot's
 pages once for its ``B x heads`` queries over ``start + B`` rows with
-nothing masked inside the block (``lfm2_moe.attend_rows``, the query rows
+nothing masked inside the block (``blocks.attend_rows``, the query rows
 head-major so that a K/V head's queries stay together), runs the held
 experts over ``slots x B`` rows and the head at every block position, and
 chooses ON THE DEVICE (:func:`unmask`): at each masked position the most
 likely token other than the mask id and its probability, the ``fix``
 most confident positions taking theirs.  Out come the block's tokens
-after the pass and ``mla_moe``'s routing counts; a pass can therefore be
+after the pass and the expert layer's routing counts; a pass can therefore be
 launched on its predecessor's output without the host seeing a token.
 
 Generation (the engine's part, ``serving/decode.py``): a prefill commits
@@ -67,23 +68,25 @@ throughout.
 
 from __future__ import annotations
 
-import functools
-import json
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dist_keras_tpu.models.layers import glorot_uniform, select_top_k
-from dist_keras_tpu.models.lfm2_moe import _qkv, attend_rows
-from dist_keras_tpu.models.mla_moe import (
-    _swiglu_params,
-    _zero_counts,
+from dist_keras_tpu.models.blocks import (
+    FamilyDecoder,
     add_counts,
+    attend_rows,
+    logits,
     moe_layer,
     observe_routing,
+    qkv_normed_rotated,
     rms_norm,
+    swiglu_params,
+    zero_counts,
 )
+from dist_keras_tpu.models.layers import glorot_uniform, select_top_k
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
 
 FAMILY = "sdar_moe"
@@ -188,7 +191,7 @@ def init_layer_params(key, cfg, layer):
         "ffn_norm": jnp.ones((d,)),
         "moe": {
             "router": glorot_uniform(kr, (d, n_all)),
-            "experts": _swiglu_params(ke, d, cfg["moe_d_ff"], (n_held,)),
+            "experts": swiglu_params(ke, d, cfg["moe_d_ff"], (n_held,)),
         },
     }
 
@@ -217,20 +220,12 @@ def route(moe, x, cfg):
 
 
 def _ffn(blk, x, cfg, valid, counts):
-    """``mla_moe``'s expert layer (the held experts' part, its routing
+    """The shared expert layer (the held experts' part, its routing
     counts) under this family's router; no shared expert."""
     out, c = moe_layer(blk["moe"],
                        rms_norm(blk["ffn_norm"], x, cfg["rms_norm_eps"]),
                        cfg, valid, router=route)
     return x + out, add_counts(counts, c)
-
-
-def _logits(params, hs, cfg):
-    with jax.named_scope("head"):
-        # behind a barrier, as in ``mla_moe._logits``: the compiler
-        # otherwise folds the norm's weight into the head
-        return jax.lax.optimization_barrier(rms_norm(
-            params["norm_f"], hs, cfg["rms_norm_eps"])) @ params["head"]
 
 
 def unmask(logits, tokens, fix, mask_id):
@@ -262,10 +257,10 @@ def _sequence_layers(params, tokens, valid, cfg, write):
     positions = jnp.arange(t, dtype=jnp.int32)
     with jax.named_scope("embed"):
         hs = params["embed"][tokens]
-    counts = _zero_counts(cfg, t)
+    counts = zero_counts(cfg, t)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, cfg["rms_norm_eps"])
-        q, entry = _qkv(blk["attn"], y, positions, cfg)
+        q, entry = qkv_normed_rotated(blk["attn"], y, positions, cfg)
         write(li, entry)
         with jax.named_scope("attend"):
             v, k = jnp.split(entry, 2, axis=-1)
@@ -285,7 +280,7 @@ def forward(params, tokens, cfg):
     OF that position."""
     hs, _ = _sequence_layers(params, tokens, jnp.ones(tokens.shape, bool),
                              cfg, lambda li, entry: None)
-    return _logits(params, hs, cfg)
+    return logits(params, hs, cfg)
 
 
 def prefill_step(cfg, params, kv, tokens, length, page_idx, page_off):
@@ -331,10 +326,10 @@ def decode_step(cfg, params, kv, tokens, positions, page_tables,
     valid = jnp.repeat(lengths > 0, b)
     with jax.named_scope("embed"):
         hs = params["embed"][tokens.reshape(-1)]
-    counts = _zero_counts(cfg)
+    counts = zero_counts(cfg)
     for li, blk in enumerate(params["blocks"]):
         y = rms_norm(blk["op_norm"], hs, eps)
-        q, entry = _qkv(blk["attn"], y, at, cfg)
+        q, entry = qkv_normed_rotated(blk["attn"], y, at, cfg)
         with jax.named_scope("kv_write"):
             kv = kv.at[li, write_page[:, None],
                        write_off[:, None] + within].set(
@@ -352,16 +347,16 @@ def decode_step(cfg, params, kv, tokens, positions, page_tables,
             hs = hs + jnp.einsum("shk,hkd->sd", a.reshape(s * b, h, -1),
                                  blk["attn"]["wo"])
         hs, counts = _ffn(blk, hs, cfg, valid, counts)
-    logits = _logits(params, hs, cfg).reshape(s, b, -1)
+    at_block = logits(params, hs, cfg).reshape(s, b, -1)
     with jax.named_scope("unmask"):
-        after = unmask(logits, tokens, fix, cfg["mask_token_id"])
+        after = unmask(at_block, tokens, fix, cfg["mask_token_id"])
     return jnp.concatenate([after.reshape(-1), counts]), kv
 
 
 def observe_step(counts, at, lengths=None, page_size=None, fix=None,
                  folded=0):
     """The counts behind a step's tokens -> the registry
-    (``mla_moe.observe_routing``).  A pass hands in its entries'
+    (``blocks.observe_routing``).  A pass hands in its entries'
     ``lengths`` (host values, zeros for padding: the rows its read covers
     in each layer, the open blocks' among them), what each entry was to
     ``fix`` and how many of its commits were ``folded`` (their sequence's
@@ -392,44 +387,7 @@ def observe_step(counts, at, lengths=None, page_size=None, fix=None,
             100.0 * folded / commits, at=at)
 
 
-class SdarMoeDecoder:
-    """Model-contract wrapper (cfg + params + weights round-trip) that the
-    serialization layer and ``DecodeEngine`` take.  Weights are made from
-    ``seed`` on first use, so a deserialized copy that is handed its
-    weights never holds a second, random set."""
-
-    def __init__(self, cfg=None, seed=0, **cfg_kw):
-        self.cfg = cfg or sdar_moe_config(**cfg_kw)
-        self.name = "sdar_moe_decoder"
-        self._seed = seed
-        self._params = None
-
-    @property
-    def params(self):
-        if self._params is None:
-            self._params = init_params(jax.random.PRNGKey(self._seed),
-                                       self.cfg)
-        return self._params
-
-    def apply(self, params, tokens, *, training=False, rng=None):
-        return forward(params, tokens, self.cfg)
-
-    def __call__(self, tokens, *, training=False, rng=None):
-        return self.apply(self.params, jnp.asarray(tokens))
-
-    def set_params(self, params):
-        self._params = jax.tree.map(jnp.asarray, params)
-
-    def get_weights(self):
-        return [np.asarray(leaf) for leaf in jax.tree.leaves(self.params)]
-
-    def set_weights(self, weights):
-        shapes = jax.eval_shape(
-            functools.partial(init_params, cfg=self.cfg),
-            jax.random.PRNGKey(0))
-        self._params = jax.tree.unflatten(
-            jax.tree.structure(shapes), [jnp.asarray(w) for w in weights])
-
-    def to_json(self):
-        return json.dumps({"class_name": "SdarMoeDecoder",
-                           "config": self.cfg})
+class SdarMoeDecoder(FamilyDecoder):
+    family = sys.modules[__name__]
+    config = staticmethod(sdar_moe_config)
+    name = "sdar_moe_decoder"

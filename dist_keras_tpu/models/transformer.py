@@ -22,10 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dist_keras_tpu.models.blocks import attend_rows
 from dist_keras_tpu.models.layers import glorot_uniform
-# models/olmo_hybrid.py attends through it too: the function lives with
-# the first family that had ``v | k`` rows (ROADMAP D1)
-from dist_keras_tpu.models.lfm2_moe import attend_rows
 from dist_keras_tpu.ops.attention import attention  # noqa: F401 (oracle)
 from dist_keras_tpu.ops.pallas.decode_attention import latent_walked_positions
 from dist_keras_tpu.ops.pallas.flash_attention import attention_auto
@@ -206,19 +204,19 @@ def transformer_apply(params, x, cfg, *, causal=False, attn_fn=None,
 
 
 # -- what ``serving.decode.DecodeEngine`` takes from a block family -----
-# (``models/mla_moe.py``, ``models/lfm2_moe.py`` and
-# ``models/olmo_hybrid.py`` have the same names).  The cache is ONE paged
+# (every module ``models/families.py`` lists has the same names, and the
+# contract is stated there).  The cache is ONE paged
 # pool over all layers whose entry is the row ``v | k`` of a cached
 # position: every head's values, then every head's keys.  A prefill
 # attends over its own q, k, v (the flash forward) and writes a row a
 # position; a decode step writes its row and reads the slots' LIVE pages
-# where they lie, through the read the other three families share
-# (``lfm2_moe.attend_rows``: on a TPU ``latent_attention_kernel``, blocks
+# where they lie, through the read four other families share
+# (``blocks.attend_rows``: on a TPU ``latent_attention_kernel``, blocks
 # of pages double-buffered in VMEM and everything past a slot's length
 # skipped; elsewhere ``latent_attention_reference``).
 #
 # Both steps embed a token by reading its row of ``proj``, as the other
-# three families read theirs: the float32 row as stored (a ``one_hot``
+# families read theirs: the float32 row as stored (a ``one_hot``
 # product read the whole table a step and handed on the row rounded to
 # the product's precision).  An id outside the table reads a row all the
 # same: an indexed read clamps past the end and counts a negative id from

@@ -389,7 +389,7 @@ KNOWN_METRICS = {
     "decode.moe.experts_hit": "histogram",
     # one sample a prefill of the three expert families, stamped like
     # decode.prefill_s: 100 where its expert layers ran over the chosen
-    # pairs sorted by expert (a rung over mla_moe.GROUPED_OVER tokens),
+    # pairs sorted by expert (a rung over blocks.GROUPED_OVER tokens),
     # else 0; and for such a prefill the held pairs as a share of the
     # rows its passes over the experts' sorted pairs covered
     "decode.moe.prefill_grouped": "histogram",

@@ -19,51 +19,39 @@ dispatch predicate):
   chooses, nothing else does).  The pool stays in HBM; grid ``(slots,
   blocks of pages)``, each LIVE block's pages copied page by page into
   one of two VMEM buffers while the block before it computes, blocks at
-  or past a slot's length skipped.  Four families read through it:
+  or past a slot's length skipped.  Every family reads through it:
   ``models/mla_moe.py`` its latent rows (every head's absorbed query
   against one shared row), and ``models/lfm2_moe.py``,
-  ``models/olmo_hybrid.py`` and, since PR 37, ``models/transformer.py``
-  their ``v | k`` rows through ``lfm2_moe.attend_rows`` (head h's query
+  ``models/olmo_hybrid.py``, ``models/sdar_moe.py``, ``models/ouro.py``
+  and, since PR 37, ``models/transformer.py`` their ``v | k`` rows
+  through ``models/blocks.py:attend_rows`` (head h's query
   laid into the lanes of its own keys, the row's leading half the values,
   head h keeping its own lanes of the sum), each stating the pages a
   block holds for its row's width.
-- :func:`paged_attention_reference` — pure ``jnp`` over a K and a V pool
-  of ``(page_size, H, D)`` pages: gather the page table, mask, softmax.
-  NO MODEL CALLS IT since PR 37 (``models/transformer.py:decode_step``
-  did, on every backend, and paid for every slot's whole table whatever
-  its length: 13.04 ms six reads at 8 slots of 1,024-2,048 positions
-  against 2.98 through the rows, PERF.md, PR 37); it stays as the oracle
-  of the K/V kernel and of the tests of the read through rows.
-- :func:`paged_attention_kernel` — a Pallas read of the K/V pool that
-  NOTHING SERVES: a function with parity tests and a lowering case, no
-  caller in the package.  Grid ``(slots, pages)``, one whole page (all
-  heads) a step fetched through the scalar-prefetched page table (the
-  pool is never gathered), the online-softmax scratch of all heads
-  carried along the page dimension, products elementwise in float32.
-  Every table entry costs its grid step and its products, dead or not;
-  only the copy of a dead entry is saved, where it repeats the entry
-  before it (the engine leaves page 0 behind a slot's allocation).
-  Fully-masked slots (padding in a fixed-shape decode rung, ``length
-  == 0``) produce exact zeros via the same dead-row guards as the flash
-  forward.  It lost to the rows twice on the chip (5.96 against 0.98 ms
-  a read at lfm2's shape, PR 32; 4.18 against 1.39 at olmo's, PR 36).
-  Both ``paged_attention_*`` functions go with their tests made up for
-  (ROADMAP D8).
+- Why rows and nothing else: a kernel of one page a grid step over a K
+  and a V pool lost to the rows twice on the chip (5.96 against 0.98 ms
+  a read at lfm2's shape, PR 32; 4.18 against 1.39 at olmo's, PR 36:
+  every table entry cost its grid step and its products, dead or not),
+  and a ``jnp`` gather of every slot's whole K and V tables lost to them
+  too (13.04 ms six reads at 8 slots of 1,024-2,048 positions against
+  2.98, PERF.md, PR 37).  Neither is in the package; the gather is the
+  oracle of the rows' tests (``tests/test_decode.py``).
 - No gate and no knob: the platform alone picks a path; ``chip_smoke.py``
   stage D holds each kernel to a "highest" reference on the chip.
 
-Shapes: ``q (S, H, D)``; pools ``k/v (P, page_size, H, D)`` or rows ``(P,
-page_size, R)`` — PAGE-MAJOR, the one layout this module knows: a page is
-one contiguous ``(page_size, ...)`` block, so the writer's scatter over (page, offset) indexes the
-major dimensions (what the TPU compiler runs in place; a head-major pool
-is converted, whole, on the way in and out of every step) and a reader
-fetches a page with one block copy.  ``P`` may span several layers' pages
-(the decode engine passes its whole pool viewed flat over (layer, page)
-and offsets the page ids; a per-layer slice would be a copy).
+Shapes: ``q (S, H, R)``; a pool of rows ``(P, page_size, R)`` —
+PAGE-MAJOR, the one layout this module knows: a page is one contiguous
+``(page_size, R)`` block, so the writer's scatter over (page, offset)
+indexes the major dimensions (what the TPU compiler runs in place; a
+head-major pool is converted, whole, on the way in and out of every step)
+and a reader fetches a page with one block copy.  ``P`` may span several
+layers' pages (the decode engine passes its whole pool viewed flat over
+(layer, page) and offsets the page ids; a per-layer slice would be a
+copy).
 ``page_table (S, max_pages) int32`` (entries past a slot's allocation
 must hold any valid page id — masked by ``lengths``); ``lengths (S,)
-int32`` = valid KV positions per slot, INCLUDING the current token (its
-k/v is written before attention).
+int32`` = valid cached positions per slot, INCLUDING the current token
+(its row is written before attention).
 """
 
 from __future__ import annotations
@@ -84,37 +72,6 @@ from dist_keras_tpu.ops.pallas.flash_attention import (
 )
 
 
-def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
-                              *, scale=None):
-    """The read of a K and a V pool, pure ``jnp``: the oracle of
-    :func:`paged_attention_kernel` and of the tests of the read through
-    ``v | k`` rows; no model calls it (module docstring).
-
-    Gathers each slot's pages, whole ``(page_size, H, D)`` blocks by
-    page id, into a contiguous ``(S, T, H, D)`` view (T = max_pages *
-    page_size), masks positions past ``lengths``, and softmaxes — with
-    the flash dead-row guards so a ``length == 0`` padding slot yields
-    exact zeros, not NaN.
-    """
-    s, h, d = q.shape
-    scale = (d ** -0.5) if scale is None else scale
-    # (S, max_pages, ps, H, D) -> (S, T, H, D)
-    k = k_pages[page_table].reshape(s, -1, h, d)
-    v = v_pages[page_table].reshape(s, -1, h, d)
-    t = k.shape[1]
-    logits = (jnp.einsum("shd,sthd->sht", q, k)
-              .astype(jnp.float32) * scale)
-    kpos = jnp.arange(t, dtype=jnp.int32)
-    mask = kpos[None, None, :] < lengths.astype(jnp.int32)[:, None, None]
-    logits = jnp.where(mask, logits, _NEG_INF)
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - jnp.where(m <= _NEG_INF / 2, 0.0, m))
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = (jnp.einsum("sht,sthd->shd", p, v)
-           / jnp.maximum(l, 1e-30))
-    return out.astype(q.dtype)
-
-
 def latent_attention_reference(q, latent_pages, page_table, lengths, *,
                                rank, scale):
     """The read of a LATENT pool, absorbed form, pure ``jnp``.
@@ -124,14 +81,14 @@ def latent_attention_reference(q, latent_pages, page_table, lengths, *,
     rest the rotated shared key and zeros up to whole lanes.  ``q (S, H,
     R)`` are the absorbed queries (``q_nope w_uk^T | q_pe``, zeros
     behind), ``latent_pages (P, page_size, R)`` the pool (page-major, any
-    number of layers' pages flat),
-    ``page_table`` / ``lengths`` as for :func:`paged_attention_reference`
-    -> ``sum p c`` ``(S, H, rank)``; a ``length == 0`` padding slot
-    yields exact zeros (the same dead-row guard).
+    number of layers' pages flat), ``page_table`` / ``lengths`` as the
+    module docstring states them -> ``sum p c`` ``(S, H, rank)``; a
+    ``length == 0`` padding slot yields exact zeros (the flash forward's
+    dead-row guard).
 
-    Like the K/V reference it gathers each slot's whole table whatever
-    its length: the oracle of :func:`latent_attention_kernel`, which
-    walks the live pages in place, and what serves off the TPU (on a
+    It gathers each slot's whole table whatever its length: the oracle
+    of :func:`latent_attention_kernel`, which walks the live pages in
+    place, and what serves off the TPU (on a
     v5e the gather was 21.75 of a 28.19 ms decode step at 32 slots x
     6656 positions x 9 layers, PR 27; the kernel's reads 4.37 of 11.04,
     PR 28).  The gathered rows pass an optimisation barrier: without it
@@ -156,88 +113,6 @@ def latent_attention_reference(q, latent_pages, page_table, lengths, *,
     # slice of the gathered rows would be a second copy of them
     out = jnp.einsum("sht,str->shr", p, rows)[..., :rank]
     return (out / jnp.maximum(l, 1e-30)).astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# the kernel
-# ---------------------------------------------------------------------------
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, page_size, scale):
-    s, j = pl.program_id(0), pl.program_id(1)
-    nj = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[s]
-    q = q_ref[0].astype(jnp.float32)                # (H, D)
-    k = k_ref[0].astype(jnp.float32)                # (ps, H, D)
-    v = v_ref[0].astype(jnp.float32)                # (ps, H, D)
-    # one query row a head: the products are elementwise with a lane
-    # reduction, every head at once; the page's positions stay the
-    # leading (untiled) dimension throughout
-    logits = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
-    kpos = (j * page_size
-            + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0))
-    logits = jnp.where(kpos < length, logits, _NEG_INF)     # (ps, H, 1)
-    m_prev = m_scr[...]                             # (H, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=0))
-    # same dead-row shift as the flash forward: a fully-masked tile
-    # (page past length / padding slot) contributes exactly nothing
-    safe_m = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-    p = jnp.exp(logits - safe_m[None])              # (ps, H, 1)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=0)
-    acc_scr[...] = acc_scr[...] * corr + jnp.sum(p * v, axis=0)
-    m_scr[...] = m_new
-
-    @pl.when(j == nj - 1)
-    def _emit():
-        l_safe = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-
-
-def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
-                           *, scale=None, interpret=False):
-    """The Pallas read of a K/V pool: the contract of
-    :func:`paged_attention_reference`, one page a grid step.  No caller
-    in the package (module docstring)."""
-    s, h, d = q.shape
-    ps = k_pages.shape[1]
-    n_pages = page_table.shape[1]
-    scale = (d ** -0.5) if scale is None else scale
-    kernel = functools.partial(_decode_kernel, page_size=ps, scale=scale)
-    # index maps see (*grid_indices, *scalar_prefetch_refs): the page
-    # table picks each grid step's K/V page BEFORE its DMA issues
-    kv_map = lambda si, j, pt, ln: (pt[si, j], 0, 0, 0)       # noqa: E731
-    q_map = lambda si, j, pt, ln: (si, 0, 0)                  # noqa: E731
-    extra = ({} if interpret else {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))})
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, h, d), q_map),
-            pl.BlockSpec((1, ps, h, d), kv_map),
-            pl.BlockSpec((1, ps, h, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), q_map),
-        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, d), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_sds((s, h, d), q.dtype, q),
-        interpret=interpret,
-        name=_kernel_name("paged_decode"),
-        **extra,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------

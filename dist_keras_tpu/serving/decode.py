@@ -57,9 +57,9 @@ Design points (each mirrors an existing engine contract):
   (``olmo_hybrid``: 14 MB at the published widths) the count is part of
   sizing the replica, as pages are, and the door refuses typed when every
   row is held.  The family is named by the model's ``cfg`` and
-  taken once, at construction (``_FAMILIES``: its two step functions and
-  its pools); the scheduler, the allocator and recovery's replay see no
-  family.
+  taken once, at construction (``models/families.py``, which states
+  what a family is: its two step functions and its pools); the
+  scheduler, the allocator and recovery's replay see no family.
   Admission reserves a sequence's WORST-CASE page count up front, so
   decode never stalls mid-sequence on KV: exhaustion is a typed
   ``Overloaded(reason="kv_exhausted")`` strictly at the door (rejected,
@@ -147,14 +147,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models import (
-    lfm2_moe,
-    mla_moe,
-    olmo_hybrid,
-    ouro,
-    sdar_moe,
-    transformer,
-)
+from dist_keras_tpu.models.families import DECODERS, family_of
 from dist_keras_tpu.observability import events, metrics, perf, spans
 from dist_keras_tpu.observability import slo as _slo
 from dist_keras_tpu.resilience.faults import fault_point
@@ -262,30 +255,6 @@ class Generation:
 
     def done(self):
         return self.future.done()
-
-
-# A block family is a module under ``models/`` with ``FAMILY`` (the name a
-# model's ``cfg["family"]`` gives; a cfg that names none is a
-# ``Transformer``'s), ``vocab(cfg)`` (which also refuses what the family
-# cannot decode), ``cache_pools(cfg)`` (for each pool ``(layers, rows,
-# entry)``: how many layers it spans (ENTRIES: a looped family states
-# passes times layers), whether its rows are ``"page"``s of
-# cached positions or one ``"sequence"`` each, and the trailing shape of
-# one entry), ``step_width(cfg)`` (positions a slot a step: 1 where a
-# step yields one token a sequence; a family that generates in BLOCKS
-# states the block's length, and with ``step_fixes(cfg)`` the id that
-# stands at a block position nothing is fixed at yet and how many such
-# positions a pass fixes; its ``decode_step`` is over ENTRIES, of which a
-# sequence may hold two, :func:`_step_views`), ``prefill_step`` /
-# ``decode_step`` (``(cfg,
-# params, *pools, ...) -> (int32 array, *pools)``: the tokens first, then
-# whatever counts the family sends along; a family with a per-sequence
-# pool is also handed the state rows, last) and ``observe_step(counts, at,
-# lengths=None, page_size=None)`` for those counts (None when the family
-# sends none).
-_FAMILIES = {m.FAMILY: m
-             for m in (transformer, mla_moe, lfm2_moe, olmo_hybrid,
-                       sdar_moe, ouro)}
 
 
 def _step_views(packed, pmax, state=False, width=1):
@@ -477,15 +446,12 @@ class DecodeEngine:
     """Continuous-batching decode over a causal decoder.
 
     Args:
-      keras_model: a ``models.transformer.Transformer``, a
-        ``models.mla_moe.LatentMoEDecoder``, a
-        ``models.lfm2_moe.Lfm2MoeDecoder`` or a
-        ``models.olmo_hybrid.OlmoHybridDecoder`` (or anything the
-        serialization layer round-trips to one); its ``cfg`` names the
-        block family.  A ``Transformer`` decodes with token in == logit
-        out, so its config must have ``input_dim == n_classes`` (the
-        vocabulary) and dense feed-forwards (its Switch-MoE blocks drop
-        tokens over capacity and have no decode step).
+      keras_model: a decoder of a family in ``models/families.py`` (or
+        anything the serialization layer round-trips to one); its ``cfg``
+        names the block family.  A ``Transformer`` decodes with token in
+        == logit out, so its config must have ``input_dim == n_classes``
+        (the vocabulary) and dense feed-forwards (its Switch-MoE blocks
+        drop tokens over capacity and have no decode step).
       replicas: replica count (default: one per visible device).
       prefill_ladder: ascending fixed PROMPT shapes; a prompt runs
         padded to the smallest rung that fits (``ValueError`` past the
@@ -528,16 +494,13 @@ class DecodeEngine:
         cfg = getattr(model, "cfg", None)
         if cfg is None:
             raise ValueError(
-                "DecodeEngine needs a decoder's model contract (a cfg "
-                "dict: models.transformer.Transformer, "
-                "models.mla_moe.LatentMoEDecoder, "
-                "models.lfm2_moe.Lfm2MoeDecoder or "
-                f"models.olmo_hybrid.OlmoHybridDecoder); got "
-                f"{type(model).__name__}")
+                "DecodeEngine needs a decoder of a family in "
+                f"models/families.py ({', '.join(DECODERS)}: the model "
+                f"contract with a cfg dict); got {type(model).__name__}")
         self.cfg = cfg
         # the model's block family, looked up once: everything below
         # this line sees pools and steps, no family
-        self._family = _FAMILIES[cfg.get("family", transformer.FAMILY)]
+        self._family = family_of(cfg)
         self.vocab = self._family.vocab(cfg)
         # positions a slot a step: 1, or the length of the blocks the
         # family generates in (then: the id of a position nothing is

@@ -32,14 +32,6 @@ def serialize_model(model):
     }
 
 
-# the decoders ``DecodeEngine`` serves, by class name: the module of
-# ``models/`` each lives in (a ``cfg`` in, ``set_weights`` after)
-_DECODERS = {"Transformer": "transformer", "LatentMoEDecoder": "mla_moe",
-             "Lfm2MoeDecoder": "lfm2_moe",
-             "OlmoHybridDecoder": "olmo_hybrid",
-             "SdarMoeDecoder": "sdar_moe", "OuroDecoder": "ouro"}
-
-
 def deserialize_model(d):
     """dict -> Model, same contract as utils.py:~55.
 
@@ -49,15 +41,13 @@ def deserialize_model(d):
     """
     import json
 
+    from dist_keras_tpu.models.families import DECODERS
     from dist_keras_tpu.models.model import model_from_json
 
     arch = json.loads(d["model"])
-    if arch.get("class_name") in _DECODERS:
-        import importlib
-
-        module = importlib.import_module(
-            "dist_keras_tpu.models." + _DECODERS[arch["class_name"]])
-        model = getattr(module, arch["class_name"])(cfg=arch["config"])
+    if arch.get("class_name") in DECODERS:
+        # a decoder ``DecodeEngine`` serves: a ``cfg`` in, its weights after
+        model = DECODERS[arch["class_name"]](cfg=arch["config"])
         model.set_weights(d["weights"])
         return model
     if arch.get("class_name") == "Sequential" and "layers" in arch and all(

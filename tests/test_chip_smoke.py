@@ -70,8 +70,8 @@ def chip_kernel_cases():
 
 CASES = ["flash_fwd/bf16_train", "flash_bwd/bf16_train",
          "flash_fwd/f32_prefill", "flash_bwd/f32_prefill",
-         "paged_decode/f32", "latent_decode/f32",
-         "latent_decode/kv_rows_f32", "gdn_state_step/f32"]
+         "latent_decode/f32", "latent_decode/kv_rows_f32",
+         "gdn_state_step/f32"]
 
 
 @pytest.fixture(scope="module")

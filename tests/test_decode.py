@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe
+from dist_keras_tpu.models import blocks, lfm2_moe, mla_moe
 from dist_keras_tpu.models.transformer import (
     Transformer,
     apply_block,
@@ -1181,7 +1181,41 @@ def test_generate_endpoint_rejects_bad_input(served_decode):
     assert code == 400
 
 
-# -- kernel parity at every ladder shape -------------------------------
+# -- the read of a K and a V pool: the oracle of the read through rows --
+_NEG_INF = -1e30
+
+
+def paged_attention_reference(q, k_pages, v_pages, page_table, lengths,
+                              *, scale=None):
+    """The read of a K and a V pool of ``(page_size, H, D)`` pages, pure
+    ``jnp``: the oracle of the read through ``v | k`` rows (the package
+    decoded through it until PR 37 and kept it until PR 48).
+
+    Gathers each slot's pages, whole ``(page_size, H, D)`` blocks by
+    page id, into a contiguous ``(S, T, H, D)`` view (T = max_pages *
+    page_size), masks positions past ``lengths``, and softmaxes — with
+    the flash dead-row guards so a ``length == 0`` padding slot yields
+    exact zeros, not NaN.
+    """
+    s, h, d = q.shape
+    scale = (d ** -0.5) if scale is None else scale
+    # (S, max_pages, ps, H, D) -> (S, T, H, D)
+    k = k_pages[page_table].reshape(s, -1, h, d)
+    v = v_pages[page_table].reshape(s, -1, h, d)
+    t = k.shape[1]
+    logits = (jnp.einsum("shd,sthd->sht", q, k)
+              .astype(jnp.float32) * scale)
+    kpos = jnp.arange(t, dtype=jnp.int32)
+    mask = kpos[None, None, :] < lengths.astype(jnp.int32)[:, None, None]
+    logits = jnp.where(mask, logits, _NEG_INF)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.exp(logits - jnp.where(m <= _NEG_INF / 2, 0.0, m))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = (jnp.einsum("sht,sthd->shd", p, v)
+           / jnp.maximum(l, 1e-30))
+    return out.astype(q.dtype)
+
+
 def test_paged_attention_reference_matches_dense():
     # the reference itself against plain dense attention over the
     # gathered pages — anchors the whole parity chain
@@ -1193,8 +1227,7 @@ def test_paged_attention_reference_matches_dense():
     vp = jnp.asarray(rng.normal(size=(pool, ps, heads, dh)), jnp.float32)
     pt = jnp.asarray(rng.integers(0, pool, size=(2, npg)), jnp.int32)
     lengths = jnp.asarray([5, 12], jnp.int32)
-    got = decode_attention.paged_attention_reference(q, kp, vp, pt,
-                                                     lengths)
+    got = paged_attention_reference(q, kp, vp, pt, lengths)
     for s in range(2):
         t = int(lengths[s])
         k = np.concatenate([np.asarray(kp[pt[s, j]])
@@ -1230,47 +1263,6 @@ def _paged_case(lengths, heads=2, head_dim=64, page_size=8, n_pages=3,
             jnp.asarray(lengths, jnp.int32))
 
 
-def _assert_kernel_equals_reference(args):
-    """The interpreted kernel against the reference, both float32 on the
-    CPU: they differ by the order of float32 sums alone (read 0 to 4e-7
-    of the largest output), limit 1e-5; a wrong page or mask is order 1.
-    A ``length == 0`` slot yields exact zeros, not small values."""
-    want = np.asarray(decode_attention.paged_attention_reference(*args))
-    got = np.asarray(jax.jit(functools.partial(
-        decode_attention.paged_attention_kernel, interpret=True))(*args))
-    assert got.shape == want.shape
-    assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))
-    for i, n in enumerate(np.asarray(args[4])):
-        if n == 0:
-            assert not got[i].any(), i
-
-
-@pytest.mark.parametrize("slots", [1, 4, 8])
-def test_kernel_parity_every_decode_ladder_shape(slots):
-    """``paged_attention_kernel`` at each decode-ladder rung, its slots'
-    lengths cycling through the whole table, a padding slot, one
-    position and a page boundary."""
-    picks = (24, 0, 1, 8)
-    _assert_kernel_equals_reference(
-        _paged_case([picks[i % 4] for i in range(slots)], seed=slots))
-
-
-_PAGED_LENGTH_CASES = {
-    "padding_slot_then_one_position": dict(lengths=[0, 1]),
-    "partial_page": dict(lengths=[3, 13]),
-    "page_boundary": dict(lengths=[8, 16]),
-    "whole_table": dict(lengths=[24, 24]),
-    "second_layer_of_a_flat_pool": dict(lengths=[5, 17, 24], layer=1,
-                                        layers=2),
-}
-
-
-@pytest.mark.parametrize("name", sorted(_PAGED_LENGTH_CASES))
-def test_paged_kernel_equals_the_reference(name):
-    _assert_kernel_equals_reference(
-        _paged_case(**_PAGED_LENGTH_CASES[name]))
-
-
 # -- the transformer step's read: ``v | k`` rows through ``attend_rows`` --
 def _rows_case(lengths, n_pages, **kw):
     """``_paged_case`` at 4 heads of 128 and the same pool as rows ``v |
@@ -1285,7 +1277,7 @@ def _rows_case(lengths, n_pages, **kw):
 def _read_through_the_interpreted_kernel(monkeypatch):
     """What ``attend_rows`` dispatches to on a TPU, interpreted here."""
     monkeypatch.setattr(
-        lfm2_moe, "latent_attention_auto", functools.partial(
+        blocks, "latent_attention_auto", functools.partial(
             decode_attention.latent_attention_kernel, interpret=True))
 
 
@@ -1300,6 +1292,11 @@ _ROWS_CASES = {
                                         layer=1, layers=2),
     "rung_8_with_padding_slots": dict(
         lengths=[21, 0, 8, 0, 0, 30, 0, 1], n_pages=4),
+    # a table of 3 pages (a block and a half): lengths that end inside a
+    # page, on a page's edge and at the table's end
+    "partial_page": dict(lengths=[3, 13], n_pages=3),
+    "page_boundary": dict(lengths=[8, 16], n_pages=3),
+    "whole_table": dict(lengths=[24, 24], n_pages=3),
 }
 
 
@@ -1318,10 +1315,10 @@ def test_read_through_rows_equals_the_kv_reference(name, path, monkeypatch):
     if path == "kernel":
         _read_through_the_interpreted_kernel(monkeypatch)
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(decode_attention.paged_attention_reference(
+        want = np.asarray(paged_attention_reference(
             q, kp, vp, table, lengths))
-        got = np.asarray(lfm2_moe.attend_rows(q, rows, table, lengths,
-                                              q.shape[1], 2))
+        got = np.asarray(blocks.attend_rows(q, rows, table, lengths,
+                                            q.shape[1], 2))
     assert got.shape == want.shape
     assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))
     for i, n in enumerate(np.asarray(lengths)):

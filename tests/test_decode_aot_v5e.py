@@ -76,7 +76,7 @@ def _roots(text):
 def _expert_loops(text):
     """The loops of the held experts' grouped form in a compiled program
     (one over the experts, inside it one over an expert's sorted pairs):
-    what ``mla_moe.held_experts`` runs in a call of more than 1,024 tokens
+    what ``blocks.held_experts`` runs in a call of more than 1,024 tokens
     (PR 47), and in no shorter one."""
     return [line for line in text.splitlines()
             if " while(" in line and "moe_experts/while" in line]
@@ -356,7 +356,7 @@ def test_latent_step_leaves_the_pool_in_place(topo, as_tpu, phase, rung,
     assert m.temp_size_in_bytes < temp_gb * GB, m.temp_size_in_bytes
     if phase == "prefill":
         assert "flash_fwd" in text
-        # both rungs are over ``mla_moe.GROUPED_OVER``: each of the eight
+        # both rungs are over ``blocks.GROUPED_OVER``: each of the eight
         # expert layers runs its held experts over the sorted pairs, a
         # loop over the experts around a loop over one expert's pairs, and
         # the flash forward stays the program's only kernel

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from benchmark import weights
 from benchmark.families import lfm2_moe as family
 from benchmark.reference import lfm2_moe_ref as ref
-from dist_keras_tpu.models import lfm2_moe, mla_moe
+from dist_keras_tpu.models import blocks, lfm2_moe, mla_moe
 from dist_keras_tpu.observability import metrics
 from dist_keras_tpu.resilience import faults
 from dist_keras_tpu.resilience.faults import FaultInjected
@@ -250,7 +250,7 @@ def test_steps_over_the_pools_equal_the_reference_at_every_position(highest):
                          n_pages, n_pages], jnp.int32),
             jnp.asarray([at[0] % ps, at[1] % ps, 0, 0], jnp.int32),
             jnp.asarray(lengths), jnp.asarray(rows + [n_rows, n_rows]))
-        got = lfm2_moe._logits(params, hs, cfg)
+        got = blocks.logits(params, hs, cfg)
         for slot in (0, 1):
             np.testing.assert_allclose(got[slot], wants[slot][at[slot]],
                                        atol=TOL, rtol=0)
@@ -299,11 +299,11 @@ def test_a_decode_step_without_its_state_is_not_the_references(highest):
             jnp.asarray([1], jnp.int32), jnp.asarray([2], jnp.int32),
             jnp.asarray([7], jnp.int32), jnp.asarray([0], jnp.int32))
     hs, *_ = lfm2_moe._decode_layers(cfg, params, kv, state, *args)
-    assert float(jnp.abs(lfm2_moe._logits(params, hs, cfg)[0]
+    assert float(jnp.abs(blocks.logits(params, hs, cfg)[0]
                          - want).max()) <= TOL
     hs, *_ = lfm2_moe._decode_layers(cfg, params, kv,
                                      jnp.zeros_like(state), *args)
-    assert float(jnp.abs(lfm2_moe._logits(params, hs, cfg)[0]
+    assert float(jnp.abs(blocks.logits(params, hs, cfg)[0]
                          - want).max()) > 30 * TOL
 
 
@@ -537,7 +537,7 @@ def test_routing_selects_by_score_plus_bias_and_weighs_by_score(case):
     router = np.zeros((8, 8), np.float32)
     router[0] = [_logit(p) for p in scores]
     x = jnp.zeros((1, 8)).at[0, 0].set(1.0)
-    idx, w = mla_moe.route(_moe_with(router, bias, cfg), x, cfg)
+    idx, w = blocks.route_sigmoid(_moe_with(router, bias, cfg), x, cfg)
     assert sorted(np.asarray(idx[0]).tolist()) == chosen
     s = np.asarray(scores)[np.asarray(idx[0])]
     # weights from s alone (no bias), over their sum PLUS 1e-6, times the
@@ -547,7 +547,7 @@ def test_routing_selects_by_score_plus_bias_and_weighs_by_score(case):
     if case == "tiny_scores":
         assert abs(float(w[0].sum()) - 0.75) < 1e-3
         other = {**cfg, "route_norm_eps": 1e-20}
-        _, w20 = mla_moe.route(_moe_with(router, bias, cfg), x, other)
+        _, w20 = blocks.route_sigmoid(_moe_with(router, bias, cfg), x, other)
         assert abs(float(w20[0].sum()) - 1.0) < 1e-3
     want_idx, want_w = ref.routing(_moe_with(router, bias, cfg), x,
                                    family.reference_config(cfg))
@@ -564,11 +564,11 @@ def test_every_expert_is_held_and_none_is_shared(highest):
     moe = weights_for(cfg)["blocks"][3]["moe"]
     assert "shared" not in moe
     x = jax.random.normal(jax.random.PRNGKey(0), (24, cfg["d_model"]))
-    got, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((24,), bool))
+    got, counts = blocks.moe_layer(moe, x, cfg, jnp.ones((24,), bool))
     want = ref.expert_layer(moe, x, family.reference_config(cfg))
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
     assert int(counts[:8].sum()) == int(counts[-1]) == 24 * cfg["top_k"]
-    _, counts = mla_moe.moe_layer(moe, x, cfg, jnp.arange(24) < 10)
+    _, counts = blocks.moe_layer(moe, x, cfg, jnp.arange(24) < 10)
     assert int(counts[:8].sum()) == int(counts[-1]) == 10 * cfg["top_k"]
 
 

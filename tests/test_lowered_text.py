@@ -1,5 +1,4 @@
-"""The five block families whose step is one token a slot compile to the
-programs recorded here.
+"""The six block families compile to the programs recorded here.
 
 PR 32 widened the family seam (a pool states the layers it spans and
 whether its rows are pages or sequences; a family with per-sequence state
@@ -32,9 +31,10 @@ older entries pass as recorded.
 **PR 37 meant to change the transformer family's four entries, and only
 those.**  Its cache became ONE pool of ``v | k`` rows (a prefill writes a
 row a position where it wrote a K and a V entry; a decode step reads the
-slots' live pages through ``lfm2_moe.attend_rows``, on a TPU the
-``latent_decode`` kernel, where it gathered every slot's whole table), so
-its CPU and TPU decode programs now differ.  The four hashes were recorded
+slots' live pages through ``attend_rows`` (then ``lfm2_moe``'s, since PR
+48 ``models/blocks.py``'s), on a TPU the ``latent_decode`` kernel, where
+it gathered every slot's whole table), so its CPU and TPU decode programs
+now differ.  The four hashes were recorded
 anew on that PR's tree; the kernel, its dispatch and ``attend_rows`` were
 not edited, and the eight entries of ``mla_moe`` and ``lfm2_moe`` pass as
 recorded.
@@ -62,12 +62,13 @@ entries.**  ``models/ouro.py`` (one stack of layers run several times a
 token as a loop of both programs, a cache entry a (pass, layer)) needed
 no line of the engine but the registry's entry: the sixteen older entries
 pass as recorded (``sdar_moe``'s programs, whose step is a pass over
-blocks, are not pinned here), and this family's four were recorded on
-that PR's tree.
+blocks, were not pinned here until PR 48), and this family's four were
+recorded on that PR's tree.
 
-**PR 47 gave ``mla_moe.held_experts`` a second form and was held to
-leaving every program here as it was.**  A call of more than 1,024 tokens
-(``mla_moe.GROUPED_OVER``) runs the held experts over the chosen pairs
+**PR 47 gave ``held_experts`` (then ``mla_moe``'s, since PR 48
+``models/blocks.py``'s) a second form and was held to leaving every
+program here as it was.**  A call of more than 1,024 tokens
+(``GROUPED_OVER``) runs the held experts over the chosen pairs
 sorted by expert, an expert at a time through plain products; a call of
 1,024 or fewer runs the masked dense pass, the same operations in the
 same order (the sum, then the counts).  The choice is by the call's
@@ -77,6 +78,22 @@ entries pass as recorded (``mla_moe``'s and ``lfm2_moe``'s, which call
 the function, among them), and so do the decode programs and the prefill
 rungs of 256-1,024 tokens that ``lfm2_serve_turns`` and
 ``sdar_serve_blocks`` run.
+
+**PR 48 moved what the families share into ``models/blocks.py`` and was
+held to leaving every program here as it was.**  Before a function moved,
+``sdar_moe``'s four programs (a pass over blocks: the rung's spare entries,
+a block's tokens an entry and one more column in the packed array) were
+recorded on commit 21f5319 (PR 47), with this file alone edited: PR 43's
+programs are pinned from there on.  The functions then moved with their
+bodies and scopes letter for letter (``rms_norm``, the rotations, SwiGLU,
+the head, the ``v | k`` rows, the taps, the expert layer with
+``GROUPED_OVER``; the five decoder classes became one), and the
+twenty-four entries pass as recorded.  One order had to be kept by hand:
+the head names its table BEHIND the norm (``blocks.logits`` takes the
+parameter tree, not the table: a tied table transposed in the caller is
+lowered in front of the norm, and ``lfm2_moe``'s four hashes move).
+Whoever edits a function of ``models/blocks.py`` finds in its docstring
+which families call it; all of them are pinned here.
 
 A change that means to alter one of these programs records the new hash
 and says so; a change that does not, and fails here, has moved a
@@ -89,7 +106,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dist_keras_tpu.models import lfm2_moe, mla_moe, olmo_hybrid, ouro
+from dist_keras_tpu.models import (
+    lfm2_moe,
+    mla_moe,
+    olmo_hybrid,
+    ouro,
+    sdar_moe,
+)
 from dist_keras_tpu.models.transformer import Transformer, transformer_config
 from dist_keras_tpu.serving import DecodeEngine
 
@@ -132,9 +155,16 @@ def _ouro():
         head_dim=16, d_ff=96, n_layers=3), seed=1)
 
 
+def _sdar_moe():
+    return sdar_moe.SdarMoeDecoder(cfg=sdar_moe.sdar_moe_config(
+        vocab_size=128, seq_len=48, d_model=64, n_heads=8, n_kv_heads=2,
+        head_dim=16, moe_d_ff=48, n_routed_experts=16, top_k=4, n_layers=3,
+        held_experts=[4, 5, 6, 7]), seed=1)
+
+
 MODELS = {"transformer": _transformer, "mla_moe": _mla_moe,
           "lfm2_moe": _lfm2_moe, "olmo_hybrid": _olmo_hybrid,
-          "ouro": _ouro}
+          "ouro": _ouro, "sdar_moe": _sdar_moe}
 # what a family's engine is given beside the ladders (a state row of this
 # family is large: the engine is told how many it holds)
 ENGINE = {"olmo_hybrid": dict(state_rows=3)}
@@ -188,6 +218,15 @@ RECORDED = {
         "f18f0e83b96469e9336f0281f852d4b5c51f34d918dde9f5819a6f721d674517",
     ("ouro", "decode", "tpu"):
         "44b36cf01f363809ef2b6425764d4d2ba15ce62fc9cf90827c4d55d87a36b142",
+    # recorded on commit 21f5319 (PR 47), before PR 48 moved a function
+    ("sdar_moe", "prefill", "cpu"):
+        "807d8f465e81df32f3b283e92b49b5b74e22b7fd2a95e8c24c2a6a86a1a325e3",
+    ("sdar_moe", "decode", "cpu"):
+        "5571c8efdb0cff233f12d94685cc9db804ee2d9c81915184e43509c1d1f44a92",
+    ("sdar_moe", "prefill", "tpu"):
+        "7f6c23dd9964c4bac600b688a192c63de24f63490e0a908bdaeb6c7ff5ad66a0",
+    ("sdar_moe", "decode", "tpu"):
+        "fe114b6f57e5db4cbabcdeb418d3f565523e66a76aae09253477303a7708e6b3",
 }
 
 
@@ -207,8 +246,12 @@ def lowered_hash(family, phase, platform):
         # a family with per-sequence state has one more packed column
         rows = int(eng._state)
         if phase == "decode":
-            # behind the pools the output of the step before it
-            step, n, carried = eng._decode_jit, 4 * (pmax + 5 + rows), \
+            # behind the pools the output of the step before it; a pass
+            # over blocks holds the rung's spare entries, a block's tokens
+            # an entry and one more column (``_step_views``)
+            width = eng._width
+            step, n, carried = eng._decode_jit, eng._entries(4) * (
+                pmax + 5 + rows + (width if width > 1 else 0)), \
                 (rep.no_tokens,)
         else:
             step, n, carried = eng._prefill_jit, 3 * 16 + 1 + rows, ()

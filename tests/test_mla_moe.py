@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from benchmark.families import mla_moe as family
 from benchmark.reference import mla_moe_ref as ref
-from dist_keras_tpu.models import mla_moe, transformer
+from dist_keras_tpu.models import blocks, mla_moe, transformer
 from dist_keras_tpu.models.layers import select_top_k
 from dist_keras_tpu.models.transformer import Transformer, transformer_config
 from dist_keras_tpu.observability import metrics
@@ -129,7 +129,7 @@ def test_steps_over_the_pool_equal_the_reference_at_every_position(highest):
     prompts = [6, 9]
     ps, n_pages = 4, 12
     pool = jnp.zeros((cfg["n_layers"], n_pages + 1, ps)
-                     + mla_moe.cache_entry_shapes(cfg)[0])
+                     + mla_moe.cache_pools(cfg)[0][2])
     pages = [[7, 2, 9, 4, 0], [5, 11, 1, 8, 3]]       # scratch page is 12
     for toks, n, mine in zip(seqs, prompts, pages):
         rung = 16
@@ -156,7 +156,7 @@ def test_steps_over_the_pool_equal_the_reference_at_every_position(highest):
                          n_pages, n_pages], jnp.int32),
             jnp.asarray([at[0] % ps, at[1] % ps, 0, 0], jnp.int32),
             jnp.asarray(lengths))
-        got = mla_moe._logits(params, hs, cfg)
+        got = blocks.logits(params, hs, cfg)
         for slot in (0, 1):
             np.testing.assert_allclose(got[slot], wants[slot][at[slot]],
                                        atol=TOL, rtol=0)
@@ -229,7 +229,7 @@ def test_routing_selects_by_score_plus_bias_and_weighs_by_score(case):
     router = np.zeros((8, 8), np.float32)
     router[0] = [_logit(p) for p in scores]
     x = jnp.zeros((1, 8)).at[0, 0].set(1.0)
-    idx, w = mla_moe.route(_moe_with(router, bias, cfg), x, cfg)
+    idx, w = blocks.route_sigmoid(_moe_with(router, bias, cfg), x, cfg)
     assert sorted(np.asarray(idx[0]).tolist()) == chosen
     s = np.asarray(scores)[np.asarray(idx[0])]
     # weights from s alone (no bias), normalised, times the scaling factor
@@ -251,26 +251,26 @@ def test_every_token_to_one_expert_drops_none(highest):
     moe = {**moe, "router": jnp.zeros_like(moe["router"]),
            "router_bias": jnp.zeros((8,)).at[jnp.asarray([1, 2, 3])].set(1.)}
     x = jax.random.normal(jax.random.PRNGKey(0), (24, cfg["d_model"]))
-    got, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((24,), bool))
+    got, counts = blocks.moe_layer(moe, x, cfg, jnp.ones((24,), bool))
     assert counts.tolist() == [24, 24, 2, 72]
     want = ref.expert_layer(moe, x, conf, (2, 3))
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
     # padding tokens reach no expert and count nowhere
     valid = jnp.arange(24) < 10
-    _, counts = mla_moe.moe_layer(moe, x, cfg, valid)
+    _, counts = blocks.moe_layer(moe, x, cfg, valid)
     assert counts.tolist() == [10, 10, 2, 30]
     # nor does the grouped form know a capacity: the same router over more
     # tokens than the crossover, every pair on two experts, five passes each
     n = N_OVER[0]
     x = jax.random.normal(jax.random.PRNGKey(0), (n, cfg["d_model"]))
-    got, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((n,), bool))
+    got, counts = blocks.moe_layer(moe, x, cfg, jnp.ones((n,), bool))
     assert counts.tolist() == [n, n, 2, 3 * n]
     want = ref.expert_layer(moe, x, conf, (2, 3))
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
 
 
 # -- (4b) the two forms of the held experts' sum ---------------------------
-# a call of more than mla_moe.GROUPED_OVER tokens runs the pairs sorted by
+# a call of more than blocks.GROUPED_OVER tokens runs the pairs sorted by
 # expert, an expert at a time; the dense form of the same call is what the
 # same function gives with the crossover out of reach
 N_OVER = (1280, 2560)
@@ -282,7 +282,7 @@ EXACT, ROUNDED = 1e-6, 2e-2
 
 def _dense_form(monkeypatch, fn, *args, **kw):
     with monkeypatch.context() as m:
-        m.setattr(mla_moe, "GROUPED_OVER", 10 ** 9)
+        m.setattr(blocks, "GROUPED_OVER", 10 ** 9)
         return fn(*args, **kw)
 
 
@@ -311,7 +311,7 @@ def _grouped_case(case, n):
         idx[:, 1] = 3
     valid = (((at < 300) | (at >= 420)) & (at < n - 130)
              if case.startswith("padding") else np.ones(n, bool))
-    experts = mla_moe._swiglu_params(jax.random.PRNGKey(3), 64, 48,
+    experts = blocks.swiglu_params(jax.random.PRNGKey(3), 64, 48,
                                      (len(held),))
     x = jax.random.normal(jax.random.PRNGKey(4), (n, 64))
     w = jnp.asarray(rng.uniform(0.1, 1.0, idx.shape), jnp.float32)
@@ -335,12 +335,12 @@ def test_grouped_form_equals_the_dense_form(monkeypatch, case, n, precision,
     and one without takes none."""
     args = _grouped_case(case, n)
     with jax.default_matmul_precision(precision or "default"):
-        want, want_sizes = _dense_form(monkeypatch, mla_moe.held_experts,
+        want, want_sizes = _dense_form(monkeypatch, blocks.held_experts,
                                        *args)
-        got, sizes = mla_moe.held_experts(*args)
+        got, sizes = blocks.held_experts(*args)
     sizes = np.asarray(sizes)
     assert sizes.tolist() == want_sizes.tolist()
-    tile = mla_moe.GROUP_TILE_ROWS
+    tile = blocks.GROUP_TILE_ROWS
     assert all(size == 0 or size > tile for size in sizes)
     # (all to one: 1,280 and 2,560 pairs, whole passes and none behind)
     assert any(sizes % tile) or case in ("no_pair_held",
@@ -363,10 +363,10 @@ def test_rows_behind_an_experts_pairs_are_selected_away(monkeypatch,
         "padding_in_the_middle_and_at_the_end", N_OVER[0])
     x = jnp.where(valid[:, None], x, jnp.nan)
     args = (experts, x, idx, w, first, valid)
-    want, _ = _dense_form(monkeypatch, mla_moe.held_experts, *args)
-    got, sizes = mla_moe.held_experts(*args)
+    want, _ = _dense_form(monkeypatch, blocks.held_experts, *args)
+    got, sizes = blocks.held_experts(*args)
     # some expert's last pass is not full: rows behind its pairs there are
-    assert any(np.asarray(sizes) % mla_moe.GROUP_TILE_ROWS)
+    assert any(np.asarray(sizes) % blocks.GROUP_TILE_ROWS)
     real = np.asarray(valid)
     assert np.isfinite(np.asarray(got)).all()
     assert np.isfinite(np.asarray(want)[real]).all()
@@ -415,9 +415,9 @@ def test_each_familys_layer_takes_the_grouped_form_over_the_crossover(
     n = N_OVER[0]
     x = jax.random.normal(jax.random.PRNGKey(7), (n, cfg["d_model"]))
     valid = jnp.arange(n) < n - 9
-    want, want_counts = _dense_form(monkeypatch, mla_moe.moe_layer, moe, x,
+    want, want_counts = _dense_form(monkeypatch, blocks.moe_layer, moe, x,
                                     cfg, valid, **kw)
-    got, counts = mla_moe.moe_layer(moe, x, cfg, valid, **kw)
+    got, counts = blocks.moe_layer(moe, x, cfg, valid, **kw)
     assert counts.tolist() == want_counts.tolist()
     assert counts[-1] == (n - 9) * cfg["top_k"]
     top = max(float(jnp.max(jnp.abs(want))), 1.0)
@@ -481,13 +481,13 @@ def test_shares_add_up_to_the_uncut_layer(highest, per_share):
     x = jax.random.normal(jax.random.PRNGKey(9), (20, whole["d_model"]))
     valid = jnp.ones((20,), bool)
     uncut = ref.expert_layer(moe, x, conf, tuple(range(8)))
-    shared = mla_moe.swiglu(moe["shared"], x)
+    shared = blocks.swiglu(moe["shared"], x)
     total, pairs = jnp.zeros_like(uncut), 0
     for first in range(0, 8, per_share):
         held = tuple(range(first, first + per_share))
         mine = {**moe, "experts": jax.tree.map(
             lambda leaf: leaf[first:first + per_share], moe["experts"])}
-        part, counts = mla_moe.moe_layer(mine, x, config(held=held), valid)
+        part, counts = blocks.moe_layer(mine, x, config(held=held), valid)
         # the program's share is the reference's share
         np.testing.assert_allclose(
             part, ref.expert_layer(mine, x, conf, held), atol=TOL, rtol=0)
@@ -502,7 +502,8 @@ def test_pool_shape_is_one_latent_pool_and_the_old_family_is_unchanged():
     cfg = config()
     width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
     lanes = -(-width // mla_moe.LANES) * mla_moe.LANES
-    assert mla_moe.cache_entry_shapes(cfg) == ((lanes,),)
+    assert mla_moe.cache_pools(cfg) == (
+        (cfg["n_layers"], "page", (lanes,)),)
     with engine_for(cfg, weights_for(cfg), num_pages=20) as eng:
         assert eng.pool_shapes == ((cfg["n_layers"], 21, 4, lanes),)
         (pool,) = eng._replicas[0].pools
@@ -600,7 +601,7 @@ def test_a_prefill_stamps_its_form_and_its_tile_fill(monkeypatch, highest):
 
     for name in ("decode.moe.prefill_grouped", "decode.moe.tile_fill_pct"):
         assert metrics.KNOWN_METRICS[name] == "histogram"
-    rung, tile = N_OVER[0] + 8, mla_moe.GROUP_TILE_ROWS
+    rung, tile = N_OVER[0] + 8, blocks.GROUP_TILE_ROWS
     # ONE expert layer, so that the counts are that layer's own
     cfg = config(seq_len=rung + 8, n_layers=2)
     params = weights_for(cfg)
@@ -635,7 +636,7 @@ def test_a_prefill_stamps_its_form_and_its_tile_fill(monkeypatch, highest):
             jnp.zeros((rung,), jnp.int32))
     out = np.asarray(step(*args)[0])
     first, counts = out[0], out[1:]
-    assert counts.shape == (3 + mla_moe.N_COUNTS + 1,)
+    assert counts.shape == (3 + blocks.N_COUNTS + 1,)
     sizes, covered = counts[:3].astype(np.int64), -1 - int(counts[-1])
     assert covered == tile * (-(-sizes // tile)).sum() > sizes.sum() > 0
     assert fill[0][1] == 100.0 * sizes.sum() / covered
@@ -694,15 +695,15 @@ def test_only_a_long_prefills_counts_grow(module):
                              params, *pools, *args)[0]
         return out.shape[0] - (1 if width == 1 else 0)
 
-    assert prefill_width(mla_moe.GROUPED_OVER) == held + mla_moe.N_COUNTS
-    assert prefill_width(rows) == held + mla_moe.N_COUNTS + 1
+    assert prefill_width(blocks.GROUPED_OVER) == held + blocks.N_COUNTS
+    assert prefill_width(rows) == held + blocks.N_COUNTS + 1
     if module != "mla_moe":
         return
     step, _ = jax.eval_shape(
         functools.partial(mla_moe.decode_step, cfg), params, *pools,
         ints(rows), ints(rows), ints(rows, 2), ints(rows), ints(rows),
         ints(rows))
-    assert step.shape == (rows + held + mla_moe.N_COUNTS,)
+    assert step.shape == (rows + held + blocks.N_COUNTS,)
 
 
 def test_walked_positions_are_stamped_once_a_step_with_the_block_arithmetic():
@@ -725,7 +726,7 @@ def test_walked_positions_are_stamped_once_a_step_with_the_block_arithmetic():
     # known lengths: a padding slot, one position, a block to the last
     # position, one past it, and three blocks and a bit
     lengths = np.asarray([0, 1, block, block + 1, 3 * block + 7], np.int32)
-    counts = np.zeros((3 + mla_moe.N_COUNTS,), np.int32)
+    counts = np.zeros((3 + blocks.N_COUNTS,), np.int32)
     at = time.perf_counter()
     mla_moe.observe_step(counts, at, lengths=lengths, page_size=page)
     assert walked_h.samples_between(at, at + 1e-6)[0] == [
@@ -771,7 +772,7 @@ def test_held_share_reads_an_eighth_on_uniform_routing():
     moe = {**moe, "router_bias": jnp.zeros((16,)),
            "router": router / jnp.linalg.norm(router, axis=0)}
     x = jax.random.normal(jax.random.PRNGKey(2), (4096, cfg["d_model"]))
-    _, counts = mla_moe.moe_layer(moe, x, cfg, jnp.ones((4096,), bool))
+    _, counts = blocks.moe_layer(moe, x, cfg, jnp.ones((4096,), bool))
     before = [metrics.counter(n).value for n in PAIRS]
     mla_moe.observe_step(counts, at=0.0)
     total, held = (metrics.counter(n).value - b

@@ -25,12 +25,13 @@ from benchmark import weights
 from benchmark.families import olmo_hybrid as family
 from benchmark.reference import olmo_hybrid_ref as ref
 from dist_keras_tpu.models import olmo_hybrid
+from dist_keras_tpu.models.families import FAMILIES
 from dist_keras_tpu.observability import metrics
 from dist_keras_tpu.ops import gated_delta
 from dist_keras_tpu.ops.pallas import gated_delta as pallas_gated_delta
 from dist_keras_tpu.resilience import faults
 from dist_keras_tpu.resilience.faults import FaultInjected
-from dist_keras_tpu.serving.decode import DecodeEngine, _FAMILIES
+from dist_keras_tpu.serving.decode import DecodeEngine
 from dist_keras_tpu.serving.engine import Overloaded
 from dist_keras_tpu.utils.serialization import (
     deserialize_model,
@@ -232,7 +233,7 @@ def test_serialization_round_trip_holds_no_second_set_of_weights():
     for a, b in zip(jax.tree.leaves(model.params),
                     jax.tree.leaves(back.params)):
         np.testing.assert_array_equal(a, b)
-    assert _FAMILIES["olmo_hybrid"] is olmo_hybrid and len(_FAMILIES) == 6
+    assert FAMILIES["olmo_hybrid"] is olmo_hybrid and len(FAMILIES) == 6
 
 
 @pytest.mark.parametrize("bad,match", [

@@ -28,10 +28,11 @@ from benchmark.families import ouro as family
 from benchmark.reference import ouro_ref as ref
 from benchmark.reference.transformer_ref import Precision
 from dist_keras_tpu.models import ouro
+from dist_keras_tpu.models.families import FAMILIES
 from dist_keras_tpu.observability import metrics
 from dist_keras_tpu.resilience import faults
 from dist_keras_tpu.resilience.faults import FaultInjected
-from dist_keras_tpu.serving.decode import DecodeEngine, _FAMILIES
+from dist_keras_tpu.serving.decode import DecodeEngine
 from dist_keras_tpu.serving.engine import Overloaded
 from dist_keras_tpu.utils.serialization import (
     deserialize_model,
@@ -289,9 +290,9 @@ def test_serialization_round_trip_holds_no_second_set_of_weights():
     for a, b in zip(jax.tree.leaves(model.params),
                     jax.tree.leaves(back.params)):
         np.testing.assert_array_equal(a, b)
-    assert _FAMILIES["ouro"] is ouro and len(_FAMILIES) == 6
+    assert FAMILIES["ouro"] is ouro and len(FAMILIES) == 6
     # a cfg that names no family is still a Transformer's
-    assert _FAMILIES["transformer"].FAMILY == "transformer"
+    assert FAMILIES["transformer"].FAMILY == "transformer"
 
 
 @pytest.mark.parametrize("bad,match", [

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from benchmark import weights
 from benchmark.families import sdar_moe as family
 from benchmark.reference import sdar_moe_ref as ref
-from dist_keras_tpu.models import mla_moe, sdar_moe
+from dist_keras_tpu.models import blocks, mla_moe, sdar_moe
 from dist_keras_tpu.observability import metrics
 from dist_keras_tpu.serving import DecodeEngine
 from dist_keras_tpu.serving.decode import _step_views
@@ -425,7 +425,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(highest):
         held = list(range(first, first + 4))
         share = {"router": moe["router"], "experts": jax.tree.map(
             lambda leaf: leaf[first:first + 4], moe["experts"])}
-        part, counts = mla_moe.moe_layer(
+        part, counts = blocks.moe_layer(
             share, x, config(held_experts=held), jnp.ones((24,), bool),
             router=sdar_moe.route)
         np.testing.assert_allclose(
